@@ -1254,6 +1254,24 @@ class VolumeServer:
         self.heartbeat_once()
         return {"size": os.path.getsize(v.base_path + ".dat")}
 
+    def ec_backend_status(self) -> dict:
+        """The encoder factory's selection audit for THIS process — the
+        backend it runs and the device jax reported to it. HTTP /status
+        carries it whole; shell tools read it there instead of building
+        an encoder (and touching a device) of their own."""
+        return dict(self.store.encoder.selection)
+
+    def _ec_backend_wire(self) -> dict:
+        sel = self.store.encoder.selection
+        out = {
+            k: str(sel[k])
+            for k in ("backend", "source", "reason", "requested")
+            if sel.get(k) is not None
+        }
+        if sel.get("device"):
+            out["device"] = dict(sel["device"])
+        return out
+
     def _rpc_volume_status(self, req: dict, ctx) -> dict:
         vid = int(req["volume_id"])
         v = self.store.get_volume(vid)
@@ -1266,6 +1284,7 @@ class VolumeServer:
                 "read_only": v.read_only,
                 "rack": self.rack,
                 "data_center": self.data_center,
+                "ec_backend": self._ec_backend_wire(),
             }
         ev = self.store.get_ec_volume(vid)
         if ev is not None:
@@ -1308,6 +1327,7 @@ class VolumeServer:
                 # audits read the holder's rack/zone straight off status
                 "rack": self.rack,
                 "data_center": self.data_center,
+                "ec_backend": self._ec_backend_wire(),
             }
         raise rpc.NotFoundFault(f"volume {vid} not found")
 
@@ -2898,6 +2918,7 @@ class _Handler(http.server.BaseHTTPRequestHandler):
                 {
                     "volumes": self.vs.store.volume_infos(),
                     "ec_volumes": [i.to_dict() for i in self.vs.store.ec_volume_infos()],
+                    "ec_backend": self.vs.ec_backend_status(),
                 },
                 head=head,
             )

@@ -59,6 +59,16 @@ def _load_guard():
     )
 
 
+def _build_native() -> None:
+    """Start-up of a process that owns a Store: build libweedtpu.so (it is
+    not in git) once, before any thread needs it. A failure is an error
+    with the compiler's output — never a quiet switch to the per-byte
+    Python CRC32C over gigabyte volumes."""
+    from seaweedfs_tpu.utils import native
+
+    native.build()
+
+
 def _maybe_metrics(port: int):
     if port:
         from seaweedfs_tpu.stats import start_metrics_server
@@ -133,6 +143,7 @@ def _volume_conf(p: argparse.ArgumentParser) -> None:
 def _volume_run(args: argparse.Namespace) -> int:
     from seaweedfs_tpu.cluster.volume_server import VolumeServer
 
+    _build_native()
     vs = VolumeServer(
         args.dir or ["./data"],
         args.mserver,
@@ -182,6 +193,7 @@ def _server_run(args: argparse.Namespace) -> int:
     from seaweedfs_tpu.cluster.master import MasterServer
     from seaweedfs_tpu.cluster.volume_server import VolumeServer
 
+    _build_native()
     m = MasterServer(
         port=args.masterPort,
         host=args.ip,

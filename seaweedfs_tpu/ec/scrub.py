@@ -46,7 +46,7 @@ from seaweedfs_tpu.ec import stripe
 from seaweedfs_tpu.obs import trace as trace_mod
 
 
-#: finding classes — the detection taxonomy the counters/quarantine use
+#: finding classes — the detection classes the counters/quarantine use
 OK = "ok"
 CORRUPT = "corrupt"          # bytes present, CRC32 disagrees with .eci
 TRUNCATED = "truncated"      # file shorter than the stripe geometry demands

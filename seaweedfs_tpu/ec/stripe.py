@@ -66,7 +66,7 @@ from seaweedfs_tpu.utils import config
 #: inflight depth of the streaming encode/rebuild pipelines: how many
 #: batches may be in the read->device->write pipe at once. 1 restores the
 #: pre-r6 behavior (one batch overlapped), 2 = double buffering, 3 = triple.
-#: Deeper pipelines hide longer device/tunnel latencies at the cost of
+#: Deeper pipelines hide longer device latencies at the cost of
 #: (depth+1) staging buffers of `max_batch_bytes` each.
 DEFAULT_PIPELINE_DEPTH = config.env("WEEDTPU_PIPELINE_DEPTH")
 
@@ -448,6 +448,7 @@ def encoder_for_info(
             backend=default.backend,
             pallas_mxu=default.pallas_mxu,
             pallas_tile=default.pallas_tile,
+            pallas_interpret=default.pallas_interpret,
             mesh_shape=default.mesh_shape,
             mesh_rebuild=default.mesh_rebuild,
         )

@@ -24,6 +24,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from seaweedfs_tpu.ops import gf8
+from seaweedfs_tpu.utils.devices import setup_compile_cache
+
+setup_compile_cache()
 
 
 def bytes_to_bits(x: jax.Array) -> jax.Array:
@@ -76,8 +79,7 @@ def gf_apply(b_bits: jax.Array, data: jax.Array) -> jax.Array:
 # requires matching shape+dtype), so this is NOT output aliasing — it is a
 # deterministic early-release hint: the batch's input HBM is freed as soon
 # as the dispatch consumes it rather than when host-side references die,
-# bounding a depth-N pipeline's inflight footprint. Whether that moves the
-# steady number is one of the device-window hypotheses to measure. Only
+# bounding a depth-N pipeline's inflight footprint. Only
 # selected off-CPU — XLA CPU ignores donation and warns.
 _gf_apply_donated = jax.jit(_gf_apply_impl, donate_argnums=(1,))
 
@@ -86,10 +88,7 @@ _gf_apply_donated = jax.jit(_gf_apply_impl, donate_argnums=(1,))
 def donation_supported() -> bool:
     """Buffer donation is a no-op (plus a warning per dispatch) on the XLA
     CPU backend; only the accelerator paths should request it."""
-    try:
-        return jax.devices()[0].platform != "cpu"
-    except Exception:  # noqa: BLE001 — no backend: no donation either
-        return False
+    return jax.devices()[0].platform != "cpu"
 
 
 @functools.lru_cache(maxsize=256)

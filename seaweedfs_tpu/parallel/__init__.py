@@ -1,13 +1,10 @@
 """Multi-chip parallel paths (dp x sp shard_map encode/rebuild, ring
-rebuild). `shard_map` is resolved here once: newer jax exports it as
-`jax.shard_map`; this image's 0.4.x only has the experimental module —
-without the fallback every sharded path dies at trace time on
-`AttributeError: jax.shard_map` (the whole test_parallel suite failed at
-the seed for exactly this)."""
+rebuild)."""
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # jax < 0.6 spelling
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+from seaweedfs_tpu.utils.devices import setup_compile_cache
+
+setup_compile_cache()
+
+shard_map = jax.shard_map

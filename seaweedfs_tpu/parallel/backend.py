@@ -150,6 +150,8 @@ class MeshDispatch:
         self._apply_fns: dict = {}
         self._rebuild_fns: dict = {}
         self._lock = threading.Lock()
+        #: distinct devices the last dispatched batch lay on (_check_spread)
+        self.last_spread = 0
         try:
             from seaweedfs_tpu import stats
 
@@ -159,6 +161,17 @@ class MeshDispatch:
 
     def shape_str(self) -> str:
         return f"{self.dp}x{self.sp}"
+
+    def _check_spread(self, x) -> None:
+        """A sharded batch really lies on every device of the mesh: a
+        placement that quietly put everything on device 0 would still be
+        byte-correct, and would never have been a mesh."""
+        self.last_spread = len({s.device for s in x.addressable_shards})
+        if self.last_spread != self.n_devices:
+            raise RuntimeError(
+                f"mesh {self.shape_str()} batch lies on {self.last_spread} "
+                f"devices, want {self.n_devices}"
+            )
 
     # -- cached compiled functions -------------------------------------------
 
@@ -240,6 +253,7 @@ class MeshDispatch:
             flat = shards
         padded, w = self._pad_cols(flat, self.width_align)
         x = jax.device_put(padded, self._col_sharding)
+        self._check_spread(x)
         out = self._apply_fn(m)(x)
         r = m.shape[0]
         if batched:
@@ -279,6 +293,7 @@ class MeshDispatch:
         # [k*wd, (k+1)*wd) of every survivor — a pure column partition
         surv = padded.reshape(s, self.dp, wd).transpose(1, 0, 2)
         out = self._rebuild_fn(recon_m)(surv)  # (dp, L, wd) device, async
+        self._check_spread(out)
         rows = recon_m.shape[0]
 
         if batched:

@@ -76,10 +76,7 @@ def make_ring_rebuild_fn(mesh: Mesh, recon_m: np.ndarray, donate: bool = False):
         # the loop carry varies per device (each chip accumulates its own
         # tile) — mark the unvarying zeros init accordingly or the scan
         # carry types mismatch under shard_map's varying-axes checks
-        if hasattr(jax.lax, "pcast"):  # jax>=0.9 spelling
-            acc0 = jax.lax.pcast(acc0, ("dp", "sp"), to="varying")
-        elif hasattr(jax.lax, "pvary"):  # deprecated predecessor
-            acc0 = jax.lax.pvary(acc0, ("dp", "sp"))
+        acc0 = jax.lax.pcast(acc0, ("dp", "sp"), to="varying")
 
         def body(k, carry):
             block, acc = carry
@@ -103,4 +100,5 @@ def make_ring_rebuild_fn(mesh: Mesh, recon_m: np.ndarray, donate: bool = False):
     def run(survivors: np.ndarray) -> jax.Array:
         return rebuild(place_survivors(mesh, survivors, n_surv, s_pad))
 
+    run.jitted = rebuild  # what compile tests lower for a described mesh
     return run

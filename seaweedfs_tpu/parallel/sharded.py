@@ -240,9 +240,16 @@ def make_distributed_rebuild_fn(mesh: Mesh, recon_m: np.ndarray, donate: bool = 
     def _rebuild(survivors):
         # local view: (B/dp, s_pad/sp, N) whole-shard rows ->
         # (B/dp, s_pad, N/sp) full survivor set for this chip's byte tile
+        # The byte axis is split as an axis of its own, (.., sp, N/sp), so
+        # the collective never slices the minor (lane) dimension: splitting
+        # axis 2 of the (B, S, N) block directly compiles for the v5e in
+        # time LINEAR in N (~10 min at the rebuild pipeline's 4 MiB width).
+        b, s_local, n = survivors.shape
+        sp = mesh.shape["sp"]
         regrouped = jax.lax.all_to_all(
-            survivors, "sp", split_axis=2, concat_axis=1, tiled=True
-        )
+            survivors.reshape(b, s_local, sp, n // sp),
+            "sp", split_axis=2, concat_axis=1, tiled=True,
+        ).reshape(b, s_local * sp, n // sp)
         return rs_jax.gf_apply(b_rec, regrouped)
 
     donate_argnums = (0,) if donate else ()
@@ -251,4 +258,5 @@ def make_distributed_rebuild_fn(mesh: Mesh, recon_m: np.ndarray, donate: bool = 
     def run(survivors: np.ndarray) -> jax.Array:
         return rebuild(place_survivors(mesh, survivors, n_surv, s_pad))
 
+    run.jitted = rebuild  # what compile tests lower for a described mesh
     return run

@@ -1322,11 +1322,10 @@ def do_ec_status(args: list[str], env: CommandEnv, w: TextIO) -> None:
         )
         rebuilds_done = int(_metric_sum(rows, "weedtpu_ec_rebuild_seconds_count"))
         converts_done = int(_metric_sum(rows, "weedtpu_ec_convert_seconds_count"))
-        backends = sorted(
-            f"{labels.get('backend')}({labels.get('source')})"
-            for name, labels, v in rows
-            if name == "weedtpu_ec_backend_selected" and v == 1.0
-        )
+        try:
+            backend = _backend_brief(_server_status(url).get("ec_backend") or {})
+        except Exception:  # noqa: BLE001 — metrics answered, status did not
+            backend = "?"
         # xorsched schedule-cache state (only exported once the server has
         # dispatched through the xorsched path at least once)
         xs_hits = int(_metric_sum(rows, "weedtpu_xorsched_schedule_cache", event="hits"))
@@ -1362,7 +1361,7 @@ def do_ec_status(args: list[str], env: CommandEnv, w: TextIO) -> None:
             f"convert={convert_inflight}inflight/{converts_done}done "
             f"cache={cache_hits}hit/{cache_misses}miss({cache_rate}) "
             f"{cache_mb:.1f}MB evict={cache_evict} inval={cache_inval} "
-            f"backend={','.join(backends) or '?'}{xs}\n"
+            f"backend={backend}{xs}\n"
         )
 
 
@@ -1383,52 +1382,61 @@ register(
 # -- ec.backend --------------------------------------------------------------
 
 
+def _server_status(url: str) -> dict:
+    """One volume server's HTTP /status document."""
+    return _fetch_json(f"http://{url}/status", timeout=5.0)
+
+
+def _backend_brief(sel: dict) -> str:
+    """`backend(source)[@platform xN]` — the ec.status column."""
+    dev = sel.get("device") or {}
+    on = f"@{dev.get('platform')}x{dev.get('count')}" if dev else ""
+    return f"{sel.get('backend', '?')}({sel.get('source', '?')}){on}"
+
+
 def do_ec_backend(args: list[str], env: CommandEnv, w: TextIO) -> None:
-    """Operator view of the encoder factory's selection audit: which codec
-    backend `new_encoder("auto")` picks HERE and why — the evidence file/
-    round behind a fused-kernel or mesh promotion, the mesh shape and
-    rebuild variant when the pod path is selected, and the reason string
-    when conservative defaults hold. Read-only; no cluster lock."""
+    """Operator view of each volume server's encoder selection audit, read
+    from the servers' /status: which codec backend the server's
+    `new_encoder("auto")` picked and why, and the device jax reported to
+    it. The shell builds no encoder of its own: the process that owns the
+    chip is the only one that can say what it runs on, and a tool that
+    asked jax would take the chip from it. Read-only; no cluster lock."""
     parse_flags(args)
-    from seaweedfs_tpu.ops.rs_codec import new_encoder
-
-    enc = new_encoder()
-    sel = dict(enc.selection)
-    sel.pop("mesh", None)  # the nested decision dict is too noisy for a shell line
-    w.write(
-        "ec.backend: "
-        + " ".join(f"{k}={sel[k]}" for k in sorted(sel) if sel[k] is not None)
-        + "\n"
-    )
-    mesh_dec = enc.selection.get("mesh")
-    if isinstance(mesh_dec, dict) and enc.backend != "mesh":
+    nodes = env.topology_nodes()
+    if not nodes:
+        raise ShellError("no volume servers")
+    for n in sorted(nodes, key=lambda n: n["url"]):
+        url = n["url"]
+        try:
+            sel = dict(_server_status(url).get("ec_backend") or {})
+        except Exception as e:  # noqa: BLE001 — node HTTP down
+            w.write(f"ec.backend: {url}: UNREACHABLE ({e})\n")
+            continue
+        mesh_dec = sel.pop("mesh", None)  # nested decision: own line below
+        dev = sel.pop("device", None) or {}
+        if dev:
+            sel["device"] = (
+                f"{dev.get('platform')}:{dev.get('kind')}x{dev.get('count')}"
+            )
         w.write(
-            f"ec.backend: mesh not promoted: {mesh_dec.get('reason', 'n/a')}\n"
+            f"ec.backend: {url}: "
+            + " ".join(f"{k}={sel[k]}" for k in sorted(sel) if sel[k] is not None)
+            + "\n"
         )
-    if enc.backend in ("numpy", "native", "xorsched"):
-        # CPU-floor audit: which of the three host paths serves, the BENCH
-        # evidence round behind an xorsched promotion (- when defaults
-        # held), the SIMD level the xor executor would run at, and the
-        # compiled-schedule LRU state of THIS process
-        from seaweedfs_tpu.ops import xorsched
-
-        ci = xorsched.schedule_cache_info()
-        w.write(
-            "ec.backend: cpu floor: "
-            f"path={enc.backend} "
-            f"evidence_round={enc.selection.get('evidence_round', '-')} "
-            f"xor_simd={xorsched.native_level()} "
-            f"sched_cache={ci['hits']}hit/{ci['misses']}miss "
-            f"size={ci['size']}/{ci['cap']} evict={ci['evictions']}\n"
-        )
+        if isinstance(mesh_dec, dict) and sel.get("backend") != "mesh":
+            w.write(
+                f"ec.backend: {url}: mesh not promoted: "
+                f"{mesh_dec.get('reason', 'n/a')}\n"
+            )
 
 
 register(
     ShellCommand(
         "ec.backend",
-        "ec.backend\n\treport the encoder factory's backend selection audit "
-        "(evidence file,\n\tmesh shape/evidence round when the pod path is "
-        "promoted, and the reason\n\ta conservative default held otherwise)",
+        "ec.backend\n\treport each volume server's backend selection audit, "
+        "read from its\n\t/status (backend, device, evidence file, mesh "
+        "shape/evidence round when\n\tthe pod path is promoted, and the "
+        "reason a conservative default held)",
         do_ec_backend,
     )
 )

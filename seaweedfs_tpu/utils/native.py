@@ -1,6 +1,14 @@
 """ctypes binding to libweedtpu.so (native/weedtpu.cc) — the C++ runtime
-kernels (CRC32C, AVX2 GF(2^8) baseline). Builds the library on first use if
-the toolchain is present; everything degrades to pure-Python fallbacks.
+kernels (CRC32C, AVX2 GF(2^8) baseline, the xorsched executor).
+
+The library is generated output and is not in git. `build()` is the one
+explicit build step (`make -C native`, which compiles to a temporary name
+and renames, so no process ever maps a half-written file); server start-up,
+`chip_smoke.py` and the tests' conftest call it once, before any thread or
+child needs the library, and a failure there is an error that carries the
+compiler's output. `load()` never builds: it maps what is there, and a
+caller that finds nothing (`None`) is a tool or a library user running on
+the pure-Python fallbacks by its own choice of not building.
 """
 
 from __future__ import annotations
@@ -19,42 +27,42 @@ _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
 
-def _build() -> bool:
+class NativeBuildError(RuntimeError):
+    """`make -C native` failed, or what it built cannot be loaded."""
+
+
+def build() -> str:
+    """Build libweedtpu.so from native/weedtpu.cc if it is missing or older
+    than its source (make's own rule), atomically, then load it. Returns
+    the library's path; raises NativeBuildError with make's output."""
+    global _load_failed
     try:
-        subprocess.run(
+        proc = subprocess.run(
             ["make", "-C", _NATIVE_DIR],
-            check=True,
             capture_output=True,
-            timeout=120,
+            text=True,
+            timeout=300,
         )
-        return os.path.exists(_LIB_PATH)
-    except Exception:
-        return False
-
-
-def _stale() -> bool:
-    """True when the .so is missing or older than its source."""
-    try:
-        so_mtime = os.path.getmtime(_LIB_PATH)
-    except OSError:
-        return True
-    try:
-        src_mtime = os.path.getmtime(os.path.join(_NATIVE_DIR, "weedtpu.cc"))
-    except OSError:
-        return False
-    return src_mtime > so_mtime
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise NativeBuildError(f"make -C {_NATIVE_DIR}: {e}") from e
+    if proc.returncode != 0 or not os.path.exists(_LIB_PATH):
+        raise NativeBuildError(
+            f"make -C {_NATIVE_DIR} exited {proc.returncode}:\n"
+            f"{proc.stdout}{proc.stderr}"
+        )
+    with _lock:
+        _load_failed = False  # a load that failed before the build may retry
+    if load() is None:
+        raise NativeBuildError(f"{_LIB_PATH} was built but does not load")
+    return _LIB_PATH
 
 
 def load() -> Optional[ctypes.CDLL]:
-    """The loaded library, (re)building it when missing or out of date;
-    None if unavailable."""
+    """The loaded library; None if it has not been built or cannot load."""
     global _lib, _load_failed
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        if _stale() and not _build() and not os.path.exists(_LIB_PATH):
-            _load_failed = True
-            return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
             lib.weedtpu_crc32c.restype = ctypes.c_uint32
@@ -67,8 +75,8 @@ def load() -> Optional[ctypes.CDLL]:
             lib.weedtpu_gf_matrix_apply.restype = None
             _lib = lib
         except (OSError, AttributeError):
-            # OSError: unloadable .so; AttributeError: a stale binary
-            # missing expected symbols. Either way fall back to Python.
+            # OSError: no such file / unloadable .so; AttributeError: a
+            # binary missing expected symbols
             _load_failed = True
         return _lib
 

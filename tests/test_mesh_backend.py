@@ -320,15 +320,15 @@ def test_mesh_evidence_newest_round_wins(tmp_path):
     assert not ok and dec["evidence_file"] == "MULTICHIP_r91.json"
 
 
-def test_committed_multichip_r06_never_promotes_on_this_box():
-    """The artifact this PR commits is a CPU host-device run: the
-    evidence rule must refuse it (platform gate), so `auto` on a future
-    8-device host cannot silently flip to mesh without on-chip numbers."""
-    ev = rs_codec.load_mesh_evidence()
-    assert ev is not None and ev["_file"] >= "MULTICHIP_r06.json"
-    if ev["_file"] == "MULTICHIP_r06.json":
-        ok, dec = rs_codec.pick_mesh_backend(8)
-        assert not ok
+def test_cpu_platform_multichip_record_never_promotes(tmp_path):
+    """A record of a CPU host-device run — what `BENCH_MODE=mesh` writes
+    off the chip — must be refused by the evidence rule (platform gate), so
+    `auto` on an 8-device host cannot flip to mesh without on-chip numbers."""
+    _write_multichip(tmp_path, _evidence(platform="cpu (cpu)"), name="MULTICHIP_r06.json")
+    ev = rs_codec.load_mesh_evidence(str(tmp_path))
+    assert ev is not None and ev["_file"] == "MULTICHIP_r06.json"
+    ok, dec = rs_codec.pick_mesh_backend(8, art_dir=str(tmp_path))
+    assert not ok and "not an on-chip measurement" in dec["reason"]
 
 
 def test_new_encoder_auto_promotes_to_mesh_on_evidence(tmp_path, monkeypatch):
@@ -372,14 +372,29 @@ def test_new_encoder_auto_keeps_backend_without_mesh_evidence(tmp_path, monkeypa
 # -- shell audit command ------------------------------------------------------
 
 
-def test_ec_backend_shell_command_reports_selection():
-    from seaweedfs_tpu.shell import commands
+def test_ec_backend_shell_command_reports_selection(tmp_path):
+    """`ec.backend` reads each volume server's selection from its /status —
+    the shell builds no encoder of its own."""
+    from seaweedfs_tpu.cluster.master import MasterServer
+    from seaweedfs_tpu.cluster.volume_server import VolumeServer
+    from seaweedfs_tpu.shell import CommandEnv, commands
 
-    buf = io.StringIO()
-    commands()["ec.backend"].do([], None, buf)
+    master = MasterServer(port=0, reap_interval=3600)
+    master.start()
+    vs = VolumeServer([str(tmp_path)], master.address, heartbeat_interval=0.3)
+    vs.start()
+    try:
+        with CommandEnv(master.address) as env:
+            buf = io.StringIO()
+            commands()["ec.backend"].do([], env, buf)
+    finally:
+        vs.stop()
+        master.stop()
     out = buf.getvalue()
-    assert out.startswith("ec.backend: ")
-    assert "backend=" in out and "source=" in out
+    assert out.startswith(f"ec.backend: {vs.url}: ")
+    sel = vs.store.encoder.selection
+    assert f"backend={sel['backend']}" in out and f"source={sel['source']}" in out
+    assert "device=cpu:cpu" in out  # what jax reported to the server
 
 
 # -- BENCH_MODE=mesh smoke (tier-1) -------------------------------------------
@@ -388,8 +403,7 @@ def test_ec_backend_shell_command_reports_selection():
 def test_bench_mesh_smoke_schema_and_byte_verify(tmp_path):
     """Scaled-down run of bench.py's mesh harness on the forced 8-device
     CPU mesh: per-shape encode + both rebuild variants measured, every
-    shape byte-verified, artifact body round-trips through
-    device_window's MULTICHIP assembler."""
+    shape byte-verified."""
     import sys
 
     sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
@@ -412,13 +426,6 @@ def test_bench_mesh_smoke_schema_and_byte_verify(tmp_path):
     for key in ("encode_gbps", "rebuild_ring_gbps", "rebuild_alltoall_gbps"):
         assert rec[key] > 0
     assert out["single_device"]["encode_gbps"] > 0
-    # assembler round-trip: this is exactly what a device window commits
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "scripts"))
-    import device_window
-
-    meas = device_window.assemble_multichip(out)
-    assert meas["shapes"] == out["shapes"] and meas["round"] == 6
 
 
 # -- ingest persistent staging ring (ROADMAP follow-up 1) ---------------------

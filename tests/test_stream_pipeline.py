@@ -44,7 +44,7 @@ TILE_EDGE_SIZES = [
 def test_encode_batch_tile_edges_match_golden(backend, n):
     rng = np.random.default_rng(n)
     data = rng.integers(0, 256, size=(2, 10, n), dtype=np.uint8)
-    enc = Encoder(10, 4, backend=backend)
+    enc = Encoder(10, 4, backend=backend, pallas_interpret=True)
     got = enc.encode_batch(data)
     pm = gf8.parity_matrix(10, 4)
     for b in range(2):
@@ -62,7 +62,7 @@ def test_reconstruct_batch_tile_edges_match_golden(backend, n):
     lost = [0, 5, 11, 13]
     survivors = [i for i in range(14) if i not in lost][:10]
     stack = np.stack([full[s] for s in survivors])[None]
-    enc = Encoder(10, 4, backend=backend)
+    enc = Encoder(10, 4, backend=backend, pallas_interpret=True)
     out = enc.reconstruct_batch(stack, survivors, lost)
     for k, w in enumerate(lost):
         np.testing.assert_array_equal(out[0, k], full[w], err_msg=f"n={n} shard {w}")
@@ -70,7 +70,7 @@ def test_reconstruct_batch_tile_edges_match_golden(backend, n):
 
 @pytest.mark.parametrize("backend", ["numpy", "jax", "pallas"])
 def test_encode_empty_width(backend):
-    enc = Encoder(10, 4, backend=backend)
+    enc = Encoder(10, 4, backend=backend, pallas_interpret=True)
     out = enc.encode_batch(np.zeros((1, 10, 0), dtype=np.uint8))
     assert out.shape == (1, 14, 0)
 
