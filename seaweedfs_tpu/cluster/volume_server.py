@@ -1520,9 +1520,13 @@ class VolumeServer:
                     # included, so journaled state from before a restart
                     # (no live builder) is scrubbed from disk too
                     self._ingest.discard(vid, v.base_path)
-                stripe.write_ec_files(
-                    v.base_path, encoder=self.store.encoder, **kwargs
-                )
+                # the run span is write_ec_files' own (it adds bytes and
+                # batches); opened here so that it carries the volume id
+                with trace_mod.ensure("encode.run", klass="maint"):
+                    trace_mod.annotate(volume=vid)
+                    stripe.write_ec_files(
+                        v.base_path, encoder=self.store.encoder, **kwargs
+                    )
             stripe.write_sorted_file_from_idx(v.base_path)
         stats.EcEncodeSeconds.observe(time.monotonic() - t0)
         stats.EcEncodeBytes.inc(os.path.getsize(v.base_path + ".dat"))

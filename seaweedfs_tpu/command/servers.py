@@ -69,6 +69,31 @@ def _build_native() -> None:
     native.build()
 
 
+def _mirror_spans_to_profiler(vs) -> None:
+    """Start-up of a chip-owning server: put the program's spans on the
+    device trace's clock. Every recorded span (obs/trace.py) also opens a
+    `jax.profiler.TraceAnnotation`, so a profiler session on this process
+    holds `rpc.server`, `encode.*`, `rebuild.*`, ... on its host threads'
+    lines beside the device's operations; outside a session the annotation
+    is the profiler's own no-op. Only where the store's codec runs on a
+    device backend, which has imported jax already: a CPU server and the
+    shell never import it for this."""
+    from seaweedfs_tpu.ops.rs_codec import DEVICE_BACKENDS
+
+    if vs.store.encoder.backend not in DEVICE_BACKENDS:
+        return
+    import jax
+
+    from seaweedfs_tpu.obs import trace
+
+    def open_annotation(name, attrs):
+        annotation = jax.profiler.TraceAnnotation(name, **(attrs or {}))
+        annotation.__enter__()
+        return annotation
+
+    trace.set_mirror(open_annotation)
+
+
 def _maybe_metrics(port: int):
     if port:
         from seaweedfs_tpu.stats import start_metrics_server
@@ -156,6 +181,7 @@ def _volume_run(args: argparse.Namespace) -> int:
         guard=_load_guard(),
         needle_map_kind=args.index,
     )
+    _mirror_spans_to_profiler(vs)
     vs.start()
     _maybe_metrics(args.metricsPort)
     print(f"volume server on http {vs.url} grpc {vs.grpc_address}")
@@ -204,6 +230,7 @@ def _server_run(args: argparse.Namespace) -> int:
     vs = VolumeServer(
         args.dir or ["./data"], m.address, port=args.port, host=args.ip
     )
+    _mirror_spans_to_profiler(vs)
     vs.start()
     parts = [
         f"master {m.address} (http :{m.http_port})",

@@ -199,10 +199,11 @@ def _encode_rows(
     pipeline_depth: Optional[int] = None,
     crcs: Optional[list] = None,
     ring_cache: Optional[dict] = None,
-) -> None:
+) -> int:
     """Encode `n_rows` rows of `block_size` blocks as a stream of flat
     (DATA_SHARDS, width) device dispatches over reused staging buffers.
-    Output files receive bytes in row-major order.
+    Output files receive bytes in row-major order. Returns the number of
+    batches dispatched.
 
     Depth-N pipeline: up to `pipeline_depth` batches' parity computes
     on-device (async dispatch) while the next batch's disk reads run;
@@ -220,7 +221,7 @@ def _encode_rows(
     keeps the staging ring alive ACROSS calls — the inline-ingest
     builder's per-poll path."""
     if n_rows <= 0:
-        return
+        return 0
     if buffer_size > block_size:
         buffer_size = block_size
     if block_size % buffer_size:
@@ -234,27 +235,33 @@ def _encode_rows(
     span = _aligned(batch_cap * buffer_size, align)
     ring = _ring_for(ring_cache, depth + 1, (k, span))
     inflight: deque = deque()  # FIFO of (parity_handle, width)
+    n_batches = 0
 
     def drain_one() -> None:
         parity, width = inflight.popleft()
         with trace_mod.span("encode.drain", width=width):
-            parity_np = np.asarray(parity)  # sync point
-        if k + parity_np.shape[0] != len(outputs):
-            # a geometry-mismatched encoder must fail loudly, not leave
-            # trailing .ecNN files silently empty
-            raise ValueError(
-                f"encoder produced {parity_np.shape[0]} parity shards; "
-                f"layout wants {len(outputs) - k}"
-            )
-        for p in range(parity_np.shape[0]):
-            row = np.ascontiguousarray(parity_np[p, :width])
-            outputs[k + p].write(row)
-            if crcs is not None:
-                crcs[k + p] = zlib.crc32(row, crcs[k + p])
+            with trace_mod.span("encode.sync", bytes=(len(outputs) - k) * width):
+                parity_np = np.asarray(parity)  # sync point: device wait + D2H
+            if k + parity_np.shape[0] != len(outputs):
+                # a geometry-mismatched encoder must fail loudly, not leave
+                # trailing .ecNN files silently empty
+                raise ValueError(
+                    f"encoder produced {parity_np.shape[0]} parity shards; "
+                    f"layout wants {len(outputs) - k}"
+                )
+            for p in range(parity_np.shape[0]):
+                row = np.ascontiguousarray(parity_np[p, :width])
+                with trace_mod.span("encode.write", bytes=width):
+                    outputs[k + p].write(row)
+                if crcs is not None:
+                    with trace_mod.span("encode.crc", bytes=width):
+                        crcs[k + p] = zlib.crc32(row, crcs[k + p])
 
     def flush(batch: list) -> None:
+        nonlocal n_batches
         if not batch:
             return
+        n_batches += 1
         width = len(batch) * buffer_size
         while len(inflight) >= depth:
             drain_one()
@@ -264,29 +271,34 @@ def _encode_rows(
             # shard (k large sequential reads per row-run instead of one
             # seek per segment x shard — keeps readahead alive at 1 GiB
             # block strides)
-            i = 0
-            while i < len(batch):
-                row, seg0 = batch[i]
-                j = i
-                while j + 1 < len(batch) and batch[j + 1] == (row, batch[j][1] + 1):
-                    j += 1
-                row_start = start_offset + row * block_size * k
-                for d in range(k):
-                    read_padded_into(
-                        f,
-                        row_start + d * block_size + seg0 * buffer_size,
-                        staging[d, i * buffer_size : (j + 1) * buffer_size],
-                    )
-                i = j + 1
+            with trace_mod.span("encode.read", bytes=k * width):
+                i = 0
+                while i < len(batch):
+                    row, seg0 = batch[i]
+                    j = i
+                    while j + 1 < len(batch) and batch[j + 1] == (row, batch[j][1] + 1):
+                        j += 1
+                    row_start = start_offset + row * block_size * k
+                    for d in range(k):
+                        read_padded_into(
+                            f,
+                            row_start + d * block_size + seg0 * buffer_size,
+                            staging[d, i * buffer_size : (j + 1) * buffer_size],
+                        )
+                    i = j + 1
             view = staging[:, :width]
             for d in range(k):
-                outputs[d].write(view[d])
+                with trace_mod.span("encode.write", bytes=width):
+                    outputs[d].write(view[d])
                 if crcs is not None:
-                    crcs[d] = zlib.crc32(view[d], crcs[d])
+                    with trace_mod.span("encode.crc", bytes=width):
+                        crcs[d] = zlib.crc32(view[d], crcs[d])
             aw = _aligned(width, align)  # <= span: roundup is monotone
             if aw > width:
                 staging[:, width:aw] = 0  # tail batch: pad columns are zeros
-        inflight.append((enc.encode_parity_lazy(staging[:, :aw], donate=True), width))
+        with trace_mod.span("encode.dispatch", bytes=k * aw):
+            parity = enc.encode_parity_lazy(staging[:, :aw], donate=True)  # H2D + launch
+        inflight.append((parity, width))
 
     try:
         # iterate segments in global order (row-major, then segment in block)
@@ -303,6 +315,7 @@ def _encode_rows(
     except BaseException:
         _discard_inflight(inflight)
         raise
+    return n_batches
 
 
 def stripe_layout(
@@ -358,40 +371,42 @@ def write_ec_files(
     )
 
     crcs = [0] * enc.total_shards
-    try:
-        with ExitStack() as stack:
-            f = stack.enter_context(open(dat_path, "rb"))
-            outputs = [
-                stack.enter_context(open(shard_file_name(base_file_name, s), "wb"))
-                for s in range(enc.total_shards)
-            ]
-            _encode_rows(
-                f, enc, outputs, 0, large_block_size, n_large, buffer_size,
-                max_batch_bytes, pipeline_depth, crcs,
-            )
-            _encode_rows(
-                f,
-                enc,
-                outputs,
-                n_large * large_row,
-                small_block_size,
-                n_small,
-                min(buffer_size, small_block_size),
-                max_batch_bytes,
-                pipeline_depth,
-                crcs,
-            )
-    except BaseException:
-        for s in range(enc.total_shards):
-            try:
-                os.unlink(shard_file_name(base_file_name, s))
-            except OSError:
-                pass
-        raise
-    write_ec_info(
-        base_file_name, large_block_size, small_block_size, dat_size,
-        shard_crcs=crcs, geometry=geometry_of(enc),
-    )
+    with trace_mod.ensure("encode.run", klass="maint"):
+        try:
+            with ExitStack() as stack:
+                f = stack.enter_context(open(dat_path, "rb"))
+                outputs = [
+                    stack.enter_context(open(shard_file_name(base_file_name, s), "wb"))
+                    for s in range(enc.total_shards)
+                ]
+                batches = _encode_rows(
+                    f, enc, outputs, 0, large_block_size, n_large, buffer_size,
+                    max_batch_bytes, pipeline_depth, crcs,
+                )
+                batches += _encode_rows(
+                    f,
+                    enc,
+                    outputs,
+                    n_large * large_row,
+                    small_block_size,
+                    n_small,
+                    min(buffer_size, small_block_size),
+                    max_batch_bytes,
+                    pipeline_depth,
+                    crcs,
+                )
+        except BaseException:
+            for s in range(enc.total_shards):
+                try:
+                    os.unlink(shard_file_name(base_file_name, s))
+                except OSError:
+                    pass
+            raise
+        write_ec_info(
+            base_file_name, large_block_size, small_block_size, dat_size,
+            shard_crcs=crcs, geometry=geometry_of(enc),
+        )
+        trace_mod.annotate(bytes=dat_size, batches=batches)
 
 
 def geometry_of(enc: Encoder) -> CodeGeometry:
@@ -1035,7 +1050,8 @@ def rebuild_ec_files_from_projections(
             def drain_one() -> None:
                 lazy, valid, width = inflight.popleft()
                 with trace_mod.span("rebuild.drain", width=width):
-                    out = np.asarray(lazy).reshape(rows, width)  # sync point
+                    with trace_mod.span("rebuild.sync", bytes=rows * width):
+                        out = np.asarray(lazy).reshape(rows, width)  # sync point
                     for k, s in enumerate(missing):
                         row = np.ascontiguousarray(out[k, :valid])
                         outs[s].write(row)
@@ -1058,16 +1074,18 @@ def rebuild_ec_files_from_projections(
                         staging = ring.take()
                         for i, g in enumerate(groups):
                             g.read_into(off, staging[i, : rows * width])
-                    combined = enc.project_lazy(
-                        combine, staging[:, : rows * width], donate=True
-                    )  # async
+                    with trace_mod.span("rebuild.dispatch", bytes=len(groups) * rows * width):
+                        combined = enc.project_lazy(
+                            combine, staging[:, : rows * width], donate=True
+                        )  # async
                     inflight.append((combined, valid, width))
                 while inflight:
                     drain_one()
             except BaseException:
                 _discard_inflight(inflight)
                 raise
-        _verify_rebuilt_crcs(base_file_name, crcs)
+        with trace_mod.span("rebuild.verify"):
+            _verify_rebuilt_crcs(base_file_name, crcs)
     except BaseException:
         for s in missing:
             try:
@@ -1142,11 +1160,15 @@ def rebuild_ec_files_from_sources(
             def drain_one() -> None:
                 lazy, valid = inflight.popleft()
                 with trace_mod.span("rebuild.drain", width=valid):
-                    out = np.asarray(lazy)  # (len(missing), width) — sync point
+                    with trace_mod.span("rebuild.sync", bytes=len(missing) * valid):
+                        # (len(missing), width) — sync point: device wait + D2H
+                        out = np.asarray(lazy)
                     for k, s in enumerate(missing):
                         row = out[k, :valid]
-                        outs[s].write(row)
-                        crcs[s] = zlib.crc32(row, crcs[s])
+                        with trace_mod.span("rebuild.write", bytes=valid):
+                            outs[s].write(row)
+                        with trace_mod.span("rebuild.crc", bytes=valid):
+                            crcs[s] = zlib.crc32(row, crcs[s])
 
             def issue_prefetch(bi: int) -> None:
                 if bi < len(batches):
@@ -1163,21 +1185,24 @@ def rebuild_ec_files_from_sources(
                         drain_one()
                     with trace_mod.span("rebuild.stage", batch=bi, width=width):
                         staging = ring.take()
-                        for i, s in enumerate(survivors):
-                            sources[s].read_into(off, staging[i, :width])
+                        with trace_mod.span("rebuild.read", bytes=len(survivors) * width):
+                            for i, s in enumerate(survivors):
+                                sources[s].read_into(off, staging[i, :width])
                         aw = _aligned(width, align)  # <= span: roundup is monotone
                         if aw > width:
                             staging[:, width:aw] = 0  # tail: pad columns are zeros
-                    decoded = enc.reconstruct_lazy(
-                        staging[:, :aw], survivors, missing, donate=True
-                    )  # async
+                    with trace_mod.span("rebuild.dispatch", bytes=len(survivors) * aw):
+                        decoded = enc.reconstruct_lazy(
+                            staging[:, :aw], survivors, missing, donate=True
+                        )  # async: H2D + launch
                     inflight.append((decoded, valid))
                 while inflight:
                     drain_one()
             except BaseException:
                 _discard_inflight(inflight)
                 raise
-        _verify_rebuilt_crcs(base_file_name, crcs)
+        with trace_mod.span("rebuild.verify"):
+            _verify_rebuilt_crcs(base_file_name, crcs)
     except BaseException:
         for s in missing:
             try:
@@ -1185,6 +1210,8 @@ def rebuild_ec_files_from_sources(
             except OSError:
                 pass
         raise
+    # the caller's run span (rebuild_ec_files' or the RPC's rebuild.run)
+    trace_mod.annotate(bytes=len(missing) * shard_size, batches=len(batches))
     return missing
 
 
@@ -1376,7 +1403,8 @@ def _rebuild_fused(
             lazy, segs = inflight.popleft()
             width = sum(t for _, _, _, t in segs)
             with trace_mod.span("rebuild.drain", width=width):
-                dec = np.asarray(lazy)  # (max_m, span) — the sync point
+                with trace_mod.span("rebuild.sync"):
+                    dec = np.asarray(lazy)  # (max_m, span) — the sync point
                 col = 0
                 for gi, mi, off, length in segs:
                     if gi not in failed:
@@ -1434,7 +1462,8 @@ def _rebuild_fused(
                 # drop any block of a now-failed group before dispatching
                 blocks = [b for b in blocks if b["_gi"] not in failed]
                 if blocks:
-                    decoded = base_enc.reconstruct_block(staging, blocks)
+                    with trace_mod.span("rebuild.dispatch", blocks=len(blocks)):
+                        decoded = base_enc.reconstruct_block(staging, blocks)
                     inflight.append((decoded, segs))
             while inflight:
                 drain_one()
@@ -1453,7 +1482,8 @@ def _rebuild_fused(
             errors[job["base"]] = failed[gi]
             continue
         try:
-            _verify_rebuilt_crcs(job["base"], crcs[mi])
+            with trace_mod.span("rebuild.verify"):
+                _verify_rebuilt_crcs(job["base"], crcs[mi])
         except Exception as e:  # noqa: BLE001 — per-volume verify failure
             # unlinks only that volume; the rest of the cohort is good
             for s in job["missing"]:
@@ -1513,7 +1543,8 @@ def _rebuild_group(
         def drain_one() -> None:
             lazy, segs, valid = inflight.popleft()
             with trace_mod.span("rebuild.drain", width=valid):
-                dec = np.asarray(lazy)  # (len(missing), width) — sync point
+                with trace_mod.span("rebuild.sync", bytes=len(missing) * valid):
+                    dec = np.asarray(lazy)  # (len(missing), width) — sync point
                 col = 0
                 for ji, off, length in segs:
                     for k, s in enumerate(missing):
@@ -1548,9 +1579,10 @@ def _rebuild_group(
                     aw = _aligned(width, align)
                     if aw > width:
                         staging[:, width:aw] = 0  # pad columns are zeros
-                decoded = enc.reconstruct_lazy(
-                    staging[:, :aw], survivors, missing, donate=True
-                )
+                with trace_mod.span("rebuild.dispatch", bytes=len(survivors) * aw):
+                    decoded = enc.reconstruct_lazy(
+                        staging[:, :aw], survivors, missing, donate=True
+                    )
                 inflight.append((decoded, segs, width))
             while inflight:
                 drain_one()
@@ -1558,7 +1590,8 @@ def _rebuild_group(
             _discard_inflight(inflight)
             raise
     for ji, job in enumerate(members):
-        _verify_rebuilt_crcs(job["base"], crcs[ji])
+        with trace_mod.span("rebuild.verify"):
+            _verify_rebuilt_crcs(job["base"], crcs[ji])
 
 
 def rebuild_ec_files(
@@ -1590,7 +1623,7 @@ def rebuild_ec_files(
     present, missing, shard_size = _check_rebuild_geometry(base_file_name, enc)
     if not missing:
         return []
-    with ExitStack() as stack:
+    with ExitStack() as stack, trace_mod.ensure("rebuild.run", klass="maint"):
         sources = {
             s: stack.enter_context(LocalSlabSource(shard_file_name(base_file_name, s)))
             for s in present

@@ -68,9 +68,21 @@ SPAN_NAMES: dict[str, str] = {
     "cache.miss": "decoded-interval cache consulted and empty for this interval",
     "rebuild.run": "one whole-volume rebuild (local or distributed)",
     "rebuild.stage": "staging-ring fill for one rebuild batch (disk/wire)",
-    "rebuild.drain": "device sync + shard write-out for one rebuild batch",
+    "rebuild.read": "survivor slabs read into the staging slot (child of rebuild.stage)",
+    "rebuild.dispatch": "reconstruct_lazy: device_put (H2D) + the jit call, until it returns",
+    "rebuild.drain": "device sync + shard write-out + CRC for one rebuild batch",
+    "rebuild.sync": "np.asarray of one batch's decode: device wait + D2H, nothing else",
+    "rebuild.write": "one rebuilt shard's bytes of one batch written to its file",
+    "rebuild.crc": "zlib.crc32 fold over one rebuilt shard's bytes of one batch",
+    "rebuild.verify": "rebuilt shards' CRC32s checked against the .eci record",
+    "encode.run": "one whole-volume encode: .dat -> shard files + .eci (write_ec_files)",
     "encode.stage": "staging-ring fill for one encode batch",
-    "encode.drain": "device sync + shard write-out for one encode batch",
+    "encode.read": ".dat rows read into the staging slot (child of encode.stage)",
+    "encode.dispatch": "encode_parity_lazy: device_put (H2D) + the jit call, until it returns",
+    "encode.drain": "device sync + shard write-out + CRC for one encode batch",
+    "encode.sync": "np.asarray of one batch's parity: device wait + D2H, nothing else",
+    "encode.write": "one shard's bytes of one batch written to its file (data at fill, parity at drain)",
+    "encode.crc": "zlib.crc32 fold over one shard's bytes of one batch",
     "ingest.encode": "inline-EC encode of newly-final large rows (one poll)",
     "ingest.seal": "inline-EC seal finalization of one volume",
     "ingest.spread.commit": "seal-time commit of one pre-spread parity shard",
@@ -97,6 +109,22 @@ _cv: contextvars.ContextVar[Optional["Span"]] = contextvars.ContextVar(
 
 #: guards only the first-child list publication in Span.add_child
 _first_child_lock = threading.Lock()
+
+#: the profiler mirror: None, or a callable (name, attrs) that opens an
+#: event on another clock and returns an object whose __exit__ closes it.
+#: Called on the recording thread at every RECORDED span's and root's
+#: __enter__, closed at its __exit__ — never when tracing is off or no
+#: trace is ambient. The chip-owning server installs
+#: jax.profiler.TraceAnnotation here at boot (command/servers.py), so a
+#: profiler session holds the program's spans beside the device's
+#: operations; this module itself imports nothing of jax.
+_mirror = None
+
+
+def set_mirror(opener) -> None:
+    """Install (or with None remove) the process's profiler mirror."""
+    global _mirror
+    _mirror = opener
 
 
 def enabled() -> bool:
@@ -219,8 +247,7 @@ class TraceRing:
         )
         self._sample = sample
         self.errors_cap = errors_cap
-        s = seed if seed is not None else config.env("WEEDTPU_TRACE_SEED")
-        self._rng = random.Random(s or None)
+        self._rng = random.Random(seed or None)
         self._sampled: list[_Completed] = []
         self._errors: list[_Completed] = []
         #: (kind, class) -> ascending-by-duration list of _Completed
@@ -335,18 +362,21 @@ class span:  # noqa: N801 — reads as a statement: `with span("ec.gather"):`
     """Record one child span under the ambient trace; a no-op (and
     allocation-free beyond this tiny object) when no trace is active."""
 
-    __slots__ = ("_name", "_attrs", "_sp", "_tok")
+    __slots__ = ("_name", "_attrs", "_sp", "_tok", "_mirrored")
 
     def __init__(self, _name: str, **attrs):
         self._name = _name
         self._attrs = attrs or None
         self._sp = None
         self._tok = None
+        self._mirrored = None
 
     def __enter__(self) -> Optional[Span]:
         parent = _cv.get()
         if parent is None:
             return None
+        if _mirror is not None:
+            self._mirrored = _mirror(self._name, self._attrs)
         sp = Span(self._name, self._attrs, parent.trace)
         parent.add_child(sp)
         self._sp = sp
@@ -361,20 +391,26 @@ class span:  # noqa: N801 — reads as a statement: `with span("ec.gather"):`
         if et is not None and sp.error is None:
             sp.error = et.__name__
         _cv.reset(self._tok)
+        if self._mirrored is not None:
+            self._mirrored.__exit__(None, None, None)
         return False
 
 
 class _RootCtx:
-    __slots__ = ("_state", "_root", "_tok", "_ring")
+    __slots__ = ("_state", "_attrs", "_root", "_tok", "_ring", "_mirrored")
 
-    def __init__(self, state: _TraceState, ring: TraceRing):
+    def __init__(self, state: _TraceState, ring: TraceRing, attrs: Optional[dict] = None):
         self._state = state
+        self._attrs = attrs or None
         self._ring = ring
         self._root = None
         self._tok = None
+        self._mirrored = None
 
     def __enter__(self) -> Span:
-        root = Span(self._state.kind, None, self._state)
+        if _mirror is not None:
+            self._mirrored = _mirror(self._state.kind, self._attrs)
+        root = Span(self._state.kind, self._attrs, self._state)
         self._root = root
         self._tok = _cv.set(root)
         return root
@@ -387,6 +423,8 @@ class _RootCtx:
             error = f"{et.__name__}: {ev}"[:200]
             root.error = et.__name__
         _cv.reset(self._tok)
+        if self._mirrored is not None:
+            self._mirrored.__exit__(None, None, None)
         self._ring.offer(_Completed(root, self._state, error))
         return False
 
@@ -402,24 +440,34 @@ def start(kind: str, klass: str = "healthy", trace_id=None, ring: Optional[Trace
     return _RootCtx(_TraceState(tid, kind, klass), ring or RING)
 
 
-def continue_trace(kind: str, trace_id, klass: str = "rpc", ring: Optional[TraceRing] = None):
+def continue_trace(
+    kind: str, trace_id, klass: str = "rpc", ring: Optional[TraceRing] = None, **attrs
+):
     """Root trace ONLY when a propagated id arrived — the RPC server
     seam: un-traced callers (heartbeats, bare clients) cost nothing,
-    traced callers get their id continued in this process's ring."""
+    traced callers get their id continued in this process's ring.
+    `attrs` are the root span's from its start (the RPC's method), so
+    the profiler mirror sees them too."""
     tid = valid_id(trace_id)
     if tid is None or not enabled():
         return _NULL
-    return _RootCtx(_TraceState(tid, kind, klass), ring or RING)
+    return _RootCtx(_TraceState(tid, kind, klass), ring or RING, attrs)
 
 
 def ensure(kind: str, klass: str = "maint"):
     """A span under the ambient trace when one is active, else a fresh
-    root trace — maintenance paths (rebuild, convert, scrub repair,
-    seal) are always visible in the ring, and nest correctly when an
-    operator's shell trace reached them over RPC."""
-    if _cv.get() is not None:
-        return span(kind)
-    return start(kind, klass=klass)
+    root trace — maintenance paths (encode, rebuild, convert, scrub
+    repair, seal) are always visible in the ring, and nest correctly
+    when an operator's shell trace reached them over RPC. Where the
+    ambient span already IS this kind (the RPC opened the run span and
+    the pipeline it calls ensures one too), that span is the run: both
+    annotate the same one."""
+    cur = _cv.get()
+    if cur is None:
+        return start(kind, klass=klass)
+    if cur.name == kind:
+        return attach(cur)
+    return span(kind)
 
 
 def current() -> Optional[Span]:
@@ -472,22 +520,6 @@ class attach:  # noqa: N801 — `with attach(parent):` in worker threads
         return False
 
 
-def traced(name: str, **attrs):
-    """Decorator form of `span` for whole-function stages."""
-
-    def deco(fn):
-        import functools
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            with span(name, **attrs):
-                return fn(*a, **kw)
-
-        return wrapper
-
-    return deco
-
-
 # -- the /debug/traces surface -------------------------------------------------
 
 
@@ -533,10 +565,13 @@ def render_trace(trace: dict) -> str:
       +-   0.1ms   810.9ms ec.recover
       |  +-   0.2ms   540.0ms ec.gather shard=3
       ...
-    """
+
+    The root's own attributes (an RPC's method, the shell's command)
+    follow its class on the first line."""
+    root_attrs = "".join(f" {k}={v}" for k, v in (trace["root"].get("attrs") or {}).items())
     lines = [
         f"trace={trace['trace_id']} {trace['kind']} "
-        f"class={trace['class']} {trace['duration_s'] * 1e3:.1f}ms"
+        f"class={trace['class']}{root_attrs} {trace['duration_s'] * 1e3:.1f}ms"
         + (f" ERROR={trace['error']}" if trace.get("error") else "")
     ]
 

@@ -42,6 +42,8 @@ EVIDENCE_MAX_AGE_DAYS = config.env("WEEDTPU_EVIDENCE_MAX_AGE_DAYS")
 FUSED_VARIANTS = ("int8", "bf16", "u8", "mplane", "dma")
 
 _BACKENDS = ("numpy", "native", "xorsched", "jax", "pallas", "mesh")
+#: the backends whose codec runs on a device jax holds (the rest are CPU floors)
+DEVICE_BACKENDS = ("jax", "pallas", "mesh")
 
 
 # -- code-family registry (the geometry-flexible seam) ------------------------
@@ -1473,7 +1475,7 @@ def new_encoder(
         data_shards, parity_shards, matrix_kind=matrix_kind, backend=backend,
         **pallas_kwargs,
     )
-    if enc.backend in ("jax", "pallas", "mesh") and "device" not in selection:
+    if enc.backend in DEVICE_BACKENDS and "device" not in selection:
         # a forced device backend reports what it will run on, like auto
         from seaweedfs_tpu.utils.devices import describe_devices
 

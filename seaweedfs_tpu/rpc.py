@@ -190,10 +190,8 @@ def _wrap_unary(fn, method: str = ""):
         t0 = time.monotonic()
         try:
             with trace_mod.continue_trace(
-                "rpc.server", _inbound_trace_id(context)
-            ) as sp:
-                if sp is not None:
-                    sp.annotate(method=method)
+                "rpc.server", _inbound_trace_id(context), method=method
+            ):
                 try:
                     return fn(request, context)
                 except RpcFault as e:
@@ -215,10 +213,8 @@ def _wrap_stream(fn, method: str = ""):
         t0 = time.monotonic()
         try:
             with trace_mod.continue_trace(
-                "rpc.server", _inbound_trace_id(context)
-            ) as sp:
-                if sp is not None:
-                    sp.annotate(method=method)
+                "rpc.server", _inbound_trace_id(context), method=method
+            ):
                 try:
                     yield from fn(request, context)
                 except RpcFault as e:

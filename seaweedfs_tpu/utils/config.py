@@ -519,11 +519,6 @@ register_env(
     parse=_clamped_int(1),
 )
 register_env(
-    "WEEDTPU_TRACE_SEED", int, 0,
-    "Seed for the trace-sampling RNG (deterministic retention for "
-    "tests/replays); 0 = OS entropy.",
-)
-register_env(
     "WEEDTPU_XORSCHED_TILE_KB", int, 4,
     "Width-axis tile of the xorsched executors, in KB per shard: each "
     "tile keeps the whole bit-plane slot frame (inputs + grouped temps + "

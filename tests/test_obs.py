@@ -8,6 +8,7 @@ back) including the hedge, coalesce, and rebuild slab/trace branches."""
 import io
 import json
 import logging
+import os
 import threading
 import time
 import urllib.request
@@ -17,6 +18,7 @@ import pytest
 from seaweedfs_tpu.cluster.client import MasterClient
 from seaweedfs_tpu.cluster.master import MasterServer
 from seaweedfs_tpu.cluster.volume_server import VolumeServer
+from seaweedfs_tpu.ec import stripe as stripe_mod
 from seaweedfs_tpu.obs import trace
 from seaweedfs_tpu.shell import CommandEnv, run_command
 from seaweedfs_tpu.utils import glog
@@ -626,3 +628,173 @@ def test_trace_id_round_trips_shell_rebuild_trace_and_slab(cluster):
 
     for fid, payload in fids:
         assert client.read(fid) == payload
+
+
+def test_ec_trace_renders_the_bulk_stages_of_one_shell_encode_and_rebuild(cluster):
+    """One `ec.encode` through the shell: `ec.trace -traceId <its id>` shows
+    the generate RPC's run span (volume id, bytes, batches) and the per-batch
+    stages under the shell's id; the local rebuild RPC's run span likewise."""
+    master, servers, client, env = cluster
+    trace.RING.clear()
+    vid, _ = _ec_spread_volume(client, env)
+
+    def leg(command, method, run):
+        snap = trace.RING.snapshot(limit=100000)
+        (shell,) = [t for t in snap if t["kind"] == "shell.command"
+                    and t["root"].get("attrs", {}).get("command") == command]
+        (rpc_leg,) = [t for t in snap if t["kind"] == "rpc.server" and t["trace_id"] == shell["trace_id"]
+                      and t["root"].get("attrs", {}).get("method") == method]
+        (run_span,) = [s for s in trace.iter_spans(rpc_leg) if s["name"] == run]
+        return shell["trace_id"], run_span, {s["name"] for s in trace.iter_spans(rpc_leg)}
+
+    tid, run, names = leg("ec.encode", "VolumeEcShardsGenerate", "encode.run")
+    assert run["attrs"]["volume"] == vid and run["attrs"]["batches"] >= 1 and run["attrs"]["bytes"] > 0
+    assert {f"encode.{s}" for s in ("stage", "read", "write", "crc", "dispatch", "drain", "sync")} <= names
+    rendered = _shell(env, f"ec.trace -traceId {tid}")
+    for want in (tid, f"encode.run volume={vid}", "encode.read bytes=", "encode.sync bytes=", "encode.crc bytes="):
+        assert want in rendered, (want, rendered)
+
+    # the local rebuild RPC, on a second volume whose 14 shards stay on its server
+    fid = client.submit(os.urandom(3000)).fid
+    vid2 = int(fid.split(",", 1)[0])
+    assert vid2 != vid
+    holder = next(s for s in servers if s.store.get_volume(vid2) is not None)
+    env.vs_call(holder.grpc_address, "VolumeEcShardsGenerate",
+                {"volume_id": vid2, "large_block_size": LARGE, "small_block_size": SMALL})
+    os.unlink(stripe_mod.shard_file_name(holder.store.get_volume(vid2).base_path, 0))
+    trace.RING.clear()
+    with trace.start("shell.command", klass="shell") as root:
+        root.annotate(command="ec.rebuild")
+        got = env.vs_call(holder.grpc_address, "VolumeEcShardsRebuild", {"volume_id": vid2})
+    assert got["rebuilt_shard_ids"] == [0]
+    _, run, names = leg("ec.rebuild", "VolumeEcShardsRebuild", "rebuild.run")
+    assert run["attrs"]["volume"] == vid2 and run["attrs"]["batches"] >= 1 and run["attrs"]["bytes"] > 0
+    assert {f"rebuild.{s}" for s in ("read", "write", "crc", "dispatch", "sync", "verify")} <= names
+
+
+# -- the profiler mirror (PR 25): the program's spans on another clock ---------
+
+
+@pytest.fixture
+def mirror(on):
+    """A recording mirror: (event, name, attrs, thread id) per call."""
+    calls = []
+
+    class _Open:
+        def __init__(self, name, attrs):
+            self.name = name
+            calls.append(("enter", name, dict(attrs or {}), threading.get_ident()))
+
+        def __exit__(self, *exc):
+            calls.append(("exit", self.name, None, threading.get_ident()))
+
+    trace.set_mirror(_Open)
+    try:
+        yield calls
+    finally:
+        trace.set_mirror(None)
+
+
+def test_mirror_gets_enter_exit_in_pairs_on_the_recording_thread(mirror):
+    ring = trace.TraceRing(capacity=8, slowest_n=2, sample=1.0, seed=1)
+    both_alive = threading.Barrier(2, timeout=30)  # or the second thread may reuse the first's id
+
+    def rpc(method):
+        both_alive.wait()
+        with trace.continue_trace("rpc.server", "abc123", ring=ring, method=method):
+            with trace.ensure("encode.run"):
+                with trace.ensure("encode.run"):  # the pipeline under the RPC: the same span, no second event
+                    with trace.span("encode.read", bytes=7):
+                        pass
+                with pytest.raises(ValueError):
+                    with trace.span("encode.sync"):
+                        raise ValueError("boom")
+        both_alive.wait()
+
+    workers = [threading.Thread(target=rpc, args=(m,)) for m in ("A", "B")]
+    for w in workers:
+        w.start()
+    for w in workers:
+        w.join(timeout=30)
+        assert not w.is_alive()
+    by_thread = {}
+    for event, name, attrs, tid in mirror:
+        by_thread.setdefault(tid, []).append((event, name, attrs))
+    assert len(by_thread) == 2 and threading.get_ident() not in by_thread
+    for events in by_thread.values():
+        method = events[0][2]["method"]
+        assert events == [
+            ("enter", "rpc.server", {"method": method}), ("enter", "encode.run", {}),
+            ("enter", "encode.read", {"bytes": 7}), ("exit", "encode.read", None),
+            ("enter", "encode.sync", {}), ("exit", "encode.sync", None),
+            ("exit", "encode.run", None), ("exit", "rpc.server", None),
+        ]
+    # what the mirror saw is what the ring holds
+    assert sorted(t["root"]["attrs"]["method"] for t in ring.snapshot()) == ["A", "B"]
+
+
+def test_mirror_is_not_called_without_a_recorded_span(mirror, monkeypatch):
+    with trace.span("encode.read", bytes=1):  # no ambient trace: nothing is recorded
+        pass
+    assert trace.continue_trace("rpc.server", None, method="X") is trace._NULL
+    monkeypatch.setenv("WEEDTPU_TRACE", "off")
+    with trace.start("http.read"):
+        with trace.ensure("encode.run"):
+            with trace.span("encode.read", bytes=1):
+                pass
+    assert mirror == []
+
+
+def test_spans_land_in_a_profiler_session_with_their_attributes(on, tmp_path):
+    """What the chip-owning server installs at boot, under a real profiler
+    session (CPU here): names and attributes survive into ProfileData, on
+    the recording thread's line, nested."""
+    import glob
+    import types
+
+    import jax
+
+    from seaweedfs_tpu.command import servers
+
+    def vs(backend):
+        return types.SimpleNamespace(store=types.SimpleNamespace(encoder=types.SimpleNamespace(backend=backend)))
+
+    try:
+        servers._mirror_spans_to_profiler(vs("xorsched"))
+        assert trace._mirror is None  # a CPU backend: no mirror, jax not imported for it
+        servers._mirror_spans_to_profiler(vs("jax"))
+        assert trace._mirror is not None
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            with trace.continue_trace("rpc.server", "abc123", method="VolumeEcShardsGenerate"):
+                with trace.ensure("encode.run"):
+                    with trace.span("encode.read", bytes=4096):
+                        time.sleep(0.002)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        trace.set_mirror(None)
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    found = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.split("#")[0] in ("rpc.server", "encode.run", "encode.read"):
+                    found[e.name.split("#")[0]] = (line.name, e.start_ns, e.start_ns + e.duration_ns,
+                                                   e.name + repr(sorted((str(k), v) for k, v in e.stats)))
+    rpc, run, read = found["rpc.server"], found["encode.run"], found["encode.read"]
+    assert rpc[0] == run[0] == read[0]  # one thread, one line
+    assert rpc[1] <= run[1] <= read[1] and read[2] <= run[2] <= rpc[2]
+    assert read[2] - read[1] >= 2_000_000  # nanoseconds
+    assert "VolumeEcShardsGenerate" in rpc[3] and "4096" in read[3]
+
+
+def test_obs_trace_imports_no_jax():
+    import subprocess
+    import sys
+
+    code = ("import sys; from seaweedfs_tpu.obs import trace; trace.set_mirror(None); "
+            "assert 'jax' not in sys.modules and 'jaxlib' not in sys.modules, sorted(m for m in sys.modules if 'jax' in m)")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)

@@ -339,3 +339,78 @@ def test_kernel_sweep_smoke_gate():
         tag = "pallas-auto" if mxu == "int8" else f"pallas-{mxu}-auto"
         assert tag in seen, f"variant {mxu} missing from the smoke gate: {sorted(seen)}"
     assert any(v.startswith("rebuild-") for v in seen)
+
+
+# -- the bulk pipelines seen from inside: one stage catalog, every batch --------
+
+
+def _self_ms(sp):
+    return sp["dur_ms"] - sum(c["dur_ms"] for c in sp.get("spans", ()))
+
+
+@pytest.mark.parametrize("pipeline", ["encode", "rebuild"])
+def test_bulk_stage_spans_account_for_a_run(tmp_path, monkeypatch, pipeline):
+    """Through the real write_ec_files / rebuild_ec_files: the run's span
+    tree holds every stage once per batch (a write and a CRC per shard and
+    batch), `bytes` over the writes is what the files hold and over the reads
+    what was staged, the stages' self times account for the run, and the
+    shards are those of a run with WEEDTPU_TRACE=off."""
+    from seaweedfs_tpu.obs import trace
+
+    # a run's wall must be its stages', not this box's spiky fsync of the .eci
+    monkeypatch.setattr(os, "fsync", lambda fd: None)
+    lost = [0, 3, 11, 13]
+    sizes = dict(large_block_size=1 << 20, small_block_size=1 << 16)
+    batch = 10 * 4 * (1 << 16)  # four 64 KiB segments wide
+
+    def run(sub, mode):
+        monkeypatch.setenv("WEEDTPU_TRACE", mode)
+        trace.RING.clear()
+        d = tmp_path / sub
+        d.mkdir()
+        base = _write_dat(d, 6_000_000)
+        stripe.write_ec_files(base, buffer_size=1 << 16, encoder=ENC, max_batch_bytes=batch, **sizes)
+        encode = trace.RING.snapshot(kind="encode.run")
+        for s in lost:
+            os.unlink(stripe.shard_file_name(base, s))
+        trace.RING.clear()
+        assert stripe.rebuild_ec_files(base, encoder=ENC, buffer_size=1 << 16, max_batch_bytes=batch) == lost
+        rebuild = trace.RING.snapshot(kind="rebuild.run")
+        shards = [open(stripe.shard_file_name(base, s), "rb").read() for s in range(TOTAL_SHARDS_COUNT)]
+        return {"encode": encode, "rebuild": rebuild}, shards
+
+    traced, shards = run("on", "on")
+    untraced, shards_off = run("off", "off")
+    assert shards == shards_off and untraced == {"encode": [], "rebuild": []}
+
+    (t,) = traced[pipeline]
+    root = t["root"]
+    spans = [s for s in trace.iter_spans(t) if s is not root]
+    count = {n: sum(1 for s in spans if s["name"] == n) for n in {s["name"] for s in spans}}
+    shard_size = len(shards[0])
+    batches = -(-shard_size // (4 * (1 << 16)))
+    assert batches == 3 and root["attrs"]["batches"] == batches
+    per_shard = TOTAL_SHARDS_COUNT if pipeline == "encode" else len(lost)
+    want = {f"{pipeline}.{s}": batches for s in ("stage", "read", "dispatch", "drain", "sync")}
+    want.update({f"{pipeline}.write": per_shard * batches, f"{pipeline}.crc": per_shard * batches})
+    if pipeline == "rebuild":
+        want["rebuild.verify"] = 1
+    assert count == want
+    assert set(want) <= set(trace.SPAN_NAMES)
+
+    def bytes_of(name):
+        return sum(s["attrs"]["bytes"] for s in spans if s["name"] == name)
+
+    assert bytes_of(f"{pipeline}.write") == bytes_of(f"{pipeline}.crc") == per_shard * shard_size
+    if pipeline == "encode":
+        assert root["attrs"]["bytes"] == 6_000_000
+        assert bytes_of("encode.read") == 10 * shard_size  # the .dat and the last row's zero fill
+    else:
+        assert root["attrs"]["bytes"] == len(lost) * shard_size
+        assert bytes_of("rebuild.read") == 10 * shard_size  # ten survivor slabs (a whole number of buffers here)
+    # drain means the same in both: the sync, the writes and the CRCs are its children
+    for drain in (s for s in spans if s["name"] == f"{pipeline}.drain"):
+        assert [c["name"] for c in drain["spans"]][0] == f"{pipeline}.sync"
+        assert {c["name"] for c in drain["spans"][1:]} == {f"{pipeline}.write", f"{pipeline}.crc"}
+    named = sum(_self_ms(s) for s in spans)
+    assert named >= 0.9 * root["dur_ms"], (named, root["dur_ms"])
