@@ -16,10 +16,21 @@ matmul is column-independent, so the flat form is byte-identical to any
 per-segment batching. The streaming paths run a configurable depth-N
 inflight pipeline (double/triple buffering) over a ring of staging buffers:
 batch K's parity/decode computes on-device while batches K+1..K+depth read
-from disk, with no per-batch host allocation (readinto straight into the
+from disk, with no per-batch host allocation (reads land straight in the
 staging ring, buffer donation releasing batch HBM early on device
-backends) and the
-per-shard CRC32 folded into the same pass so shard bytes are touched once.
+backends) and the per-shard CRC32 folded over the same staged bytes as they
+are written, so a shard's bytes are touched once per stage (read, write,
+CRC) and no finished file is read back.
+
+The host side of a batch runs per shard on shard lanes (`_ShardLanes`): the
+pipeline's own thread lays a batch out, waits for its reads, dispatches and
+syncs; each shard's slab read, file write and CRC fold are tasks on the
+process's lane threads, one shard's tasks in submission order, different
+shards at once. Local files are read positionally (`pread_padded_into`); a
+source that is no file, or a `SlabSource` that does not say `lane_reads`,
+is read on the pipeline's own thread. `write_ec_files` (`_encode_rows`) and
+`rebuild_ec_files` / `rebuild_ec_files_from_sources` use them; the batch,
+fused, projection and serial rebuilds do their host work on one thread.
 
 The engine is backend-agnostic through the Encoder seam: the same flat
 (shards, width) dispatch shape serves the device paths (jax/pallas/mesh)
@@ -30,6 +41,7 @@ so the staging-batch geometry here needs no backend-specific casing.
 
 from __future__ import annotations
 
+import io
 import json
 import os
 import threading
@@ -117,6 +129,39 @@ def read_padded_into(f, offset: int, out: np.ndarray) -> None:
         out[got:] = 0
 
 
+def pread_padded_into(fd: int, offset: int, out: np.ndarray) -> None:
+    """`read_padded_into` on a descriptor at an explicit offset: no file
+    position is read or moved, so any number of threads may read one file
+    at once (the shard lanes do). Short reads are continued; what lies past
+    EOF is zero-filled."""
+    mv = memoryview(out).cast("B")
+    got = 0
+    while got < len(mv):
+        if hasattr(os, "preadv"):
+            n = os.preadv(fd, [mv[got:]], offset + got)
+        else:  # no preadv on this platform: one copy through a bytes object
+            piece = os.pread(fd, len(mv) - got, offset + got)
+            n = len(piece)
+            mv[got : got + n] = piece
+        if n == 0:
+            break
+        got += n
+    if got < out.size:
+        out[got:] = 0
+
+
+def _fd_of(f) -> Optional[int]:
+    """The descriptor of `f` where it is a real OS file, else None: a shim
+    (`convert._VirtualDat`, a BytesIO) keeps state behind `seek`/`readinto`
+    and is read on the calling thread only."""
+    if not isinstance(f, (io.FileIO, io.BufferedReader, io.BufferedRandom)):
+        return None
+    try:
+        return f.fileno()
+    except OSError:  # a buffered reader over something that is no file
+        return None
+
+
 class _StagingRing:
     """`slots` reused host staging buffers for a depth-N pipeline.
 
@@ -187,6 +232,137 @@ def _discard_inflight(inflight: deque) -> None:
             pass
 
 
+# -- shard lanes: a batch's per-shard host work on the host's cores -----------
+
+#: the process's lane threads: made at the first run that wants them, kept
+#: for the life of the process (idle they cost nothing, and the inline-ingest
+#: path calls `_encode_rows` once per poll), shared by every run.
+#: -> (executor, threads)
+_lane_threads: Optional[tuple] = None
+_lane_threads_lock = threading.Lock()
+
+
+def _lane_pool() -> Optional[tuple]:
+    """(executor, threads) for this host, or None where it has one or two
+    cores: one thread fewer than `os.cpu_count()`, so that the calling
+    thread and the server's own keep a core."""
+    global _lane_threads
+    threads = (os.cpu_count() or 1) - 1
+    if threads < 2:
+        return None
+    pool = _lane_threads
+    if pool is None or pool[1] != threads:  # the second: only a test changes the count
+        with _lane_threads_lock:
+            pool = _lane_threads
+            if pool is None or pool[1] != threads:
+                pool = _lane_threads = (
+                    ThreadPoolExecutor(max_workers=threads, thread_name_prefix="ec-lane"),
+                    threads,
+                )
+    return pool
+
+
+class _LaneBatch:
+    """What a `join` waits for: the tasks submitted under it and not finished."""
+
+    __slots__ = ("left",)
+
+    def __init__(self):
+        self.left = 0
+
+
+class _ShardLanes:
+    """One pipeline run's per-shard host work (slab reads, file writes, CRC
+    folds: calls that release the GIL), spread over the process's lane threads.
+
+    What a lane guarantees: the tasks of one shard run one at a time, in the
+    order they were submitted, on whichever thread owns the shard just then;
+    tasks of different shards run at once, on at most `n` threads (one per
+    shard, and no more than the host's cores less one). So a shard's file
+    receives its batches in batch order and its CRC is folded in that order,
+    whatever the other shards do. Runs share the threads and nothing else:
+    each has its own queues, so two volumes encoded at once neither wait for
+    one another's joins nor mix their order.
+
+    `n` == 0 (a host of one or two cores) is the inline form: `submit` runs
+    the task there and then on the calling thread, and raises what it raises.
+
+    The first exception of a lane task is kept, the run's tasks that have not
+    started are cancelled, and the next `join` raises it on the calling
+    thread. `abort` is the failure path's: cancel, then wait for what runs."""
+
+    def __init__(self, shards: int):
+        pool = _lane_pool()
+        self._pool = pool[0] if pool else None
+        self.n = min(shards, pool[1]) if pool else 0
+        self._cv = threading.Condition()
+        self._queues: dict[int, deque] = {}  # shard -> its waiting tasks; present: a thread owns it
+        self._open = 0  # tasks submitted and not finished
+        self._error: Optional[BaseException] = None
+        self._cancelled = False
+
+    def submit(self, batch: _LaneBatch, shard: int, fn: Callable, *args) -> None:
+        """Queue `fn(*args)` behind `shard`'s earlier tasks. Spans it records
+        become children of the span that is ambient here."""
+        if not self.n:
+            fn(*args)
+            return
+        task = (batch, trace_mod.current(), fn, args)
+        with self._cv:
+            batch.left += 1
+            self._open += 1
+            queue = self._queues.get(shard)
+            if queue is not None:
+                queue.append(task)
+                return
+            self._queues[shard] = deque((task,))
+        self._pool.submit(self._own, shard)
+
+    def _own(self, shard: int) -> None:
+        """On a lane thread: run `shard`'s tasks until none waits."""
+        done = None
+        while True:
+            with self._cv:
+                if done is not None:
+                    done.left -= 1
+                    self._open -= 1
+                    if not done.left or not self._open:
+                        self._cv.notify_all()
+                queue = self._queues[shard]
+                if not queue:
+                    del self._queues[shard]
+                    return
+                done, parent, fn, args = queue.popleft()
+                cancelled = self._cancelled
+            if cancelled:
+                continue
+            try:
+                with trace_mod.attach(parent):
+                    fn(*args)
+            except BaseException as e:  # noqa: BLE001 — kept for the calling thread
+                with self._cv:
+                    self._cancelled = True
+                    if self._error is None:
+                        self._error = e
+
+    def join(self, *batches: _LaneBatch) -> None:
+        """Block until every task of `batches` (of the whole run, where none
+        is given) has finished; raise the run's first exception."""
+        with self._cv:
+            while any(b.left for b in batches) if batches else self._open:
+                self._cv.wait()
+            if self._error is not None:
+                raise self._error
+
+    def abort(self) -> None:
+        """Cancel the run's tasks that have not started and wait for those
+        that run: after it no lane touches the run's files or staging slots."""
+        with self._cv:
+            self._cancelled = True
+            while self._open:
+                self._cv.wait()
+
+
 def _encode_rows(
     f,
     enc: Encoder,
@@ -208,10 +384,26 @@ def _encode_rows(
     Depth-N pipeline: up to `pipeline_depth` batches' parity computes
     on-device (async dispatch) while the next batch's disk reads run;
     the np.asarray in drain_one() is the per-batch synchronization point,
-    and drains happen FIFO so parity files receive bytes in order. Data
-    shards stream to disk at fill time (their bytes never cross the
-    device); when `crcs` is given, each shard's running CRC32 is folded
-    in the same pass — bytes are touched once, no second host pass.
+    and drains happen FIFO so parity files receive bytes in order.
+
+    Who does what (`_ShardLanes`). The calling thread lays out a batch,
+    waits for its reads, dispatches it, and syncs its parity; the per-shard
+    work runs on the lanes. Where `f` is a real OS file each data shard's
+    slabs are read on a lane, positionally (`pread_padded_into`: no seek on
+    a handle that two threads touch); a source that is no file (`convert.
+    _VirtualDat`) is read on the calling thread, in row-run order, through
+    `seek`/`readinto`. Each data shard's write and, when `crcs` is given,
+    its CRC32 fold are queued behind its read and run beside the dispatch,
+    the device and the next batch's reads; the parity rows' follow their
+    sync. A batch's staging slot is pinned until its data shards are
+    written: drain_one() joins them before the slot can come round again,
+    and the parity array of a drain lives until the next drain (or the end)
+    has joined its writes. Data shards' bytes never cross the device, and
+    every byte is still touched once per stage: one read into staging, one
+    write from there, one CRC fold over the same memory; no second pass over
+    a finished file. On return every lane task of the call has finished; on
+    a failure the lanes are aborted before the inflight device work is
+    discarded, so the caller may unlink.
 
     On a mesh-backend encoder the staging span is rounded up to the
     encoder's `width_align` (dp*sp) and each dispatch covers the aligned
@@ -220,6 +412,8 @@ def _encode_rows(
     with no dispatcher-side pad copy. `ring_cache` (a caller-owned dict)
     keeps the staging ring alive ACROSS calls — the inline-ingest
     builder's per-poll path."""
+    lanes = _ShardLanes(len(outputs))
+    trace_mod.annotate(lanes=lanes.n)  # on the caller's run span; 0 = inline
     if n_rows <= 0:
         return 0
     if buffer_size > block_size:
@@ -234,11 +428,30 @@ def _encode_rows(
     batch_cap = max(1, max_batch_bytes // (k * buffer_size))
     span = _aligned(batch_cap * buffer_size, align)
     ring = _ring_for(ring_cache, depth + 1, (k, span))
-    inflight: deque = deque()  # FIFO of (parity_handle, width)
+    fd = _fd_of(f)
+    inflight: deque = deque()  # FIFO of (parity_handle, width, the batch's data-shard tasks)
+    parity_tasks = _LaneBatch()  # the last drain's parity writes; their args keep its array alive
     n_batches = 0
 
+    def read_slabs(shards: Sequence[int], staging: np.ndarray, runs: list) -> None:
+        # the runs' columns are 0..width: one span for what `shards` get of a batch
+        with trace_mod.span("encode.read", bytes=len(shards) * runs[-1][2]):
+            for off, lo, hi in runs:
+                for d in shards:
+                    if fd is None:
+                        read_padded_into(f, off + d * block_size, staging[d, lo:hi])
+                    else:
+                        pread_padded_into(fd, off + d * block_size, staging[d, lo:hi])
+
+    def put(s: int, row: np.ndarray) -> None:
+        with trace_mod.span("encode.write", bytes=row.size):
+            outputs[s].write(row)
+        if crcs is not None:
+            with trace_mod.span("encode.crc", bytes=row.size):
+                crcs[s] = zlib.crc32(row, crcs[s])
+
     def drain_one() -> None:
-        parity, width = inflight.popleft()
+        parity, width, data_tasks = inflight.popleft()
         with trace_mod.span("encode.drain", width=width):
             with trace_mod.span("encode.sync", bytes=(len(outputs) - k) * width):
                 parity_np = np.asarray(parity)  # sync point: device wait + D2H
@@ -249,13 +462,12 @@ def _encode_rows(
                     f"encoder produced {parity_np.shape[0]} parity shards; "
                     f"layout wants {len(outputs) - k}"
                 )
+            with trace_mod.span("encode.wait"):
+                lanes.join(data_tasks, parity_tasks)
             for p in range(parity_np.shape[0]):
-                row = np.ascontiguousarray(parity_np[p, :width])
-                with trace_mod.span("encode.write", bytes=width):
-                    outputs[k + p].write(row)
-                if crcs is not None:
-                    with trace_mod.span("encode.crc", bytes=width):
-                        crcs[k + p] = zlib.crc32(row, crcs[k + p])
+                lanes.submit(
+                    parity_tasks, k + p, put, k + p, np.ascontiguousarray(parity_np[p, :width])
+                )
 
     def flush(batch: list) -> None:
         nonlocal n_batches
@@ -270,35 +482,39 @@ def _encode_rows(
             # read runs of consecutive segments as one contiguous slab per
             # shard (k large sequential reads per row-run instead of one
             # seek per segment x shard — keeps readahead alive at 1 GiB
-            # block strides)
-            with trace_mod.span("encode.read", bytes=k * width):
-                i = 0
-                while i < len(batch):
-                    row, seg0 = batch[i]
-                    j = i
-                    while j + 1 < len(batch) and batch[j + 1] == (row, batch[j][1] + 1):
-                        j += 1
-                    row_start = start_offset + row * block_size * k
-                    for d in range(k):
-                        read_padded_into(
-                            f,
-                            row_start + d * block_size + seg0 * buffer_size,
-                            staging[d, i * buffer_size : (j + 1) * buffer_size],
-                        )
-                    i = j + 1
+            # block strides): (shard 0's offset in f, first column, end)
+            runs = []
+            i = 0
+            while i < len(batch):
+                row, seg0 = batch[i]
+                j = i
+                while j + 1 < len(batch) and batch[j + 1] == (row, batch[j][1] + 1):
+                    j += 1
+                runs.append(
+                    (
+                        start_offset + row * block_size * k + seg0 * buffer_size,
+                        i * buffer_size,
+                        (j + 1) * buffer_size,
+                    )
+                )
+                i = j + 1
+            data_tasks = _LaneBatch()
+            if fd is None:  # no OS file: here, run by run through seek/readinto
+                read_slabs(range(k), staging, runs)
+            else:
+                for d in range(k):
+                    lanes.submit(data_tasks, d, read_slabs, (d,), staging, runs)
+                with trace_mod.span("encode.wait"):
+                    lanes.join(data_tasks)
             view = staging[:, :width]
             for d in range(k):
-                with trace_mod.span("encode.write", bytes=width):
-                    outputs[d].write(view[d])
-                if crcs is not None:
-                    with trace_mod.span("encode.crc", bytes=width):
-                        crcs[d] = zlib.crc32(view[d], crcs[d])
+                lanes.submit(data_tasks, d, put, d, view[d])
             aw = _aligned(width, align)  # <= span: roundup is monotone
             if aw > width:
                 staging[:, width:aw] = 0  # tail batch: pad columns are zeros
         with trace_mod.span("encode.dispatch", bytes=k * aw):
             parity = enc.encode_parity_lazy(staging[:, :aw], donate=True)  # H2D + launch
-        inflight.append((parity, width))
+        inflight.append((parity, width, data_tasks))
 
     try:
         # iterate segments in global order (row-major, then segment in block)
@@ -312,7 +528,10 @@ def _encode_rows(
         flush(pending)
         while inflight:
             drain_one()
+        with trace_mod.span("encode.wait"):
+            lanes.join()
     except BaseException:
+        lanes.abort()
         _discard_inflight(inflight)
         raise
     return n_batches
@@ -356,12 +575,13 @@ def write_ec_files(
 ) -> None:
     """<base>.dat -> <base>.ec00 .. .ec13 (WriteEcFiles semantics).
 
-    Each shard's CRC32 is computed inline as its bytes stream through the
-    encode pipeline (one touch per byte — no second host read-back pass)
-    and recorded in the .eci sidecar for later shard verification. A
-    mid-stream failure drains the inflight device work and unlinks every
-    partial .ecNN file — a crashed encode never leaves a truncated shard
-    set that a later rebuild would mistake for truth."""
+    Each shard's CRC32 is folded over its staged bytes as they are written
+    (on the shard's lane, in batch order — no read-back pass over a
+    finished file) and recorded in the .eci sidecar for later shard
+    verification. A mid-stream failure, on this thread or on a lane, stops
+    the lanes, drains the inflight device work and unlinks every partial
+    .ecNN file — a crashed encode never leaves a truncated shard set that a
+    later rebuild would mistake for truth."""
     enc = encoder or new_encoder()
     dat_path = base_file_name + ".dat"
     dat_size = os.path.getsize(dat_path)
@@ -610,7 +830,18 @@ class SlabSource:
     soon (a hint — sources may start the work asynchronously) and
     `read_into(offset, out)` when the bytes must land in a staging view.
     Reads past the shard's end zero-fill, exactly like `read_padded_into`,
-    so every backend is byte-interchangeable under the decode."""
+    so every backend is byte-interchangeable under the decode.
+
+    `lane_reads` says whether `read_into` may run on a shard lane of
+    `rebuild_ec_files_from_sources`, that is on another thread than the
+    pipeline's, at the same time as other sources' reads. A source says yes
+    only if its `read_into` shares no state with anything else that runs
+    meanwhile (`LocalSlabSource`: a positional read of its own file). The
+    base class says no, and such a source is read on the pipeline's own
+    thread, one after another, after its `prefetch` hints: the order it has
+    always seen."""
+
+    lane_reads = False
 
     def prefetch(self, offset: int, length: int) -> None:  # noqa: B027 — hint
         pass
@@ -629,14 +860,17 @@ class SlabSource:
 
 
 class LocalSlabSource(SlabSource):
-    """Today's path: `readinto` straight from a local shard file."""
+    """A local shard file, read positionally straight into the staging view:
+    no seek, no position shared between calls, so it may be read on a lane."""
+
+    lane_reads = True
 
     def __init__(self, path: str):
         # weedlint: ignore[open-no-ctx] handle owned by the source, closed in close()
         self._f = open(path, "rb")
 
     def read_into(self, offset: int, out: np.ndarray) -> None:
-        read_padded_into(self._f, offset, out)
+        pread_padded_into(self._f.fileno(), offset, out)
 
     def close(self) -> None:
         self._f.close()
@@ -1119,7 +1353,16 @@ def rebuild_ec_files_from_sources(
     decodes on-device through the same depth-N inflight deque as the local
     path. Rebuilt shards stream to `<base>.ecNN` with CRC32 folded in and
     verified against the .eci record when present; any failure drains
-    inflight device work and unlinks the partial outputs."""
+    inflight device work and unlinks the partial outputs.
+
+    Host work per batch (`_ShardLanes`): sources that say `lane_reads` (the
+    local files) are read on the lanes, all at once; the others (remote,
+    trace, projection sources, anything that does not say) on this thread,
+    one after another as before, while the lanes read; the dispatch waits
+    for all of them. Rebuilt rows are written and CRC'd on the lanes, one
+    shard's batches in order. A lane's exception is raised here at the next
+    join; before the partial outputs are unlinked every lane task of the run
+    has finished or been cancelled."""
     enc = encoder or encoder_for_base(base_file_name)
     present = sorted(sources)
     if missing is None:
@@ -1141,6 +1384,8 @@ def rebuild_ec_files_from_sources(
     span = _aligned(chunks_per_batch * buffer_size, align)
     ring = _StagingRing(depth + 1, (enc.data_shards, span))
     crcs = {s: 0 for s in missing}
+    lanes = _ShardLanes(len(survivors) + len(missing))
+    trace_mod.annotate(lanes=lanes.n)  # on the caller's run span; 0 = inline
     #: (offset, valid_bytes, staged_width) per batch, precomputed so the
     #: prefetch cursor can run `ahead` batches past the read cursor
     batches = []
@@ -1156,6 +1401,17 @@ def rebuild_ec_files_from_sources(
                 for s in missing
             }
             inflight: deque = deque()  # FIFO of (decoded_handle, valid_bytes)
+            written = _LaneBatch()  # the last drain's writes; their args keep its array alive
+
+            def read_slab(src: SlabSource, off: int, out: np.ndarray) -> None:
+                with trace_mod.span("rebuild.read", bytes=out.size):
+                    src.read_into(off, out)
+
+            def put(s: int, row: np.ndarray) -> None:
+                with trace_mod.span("rebuild.write", bytes=row.size):
+                    outs[s].write(row)
+                with trace_mod.span("rebuild.crc", bytes=row.size):
+                    crcs[s] = zlib.crc32(row, crcs[s])
 
             def drain_one() -> None:
                 lazy, valid = inflight.popleft()
@@ -1163,12 +1419,10 @@ def rebuild_ec_files_from_sources(
                     with trace_mod.span("rebuild.sync", bytes=len(missing) * valid):
                         # (len(missing), width) — sync point: device wait + D2H
                         out = np.asarray(lazy)
+                    with trace_mod.span("rebuild.wait"):
+                        lanes.join(written)
                     for k, s in enumerate(missing):
-                        row = out[k, :valid]
-                        with trace_mod.span("rebuild.write", bytes=valid):
-                            outs[s].write(row)
-                        with trace_mod.span("rebuild.crc", bytes=valid):
-                            crcs[s] = zlib.crc32(row, crcs[s])
+                        lanes.submit(written, s, put, s, out[k, :valid])
 
             def issue_prefetch(bi: int) -> None:
                 if bi < len(batches):
@@ -1185,9 +1439,19 @@ def rebuild_ec_files_from_sources(
                         drain_one()
                     with trace_mod.span("rebuild.stage", batch=bi, width=width):
                         staging = ring.take()
-                        with trace_mod.span("rebuild.read", bytes=len(survivors) * width):
-                            for i, s in enumerate(survivors):
-                                sources[s].read_into(off, staging[i, :width])
+                        reads = _LaneBatch()
+                        # sources that say they may be read on a lane first,
+                        # so that they run beside the calling thread's own
+                        for i, s in enumerate(survivors):
+                            if sources[s].lane_reads:
+                                lanes.submit(
+                                    reads, s, read_slab, sources[s], off, staging[i, :width]
+                                )
+                        for i, s in enumerate(survivors):
+                            if not sources[s].lane_reads:
+                                read_slab(sources[s], off, staging[i, :width])
+                        with trace_mod.span("rebuild.wait"):
+                            lanes.join(reads)
                         aw = _aligned(width, align)  # <= span: roundup is monotone
                         if aw > width:
                             staging[:, width:aw] = 0  # tail: pad columns are zeros
@@ -1198,7 +1462,10 @@ def rebuild_ec_files_from_sources(
                     inflight.append((decoded, valid))
                 while inflight:
                     drain_one()
+                with trace_mod.span("rebuild.wait"):
+                    lanes.join()
             except BaseException:
+                lanes.abort()
                 _discard_inflight(inflight)
                 raise
         with trace_mod.span("rebuild.verify"):
@@ -1610,7 +1877,12 @@ def rebuild_ec_files(
     device dispatch, with the same depth-N inflight pipeline as
     `_encode_rows`: up to `pipeline_depth` batches decode on-device while
     the next batch's slab reads run; drains are FIFO so rebuilt files
-    receive bytes in order. Output is byte-identical to
+    receive bytes in order. The ten survivor reads of a batch run at once
+    on the shard lanes (positional reads of the local files) and the
+    calling thread waits for all ten before it dispatches; a drained
+    batch's rebuilt rows are written and CRC'd on the lanes, each shard in
+    batch order, beside the next batch's reads, and joined at the next
+    drain or the end. Output is byte-identical to
     `rebuild_ec_files_serial` (zero-padding the tail slab is exact: GF
     matmul maps zero columns to zero columns, and the pad is trimmed
     before writing). Rebuilt shards' CRC32s are folded in as the bytes

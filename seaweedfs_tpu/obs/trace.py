@@ -68,21 +68,23 @@ SPAN_NAMES: dict[str, str] = {
     "cache.miss": "decoded-interval cache consulted and empty for this interval",
     "rebuild.run": "one whole-volume rebuild (local or distributed)",
     "rebuild.stage": "staging-ring fill for one rebuild batch (disk/wire)",
-    "rebuild.read": "survivor slabs read into the staging slot (child of rebuild.stage)",
+    "rebuild.read": "one survivor's slab read into its staging row (child of rebuild.stage; on a lane thread where the source allows)",
+    "rebuild.wait": "the calling thread blocked in a join of lane tasks (a batch's reads, the last drain's writes)",
     "rebuild.dispatch": "reconstruct_lazy: device_put (H2D) + the jit call, until it returns",
     "rebuild.drain": "device sync + shard write-out + CRC for one rebuild batch",
     "rebuild.sync": "np.asarray of one batch's decode: device wait + D2H, nothing else",
-    "rebuild.write": "one rebuilt shard's bytes of one batch written to its file",
-    "rebuild.crc": "zlib.crc32 fold over one rebuilt shard's bytes of one batch",
+    "rebuild.write": "one rebuilt shard's bytes of one batch written to its file (on a lane thread)",
+    "rebuild.crc": "zlib.crc32 fold over one rebuilt shard's bytes of one batch (on a lane thread)",
     "rebuild.verify": "rebuilt shards' CRC32s checked against the .eci record",
     "encode.run": "one whole-volume encode: .dat -> shard files + .eci (write_ec_files)",
     "encode.stage": "staging-ring fill for one encode batch",
-    "encode.read": ".dat rows read into the staging slot (child of encode.stage)",
+    "encode.read": "one data shard's slabs of one batch read into its staging row (child of encode.stage; on a lane thread), or all ten on the calling thread where the source is no file",
+    "encode.wait": "the calling thread blocked in a join of lane tasks (a batch's reads, its data shards' writes, the last drain's parity writes)",
     "encode.dispatch": "encode_parity_lazy: device_put (H2D) + the jit call, until it returns",
     "encode.drain": "device sync + shard write-out + CRC for one encode batch",
     "encode.sync": "np.asarray of one batch's parity: device wait + D2H, nothing else",
-    "encode.write": "one shard's bytes of one batch written to its file (data at fill, parity at drain)",
-    "encode.crc": "zlib.crc32 fold over one shard's bytes of one batch",
+    "encode.write": "one shard's bytes of one batch written to its file (data under its stage, parity under its drain; on a lane thread)",
+    "encode.crc": "zlib.crc32 fold over one shard's bytes of one batch (on a lane thread)",
     "ingest.encode": "inline-EC encode of newly-final large rows (one poll)",
     "ingest.seal": "inline-EC seal finalization of one volume",
     "ingest.spread.commit": "seal-time commit of one pre-spread parity shard",

@@ -8,6 +8,7 @@ import json
 import os
 import subprocess
 import sys
+import threading
 import zlib
 
 import numpy as np
@@ -341,24 +342,338 @@ def test_kernel_sweep_smoke_gate():
     assert any(v.startswith("rebuild-") for v in seen)
 
 
+# -- shard lanes: the per-shard host work of a batch on the host's cores --------
+
+LOST = [0, 3, 11, 13]
+SMALL = dict(large_block_size=16384, small_block_size=4096, buffer_size=4096)
+
+
+def _shard_bytes(base):
+    return [open(stripe.shard_file_name(base, s), "rb").read() for s in range(TOTAL_SHARDS_COUNT)]
+
+
+def _run_attr(kind, attr):
+    from seaweedfs_tpu.obs import trace
+
+    (t,) = trace.RING.snapshot(kind=kind)
+    return t["root"]["attrs"][attr]
+
+
+def _encode_then_rebuild(monkeypatch, d, size, cores, depth, max_batch_bytes):
+    """write_ec_files, then lose LOST and rebuild_ec_files, on a host that
+    says it has `cores`: -> (lanes of the encode, of the rebuild, the shard
+    files after the encode, after the rebuild, the .eci's CRCs)."""
+    from seaweedfs_tpu.obs import trace
+
+    monkeypatch.setattr(os, "cpu_count", lambda: cores)
+    d.mkdir()
+    base = _write_dat(d, size)
+    trace.RING.clear()
+    stripe.write_ec_files(base, encoder=ENC, max_batch_bytes=max_batch_bytes, pipeline_depth=depth, **SMALL)
+    encoded = _shard_bytes(base)
+    for s in LOST:
+        os.unlink(stripe.shard_file_name(base, s))
+    rebuilt = stripe.rebuild_ec_files(
+        base, encoder=ENC, buffer_size=8192, max_batch_bytes=10 * 2 * 8192, pipeline_depth=depth
+    )
+    assert rebuilt == LOST
+    return (
+        _run_attr("encode.run", "lanes"),
+        _run_attr("rebuild.run", "lanes"),
+        encoded,
+        _shard_bytes(base),
+        stripe.read_ec_info(base)["shard_crc32"],
+    )
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+@pytest.mark.parametrize(
+    "shape",
+    [
+        # two large rows and two small ones; a batch of three 4 KiB segments
+        # cuts across the four-segment large rows, and leaves a tail batch
+        ("tiers", 2 * 163_840 + 50_000, 10 * 3 * 4096),
+        # small rows only, seven of them in batches of four: a tail batch of three
+        ("tail", 6 * 40_960 + 123, 10 * 4 * 4096),
+        ("empty", 0, 10 * 4 * 4096),
+    ],
+    ids=lambda shape: shape[0],
+)
+def test_lanes_write_the_inline_orders_bytes(tmp_path, monkeypatch, shape, depth):
+    """Eight cores (seven lanes) against one core (inline, the order before
+    the lanes): all 14 shard files, the .eci's CRCs and the four rebuilt
+    shards are the same bytes, at every pipeline depth."""
+    _, size, max_batch_bytes = shape
+    lanes = _encode_then_rebuild(monkeypatch, tmp_path / "lanes", size, 8, depth, max_batch_bytes)
+    inline = _encode_then_rebuild(monkeypatch, tmp_path / "inline", size, 1, depth, max_batch_bytes)
+    assert lanes[:2] == (7, 7) and inline[:2] == (0, 0)
+    assert lanes[2] == inline[2] and lanes[4] == inline[4]
+    assert lanes[4] == [zlib.crc32(b) for b in lanes[2]]
+    assert lanes[3] == inline[3] == lanes[2]
+
+
+class _LanesSeen(stripe._ShardLanes):
+    """Every lanes object the pipelines make, kept for the test to look at."""
+
+    made: list = []
+
+    def __init__(self, shards):
+        super().__init__(shards)
+        self.made.append(self)
+
+
+class _FailingWrites:
+    """A shard file whose `write` raises from its `fail_at`-th call on."""
+
+    def __init__(self, f, fail_at):
+        self._f, self._left = f, fail_at
+
+    def write(self, b):
+        self._left -= 1
+        if self._left <= 0:
+            raise _Boom("disk full")
+        return self._f.write(b)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self._f.close()
+
+
+class _StrictSource(stripe.LocalSlabSource):
+    """A local survivor that refuses to zero-fill: a slab past its file's
+    end is an error (a survivor truncated after the geometry check)."""
+
+    def read_into(self, offset, out):
+        if offset + out.size > os.path.getsize(self._f.name):
+            raise _Boom(f"short survivor {self._f.name}")
+        super().read_into(offset, out)
+
+
+@pytest.mark.parametrize("case", ["encode_write_fails", "rebuild_write_fails", "truncated_survivor"])
+def test_lane_failure_surfaces_and_leaves_nothing(tmp_path, monkeypatch, case):
+    """An exception raised on a lane thread (a shard file's write at batch
+    2, a survivor that turns out short) reaches the caller as itself; no
+    partial .ecNN and no .eci stay, survivors do, and when the pipeline
+    returns no task of the run is queued or running."""
+    monkeypatch.setattr(os, "cpu_count", lambda: 8)
+    monkeypatch.setattr(stripe, "_ShardLanes", _LanesSeen)
+    _LanesSeen.made = []
+    base = _write_dat(tmp_path, 655_360)
+    victim = stripe.shard_file_name(base, 12 if case == "encode_write_fails" else 13)
+    real_open = open
+
+    def failing_open(path, mode="r", *a, **kw):
+        f = real_open(path, mode, *a, **kw)
+        return _FailingWrites(f, 2) if (path == victim and "w" in mode) else f
+
+    small_batches = dict(max_batch_bytes=10 * 2 * 4096, **SMALL)
+    if case == "encode_write_fails":
+        monkeypatch.setattr(stripe, "open", failing_open, raising=False)
+        with pytest.raises(_Boom, match="disk full"):
+            stripe.write_ec_files(base, encoder=ENC, **small_batches)
+        gone = range(TOTAL_SHARDS_COUNT)
+        assert not os.path.exists(base + ".eci")
+    else:
+        stripe.write_ec_files(base, encoder=ENC, **small_batches)
+        gone = [0, 13]
+        for s in gone:
+            os.unlink(stripe.shard_file_name(base, s))
+        _LanesSeen.made = []
+        if case == "rebuild_write_fails":
+            monkeypatch.setattr(stripe, "open", failing_open, raising=False)
+            with pytest.raises(_Boom, match="disk full"):
+                stripe.rebuild_ec_files(base, encoder=ENC, buffer_size=8192, max_batch_bytes=10 * 2 * 8192)
+        else:
+            survivors = [s for s in range(TOTAL_SHARDS_COUNT) if s not in gone]
+            size = os.path.getsize(stripe.shard_file_name(base, 1))
+            sources = {s: _StrictSource(stripe.shard_file_name(base, s)) for s in survivors}
+            os.truncate(stripe.shard_file_name(base, 5), size - 10_000)
+            try:
+                with pytest.raises(_Boom, match="short survivor .*ec05"):
+                    stripe.rebuild_ec_files_from_sources(
+                        base, sources, size, encoder=ENC, missing=gone,
+                        buffer_size=8192, max_batch_bytes=10 * 2 * 8192,
+                    )
+            finally:
+                for src in sources.values():
+                    src.close()
+        for s in range(TOTAL_SHARDS_COUNT):
+            if s not in gone:
+                assert os.path.exists(stripe.shard_file_name(base, s)), f"survivor {s} gone"
+    for s in gone:
+        assert not os.path.exists(stripe.shard_file_name(base, s)), f"partial {s} leaked"
+    assert _LanesSeen.made and all(lanes.n == 7 for lanes in _LanesSeen.made)
+    assert all(lanes._open == 0 and not lanes._queues for lanes in _LanesSeen.made)
+
+
+def test_two_volumes_encoded_at_once_share_the_lanes(tmp_path, monkeypatch):
+    """Two write_ec_files of different volumes from two threads, on the one
+    set of lane threads: both finish, each with the files it has alone."""
+    monkeypatch.setattr(os, "cpu_count", lambda: 4)  # three lanes for 28 shards
+    bases, alone = [], []
+    for i, size in enumerate((700_001, 523_456)):
+        d = tmp_path / f"v{i}"
+        d.mkdir()
+        bases.append(_write_dat(d, size, seed=10 + i))
+        stripe.write_ec_files(bases[i], encoder=ENC, max_batch_bytes=10 * 3 * 4096, **SMALL)
+        alone.append((_shard_bytes(bases[i]), stripe.read_ec_info(bases[i])["shard_crc32"]))
+        for s in range(TOTAL_SHARDS_COUNT):
+            os.unlink(stripe.shard_file_name(bases[i], s))
+    errors = []
+
+    def encode(base):
+        try:
+            stripe.write_ec_files(base, encoder=ENC, max_batch_bytes=10 * 3 * 4096, **SMALL)
+        except BaseException as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    threads = [threading.Thread(target=encode, args=(b,)) for b in bases]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not errors and not any(t.is_alive() for t in threads)
+    for base, (shards, crcs) in zip(bases, alone):
+        assert _shard_bytes(base) == shards
+        assert stripe.read_ec_info(base)["shard_crc32"] == crcs
+
+
+class _Shim:
+    """seek/readinto over a real file, as convert._VirtualDat has them: no
+    OS file to the pipeline, and it says on which threads it was read."""
+
+    def __init__(self, path):
+        self._f = open(path, "rb")
+        self.readers = set()
+
+    def seek(self, pos):
+        self._f.seek(pos)
+
+    def readinto(self, mv):
+        self.readers.add(threading.current_thread().name)
+        return self._f.readinto(mv)
+
+
+def test_sources_that_are_no_file_are_read_on_the_calling_thread(tmp_path, monkeypatch):
+    """A seek/readinto shim in _encode_rows and a SlabSource that does not
+    say `lane_reads` in the rebuild: every read on the calling thread, the
+    writes and CRCs on the lanes all the same, the bytes those of files."""
+    monkeypatch.setattr(os, "cpu_count", lambda: 8)
+    me = threading.current_thread().name
+    base = _write_dat(tmp_path, 6 * 40_960)  # six small rows, no large one
+    stripe.write_ec_files(
+        base, encoder=ENC, max_batch_bytes=10 * 4 * 4096,
+        large_block_size=65536, small_block_size=4096, buffer_size=4096,
+    )
+    golden, crcs_golden = _shard_bytes(base), stripe.read_ec_info(base)["shard_crc32"]
+
+    writers = set()
+
+    class Out:
+        def __init__(self):
+            self.got = bytearray()
+
+        def write(self, b):
+            writers.add(threading.current_thread().name)
+            self.got += bytes(b)
+
+    shim, outs, crcs = _Shim(base + ".dat"), [Out() for _ in range(TOTAL_SHARDS_COUNT)], [0] * TOTAL_SHARDS_COUNT
+    assert stripe._fd_of(shim) is None
+    n = stripe._encode_rows(shim, ENC, outs, 0, 4096, 6, 4096, 10 * 4 * 4096, 2, crcs)
+    shim._f.close()
+    assert n == 2 and shim.readers == {me}
+    assert [bytes(o.got) for o in outs] == golden and crcs == crcs_golden
+    assert writers and all(w.startswith("ec-lane") for w in writers)
+
+    readers = {}
+
+    class Plain(stripe.SlabSource):  # says nothing of lanes: the base class's no
+        def __init__(self, sid):
+            self._sid, self._inner = sid, stripe.LocalSlabSource(stripe.shard_file_name(base, sid))
+
+        def read_into(self, offset, out):
+            readers.setdefault(self._sid, set()).add(threading.current_thread().name)
+            self._inner.read_into(offset, out)
+
+        def close(self):
+            self._inner.close()
+
+    class Told(stripe.LocalSlabSource):
+        def read_into(self, offset, out):
+            readers.setdefault("told", set()).add(threading.current_thread().name)
+            super().read_into(offset, out)
+
+    for s in LOST:
+        os.unlink(stripe.shard_file_name(base, s))
+    survivors = [s for s in range(TOTAL_SHARDS_COUNT) if s not in LOST]
+    sources = {s: Plain(s) for s in survivors[:5]}
+    sources.update({s: Told(stripe.shard_file_name(base, s)) for s in survivors[5:]})
+    try:
+        stripe.rebuild_ec_files_from_sources(
+            base, sources, len(golden[0]), encoder=ENC, missing=LOST,
+            buffer_size=8192, max_batch_bytes=10 * 2 * 8192,
+        )
+    finally:
+        for src in sources.values():
+            src.close()
+    assert _shard_bytes(base) == golden
+    assert all(readers[s] == {me} for s in survivors[:5])
+    assert all(r.startswith("ec-lane") for r in readers["told"])
+
+
+def test_one_core_host_runs_every_task_inline(tmp_path, monkeypatch):
+    """os.cpu_count() == 1: lanes=0 on both run spans, and every read, write
+    and CRC fold runs on the calling thread."""
+    monkeypatch.setattr(os, "cpu_count", lambda: 1)
+    seen = set()
+    real_pread, real_crc = stripe.pread_padded_into, zlib.crc32
+
+    def pread(fd, offset, out):
+        seen.add(threading.current_thread().name)
+        real_pread(fd, offset, out)
+
+    def crc32(*a):
+        seen.add(threading.current_thread().name)
+        return real_crc(*a)
+
+    monkeypatch.setattr(stripe, "pread_padded_into", pread)
+    monkeypatch.setattr(stripe.zlib, "crc32", crc32)
+    got = _encode_then_rebuild(monkeypatch, tmp_path / "v", 300_000, 1, 2, 10 * 3 * 4096)
+    assert got[0] == 0 and got[1] == 0 and got[3] == got[2]
+    assert seen == {threading.current_thread().name}
+
+
 # -- the bulk pipelines seen from inside: one stage catalog, every batch --------
 
 
-def _self_ms(sp):
-    return sp["dur_ms"] - sum(c["dur_ms"] for c in sp.get("spans", ()))
+#: what the calling thread records; reads, writes and CRC folds are the lanes'
+CALLING = ("stage", "dispatch", "drain", "sync", "wait", "verify")
+
+
+def _calling_self_ms(sp):
+    """A calling-thread span's duration less its children on that thread
+    (its children on lane threads run beside it, not inside it)."""
+    return sp["dur_ms"] - sum(
+        c["dur_ms"] for c in sp.get("spans", ()) if c["name"].rsplit(".", 1)[-1] in CALLING
+    )
 
 
 @pytest.mark.parametrize("pipeline", ["encode", "rebuild"])
 def test_bulk_stage_spans_account_for_a_run(tmp_path, monkeypatch, pipeline):
-    """Through the real write_ec_files / rebuild_ec_files: the run's span
-    tree holds every stage once per batch (a write and a CRC per shard and
-    batch), `bytes` over the writes is what the files hold and over the reads
-    what was staged, the stages' self times account for the run, and the
-    shards are those of a run with WEEDTPU_TRACE=off."""
+    """Through the real write_ec_files / rebuild_ec_files, on the lanes: the
+    run's span tree holds every stage once per batch (a read per source
+    shard, a write and a CRC per shard written), `bytes` over the writes is
+    what the files hold and over the reads what was staged, a lane's spans
+    hang under the stage or drain that queued them, the calling thread's own
+    spans account for the run's wall, the run says how many lanes it had,
+    and the shards are those of a run with WEEDTPU_TRACE=off."""
     from seaweedfs_tpu.obs import trace
 
     # a run's wall must be its stages', not this box's spiky fsync of the .eci
     monkeypatch.setattr(os, "fsync", lambda fd: None)
+    monkeypatch.setattr(os, "cpu_count", lambda: 8)
     lost = [0, 3, 11, 13]
     sizes = dict(large_block_size=1 << 20, small_block_size=1 << 16)
     batch = 10 * 4 * (1 << 16)  # four 64 KiB segments wide
@@ -390,9 +705,12 @@ def test_bulk_stage_spans_account_for_a_run(tmp_path, monkeypatch, pipeline):
     shard_size = len(shards[0])
     batches = -(-shard_size // (4 * (1 << 16)))
     assert batches == 3 and root["attrs"]["batches"] == batches
+    assert root["attrs"]["lanes"] == 7
     per_shard = TOTAL_SHARDS_COUNT if pipeline == "encode" else len(lost)
-    want = {f"{pipeline}.{s}": batches for s in ("stage", "read", "dispatch", "drain", "sync")}
+    want = {f"{pipeline}.{s}": batches for s in ("stage", "dispatch", "drain", "sync")}
     want.update({f"{pipeline}.write": per_shard * batches, f"{pipeline}.crc": per_shard * batches})
+    want[f"{pipeline}.read"] = 10 * batches  # one per data shard / survivor and batch
+    want[f"{pipeline}.wait"] = 2 * batches + 1  # a stage's reads, a drain's join, the run's end
     if pipeline == "rebuild":
         want["rebuild.verify"] = 1
     assert count == want
@@ -408,9 +726,20 @@ def test_bulk_stage_spans_account_for_a_run(tmp_path, monkeypatch, pipeline):
     else:
         assert root["attrs"]["bytes"] == len(lost) * shard_size
         assert bytes_of("rebuild.read") == 10 * shard_size  # ten survivor slabs (a whole number of buffers here)
-    # drain means the same in both: the sync, the writes and the CRCs are its children
+
+    def names(sp):
+        return sorted(c["name"].rsplit(".", 1)[-1] for c in sp["spans"])
+
+    # drain means the same in both: the sync first, the join of what the lanes
+    # still hold, then the written shards' writes and CRCs, queued from here
+    written = per_shard - 10 if pipeline == "encode" else len(lost)
     for drain in (s for s in spans if s["name"] == f"{pipeline}.drain"):
-        assert [c["name"] for c in drain["spans"]][0] == f"{pipeline}.sync"
-        assert {c["name"] for c in drain["spans"][1:]} == {f"{pipeline}.write", f"{pipeline}.crc"}
-    named = sum(_self_ms(s) for s in spans)
-    assert named >= 0.9 * root["dur_ms"], (named, root["dur_ms"])
+        assert [c["name"] for c in drain["spans"]][:2] == [f"{pipeline}.sync", f"{pipeline}.wait"]
+        assert names(drain) == sorted(["sync", "wait"] + ["write", "crc"] * written)
+    # a stage holds its ten reads and the wait for them; an encode's also its
+    # data shards' writes and CRCs, which run on beside the dispatch
+    data = ["write", "crc"] * 10 if pipeline == "encode" else []
+    for stage in (s for s in spans if s["name"] == f"{pipeline}.stage"):
+        assert names(stage) == sorted(["read"] * 10 + ["wait"] + data)
+    calling = sum(_calling_self_ms(s) for s in spans if s["name"].rsplit(".", 1)[-1] in CALLING)
+    assert calling >= 0.9 * root["dur_ms"], (calling, root["dur_ms"])
