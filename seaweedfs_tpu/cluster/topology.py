@@ -33,6 +33,7 @@ class DataNode:
         self.max_volume_count = hb.max_volume_count
         self.volumes: dict[int, VolumeInformation] = {}
         self.ec_shards: dict[int, ShardBits] = {}
+        self.ec_backend: dict = dict(hb.ec_backend)
         self.last_seen = time.monotonic()
 
     @property
@@ -53,7 +54,7 @@ class DataNode:
         return self.max_volume_count - len(self.volumes) - len(self.ec_shards)
 
     def to_dict(self) -> dict:
-        return {
+        out = {
             "url": self.url,
             "public_url": self.public_url,
             "grpc_port": self.grpc_port,
@@ -66,6 +67,9 @@ class DataNode:
                 for vid, bits in self.ec_shards.items()
             ],
         }
+        if self.ec_backend:
+            out["ec_backend"] = dict(self.ec_backend)
+        return out
 
 
 class VolumeLayout:
@@ -147,6 +151,7 @@ class Topology:
             node.public_url = hb.public_url or hb.url
             node.data_center = hb.data_center
             node.rack = hb.rack
+            node.ec_backend = dict(hb.ec_backend)
 
             new_volumes = {}
             for vd in hb.volumes:

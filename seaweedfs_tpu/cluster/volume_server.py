@@ -333,6 +333,7 @@ class VolumeServer:
             volumes=self.store.volume_infos(),
             ec_shards=[i.to_dict() for i in self.store.ec_volume_infos()],
             unreachable_peers=self._unreachable_peers(),
+            ec_backend=self._ec_backend_wire(),
         )
 
     def _masters_fanout(self, method: str, req: dict, timeout: float) -> int:
@@ -1608,33 +1609,52 @@ class VolumeServer:
         shard_ids = [int(s) for s in req.get("shard_ids", [])]
         src = req["source_data_node"]  # grpc address host:port
         base = self._base_path_for(vid, collection)
-        with rpc.RpcClient(src) as c:
+        pulled = 0
+        with trace_mod.span(
+            "ec.copy", source=src, shards=",".join(map(str, shard_ids))
+        ) as sp, rpc.RpcClient(src) as c:
             names = [stripe.to_ext(s) for s in shard_ids]
             if req.get("copy_ecx_file", True):
                 names += _EC_EXTS
             for name in names:
                 try:
-                    chunks = c.stream(
-                        VOLUME_SERVICE,
-                        "VolumeEcShardFileCopy",
-                        {"volume_id": vid, "collection": collection, "ext": name},
-                    )
-                    tmp = base + name + ".cpy"
-                    try:
-                        with open(tmp, "wb") as f:
-                            for chunk in chunks:
-                                f.write(chunk)
-                            f.flush()
-                            os.fsync(f.fileno())
-                        os.replace(tmp, base + name)
-                    finally:
-                        if os.path.exists(tmp):
-                            os.remove(tmp)
+                    pulled += self._pull_ec_file(c, vid, collection, base, name)
                 except Exception:
                     if name in (".ecj", ".eci"):  # optional files
                         continue
                     raise
+            if sp is not None:
+                sp.annotate(bytes=pulled)
         return {}
+
+    @staticmethod
+    def _pull_ec_file(c, vid: int, collection: str, base: str, ext: str) -> int:
+        """Stream one file of the source's into `base + ext` (staged as
+        `.cpy`, fsynced, renamed) and count it as pulled; -> its bytes."""
+        with trace_mod.span("ec.copy.file", ext=ext) as sp:
+            chunks = c.stream(
+                VOLUME_SERVICE,
+                "VolumeEcShardFileCopy",
+                {"volume_id": vid, "collection": collection, "ext": ext},
+            )
+            tmp = base + ext + ".cpy"
+            size = 0
+            try:
+                with open(tmp, "wb") as f:
+                    for chunk in chunks:
+                        f.write(chunk)
+                        size += len(chunk)
+                    with trace_mod.span("ec.copy.fsync"):
+                        f.flush()
+                        os.fsync(f.fileno())
+                os.replace(tmp, base + ext)
+            finally:
+                if os.path.exists(tmp):
+                    os.remove(tmp)
+            stats.EcCopyBytes.labels("pulled").inc(size)
+            if sp is not None:
+                sp.annotate(bytes=size)
+            return size
 
     # -- inline-ingest parity spreading (WEEDTPU_INLINE_EC_SPREAD) -----------
 
@@ -1803,16 +1823,22 @@ class VolumeServer:
         lock = self.maintenance_lock(vid) if req["ext"] in (".dat", ".idx") else None
         if lock is not None:
             lock.acquire()
+        served = 0
         try:
             if not os.path.exists(path):
                 raise rpc.NotFoundFault(f"{path} not found")
-            with open(path, "rb") as f:
+            with trace_mod.span("ec.copy.serve", ext=req["ext"]) as sp, \
+                    open(path, "rb") as f:
                 while True:
                     chunk = f.read(_COPY_CHUNK)
                     if not chunk:
                         break
+                    served += len(chunk)
                     yield chunk
+                if sp is not None:
+                    sp.annotate(bytes=served)
         finally:
+            stats.EcCopyBytes.labels("served").inc(served)
             if lock is not None:
                 lock.release()
 
@@ -1834,13 +1860,15 @@ class VolumeServer:
                 rebuilt = stripe.rebuild_ec_files(
                     base, encoder=stripe.encoder_for_base(base, self.store.encoder)
                 )
-                stats.EcRebuildSeconds.observe(time.monotonic() - t0)
-                return {"rebuilt_shard_ids": rebuilt}
-            resp = self._ec_rebuild_remote(vid, collection, base, req)
-            trace_mod.annotate(
-                mode=resp.get("mode"), wire_bytes=resp.get("wire_bytes")
-            )
+                resp = {"rebuilt_shard_ids": rebuilt}
+            else:
+                resp = self._ec_rebuild_remote(vid, collection, base, req)
+                trace_mod.annotate(
+                    mode=resp.get("mode"), wire_bytes=resp.get("wire_bytes")
+                )
         stats.EcRebuildSeconds.observe(time.monotonic() - t0)
+        if resp.get("rebuilt_shard_ids"):
+            stats.EcRebuildRuns.labels(self.store.encoder.backend).inc()
         return resp
 
     def _ec_rebuild_remote(
@@ -2146,6 +2174,8 @@ class VolumeServer:
                         self.store.mount_ec_volume(m["vid"], base)
                 except Exception as e:  # noqa: BLE001 — rebuilt but dark
                     err = f"mount failed: {e}"[:300]
+            if rebuilt:
+                stats.EcRebuildRuns.labels(self.store.encoder.backend).inc()
             results.append(
                 {
                     "volume_id": m["vid"],

@@ -480,6 +480,7 @@ class RepairScheduler:
                     "ec_load": sum(
                         b.shard_id_count() for b in n.ec_shards.values()
                     ),
+                    "ec_backend": dict(n.ec_backend),
                 }
                 for u, n in topo.nodes.items()
                 if self._holder_live(n, now)

@@ -27,6 +27,21 @@ from typing import Optional, Sequence
 #: count; this is only the fallback when geometry is unknown (10+4).
 DEFAULT_PARITY = 4
 
+#: the codec backends that run on a device: `ops.rs_codec.DEVICE_BACKENDS`,
+#: repeated because the shell child imports this module inside its timed
+#: commands and rs_codec brings numpy and the GF tables (0.1-0.7 s)
+DEVICE_BACKENDS = ("jax", "pallas", "mesh")
+
+
+def runs_device_codec(node: dict) -> bool:
+    """True where the node's own report (`ec_backend`, carried by its
+    heartbeat: backend + the device jax gave it) says its codec runs on
+    a device. A node that reports nothing — an old server, a host
+    codec — is False, so a cluster without such a report ranks exactly
+    as it did before the report existed."""
+    brief = node.get("ec_backend") or {}
+    return brief.get("backend") in DEVICE_BACKENDS and bool(brief.get("device"))
+
 
 def domain_of(node: dict) -> tuple[str, str]:
     """One node's failure-domain identity: (data_center, rack). Rack is
@@ -179,9 +194,13 @@ def pick_rebuild_target(
     """Choose the node a whole-stripe rebuild should land on. Rebuilt
     shards all materialize on the target, so the constraint is
     (shards the target's rack already holds) + |missing| <= cap;
-    among compliant nodes prefer the one already holding the MOST of
-    this stripe's shards (fewest survivor slabs over the wire), then
-    the least EC-loaded, then url. Falls back to the least-loaded
+    among compliant nodes prefer one whose codec runs on a device
+    (`runs_device_codec`: the decode is the chip's work, and a rebuild
+    that lands beside an idle chip leaves it idle), then the one already
+    holding the MOST of this stripe's shards (fewest survivor slabs over
+    the wire), then the least EC-loaded, then url. The shell's
+    `ec.rebuild` and the master's scheduler both choose through here.
+    Falls back to the least-loaded
     compliant-less node when no rack has headroom (small topologies) —
     repairing with a violation beats not repairing — unless `strict`,
     which returns None instead of violating (used when probing whether
@@ -202,10 +221,16 @@ def pick_rebuild_target(
         return sum(1 for sids in holders.values() for h in sids if h == u)
 
     def key(n: dict):
-        # most of THIS stripe's shards first (fewest survivor slabs over
-        # the wire), then the node's cluster-wide EC load when the caller
-        # supplies it (`ec_load` on the node dict), then url
-        return (-local_shards(n), int(n.get("ec_load", 0)), n["url"])
+        # a device codec first, then most of THIS stripe's shards (fewest
+        # survivor slabs over the wire), then the node's cluster-wide EC
+        # load when the caller supplies it (`ec_load` on the node dict),
+        # then url
+        return (
+            not runs_device_codec(n),
+            -local_shards(n),
+            int(n.get("ec_load", 0)),
+            n["url"],
+        )
 
     compliant = [
         n

@@ -66,6 +66,10 @@ SPAN_NAMES: dict[str, str] = {
     "ec.decode": "GF decode dispatch (backend + batch width in attrs)",
     "cache.hit": "interval served from the decoded-interval cache (no fan-out)",
     "cache.miss": "decoded-interval cache consulted and empty for this interval",
+    "ec.copy": "VolumeEcShardsCopy on the puller: every named file of one source (source, shards, bytes)",
+    "ec.copy.file": "one file of a copy: its stream from the source written to `.cpy`, then fsync + rename (ext, bytes); self time is the stream",
+    "ec.copy.fsync": "flush + fsync of one copied file, apart from its stream",
+    "ec.copy.serve": "VolumeEcShardFileCopy on the source: one file read and streamed out (ext, bytes)",
     "rebuild.run": "one whole-volume rebuild (local or distributed)",
     "rebuild.stage": "staging-ring fill for one rebuild batch (disk/wire)",
     "rebuild.read": "one survivor's slab read into its staging row (child of rebuild.stage; on a lane thread where the source allows)",

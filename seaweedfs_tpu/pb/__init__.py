@@ -119,9 +119,17 @@ class Heartbeat:
     # cross-checks them against heartbeat silence to learn about dead
     # holders without waiting for the topology reaper
     unreachable_peers: list[str] = field(default_factory=list)
+    # which codec backend this server's encoder runs and on which device
+    # (VolumeStatus's `ec_backend` brief): the rebuild-target rule of the
+    # shell and of the scheduler prefers a node whose codec runs on a
+    # device, and learns it here. Empty from a server that predates it.
+    ec_backend: dict = field(default_factory=dict)
 
     def to_dict(self) -> dict:
-        return asdict(self)
+        d = asdict(self)
+        if not d["ec_backend"]:
+            del d["ec_backend"]
+        return d
 
     @classmethod
     def from_dict(cls, d: dict) -> "Heartbeat":
@@ -136,6 +144,7 @@ class Heartbeat:
             volumes=list(d.get("volumes", [])),
             ec_shards=list(d.get("ec_shards", [])),
             unreachable_peers=list(d.get("unreachable_peers", [])),
+            ec_backend=dict(d.get("ec_backend") or {}),
         )
 
     @property

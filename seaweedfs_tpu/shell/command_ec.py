@@ -107,12 +107,27 @@ def allocate_shards(
     )
 
 
+def _in_trace(fn):
+    """`fn` for a pool thread, under the submitting thread's ambient span:
+    ContextVars do not cross a pool's submission, and an RPC sent without
+    the command's trace id is recorded by no server."""
+    from seaweedfs_tpu.obs import trace as trace_obs
+
+    parent = trace_obs.current()
+
+    def run(*args, **kw):
+        with trace_obs.attach(parent):
+            return fn(*args, **kw)
+
+    return run
+
+
 def _parallel(work: list) -> None:
     """Run thunks concurrently, re-raising the first failure."""
     if not work:
         return
     with futures.ThreadPoolExecutor(max_workers=_POOL) as pool:
-        for f in [pool.submit(t) for t in work]:
+        for f in [pool.submit(_in_trace(t)) for t in work]:
             f.result()
 
 
@@ -384,7 +399,7 @@ def _copy_missing_to(env: CommandEnv, node: dict, vid: int, collection: str,
     with futures.ThreadPoolExecutor(max_workers=min(_POOL, max(1, len(jobs)))) as pool:
         futs = {
             pool.submit(
-                env.vs_call,
+                _in_trace(env.vs_call),
                 grpc_addr(node),
                 "VolumeEcShardsCopy",
                 {
@@ -417,6 +432,30 @@ def _ec_collections(env: CommandEnv) -> dict[int, str]:
     }
 
 
+def pick_rebuilder(
+    nodes: list[dict], holders: dict[int, list[dict]], missing: list[int], parity: int
+) -> dict:
+    """Where one volume's rebuild lands: `placement.pick_rebuild_target`, the
+    ONE definition the master's scheduler (ec/fleet.py) uses too — a node
+    whose codec runs on a device, then the one already holding the most
+    shards (fewest copies — or, in -remote mode, the fewest slabs streamed
+    over the network), then the least EC-loaded, then url. `nodes` are
+    `env.topology_nodes()` dicts: each carries its rack, its shards and
+    the `ec_backend` its heartbeat reported."""
+    from seaweedfs_tpu.ec import placement
+    from seaweedfs_tpu.utils import config as _config
+
+    return placement.pick_rebuild_target(
+        # equal holders rank by cluster-wide EC load, as the scheduler feeds it
+        [dict(n, ec_load=_node_ec_load(n)) for n in nodes],
+        {sid: [h["url"] for h in hs] for sid, hs in holders.items()},
+        {n["url"]: placement.domain_of(n) for n in nodes},
+        missing,
+        parity,
+        cap_override=int(_config.env("WEEDTPU_PLACEMENT_MAX_PER_DOMAIN")),
+    )
+
+
 def do_ec_rebuild(args: list[str], env: CommandEnv, w: TextIO) -> None:
     fl = parse_flags(args, collection="", remote=False, trace="auto")
     trace_mode = str(fl.trace).strip().lower()
@@ -433,17 +472,17 @@ def do_ec_rebuild(args: list[str], env: CommandEnv, w: TextIO) -> None:
     for vid in ec_vids:
         collection = colls.get(vid, "")
         holders = _shard_holders(nodes, vid)
-        # rebuilder = node already holding the most shards (fewest copies —
-        # or, in -remote mode, the fewest slabs streamed over the network)
-        rebuilder = max(nodes, key=lambda n: len(_node_shards_of(n, vid)))
-        addr = grpc_addr(rebuilder)
         # geometry-flexible volumes (ec.convert targets) record their own
         # (k, k+m): missing-shard detection over the legacy 14 would never
         # see a lost shard id >= 14 of a 20+4 volume, and the survivor
-        # gate would mis-assess 12+3. Old servers report 0 -> legacy.
+        # gate would mis-assess 12+3. Any holder knows it; old servers
+        # report 0 -> legacy.
         k, total = DATA_SHARDS_COUNT, TOTAL_SHARDS_COUNT
+        witness = max(nodes, key=lambda n: len(_node_shards_of(n, vid)))
         try:
-            st = env.vs_call(addr, "VolumeStatus", {"volume_id": vid}, timeout=10)
+            st = env.vs_call(
+                grpc_addr(witness), "VolumeStatus", {"volume_id": vid}, timeout=10
+            )
             k = int(st.get("data_shards") or 0) or k
             total = int(st.get("total_shards") or 0) or total
         except Exception:  # noqa: BLE001 — unknown geometry: legacy bounds
@@ -457,6 +496,8 @@ def do_ec_rebuild(args: list[str], env: CommandEnv, w: TextIO) -> None:
                 f"need {k} — data LOST\n"
             )
             continue
+        rebuilder = pick_rebuilder(nodes, holders, missing, max(1, total - k))
+        addr = grpc_addr(rebuilder)
         if fl.remote:
             # distributed path: NO bulk survivor pre-copy. The rebuilder
             # streams survivor input from peer holders while decoding —

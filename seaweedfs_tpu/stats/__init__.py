@@ -259,6 +259,21 @@ EcRebuildSeconds = REGISTRY.histogram(
     "weedtpu_ec_rebuild_seconds",
     "wall time of whole-shard ec.rebuild runs (local or remote survivors)",
 )
+EcRebuildRuns = REGISTRY.counter(
+    "weedtpu_ec_rebuild_runs_total",
+    "volumes whose missing shards THIS server rebuilt, by the codec backend "
+    "its store runs (one per VolumeEcShardsRebuild that rebuilt something, "
+    "one per volume of a batch): where a cluster's decodes really ran",
+    ("backend",),
+)
+EcCopyBytes = REGISTRY.counter(
+    "weedtpu_ec_copy_bytes_total",
+    "bytes of shard and index files moved whole between servers: `pulled` = "
+    "written here by VolumeEcShardsCopy (the spread of ec.encode, the gather "
+    "of ec.rebuild), `served` = streamed out by VolumeEcShardFileCopy; their "
+    "seconds are weedtpu_rpc_server_seconds{method} of those two RPCs",
+    ("side",),
+)
 EcRebuildRemoteBytes = REGISTRY.counter(
     "weedtpu_ec_rebuild_remote_bytes_total",
     "survivor bytes fetched from peer holders by distributed rebuilds",

@@ -1,0 +1,421 @@
+"""`ec.rebuild` over a cluster, as clusters run EC: two volumes encoded and
+spread by the shell over four servers in four racks, one server lost, the
+flagless command. Small sizes, on the CPU, compared with a plain reference
+(striping as arithmetic on the original `.dat`, numpy GF(2^8) of
+`benchmark/reference/gf8_ref.py`, which imports nothing of the program).
+
+Also: ONE definition of where a rebuild lands. The shell (`pick_rebuilder`) and
+the master's scheduler both choose through `placement.pick_rebuild_target`,
+whose first key is "runs a device codec", learnt from each server's heartbeat.
+"""
+
+import importlib.util
+import io
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+from seaweedfs_tpu import stats
+from seaweedfs_tpu.cluster.client import MasterClient
+from seaweedfs_tpu.cluster.master import MasterServer
+from seaweedfs_tpu.cluster.volume_server import VolumeServer
+from seaweedfs_tpu.ec import placement, stripe
+from seaweedfs_tpu.ec.fleet import RepairScheduler
+from seaweedfs_tpu.obs import trace
+from seaweedfs_tpu.ops.rs_codec import new_encoder
+from seaweedfs_tpu.pb import Heartbeat
+from seaweedfs_tpu.shell import CommandEnv, command_ec, run_script
+from seaweedfs_tpu.storage.needle import Needle
+from seaweedfs_tpu.storage.volume import Volume
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_spec = importlib.util.spec_from_file_location(
+    "gf8_ref", os.path.join(ROOT, "benchmark", "reference", "gf8_ref.py"))
+gf8_ref = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(gf8_ref)
+
+LARGE, SMALL = 16384, 4096
+DATA, PARITY = 10, 4
+VIDS = (1, 2)
+JAX_BRIEF = {"backend": "jax", "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+
+
+def _wait_for(cond, timeout=30.0, msg="condition"):
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if cond():
+            return
+        time.sleep(0.02)
+    raise AssertionError(f"timeout waiting for {msg}")
+
+
+def _write_volume(directory, vid, seed):
+    """A sealed volume of seeded needles, under 10 x LARGE so that every row
+    is a small-block row. -> [(fid, payload)]"""
+    rng = np.random.default_rng([seed, vid])
+    out = []
+    with Volume(directory, vid) as v:
+        for key in range(1, 25):
+            payload = rng.bytes(int(rng.integers(500, 6000)))
+            cookie = int(rng.integers(0, 1 << 32))
+            v.write_needle(Needle(cookie=cookie, id=key, data=payload))
+            out.append((f"{vid},{key:x}{cookie:08x}", payload))
+    return out
+
+
+def _reference_shards(dat: bytes) -> list[bytes]:
+    """All 14 shards of a `.dat` of small-block rows, by the striping rule and
+    the reference's parity."""
+    assert len(dat) < DATA * LARGE
+    rows = -(-len(dat) // (DATA * SMALL))
+    cells = np.zeros(rows * DATA * SMALL, dtype=np.uint8)
+    cells[:len(dat)] = np.frombuffer(dat, dtype=np.uint8)
+    cells = cells.reshape(rows, DATA, SMALL)
+    pm = gf8_ref.parity_matrix(DATA, PARITY)
+    shards = [cells[:, s, :].tobytes() for s in range(DATA)]
+    parity = [gf8_ref.gf_mat_vec(pm, cells[r]) for r in range(rows)]
+    shards += [b"".join(parity[r][p].tobytes() for r in range(rows)) for p in range(PARITY)]
+    return shards
+
+
+class Cluster:
+    """master + four volume servers, each a rack of its own; server 0 starts
+    with both volumes. `reports[i]` is what server i's heartbeat says of its
+    codec: "jax" (a real jax encoder, on the CPU here), "host" (numpy) or
+    "nothing" (a server that predates the report)."""
+
+    def __init__(self, tmp_path, reports):
+        self.master = MasterServer(port=0, reap_interval=3600)
+        self.master.start()
+        self.dirs = [str(tmp_path / f"srv{i}") for i in range(4)]
+        for d in self.dirs:
+            os.makedirs(d)
+        self.needles = {vid: _write_volume(self.dirs[0], vid, seed=28) for vid in VIDS}
+        self.reference = {}
+        for vid in VIDS:
+            with open(os.path.join(self.dirs[0], f"{vid}.dat"), "rb") as f:
+                self.reference[vid] = _reference_shards(f.read())
+        self.servers = [self._server(i, r) for i, r in enumerate(reports)]
+        self.client = MasterClient(self.master.address)
+        self.env = CommandEnv(self.master.address)
+        _wait_for(lambda: len(self.master.topology.nodes) == 4, msg="four servers joined")
+
+    def _server(self, i, report):
+        vs = VolumeServer(
+            [self.dirs[i]], self.master.address, heartbeat_interval=0.2, rack=f"r{i}",
+            max_volume_count=20,
+            encoder=new_encoder(backend="jax" if report == "jax" else "numpy"),
+        )
+        if report == "nothing":
+            vs._ec_backend_wire = dict
+        vs.start()
+        return vs
+
+    def shell(self, script):
+        out = io.StringIO()
+        run_script(self.env, script, out)
+        return out.getvalue()
+
+    def encode_and_spread(self):
+        self.shell("lock; " + "; ".join(
+            f"ec.encode -volumeId {v} -force -largeBlockSize {LARGE} -smallBlockSize {SMALL}"
+            for v in VIDS) + "; unlock")
+
+    def held(self, vid):
+        """url -> shard ids, as the master lists them."""
+        out = {}
+        for sid, nodes in self.master.topology.lookup_ec_shards(vid).items():
+            for n in nodes:
+                out.setdefault(n.url, set()).add(sid)
+        return out
+
+    def lose(self, i):
+        victim = self.servers[i]
+        victim.stop()
+        _wait_for(lambda: all(victim.url not in self.held(v) for v in VIDS), msg="the master dropped the lost server")
+
+    def close(self):
+        self.env.close()
+        self.client.close()
+        for vs in self.servers:
+            try:
+                vs.stop()
+            except Exception:  # noqa: BLE001 — the lost one is stopped already
+                pass
+        self.master.stop()
+
+
+@pytest.fixture
+def make_cluster(tmp_path, monkeypatch):
+    monkeypatch.setenv("WEEDTPU_TRACE", "on")
+    monkeypatch.setenv("WEEDTPU_TRACE_SAMPLE", "1.0")
+    made = []
+
+    def make(reports):
+        c = Cluster(tmp_path, reports)
+        made.append(c)
+        return c
+
+    yield make
+    for c in made:
+        c.close()
+
+
+def _rebuilders(output):
+    """volume -> the url `ec.rebuild`'s output names as its rebuilder."""
+    return {int(v): url for v, url in
+            re.findall(r"^ec\.rebuild volume (\d+): rebuilt \[[0-9, ]*\] on (\S+)", output, re.M)}
+
+
+@pytest.mark.parametrize("lost", [0, 1, 2, 3])
+def test_one_server_lost_is_rebuilt_on_the_device_server(make_cluster, lost):
+    """Each server in turn is lost. The server that reports a device codec is
+    the rebuilder for both volumes, whatever it holds; every lost shard comes
+    back byte-identical to the reference's; every needle reads back; the gather
+    moved exactly the files the rebuilder lacked and left none behind."""
+    device = 1 if lost != 1 else 2
+    reports = ["host"] * 4
+    reports[device] = "jax"
+    c = make_cluster(reports)
+    c.encode_and_spread()
+    spread = {vid: c.held(vid) for vid in VIDS}
+    for vid in VIDS:
+        assert sorted(len(s) for s in spread[vid].values()) == [3, 3, 4, 4]
+        assert sorted(s for ss in spread[vid].values() for s in ss) == list(range(14))
+    urls = [vs.url for vs in c.servers]
+    assert all(sum(len(spread[vid][u]) for vid in VIDS) == 7 for u in urls)
+    # the shards as ec.encode wrote them are the reference's
+    for vid in VIDS:
+        for i, u in enumerate(urls):
+            for s in spread[vid][u]:
+                with open(stripe.shard_file_name(os.path.join(c.dirs[i], str(vid)), s), "rb") as f:
+                    assert f.read() == c.reference[vid][s], (vid, s)
+
+    c.lose(lost)
+    rebuilder = c.servers[device]
+    base = {vid: os.path.join(c.dirs[device], str(vid)) for vid in VIDS}
+    lacked = {vid: set(range(14)) - spread[vid][urls[lost]] - spread[vid][rebuilder.url] for vid in VIDS}
+    expect_bytes = sum(len(c.reference[vid][s]) for vid in VIDS for s in lacked[vid])
+    pulled0 = stats.EcCopyBytes.labels("pulled").value
+    served0 = stats.EcCopyBytes.labels("served").value
+    secs0 = stats.RpcServerSeconds.labels("VolumeEcShardsCopy").sum
+    runs0 = stats.EcRebuildRuns.labels("jax").value
+    host_runs0 = stats.EcRebuildRuns.labels("numpy").value
+    trace.RING.clear()
+
+    out = c.shell("lock; ec.rebuild; unlock")
+
+    assert _rebuilders(out) == {vid: rebuilder.url for vid in VIDS}, out
+    assert stats.EcRebuildRuns.labels("jax").value - runs0 == len(VIDS)
+    assert stats.EcRebuildRuns.labels("numpy").value == host_runs0
+    assert stats.EcCopyBytes.labels("pulled").value - pulled0 == expect_bytes
+    assert stats.EcCopyBytes.labels("served").value - served0 == expect_bytes
+    assert stats.RpcServerSeconds.labels("VolumeEcShardsCopy").sum > secs0
+    for vid in VIDS:
+        lost_ids = spread[vid][urls[lost]]
+        for s in lost_ids:
+            with open(stripe.shard_file_name(base[vid], s), "rb") as f:
+                assert f.read() == c.reference[vid][s], f"volume {vid} shard {s} differs from the reference"
+        # no survivor copy left behind, nothing half-copied
+        assert set(stripe.find_local_shards(base[vid])) == spread[vid][rebuilder.url] | lost_ids
+        assert sorted(c.master.topology.lookup_ec_shards(vid)) == list(range(14))
+    assert not [n for n in os.listdir(c.dirs[device]) if n.endswith(".cpy")]
+    for vid in VIDS:
+        for fid, payload in c.needles[vid]:
+            assert c.client.read(fid) == payload
+    # every new span is recorded, under the RPC it belongs to
+    by_method = {}
+    for t in trace.RING.snapshot(kind="rpc.server", limit=100000):
+        names = {s["name"] for s in trace.iter_spans(t)}
+        by_method.setdefault(t["root"]["attrs"].get("method"), set()).update(names)
+    assert {"ec.copy", "ec.copy.file", "ec.copy.fsync"} <= by_method["VolumeEcShardsCopy"]
+    assert "ec.copy.serve" in by_method["VolumeEcShardFileCopy"]
+    assert {"ec.copy", "ec.copy.file", "ec.copy.fsync", "ec.copy.serve"} <= set(trace.SPAN_NAMES)
+
+
+@pytest.mark.parametrize("reports", ["host", "nothing"])
+def test_without_a_device_report_the_choice_is_the_old_one(make_cluster, reports):
+    """No server reports a device codec (host codecs, or servers that report
+    nothing at all): the rebuilder is a survivor holding the most shards of the
+    stripe, as before, and the scheduler's view of the same topology gives the
+    same node."""
+    c = make_cluster([reports] * 4)
+    c.encode_and_spread()
+    spread = {vid: c.held(vid) for vid in VIDS}
+    c.lose(3)
+    sched = RepairScheduler(c.master, max_inflight=1, batch=4, scan_interval=60.0, settle=0.0)
+    nodes, registry, domains, _, _ = sched._topology_view()
+    assert all(not n["ec_backend"] or n["ec_backend"]["backend"] == "numpy" for n in nodes)
+    out = c.shell("lock; ec.rebuild; unlock")
+    chosen = _rebuilders(out)
+    lost_url = c.servers[3].url
+    for vid in VIDS:
+        survivors = {u: len(s) for u, s in spread[vid].items() if u != lost_url}
+        assert survivors[chosen[vid]] == max(survivors.values())
+        missing = sorted(spread[vid][lost_url])
+        target = placement.pick_rebuild_target(nodes, registry[vid], domains, missing, PARITY)
+        assert target["url"] == chosen[vid]
+        for s in missing:
+            base = os.path.join(c.dirs[[vs.url for vs in c.servers].index(chosen[vid])], str(vid))
+            with open(stripe.shard_file_name(base, s), "rb") as f:
+                assert f.read() == c.reference[vid][s]
+
+
+# -- the rule itself ------------------------------------------------------------
+
+
+def _nodes(racks=("r0", "r1", "r2")):
+    return [{"url": f"n{i}:80", "data_center": "dc", "rack": r, "ec_load": 7} for i, r in enumerate(racks)]
+
+
+def _stripe(nodes, counts):
+    """holders of one stripe: node i holds counts[i] shards; the rest are lost."""
+    holders, sid = {}, 0
+    for n, k in zip(nodes, counts):
+        for _ in range(k):
+            holders[sid] = [n["url"]]
+            sid += 1
+    return holders, list(range(sid, 14))
+
+
+# One server of four is lost, so no surviving rack has headroom for the rebuilt
+# shards: every survivor is a candidate, as in the deployment.
+PICK_CASES = {
+    # name: (shards held per node, which node reports what, expected node[, racks])
+    "device_beats_more_shards": ((4, 4, 3), {2: JAX_BRIEF}, 2),
+    "device_with_no_shard_of_the_stripe": ((4, 4, 3, 0), {3: JAX_BRIEF}, 3, ("r0", "r1", "r2", "r0")),
+    "two_devices_most_shards_wins": ((3, 4, 4), {0: JAX_BRIEF, 2: JAX_BRIEF}, 2),
+    "host_codec_is_no_device": ((3, 4, 4), {0: {"backend": "xorsched"}}, 1),
+    "device_backend_without_a_device": ((3, 4, 4), {0: {"backend": "jax"}}, 1),
+    "mesh_counts_as_a_device_codec": ((4, 3, 4), {1: {"backend": "mesh", "device": {"platform": "tpu"}}}, 1),
+    "nobody_reports": ((3, 4, 4), {}, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PICK_CASES))
+def test_pick_rebuild_target_prefers_a_device_codec(case):
+    counts, briefs, want, *racks = PICK_CASES[case]
+    nodes = _nodes(*racks)
+    for i, b in briefs.items():
+        nodes[i]["ec_backend"] = b
+    holders, missing = _stripe(nodes, counts)
+    domains = {n["url"]: placement.domain_of(n) for n in nodes}
+    got = placement.pick_rebuild_target(nodes, holders, domains, missing, PARITY)
+    assert got["url"] == nodes[want]["url"]
+    # a cluster that reports nothing, or only host codecs, ranks as the rule
+    # did before: most shards of the stripe, then url
+    bare = [{k: v for k, v in n.items() if k != "ec_backend"} for n in nodes]
+    host = [dict(n, ec_backend={"backend": "numpy"}) for n in bare]
+    before = min(bare, key=lambda n: (-sum(n["url"] in h for h in holders.values()), n["url"]))
+    assert (placement.pick_rebuild_target(bare, holders, domains, missing, PARITY)["url"]
+            == placement.pick_rebuild_target(host, holders, domains, missing, PARITY)["url"]
+            == before["url"])
+
+
+def test_placement_names_the_codecs_device_backends_and_imports_no_codec():
+    """The shell child imports `ec.placement` inside its timed commands: it
+    must not drag numpy and the GF tables in, so it repeats the tuple."""
+    from seaweedfs_tpu.ops import rs_codec
+
+    assert placement.DEVICE_BACKENDS == rs_codec.DEVICE_BACKENDS
+    code = ("import sys; from seaweedfs_tpu.shell import command_ec; from seaweedfs_tpu.ec import placement; "
+            "sys.exit(int('numpy' in sys.modules or 'seaweedfs_tpu.ops.rs_codec' in sys.modules))")
+    assert subprocess.run([sys.executable, "-c", code], cwd=ROOT, timeout=120).returncode == 0
+
+
+def test_the_domain_cap_still_comes_before_the_device():
+    """A device codec ranks nodes; it does not make an illegal placement legal:
+    where some rack has headroom for the rebuilt shards, the target is there."""
+    nodes = _nodes([f"r{i}" for i in range(8)])
+    nodes[0]["ec_backend"] = JAX_BRIEF
+    domains = {n["url"]: placement.domain_of(n) for n in nodes}
+    holders = {s: [nodes[s % 4]["url"]] for s in range(12)}  # racks r0-r3 hold 3 each
+    got = placement.pick_rebuild_target(nodes, holders, domains, [12, 13], PARITY)
+    assert got["url"] in {n["url"] for n in nodes[4:]}
+    nodes[5]["ec_backend"] = JAX_BRIEF
+    assert placement.pick_rebuild_target(nodes, holders, domains, [12, 13], PARITY)["url"] == nodes[5]["url"]
+
+
+@pytest.mark.parametrize("device", [None, 0, 2])
+def test_shell_and_scheduler_choose_the_same_target(device):
+    """The same topology, seen through VolumeList by the shell and through the
+    topology by the scheduler, gives one target: both learn the codec from the
+    heartbeat."""
+    from seaweedfs_tpu.ec.shard_bits import EcVolumeInfo, ShardBits
+
+    m = MasterServer(port=0, reap_interval=3600, http_port=None)
+    try:
+        spread = {0: [0, 4, 8, 12], 1: [1, 5, 9, 13], 2: [2, 6, 10]}  # 3, 7, 11 were on a lost server
+        for i, sids in spread.items():
+            info = EcVolumeInfo(volume_id=9, shard_bits=ShardBits.from_ids(sids), shard_size=1000,
+                                data_shards=DATA, total_shards=DATA + PARITY).to_dict()
+            m.topology.process_heartbeat(Heartbeat(
+                ip="127.0.0.1", port=8000 + i, grpc_port=18000 + i, rack=f"r{i}", data_center="dc",
+                max_volume_count=30, ec_shards=[info],
+                ec_backend=dict(JAX_BRIEF) if i == device else {"backend": "native"}))
+        sched = RepairScheduler(m, max_inflight=1, batch=4, scan_interval=60.0, settle=0.0)
+        nodes, registry, domains, _, _ = sched._topology_view()
+        missing = [3, 7, 11]
+        theirs = placement.pick_rebuild_target(nodes, registry[9], domains, missing, PARITY)
+        shell_nodes = [dict(nd, data_center=dc, rack=rack)
+                       for dc, racks in m.topology.to_dict()["data_centers"].items()
+                       for rack, nds in racks.items() for nd in nds]
+        ours = command_ec.pick_rebuilder(shell_nodes, command_ec._shard_holders(shell_nodes, 9), missing, PARITY)
+        assert ours["url"] == theirs["url"]
+        if device is not None:
+            assert ours["url"] == f"127.0.0.1:{8000 + device}"
+        else:
+            assert ours["url"] == "127.0.0.1:8000"  # most shards, then url: as before
+    finally:
+        m._server.stop()
+
+
+def test_the_heartbeat_carries_the_backend_on_both_wires():
+    from seaweedfs_tpu.pb import MASTER_SERVICE, wire
+
+    hb = Heartbeat(ip="127.0.0.1", port=1, grpc_port=2, ec_backend={
+        "backend": "jax", "source": "platform", "requested": "auto",
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}})
+    assert Heartbeat.from_dict(hb.to_dict()).ec_backend == hb.ec_backend
+    assert "ec_backend" not in Heartbeat(ip="127.0.0.1", port=1, grpc_port=2).to_dict()
+    ser, de = wire.codec().request_serdes(MASTER_SERVICE, "Heartbeat")
+    over_the_wire = Heartbeat.from_dict(de(ser(hb.to_dict()))).ec_backend
+    assert {k: v for k, v in over_the_wire.items() if v} == hb.ec_backend
+    assert placement.runs_device_codec({"ec_backend": over_the_wire})
+    bare = Heartbeat.from_dict(de(ser(Heartbeat(ip="h", port=1, grpc_port=2).to_dict())))
+    assert not placement.runs_device_codec({"ec_backend": bare.ec_backend})
+
+
+@pytest.mark.parametrize("fault,sound", [("", True), ("flip_shard_byte", False), ("broken_apply", False)])
+def test_the_benchmark_cell_rehearses_to_its_end_and_leaves_no_process(tmp_path, fault, sound):
+    """`run.py --workload spread10p4.rebuild-serverlost --rehearse`: the whole
+    cycle on the CPU with 8 MiB volumes, never a result. Sound, all checks
+    pass; with the control's fault (a rebuilt shard altered on disk) or the
+    timed path broken underneath (the device's apply), they do not. No peer
+    outlives the run either way."""
+    work = tmp_path / "tmp"
+    work.mkdir()
+    env = dict(os.environ, JAX_PLATFORMS="cpu", TMPDIR=str(work))
+    cmd = [sys.executable, os.path.join(ROOT, "benchmark", "run.py"), "--workload",
+           "spread10p4.rebuild-serverlost", "--seed", str(2**31 + 29), "--seconds", "1", "--trace", "0",
+           "--rehearse"]
+    p = subprocess.run(cmd + (["--fault", fault] if fault else []),
+                       cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 1, p.stdout[-3000:] + p.stderr[-3000:]
+    result = json.loads(p.stdout.strip().splitlines()[-1])
+    assert result["correct"] is False and result["rehearse"] is True
+    assert result["checks_ok"] is sound, p.stdout[-4000:]
+    assert result["attempted"] >= 1
+    if sound:
+        assert result["failed"] == 0 and result["metrics"]["rebuild_MBps"]["value"] > 0
+    # every peer is gone: no process still names this run's directories
+    left = subprocess.run(["pgrep", "-f", str(work)], capture_output=True, text=True).stdout.split()
+    assert not left, f"processes left behind: {left}"
+    shutil.rmtree(work, ignore_errors=True)
