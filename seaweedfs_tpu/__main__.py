@@ -10,11 +10,19 @@ from __future__ import annotations
 import argparse
 import sys
 
-from seaweedfs_tpu.command import commands
+from seaweedfs_tpu.command import COMMAND_TABLE, commands, load_command
 
 
 def main(argv=None) -> int:
-    cmds = commands()
+    if argv is None:
+        argv = sys.argv[1:]
+    # a command line that starts with a command's name loads that command's
+    # module alone; anything else (no command, -h, a name the table lacks)
+    # loads them all and gets the usage and the errors of the whole tree
+    if argv and argv[0] in COMMAND_TABLE:
+        cmds = {argv[0]: load_command(argv[0])}
+    else:
+        cmds = commands()
     parser = argparse.ArgumentParser(
         prog="seaweedfs_tpu",
         description="TPU-native SeaweedFS-capability framework",

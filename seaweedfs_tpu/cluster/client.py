@@ -10,12 +10,9 @@ and submit (assign+upload in one call).
 
 from __future__ import annotations
 
-import http.client
 import socket
 import time
 import threading
-import urllib.error
-import urllib.request
 from dataclasses import dataclass
 from typing import Optional
 
@@ -27,15 +24,18 @@ from seaweedfs_tpu.security.jwt import mint_file_token
 
 _VID_CACHE_TTL = 30.0
 
-# Errors that mean "this replica is unusable, try the next one". A wedged
-# server surfaces a bare TimeoutError/ConnectionError from the socket layer
-# (NOT urllib.error.URLError) — catching only URLError would abort failover.
-_FAILOVER_ERRORS = (
-    urllib.error.URLError,
-    TimeoutError,
-    ConnectionError,
-    http.client.HTTPException,
-)
+
+def _failover_errors() -> tuple:
+    """Errors that mean "this replica is unusable, try the next one". A
+    wedged server surfaces a bare TimeoutError/ConnectionError from the
+    socket layer (NOT urllib.error.URLError) — catching only URLError
+    would abort failover. A function, and http.client / urllib imported
+    inside the data operations below, so that a tool which only calls the
+    master over gRPC (the shell) does not load the HTTP stack to start."""
+    import http.client
+    import urllib.error
+
+    return (urllib.error.URLError, TimeoutError, ConnectionError, http.client.HTTPException)
 
 
 class ClusterError(Exception):
@@ -112,6 +112,8 @@ class MasterClient:
             conns = self._tl.conns = {}
         c = conns.get(netloc)
         if c is None:
+            import http.client
+
             c = http.client.HTTPConnection(netloc, timeout=self.http_timeout)
             # Connect eagerly so we can disable Nagle: a reused keep-alive
             # socket otherwise serializes each small request behind the
@@ -270,6 +272,8 @@ class MasterClient:
     def upload(self, fid: str, data: bytes, mime: str = "", auth: str = "") -> int:
         """POST to the volume server owning fid's volume. `auth` is the
         JWT from Assign (required when the cluster runs secured)."""
+        import urllib.request
+
         vid = int(fid.split(",", 1)[0])
         locations = self.lookup(vid)
         if not locations:
@@ -293,7 +297,7 @@ class MasterClient:
                 with tls.urlopen(req, timeout=self.http_timeout) as r:
                     r.read()
                     return len(data)
-            except _FAILOVER_ERRORS as e:  # try a replica
+            except _failover_errors() as e:  # try a replica
                 last_err = e
         raise ClusterError(f"upload of {fid} failed: {last_err}")
 
@@ -331,7 +335,7 @@ class MasterClient:
                             c.request("GET", "/" + fid, headers=headers)
                             r = c.getresponse()
                             body = r.read()
-                        except _FAILOVER_ERRORS as e:
+                        except _failover_errors() as e:
                             self._drop_conn(loc.url)
                             last_err = e
                             continue
@@ -346,6 +350,9 @@ class MasterClient:
                     else:
                         self._mark_suspect(loc.url)
                     continue
+                import urllib.error
+                import urllib.request
+
                 try:
                     req = urllib.request.Request(f"{tls.scheme()}://{loc.url}/{fid}", headers=headers)
                     with tls.urlopen(req, timeout=self.http_timeout) as r:
@@ -354,12 +361,14 @@ class MasterClient:
                         return body, r.headers.get(trace_mod.READ_CLASS_HEADER)
                 except urllib.error.HTTPError as e:
                     last_err = f"HTTP {e.code}"
-                except _FAILOVER_ERRORS as e:
+                except _failover_errors() as e:
                     last_err = e
                     self._mark_suspect(loc.url)
         raise ClusterError(f"read of {fid} failed on all locations: {last_err}")
 
     def delete(self, fid: str) -> bool:
+        import urllib.request
+
         vid = int(fid.split(",", 1)[0])
         ok = False
         headers = {}
@@ -373,7 +382,7 @@ class MasterClient:
                 with tls.urlopen(req, timeout=self.http_timeout) as r:
                     r.read()
                     ok = True
-            except _FAILOVER_ERRORS:
+            except _failover_errors():
                 continue
         return ok
 

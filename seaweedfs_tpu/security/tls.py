@@ -28,7 +28,6 @@ import datetime
 import os
 import ssl
 import threading
-import urllib.request
 from dataclasses import dataclass
 from typing import Optional
 
@@ -261,6 +260,10 @@ def urlopen(req, timeout: float = 30.0):
     """Intra-cluster urlopen: plain HTTP when TLS is off; otherwise HTTPS
     with the cluster CA (and client cert, for data-path mTLS). Contexts
     are cached — this sits on the per-chunk hot path."""
+    # imported here: every tool configures TLS through this module before
+    # its first dial, and one that only speaks gRPC never opens a URL
+    import urllib.request
+
     if not https_enabled():
         return urllib.request.urlopen(req, timeout=timeout)
     st = _state

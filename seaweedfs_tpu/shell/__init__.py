@@ -13,6 +13,7 @@ Each command is a `ShellCommand(name, help, do)` where
 from __future__ import annotations
 
 import shlex
+import sys
 import threading
 from dataclasses import dataclass
 from typing import Callable, Iterable, Optional, TextIO
@@ -41,21 +42,68 @@ class ShellCommand:
 _REGISTRY: dict[str, ShellCommand] = {}
 
 
+# Which family module registers which command. A table and not a prefix
+# rule: the names do not follow the files (`collection.list` and
+# `volumeServer.leave` live in command_volume.py, `lock` / `unlock` in
+# command_cluster.py). A command line imports the module of the name it
+# gives: a child that runs `lock; ec.rebuild; unlock` never pays for the
+# filer, the S3 gateway or the broker. A name that is not here still works,
+# through `commands()`; tests/test_shell.py holds the table equal to what
+# the modules register.
+_FAMILIES: dict[str, tuple[str, ...]] = {
+    "command_cluster": ("cluster.check", "cluster.ps", "cluster.raft.ps", "lock", "unlock"),
+    "command_ec": (
+        "ec.backend", "ec.balance", "ec.convert", "ec.decode", "ec.encode",
+        "ec.rebuild", "ec.status", "ec.trace", "ec.verify",
+    ),
+    "command_fs": (
+        "fs.cat", "fs.cd", "fs.configure", "fs.du", "fs.ls", "fs.meta.cat",
+        "fs.meta.load", "fs.meta.save", "fs.mkdir", "fs.mv", "fs.pwd", "fs.rm", "fs.tree",
+    ),
+    "command_mq": ("mq.broker.list", "mq.topic.configure", "mq.topic.list"),
+    "command_s3": ("s3.bucket.create", "s3.bucket.delete", "s3.bucket.list", "s3.clean.uploads"),
+    "command_volume": (
+        "collection.delete", "collection.list", "volume.balance", "volume.check.disk",
+        "volume.configure.replication", "volume.delete", "volume.deleteEmpty",
+        "volume.fix.replication", "volume.fsck", "volume.grow", "volume.list",
+        "volume.mark", "volume.mount", "volume.move", "volume.tier.fetch",
+        "volume.tier.move", "volume.unmount", "volume.vacuum",
+        "volumeServer.evacuate", "volumeServer.leave",
+    ),
+}
+COMMAND_MODULE: dict[str, str] = {
+    name: module for module, names in _FAMILIES.items() for name in names
+}
+
+
 def register(cmd: ShellCommand) -> ShellCommand:
     _REGISTRY[cmd.name] = cmd
     return cmd
 
 
-def commands() -> dict[str, ShellCommand]:
-    # import for registration side effects
-    from seaweedfs_tpu.shell import command_cluster  # noqa: F401
-    from seaweedfs_tpu.shell import command_ec  # noqa: F401
-    from seaweedfs_tpu.shell import command_fs  # noqa: F401
-    from seaweedfs_tpu.shell import command_mq  # noqa: F401
-    from seaweedfs_tpu.shell import command_s3  # noqa: F401
-    from seaweedfs_tpu.shell import command_volume  # noqa: F401
+def _load_family(module: str) -> None:
+    """Import one family for its registrations. `__import__`, not
+    `importlib.import_module`: `python -X importtime` logs only the former,
+    and that log is how a tool child's start is read."""
+    __import__(f"{__name__}.{module}")
 
+
+def commands() -> dict[str, ShellCommand]:
+    """Every command of every family (`help`, tests): imports all six
+    modules for their registrations."""
+    for module in _FAMILIES:
+        _load_family(module)
     return dict(_REGISTRY)
+
+
+def find_command(name: str) -> Optional[ShellCommand]:
+    """The one command `name`, importing only the family COMMAND_MODULE
+    gives for it; a name the table lacks is looked for in all six."""
+    module = COMMAND_MODULE.get(name)
+    if module is None:
+        return commands().get(name)
+    _load_family(module)
+    return _REGISTRY.get(name)
 
 
 class CommandEnv:
@@ -279,15 +327,15 @@ def run_command(env: CommandEnv, line: str, writer: TextIO) -> None:
     if not parts or parts[0].startswith("#"):
         return
     name, args = parts[0], parts[1:]
-    cmds = commands()
     if name in ("help", "?"):
+        cmds = commands()
         if args and args[0] in cmds:
             writer.write(f"{args[0]}\n\t{cmds[args[0]].help}\n")
         else:
             for c in sorted(cmds):
                 writer.write(f"  {c:<28} {cmds[c].help.splitlines()[0]}\n")
         return
-    cmd = cmds.get(name)
+    cmd = find_command(name)
     if cmd is None:
         raise ShellError(f"unknown command {name!r} (try `help`)")
     # the shell is a trace ROOT: every RPC a command fans out carries
@@ -296,7 +344,9 @@ def run_command(env: CommandEnv, line: str, writer: TextIO) -> None:
     from seaweedfs_tpu.obs import trace as _trace
 
     with _trace.start("shell.command", klass="shell"):
-        _trace.annotate(command=name)
+        # modules: how much this process had loaded when the command began
+        # its work (for a `-c` child's first command, what its start cost)
+        _trace.annotate(command=name, modules=len(sys.modules))
         cmd.do(args, env, writer)
 
 

@@ -4,10 +4,13 @@ through the real argparse entry point."""
 
 import json
 import os
+import subprocess
+import sys
 
 import pytest
 
 from seaweedfs_tpu.__main__ import main
+from seaweedfs_tpu.command import COMMAND_TABLE, commands, load_command
 from seaweedfs_tpu.ec import stripe
 from seaweedfs_tpu.ec.constants import TOTAL_SHARDS_COUNT
 from seaweedfs_tpu.storage.needle import Needle
@@ -107,6 +110,80 @@ def test_export_lists_live_needles(vol, capsys):
 def test_version(capsys):
     assert run_cli("version") == 0
     assert "seaweedfs_tpu" in capsys.readouterr().out
+
+
+# -- the command table: a process imports the module of the command it is given ----
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_the_command_table_is_what_the_modules_register():
+    """`__main__` imports `COMMAND_TABLE[name][0]` alone for a command line
+    that starts with `name`: the table must be the registrations, name for
+    name, module for module, help for help, or a command is lost to operators."""
+    registered = commands()
+    assert len(registered) == 26
+    assert COMMAND_TABLE == {
+        name: (cmd.run.__module__.rsplit(".", 1)[1], cmd.help) for name, cmd in registered.items()}
+    assert all(cmd.configure.__module__ == cmd.run.__module__ for cmd in registered.values())
+
+
+def _child(*argv):
+    return subprocess.run([sys.executable, "-m", "seaweedfs_tpu", *argv], cwd=ROOT, timeout=120,
+                          capture_output=True, text=True)
+
+
+@pytest.mark.parametrize("argv", [(), ("-h",), ("--help",)], ids=["no-command", "-h", "--help"])
+def test_a_new_interpreter_lists_every_command_in_the_tables_order(argv):
+    """No command and `-h` load every module and print the whole tree: all 26
+    names with their help lines, in the table's order (the registration order of
+    a process that imports all four modules); 2 for a command line without a
+    command, 0 for a question."""
+    done = _child(*argv)
+    assert done.returncode == (0 if argv else 2) and not done.stderr
+    assert done.stdout.startswith("usage: seaweedfs_tpu [-h] command ...\n")
+    listing = " ".join(done.stdout.split())
+    at = [listing.find(f" {name} {help_} ") for name, (_, help_) in COMMAND_TABLE.items()]
+    assert -1 not in at and at == sorted(at), at
+
+
+@pytest.mark.parametrize("argv", [("nosuch",), ("nosuch", "-h"), ("-x",), ("shel",)],
+                         ids=["unknown", "unknown-h", "option-first", "prefix-of-a-name"])
+def test_a_command_line_that_names_no_command_fails_as_the_whole_tree_fails_it(argv, capsys):
+    with pytest.raises(SystemExit) as e:
+        main(list(argv))
+    err = capsys.readouterr().err
+    assert e.value.code == 2 and err.startswith("usage: seaweedfs_tpu [-h] command ...\n")
+    if argv[0].startswith("-"):
+        assert err.endswith(f"error: unrecognized arguments: {argv[0]}\n")
+    else:
+        assert f"argument command: invalid choice: {argv[0]!r} (choose from " in err
+        assert all(name in err for name in COMMAND_TABLE)
+
+
+@pytest.mark.parametrize("name", sorted(COMMAND_TABLE))
+def test_every_command_parses_alone(name, capsys):
+    """The parser `__main__` builds for a command line that starts with
+    `name` holds that one command: its own usage, with the profile flags."""
+    assert load_command(name) is commands()[name]
+    with pytest.raises(SystemExit) as e:
+        main([name, "-h"])
+    out = capsys.readouterr().out
+    assert e.value.code == 0 and out.startswith(f"usage: seaweedfs_tpu {name} [-h]")
+    assert "-cpuprofile CPUPROFILE" in out and "-memprofile MEMPROFILE" in out
+
+
+def test_a_new_interpreter_runs_a_command_with_its_module_alone():
+    """`version` is in `command/servers.py`: a new interpreter that runs it
+    has imported neither the offline tools (numpy, the stripe engine) nor the
+    filer-sync and load tools, and an argument error is the command's own."""
+    code = ("import sys; from seaweedfs_tpu.__main__ import main; rc = main(['version']); "
+            "print(*sorted(m for m in sys.modules if m.startswith('seaweedfs_tpu.command.'))); sys.exit(rc)")
+    done = subprocess.run([sys.executable, "-c", code], cwd=ROOT, timeout=120, capture_output=True, text=True)
+    assert done.returncode == 0 and done.stdout.splitlines()[-1] == "seaweedfs_tpu.command.servers"
+    bad = _child("shell", "-nosuchflag")
+    assert bad.returncode == 2 and bad.stderr == (
+        "usage: seaweedfs_tpu [-h] command ...\nseaweedfs_tpu: error: unrecognized arguments: -nosuchflag\n")
 
 
 def test_fix_preserves_live_empty_needle(tmp_path, capsys):

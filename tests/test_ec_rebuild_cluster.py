@@ -319,15 +319,67 @@ def test_pick_rebuild_target_prefers_a_device_codec(case):
             == before["url"])
 
 
-def test_placement_names_the_codecs_device_backends_and_imports_no_codec():
-    """The shell child imports `ec.placement` inside its timed commands: it
-    must not drag numpy and the GF tables in, so it repeats the tuple."""
+# What a tool child must not have loaded when its commands run: the codec and
+# its numpy, the offline tools that import them, and the servers and stores of
+# the shell families its command line never names.
+NOT_IN_THE_CHILD = (
+    "numpy", "jax", "sqlite3", "seaweedfs_tpu.ec.stripe", "seaweedfs_tpu.ops.rs_codec",
+    "seaweedfs_tpu.command.local", "seaweedfs_tpu.filer", "seaweedfs_tpu.s3api", "seaweedfs_tpu.mq",
+)
+# `python -m seaweedfs_tpu <argv>` is `__main__.main(argv)`. The master is real
+# and has no volume server: `ec.encode` / `ec.decode` of a volume nobody holds
+# get past their first RPCs and fail there, which is far enough.
+_CHILD = """
+import sys
+from seaweedfs_tpu.__main__ import main
+try:
+    rc = main(sys.argv[1:])
+except Exception as e:
+    rc = f"{type(e).__name__}: {e}"
+print("MODULES", *sorted(sys.modules))
+print("RC", rc)
+"""
+CHILD_CASES = {
+    # case: (argv after `shell -master <address>`, or the child's whole code; shell families loaded; RC)
+    "import-command_ec-and-placement": (
+        "import sys; from seaweedfs_tpu.shell import command_ec; from seaweedfs_tpu.ec import placement; "
+        "print('MODULES', *sorted(sys.modules)); print('RC 0')", {"command_ec"}, "0"),
+    "lock-unlock": (["-c", "lock; unlock"], {"command_cluster"}, "0"),
+    "ec.rebuild": (["-c", "lock; ec.rebuild; unlock"], {"command_cluster", "command_ec"}, "0"),
+    "ec.encode": (["-c", "lock; ec.encode -volumeId 1; unlock"], {"command_cluster", "command_ec"},
+                  "ShellError: volume 1 not found"),
+    "ec.decode": (["-c", "lock; ec.decode -volumeId 1; unlock"], {"command_cluster", "command_ec"},
+                  "ShellError: ec volume 1 not found"),
+    "volume.list": (["-c", "volume.list"], {"command_volume"}, "0"),
+    "repl-exit": ([], set(), "0"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CHILD_CASES))
+def test_a_tool_child_imports_what_its_command_line_names(case):
+    """The child the benchmark and operators time enters through `__main__`:
+    neither the CLI's table nor the shell's may load numpy, the GF tables
+    (`ec.placement` repeats the codec's tuple for that), `command/local.py`, or
+    a family of commands the line does not name. Names, not milliseconds."""
     from seaweedfs_tpu.ops import rs_codec
 
     assert placement.DEVICE_BACKENDS == rs_codec.DEVICE_BACKENDS
-    code = ("import sys; from seaweedfs_tpu.shell import command_ec; from seaweedfs_tpu.ec import placement; "
-            "sys.exit(int('numpy' in sys.modules or 'seaweedfs_tpu.ops.rs_codec' in sys.modules))")
-    assert subprocess.run([sys.executable, "-c", code], cwd=ROOT, timeout=120).returncode == 0
+    argv, families, rc = CHILD_CASES[case]
+    master = MasterServer(port=0, reap_interval=3600)
+    master.start()
+    try:
+        cmd = ([sys.executable, "-c", argv] if isinstance(argv, str)
+               else [sys.executable, "-c", _CHILD, "shell", "-master", master.address, *argv])
+        done = subprocess.run(cmd, cwd=ROOT, timeout=120, capture_output=True, text=True, input="exit\n")
+    finally:
+        master.stop()
+    assert done.returncode == 0, done.stderr
+    modules, said = done.stdout.split("MODULES ", 1)[1].split("\nRC ")
+    assert said.startswith(rc), (said, done.stderr)
+    loaded = set(modules.split())
+    assert "seaweedfs_tpu" in loaded
+    assert not [m for m in loaded for bad in NOT_IN_THE_CHILD if m == bad or m.startswith(bad + ".")]
+    assert {m.rsplit(".", 1)[1] for m in loaded if m.startswith("seaweedfs_tpu.shell.command_")} == families
 
 
 def test_the_domain_cap_still_comes_before_the_device():

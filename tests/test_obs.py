@@ -672,6 +672,23 @@ def test_ec_trace_renders_the_bulk_stages_of_one_shell_encode_and_rebuild(cluste
     assert {f"rebuild.{s}" for s in ("read", "write", "crc", "dispatch", "sync", "verify")} <= names
 
 
+def test_shell_command_root_says_how_much_its_process_had_loaded(cluster):
+    """`run_command` annotates its root with the command's name and
+    `modules=len(sys.modules)` at the command's first line: for a `shell -c`
+    child's first command that is what the child's start loaded, and
+    `render_trace` (`ec.trace`, `/debug/traces`) prints it with the root."""
+    import sys
+
+    _master, _servers, _client, env = cluster
+    trace.RING.clear()
+    assert "volume" in _shell(env, "volume.list").lower()
+    (root,) = [t for t in trace.RING.snapshot(limit=100000) if t["kind"] == "shell.command"]
+    attrs = root["root"]["attrs"]
+    assert set(attrs) == {"command", "modules"} and attrs["command"] == "volume.list"
+    assert 50 <= attrs["modules"] <= len(sys.modules)
+    assert f"command=volume.list modules={attrs['modules']}" in trace.render_trace(root)
+
+
 # -- the profiler mirror (PR 25): the program's spans on another clock ---------
 
 

@@ -4,6 +4,10 @@ them: lock, ec.encode, degraded read, ec.rebuild, ec.balance,
 volume.fix.replication (SURVEY.md §4 test strategy)."""
 
 import io
+import os
+import subprocess
+import sys
+import types
 
 import pytest
 
@@ -11,7 +15,8 @@ from seaweedfs_tpu.cluster.client import MasterClient
 from seaweedfs_tpu.cluster.master import MasterServer
 from seaweedfs_tpu.cluster.volume_server import VolumeServer
 from seaweedfs_tpu.ec.shard_bits import ShardBits
-from seaweedfs_tpu.shell import CommandEnv, ShellError, run_command, run_script
+from seaweedfs_tpu import shell
+from seaweedfs_tpu.shell import CommandEnv, ShellError, repl, run_command, run_script
 
 LARGE, SMALL = 4096, 512
 
@@ -100,6 +105,79 @@ def test_help_and_volume_list(cluster):
     assert "collection: ''" in out
     out = run(env, "cluster.check")
     assert "4 nodes" in out and "unreachable" not in out.replace("0 unreachable", "")
+
+
+# -- the name table: a command line imports the family of the name it gives ------
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_the_name_table_is_what_the_families_register():
+    """`run_command` imports the one module `COMMAND_MODULE` names for a
+    command: the table must be what the six families register, name for name
+    and module for module, or a command is lost to operators."""
+    registered = shell.commands()
+    assert len(registered) == 54
+    assert shell.COMMAND_MODULE == {
+        name: cmd.do.__module__.rsplit(".", 1)[1] for name, cmd in registered.items()}
+    assert all(shell.find_command(name) is cmd for name, cmd in registered.items())
+
+
+def test_help_is_whole_and_an_unknown_name_fails_as_it_did():
+    """`help`, `?` and `help <name>` answer from all six families, as when
+    every command line imported them; so does the search for a name the table
+    lacks, which finds a command some module registered without it."""
+    registered = shell.commands()
+    listed = run(None, "help").splitlines()
+    assert [line.split()[0] for line in listed] == sorted(registered) and len(listed) == 54
+    assert all(line == f"  {name:<28} {registered[name].help.splitlines()[0]}"
+               for line, name in zip(listed, sorted(registered)))
+    assert run(None, "?") == run(None, "help") == run(None, "help nosuch.name")
+    assert run(None, "help ec.encode") == f"ec.encode\n\t{registered['ec.encode'].help}\n"
+    assert "-volumeId" in run(None, "help ec.encode")
+    assert run(None, "") == run(None, "# a comment") == ""
+    for line in ("fs.nope -x", "ec.rebuil", "EC.REBUILD"):
+        with pytest.raises(ShellError) as e:
+            run(None, line)
+        assert str(e.value) == f"unknown command {line.split()[0]!r} (try `help`)"
+    late = shell.register(shell.ShellCommand("late.arrival", "not in the table", lambda a, e, w: w.write("ran\n")))
+    try:
+        assert "late.arrival" not in shell.COMMAND_MODULE and shell.find_command("late.arrival") is late
+        assert run(None, "late.arrival") == "ran\n"
+    finally:
+        del shell._REGISTRY["late.arrival"]
+
+
+_FAMILY_PROBE = """
+import sys
+from seaweedfs_tpu import shell
+names = sys.argv[2:]
+got = [shell.find_command(name) for name in names]
+assert all(c is not None and c.name == n and c.do.__module__ == "seaweedfs_tpu.shell." + sys.argv[1]
+           for n, c in zip(names, got)), got
+print(*sorted(m.rsplit(".", 1)[1] for m in sys.modules if m.startswith("seaweedfs_tpu.shell.command_")))
+"""
+
+
+@pytest.mark.parametrize("family", sorted(shell._FAMILIES))
+def test_a_family_registers_its_commands_with_no_other_family_loaded(family):
+    """In a new interpreter that resolves only this family's names, this
+    family alone is imported and every name is there: no family leans on a
+    registration or an import that a neighbour used to make for it."""
+    done = subprocess.run([sys.executable, "-c", _FAMILY_PROBE, family, *shell._FAMILIES[family]],
+                          cwd=ROOT, timeout=120, capture_output=True, text=True)
+    assert done.returncode == 0, done.stderr
+    assert done.stdout.split() == [family]
+
+
+def test_the_repl_resolves_each_line_as_a_script_does_and_survives_an_unknown_name():
+    env = types.SimpleNamespace(master_address="nowhere:0")
+    out = io.StringIO()
+    repl(env, io.StringIO("help ec.rebuild\nnosuch.command -x\n\n?\nexit\nhelp\n"), out)
+    said = out.getvalue()
+    assert said.startswith("seaweedfs_tpu shell — connected to nowhere:0\n> ec.rebuild\n\t")
+    assert "> error: unknown command 'nosuch.command' (try `help`)\n> > " in said
+    assert said.count("  volume.list ") == 1 and said.endswith("> ")  # `?` listed; nothing ran after `exit`
 
 
 def test_ec_encode_read_rebuild_balance(cluster):

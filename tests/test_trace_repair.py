@@ -437,6 +437,9 @@ def test_incapable_peer_refuses_projection_read(trace_cluster):
     `off` peer refuses the projection read itself — and the rebuild's
     runtime fallback still lands on slabs with zero lost bytes."""
     master, (target, peer_a, peer_b), golden = trace_cluster
+    # off BEFORE the call: the request is on the wire when `stream` returns,
+    # and a handler that read the mode first would serve it
+    peer_b._trace_repair = "off"
     with rpc.RpcClient(peer_b.grpc_address) as c:
         frames = c.stream(
             VOLUME_SERVICE,
@@ -452,7 +455,6 @@ def test_incapable_peer_refuses_projection_read(trace_cluster):
             },
             timeout=30,
         )
-        peer_b._trace_repair = "off"
         with pytest.raises(Exception, match="disabled|UNIMPLEMENTED"):
             list(frames)
 
