@@ -593,259 +593,6 @@ def mode_xor(smoke: bool = False) -> None:
     _emit(out)
 
 
-def _rebatch_storm(smoke: bool):
-    """(specs, n_signatures) for the mixed-signature rebuild storm.
-
-    Each spec is (vid, dat_bytes, missing, encoder). Three geometries:
-    the fleet default 10+4 vandermonde plus the converted-volume
-    geometries 12+3 and 20+4 cauchy (what `weed ec.convert` leaves
-    behind), with both 2-missing and 1-missing loss classes so the
-    batch crosses every axis of the signature key. Several signatures
-    carry two volumes each — grouping and fusion are both exercised.
-    Volume sizes sit in the tens-of-KB range: the storm the fusion
-    targets is SOAK_r12's dispatch-bound regime (many small volumes,
-    each formerly paying a partial-width dispatch)."""
-    from seaweedfs_tpu.ops.rs_codec import Encoder
-
-    e10 = Encoder(10, 4, backend="xorsched")
-    e12 = Encoder(12, 3, backend="xorsched", matrix_kind="cauchy")
-    e20 = Encoder(20, 4, backend="xorsched", matrix_kind="cauchy")
-    if smoke:
-        pats = [
-            (e10, [10, 13]),
-            (e10, [10, 13]),  # shares the signature above
-            (e10, [0]),
-            (e12, [0, 12]),
-            (e20, [20, 23]),
-            (e12, [5]),
-        ]
-    else:
-        pats = (
-            [(e10, [10, 13])] * 2 + [(e10, [11, 12])] * 2
-            + [(e10, [0, 5]), (e10, [2, 7])]
-            + [(e10, [0])] * 2 + [(e10, [1]), (e10, [2])]
-            + [(e12, [0, 12])] * 2 + [(e12, [3, 14]), (e12, [7, 13])]
-            + [(e12, [5])] * 2 + [(e12, [6])]
-            + [(e20, [20, 23])] * 2 + [(e20, [1, 21]), (e20, [5, 22])]
-            + [(e20, [8])] * 2 + [(e20, [9])]
-        )
-    specs = []
-    for vid, (enc, missing) in enumerate(pats, start=1):
-        specs.append((vid, 24_000 + vid * 500, list(missing), enc))
-    n_sigs = len({
-        (enc.data_shards, enc.total_shards, getattr(enc, "matrix_kind", ""),
-         tuple(missing))
-        for _, _, missing, enc in specs
-    })
-    return specs, n_sigs
-
-
-def mode_rebuild_batch(smoke: bool = False) -> None:
-    """BENCH_MODE=rebuild_batch: heterogeneous rebuild fusion — a
-    mixed-signature storm rebuilt in ONE block-diagonal fused dispatch
-    (WEEDTPU_REBUILD_FUSE=on) vs the PR 16 per-signature-group dispatch
-    loop (fuse off), measured in the SAME run. Both paths read the same
-    survivor bytes and run the same staging-ring pipeline; the delta is
-    pure per-dispatch overhead, which is exactly what a storm of small
-    volumes pays. Every rebuilt shard is byte-compared against the
-    encode-time golden before any wall number is trusted. `--smoke` is
-    the deterministic tier-1 variant: byte accounting + dispatch-count
-    asserts (homogeneous batch fuses to 1 trivially; heterogeneous batch
-    fuses to 1 only via the block-diagonal path), no timing, no `when`
-    stamp."""
-    import tempfile
-
-    import numpy as np
-
-    from seaweedfs_tpu.ec import stripe
-    from seaweedfs_tpu.ops import xorsched
-    from seaweedfs_tpu.ops.rs_codec import _host_fingerprint
-    from seaweedfs_tpu.utils import config
-
-    specs, n_sigs = _rebatch_storm(smoke)
-    out: dict = {
-        "kind": "rebuild_batch",
-        "host": _host_fingerprint(),
-        "native_level": xorsched.native_level(),
-        "tile_kb": config.env("WEEDTPU_XORSCHED_TILE_KB"),
-        "protocol": (
-            "same-run fused (WEEDTPU_REBUILD_FUSE=on, one block-diagonal "
-            "dispatch) vs unfused (per-signature-group dispatches) wall, "
-            "min-of-iters; every rebuilt shard byte-compared vs the "
-            "encode-time golden"
-        ),
-    }
-    if not smoke:
-        out["when"] = time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime())
-
-    td = tempfile.mkdtemp(prefix="rebatch_")
-    jobs = []
-    golden: list[dict[int, bytes]] = []
-    rng_total = 0
-    for vid, size, missing, enc in specs:
-        base = os.path.join(td, f"v{vid}")
-        rng = np.random.default_rng(vid)
-        with open(base + ".dat", "wb") as f:
-            f.write(rng.integers(0, 256, size, dtype=np.uint8).tobytes())
-        with open(base + ".idx", "wb"):
-            pass
-        stripe.write_ec_files(
-            base, large_block_size=16 * 1024, small_block_size=4 * 1024,
-            encoder=enc,
-        )
-        stripe.write_sorted_file_from_idx(base)
-        gold: dict[int, bytes] = {}
-        for s in missing:
-            with open(stripe.shard_file_name(base, s), "rb") as f:
-                gold[s] = f.read()
-        golden.append(gold)
-        shard_n = len(next(iter(gold.values())))
-        rng_total += sum(len(b) for b in gold.values())
-        os.unlink(base + ".dat")
-        present = [s for s in range(enc.total_shards) if s not in missing]
-        jobs.append({
-            "base": base,
-            "sources": {
-                s: stripe.LocalSlabSource(stripe.shard_file_name(base, s))
-                for s in present
-            },
-            "shard_size": shard_n,
-            "missing": missing,
-            "encoder": enc,
-        })
-    out["storm"] = {
-        "volumes": len(jobs),
-        "signatures": n_sigs,
-        "geometries": ["10+4 vandermonde", "12+3 cauchy", "20+4 cauchy"],
-        "missing_shard_bytes": rng_total,
-    }
-
-    def run(fuse: bool) -> tuple[float, dict]:
-        for (vid, size, missing, enc), job in zip(specs, jobs):
-            for s in missing:
-                p = stripe.shard_file_name(job["base"], s)
-                if os.path.exists(p):
-                    os.unlink(p)
-        t0 = time.perf_counter()
-        res = stripe.rebuild_ec_files_batch(
-            jobs, buffer_size=64 * 1024, max_batch_bytes=32 * 1024 * 1024,
-            fuse=fuse,
-        )
-        wall = time.perf_counter() - t0
-        if res["errors"]:
-            raise RuntimeError(f"rebuild errors: {res['errors']}")
-        return wall, res
-
-    def verify() -> tuple[bool, int]:
-        ok, checked = True, 0
-        for (vid, size, missing, enc), gold, job in zip(specs, golden, jobs):
-            for s in missing:
-                with open(stripe.shard_file_name(job["base"], s), "rb") as f:
-                    ok = ok and f.read() == gold[s]
-                checked += 1
-        return ok, checked
-
-    try:
-        _, res_f = run(True)
-        ok_f, n_checked = verify()
-        _, res_u = run(False)
-        ok_u, _ = verify()
-        out["fused"] = {
-            "dispatch_groups": res_f["dispatch_groups"],
-            "signature_groups": res_f["signature_groups"],
-            "volumes_fused": res_f["volumes_fused"],
-        }
-        out["unfused"] = {"dispatch_groups": res_u["dispatch_groups"]}
-        out["verify"] = {
-            "shards_checked": n_checked,
-            "fused_bytes_match": ok_f,
-            "unfused_bytes_match": ok_u,
-        }
-        if smoke:
-            # homogeneous control: one signature repeated — both paths
-            # collapse to one dispatch, so any fused-vs-unfused dispatch
-            # delta seen above is the heterogeneity, not batching itself
-            homo = [j for j, (_, _, m, e) in zip(jobs, specs)
-                    if e is specs[0][3] and m == [10, 13]]
-            for fuse in (True, False):
-                for job in homo:
-                    for s in job["missing"]:
-                        p = stripe.shard_file_name(job["base"], s)
-                        if os.path.exists(p):
-                            os.unlink(p)
-                res_h = stripe.rebuild_ec_files_batch(
-                    homo, buffer_size=64 * 1024,
-                    max_batch_bytes=32 * 1024 * 1024, fuse=fuse,
-                )
-                out[f"homogeneous_{'fused' if fuse else 'unfused'}"] = {
-                    "dispatch_groups": res_h["dispatch_groups"],
-                    "signature_groups": res_h["signature_groups"],
-                }
-            out["rebuilt_bytes"] = rng_total
-            out["ok"] = bool(
-                ok_f and ok_u
-                and res_f["dispatch_groups"] == 1
-                and res_u["dispatch_groups"] == n_sigs
-                and res_f["signature_groups"] == n_sigs
-                and out["homogeneous_fused"]["dispatch_groups"] == 1
-                and out["homogeneous_unfused"]["dispatch_groups"] == 1
-            )
-            _emit(out)
-            return
-
-        # throughput: min-of-iters on each side, warm (run() above already
-        # paid schedule compiles and staging-ring first-touch)
-        iters = 8
-        wall_f = min(run(True)[0] for _ in range(iters))
-        wall_u = min(run(False)[0] for _ in range(iters))
-        out["fused"]["wall_ms"] = round(wall_f * 1e3, 3)
-        out["unfused"]["wall_ms"] = round(wall_u * 1e3, 3)
-        out["fused_speedup"] = round(wall_u / wall_f, 2)
-
-        # executor width-scaling: the widest decode program in the storm,
-        # replayed through the native executor at 1 thread vs
-        # WEEDTPU_XORSCHED_THREADS>1 (threads=0 = hardware concurrency)
-        cores = out["host"].get("cores", 0)
-        e20 = specs[-1][3]
-        survivors = [s for s in range(24) if s not in (20, 23)][:20]
-        m = e20.reconstruction_matrix(survivors, [20, 23])
-        prog = xorsched.get_schedule(m)
-        stack = np.random.default_rng(7).integers(
-            0, 256, size=(m.shape[1], 8 << 20), dtype=np.uint8
-        )
-        ins = list(stack)
-        t1 = _min_time(lambda: xorsched.apply_native(prog, ins, threads=1), iters=5)
-        tn = _min_time(lambda: xorsched.apply_native(prog, ins, threads=0), iters=5)
-        thread_scaling = round(t1 / tn, 2)
-        out["threads"] = {
-            "cores": cores,
-            "single_ms": round(t1 * 1e3, 2),
-            "multi_ms": round(tn * 1e3, 2),
-            "scaling": thread_scaling,
-        }
-        gate: dict = {
-            "fused_speedup_15x": bool(out["fused_speedup"] >= 1.5),
-            "bytes_match": bool(ok_f and ok_u),
-            "one_dispatch": bool(res_f["dispatch_groups"] == 1),
-        }
-        if cores > 1:
-            gate["thread_scaling_15x"] = bool(thread_scaling >= 1.5)
-        else:
-            gate["thread_scaling_15x"] = False
-            out["threads"]["note"] = (
-                f"single-core host (cores={cores}): width-parallel tiles "
-                "timeslice one core, so >=1.5x executor scaling is not "
-                "measurable here — gate honestly unmet, rerun on a "
-                "multi-core host to claim it"
-            )
-        out["gate"] = gate
-        _emit(out)
-    finally:
-        for job in jobs:
-            for src in job["sources"].values():
-                src.close()
-
-
 # ---------------------------------------------------------------------------
 # stage 2c: remote degraded-read ladder (child, JAX_PLATFORMS=cpu)
 # ---------------------------------------------------------------------------
@@ -2473,8 +2220,6 @@ if __name__ == "__main__":
         mode_convert()
     elif mode == "xor":
         mode_xor(smoke="--smoke" in sys.argv)
-    elif mode == "rebuild_batch":
-        mode_rebuild_batch(smoke="--smoke" in sys.argv)
     elif mode == "dp":
         mode_dp()
     elif mode == "mesh":

@@ -28,9 +28,16 @@ syncs; each shard's slab read, file write and CRC fold are tasks on the
 process's lane threads, one shard's tasks in submission order, different
 shards at once. Local files are read positionally (`pread_padded_into`); a
 source that is no file, or a `SlabSource` that does not say `lane_reads`,
-is read on the pipeline's own thread. `write_ec_files` (`_encode_rows`) and
-`rebuild_ec_files` / `rebuild_ec_files_from_sources` use them; the batch,
-fused, projection and serial rebuilds do their host work on one thread.
+is read on the pipeline's own thread.
+
+There are two pipelined loops. `_encode_rows` is the encode's (`write_ec_files`,
+the inline-ingest and conversion builders): its batches write data rows from
+staging and parity rows from the device. `_run_rebuild` is every pipelined
+rebuild's: `rebuild_ec_files`, `rebuild_ec_files_from_sources`,
+`rebuild_ec_files_from_projections` and `rebuild_ec_files_batch` each lay
+their work out as a `_Plan` (which columns a batch holds, what fills a staging
+row, the dispatch, where a decoded row goes) and hand it over.
+`rebuild_ec_files_serial` is the one-thread oracle the tests compare with.
 
 The engine is backend-agnostic through the Encoder seam: the same flat
 (shards, width) dispatch shape serves the device paths (jax/pallas/mesh)
@@ -49,7 +56,7 @@ import zlib
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from contextlib import ExitStack
-from typing import Callable, Optional, Sequence
+from typing import Callable, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -833,7 +840,7 @@ class SlabSource:
     so every backend is byte-interchangeable under the decode.
 
     `lane_reads` says whether `read_into` may run on a shard lane of
-    `rebuild_ec_files_from_sources`, that is on another thread than the
+    `_run_rebuild`, that is on another thread than the
     pipeline's, at the same time as other sources' reads. A source says yes
     only if its `read_into` shares no state with anything else that runs
     meanwhile (`LocalSlabSource`: a positional read of its own file). The
@@ -1223,6 +1230,223 @@ class LocalProjectionSource(SlabSource):
             f.close()
 
 
+# -- the rebuild pipeline: one loop, and the plans its entry points hand it ---
+
+
+class _Seg(NamedTuple):
+    """One signature group's columns of one batch. A single-volume rebuild has
+    one group and one segment a batch; a batch of `rebuild_ec_files_batch`
+    holds a segment per group whose volumes reach into it."""
+
+    group: int  # the failure domain: a failed read stops this group and no other
+    #: what fills the slot: (source, offset, length, staging row, first column,
+    #: end): `source.read_into(offset, slot[row, first:end])`, hinted before by
+    #: `source.prefetch(offset, length)`
+    fills: list
+    #: where a decoded row goes: (decoded row, first column, end, output)
+    puts: list
+    #: the columns as a `reconstruct_block` block; None where the plan's
+    #: dispatch needs none (projections)
+    block: Optional[dict]
+
+
+class _Batch(NamedTuple):
+    width: int  # shard bytes staged per row: a volume's tail is rounded up to `buffer_size`
+    valid: int  # ... of them the shards': what is written
+    cols: int  # staging columns in use (projections fold `rows` shard bytes into each)
+    segs: list
+
+
+class _Plan(NamedTuple):
+    """What differs between the pipelined rebuilds, as data: `_run_rebuild`
+    owns everything else. Outputs are numbered in member order."""
+
+    shape: tuple  # of a staging slot: (rows, columns)
+    align: int  # dispatched columns are a multiple of it (the mesh's dp*sp)
+    batches: list  # of _Batch, in the order the output files receive them
+    members: list  # (base, group, shard ids to rebuild) per volume
+    #: (staging slot, columns to dispatch, the live segments' blocks) -> a lazy
+    #: handle: np.asarray of it is the sync point
+    dispatch: Callable
+
+
+def _windows(shard_size: int, span: int, buffer_size: int):
+    """One volume's batches: (offset, valid bytes, staged width) per `span`
+    columns of the shard, the staged width a whole number of buffers."""
+    for off in range(0, shard_size, span):
+        valid = min(span, shard_size - off)
+        yield off, valid, -(-valid // buffer_size) * buffer_size
+
+
+def _decode_blocks(staging: np.ndarray, cols: int, blocks: list):
+    """The decode of a batch of survivor slabs. One signature from column 0 on
+    (every batch of a single-volume rebuild) is one `reconstruct_lazy` over the
+    dispatched columns, donated; several signatures side by side are one
+    block-diagonal `reconstruct_block`."""
+    first = blocks[0]
+    enc = first["encoder"]
+    if len(blocks) == 1 and first["col_start"] == 0:
+        return enc.reconstruct_lazy(
+            staging[: enc.data_shards, :cols], first["survivors"], first["wanted"], donate=True
+        )  # async: H2D + launch
+    trace_mod.annotate(blocks=len(blocks))
+    return enc.reconstruct_block(staging, blocks)
+
+
+def _run_rebuild(
+    plan: _Plan, pipeline_depth: Optional[int], prefetch_batches: Optional[int]
+) -> dict:
+    """THE pipelined rebuild: every entry point below plans, this runs.
+
+    Depth-N over a ring of `depth + 1` staging slots: while `depth` batches
+    decode on the device the next one is staged. Per batch: sources are told
+    to prefetch `ahead` batches in front of the read cursor (the network runs
+    ahead of the reads); the oldest dispatches drain until fewer than `depth`
+    are inflight; a slot is taken and filled, sources that say `lane_reads`
+    on the shard lanes, all at once, the others on this thread, one after
+    another, while the lanes read; the dispatch waits for all of them. A drain
+    syncs (np.asarray: device wait + D2H), joins the previous drain's writes
+    (their arguments keep its array alive) and queues each decoded row's write
+    and CRC fold on the lane of its output file, so a file receives its
+    batches in order whatever the other files do.
+
+    Failure is scoped. A survivor read that raises fails its GROUP: the read
+    task records the exception against the group instead of raising, the
+    group's later segments stop staging, its drains stop writing, its
+    members' partial outputs are unlinked and each gets the exception; every
+    other group flows on, and a run whose groups have all failed stops. A
+    volume whose rebuilt CRCs disagree with its .eci record loses its own
+    outputs only. Anything else (dispatch, sync, a lane's write) fails the
+    RUN, in one order: abort the lanes, discard the inflight device work,
+    close the files, unlink every output, re-raise.
+
+    Returns {member index: the exception that failed it}; annotates the
+    caller's run span with `lanes`, `bytes` and `batches`."""
+    depth = DEFAULT_PIPELINE_DEPTH if pipeline_depth is None else max(1, int(pipeline_depth))
+    ahead = (
+        DEFAULT_PREFETCH_BATCHES if prefetch_batches is None else max(1, int(prefetch_batches))
+    )
+    batches = plan.batches
+    paths = [shard_file_name(base, s) for base, _, shards in plan.members for s in shards]
+    groups = {group for _, group, _ in plan.members}
+    failed: dict[int, Exception] = {}  # group -> what its read raised
+    crcs = [0] * len(paths)
+    ring = _StagingRing(depth + 1, plan.shape)
+    lanes = _ShardLanes(plan.shape[0] + len(paths))
+    trace_mod.annotate(lanes=lanes.n)  # 0 = inline
+    inflight: deque = deque()  # FIFO of (decoded handle, its batch)
+    written = _LaneBatch()  # the last drain's writes; their args keep its array alive
+    rebuilt_bytes = 0
+
+    def drop(outputs) -> None:
+        for o in outputs:
+            try:
+                os.unlink(paths[o])
+            except OSError:
+                pass
+
+    def read(group: int, src: SlabSource, off: int, out: np.ndarray) -> None:
+        if group in failed:
+            return
+        try:
+            with trace_mod.span("rebuild.read", bytes=out.size):
+                src.read_into(off, out)
+        except Exception as e:  # noqa: BLE001 — the group's failure, not the run's
+            failed.setdefault(group, e)
+
+    def put(o: int, row: np.ndarray) -> None:
+        with trace_mod.span("rebuild.write", bytes=row.size):
+            files[o].write(row)
+        with trace_mod.span("rebuild.crc", bytes=row.size):
+            crcs[o] = zlib.crc32(row, crcs[o])
+
+    def drain_one() -> None:
+        nonlocal rebuilt_bytes
+        lazy, batch = inflight.popleft()
+        rows = [p for seg in batch.segs if seg.group not in failed for p in seg.puts]
+        nbytes = sum(end - first for _, first, end, _ in rows)
+        with trace_mod.span("rebuild.drain", width=batch.valid):
+            with trace_mod.span("rebuild.sync", bytes=nbytes):
+                decoded = np.asarray(lazy)  # sync point: device wait + D2H
+            with trace_mod.span("rebuild.wait"):
+                lanes.join(written)
+            for k, first, end, o in rows:
+                lanes.submit(written, o, put, o, decoded[k, first:end])
+        rebuilt_bytes += nbytes
+
+    def issue_prefetch(bi: int) -> None:
+        if bi < len(batches):
+            for seg in batches[bi].segs:
+                if seg.group not in failed:
+                    for src, off, length, _, _, _ in seg.fills:
+                        src.prefetch(off, length)
+
+    try:
+        with ExitStack() as stack:
+            files = [stack.enter_context(open(p, "wb")) for p in paths]
+            try:
+                for j in range(min(ahead, len(batches))):
+                    issue_prefetch(j)
+                for bi, batch in enumerate(batches):
+                    if len(failed) == len(groups):
+                        break
+                    issue_prefetch(bi + ahead)  # network runs ahead of reads
+                    while len(inflight) >= depth:
+                        drain_one()
+                    with trace_mod.span("rebuild.stage", batch=bi, width=batch.width):
+                        staging = ring.take()
+                        reads = _LaneBatch()
+                        fills = [(seg.group, f) for seg in batch.segs for f in seg.fills]
+                        # sources that say they may be read on a lane first,
+                        # so that they run beside the calling thread's own
+                        for group, (src, off, _, row, first, end) in fills:
+                            if src.lane_reads:
+                                lanes.submit(
+                                    reads, src, read, group, src, off, staging[row, first:end]
+                                )
+                        for group, (src, off, _, row, first, end) in fills:
+                            if not src.lane_reads:
+                                read(group, src, off, staging[row, first:end])
+                        with trace_mod.span("rebuild.wait"):
+                            lanes.join(reads)
+                        cols = _aligned(batch.cols, plan.align)  # <= the slot's: monotone
+                        if cols > batch.cols:
+                            staging[:, batch.cols:cols] = 0  # tail: pad columns are zeros
+                    # a read may have failed its group after an earlier segment staged
+                    blocks = [seg.block for seg in batch.segs if seg.group not in failed]
+                    if blocks:
+                        with trace_mod.span("rebuild.dispatch", bytes=plan.shape[0] * cols):
+                            inflight.append((plan.dispatch(staging, cols, blocks), batch))
+                while inflight:
+                    drain_one()
+                with trace_mod.span("rebuild.wait"):
+                    lanes.join()
+            except BaseException:
+                lanes.abort()
+                _discard_inflight(inflight)
+                raise
+        errors: dict[int, Exception] = {}
+        o = 0
+        for mi, (base, group, shards) in enumerate(plan.members):
+            outputs = range(o, o + len(shards))
+            o += len(shards)
+            error = failed.get(group)
+            if error is None:
+                try:
+                    with trace_mod.span("rebuild.verify"):
+                        _verify_rebuilt_crcs(base, {s: crcs[i] for s, i in zip(shards, outputs)})
+                except Exception as e:  # noqa: BLE001 — this volume's, the others are good
+                    error = e
+            if error is not None:
+                drop(outputs)
+                errors[mi] = error
+    except BaseException:
+        drop(range(len(paths)))
+        raise
+    trace_mod.annotate(bytes=rebuilt_bytes, batches=len(batches))
+    return errors
+
+
 def rebuild_ec_files_from_projections(
     base_file_name: str,
     groups: Sequence[SlabSource],
@@ -1234,17 +1458,17 @@ def rebuild_ec_files_from_projections(
     pipeline_depth: Optional[int] = None,
     prefetch_batches: Optional[int] = None,
 ) -> list[int]:
-    """The trace-combine rebuild pipeline: every batch reads one
-    (rows x width) projected block per holder group and reconstructs the
-    missing shards with ONE fused combine dispatch — the XOR of the
-    groups' partial projections, expressed as an all-ones GF(2^8) matrix
-    applied to the (groups, rows*width) staging stack, so it rides the
-    same async-dispatch/donation/staging-ring machinery as the slab
-    pipeline. Output is byte-identical to `rebuild_ec_files_serial` on
-    the same survivor set (the projection coefficients ARE the fused
-    decode matrix, split column-wise across holders); CRC32 is folded in
-    as bytes stream out and checked against the .eci record; any failure
-    drains inflight device work and unlinks the partial outputs."""
+    """The trace-combine rebuild: every batch reads one (rows x width)
+    projected block per holder group and reconstructs the missing shards with
+    ONE fused combine dispatch — the XOR of the groups' partial projections,
+    expressed as an all-ones GF(2^8) matrix applied to the
+    (groups, rows*width) staging stack, so it rides `_run_rebuild` like the
+    slab rebuilds. Output is byte-identical to `rebuild_ec_files_serial` on
+    the same survivor set (the projection coefficients ARE the fused decode
+    matrix, split column-wise across holders); CRC32 is folded in as bytes
+    stream out and checked against the .eci record; any failure stops the
+    lanes, drains inflight device work, unlinks the partial outputs and is
+    raised as it was."""
     enc = encoder or encoder_for_base(base_file_name)
     missing = sorted(int(s) for s in missing)
     if not missing:
@@ -1258,75 +1482,35 @@ def rebuild_ec_files_from_projections(
                 f"group {getattr(g, 'holder', g)!r} projects "
                 f"{getattr(g, 'rows', None)} rows, want {rows}"
             )
-    depth = DEFAULT_PIPELINE_DEPTH if pipeline_depth is None else max(1, int(pipeline_depth))
-    ahead = (
-        DEFAULT_PREFETCH_BATCHES if prefetch_batches is None else max(1, int(prefetch_batches))
-    )
-    chunks_per_batch = max(1, max_batch_bytes // (enc.data_shards * buffer_size))
-    span = chunks_per_batch * buffer_size
+    span = max(1, max_batch_bytes // (enc.data_shards * buffer_size)) * buffer_size
     combine = np.ones((1, len(groups)), dtype=np.uint8)  # GF sum == XOR
-    ring = _StagingRing(depth + 1, (len(groups), rows * span))
-    crcs = {s: 0 for s in missing}
-    batches = []
-    off = 0
-    while off < shard_size:
-        valid = min(span, shard_size - off)
-        batches.append((off, valid, -(-valid // buffer_size) * buffer_size))
-        off += span
-    try:
-        with ExitStack() as stack:
-            outs = {
-                s: stack.enter_context(open(shard_file_name(base_file_name, s), "wb"))
-                for s in missing
-            }
-            inflight: deque = deque()  # FIFO of (combined_handle, valid, width)
-
-            def drain_one() -> None:
-                lazy, valid, width = inflight.popleft()
-                with trace_mod.span("rebuild.drain", width=width):
-                    with trace_mod.span("rebuild.sync", bytes=rows * width):
-                        out = np.asarray(lazy).reshape(rows, width)  # sync point
-                    for k, s in enumerate(missing):
-                        row = np.ascontiguousarray(out[k, :valid])
-                        outs[s].write(row)
-                        crcs[s] = zlib.crc32(row, crcs[s])
-
-            def issue_prefetch(bi: int) -> None:
-                if bi < len(batches):
-                    o, _, wd = batches[bi]
-                    for g in groups:
-                        g.prefetch(o, wd)
-
-            try:
-                for j in range(min(ahead, len(batches))):
-                    issue_prefetch(j)
-                for bi, (off, valid, width) in enumerate(batches):
-                    issue_prefetch(bi + ahead)  # network runs ahead of reads
-                    while len(inflight) >= depth:
-                        drain_one()
-                    with trace_mod.span("rebuild.stage", batch=bi, width=width):
-                        staging = ring.take()
-                        for i, g in enumerate(groups):
-                            g.read_into(off, staging[i, : rows * width])
-                    with trace_mod.span("rebuild.dispatch", bytes=len(groups) * rows * width):
-                        combined = enc.project_lazy(
-                            combine, staging[:, : rows * width], donate=True
-                        )  # async
-                    inflight.append((combined, valid, width))
-                while inflight:
-                    drain_one()
-            except BaseException:
-                _discard_inflight(inflight)
-                raise
-        with trace_mod.span("rebuild.verify"):
-            _verify_rebuilt_crcs(base_file_name, crcs)
-    except BaseException:
-        for s in missing:
-            try:
-                os.unlink(shard_file_name(base_file_name, s))
-            except OSError:
-                pass
-        raise
+    batches = [
+        _Batch(
+            width,
+            valid,
+            rows * width,
+            [
+                _Seg(
+                    0,
+                    [(g, off, width, i, 0, rows * width) for i, g in enumerate(groups)],
+                    # the combined (1, rows*width) row is (rows, width) row-major
+                    [(0, k * width, k * width + valid, k) for k in range(rows)],
+                    None,
+                )
+            ],
+        )
+        for off, valid, width in _windows(shard_size, span, buffer_size)
+    ]
+    plan = _Plan(
+        (len(groups), rows * span),
+        1,
+        batches,
+        [(base_file_name, 0, missing)],
+        lambda staging, cols, _: enc.project_lazy(combine, staging[:, :cols], donate=True),
+    )
+    errors = _run_rebuild(plan, pipeline_depth, prefetch_batches)
+    if errors:
+        raise errors[0]
     return missing
 
 
@@ -1341,28 +1525,23 @@ def rebuild_ec_files_from_sources(
     pipeline_depth: Optional[int] = None,
     prefetch_batches: Optional[int] = None,
 ) -> list[int]:
-    """The generalized (local OR remote survivor) rebuild pipeline.
+    """The generalized (local OR remote survivor) rebuild of one volume.
 
     `sources` maps present shard id -> SlabSource; `missing` defaults to
     every shard id absent from it. Survivor selection is the first
     DATA_SHARDS of the sorted present ids — the same rule as
     `rebuild_ec_files_serial` on the same survivor set, so output bytes are
-    identical regardless of where survivors live. Triple overlap: remote
-    sources are told to prefetch batch k+`prefetch_batches` (network) while
-    batch k+1 fills staging (disk / prefetched-buffer copy) and batch k
-    decodes on-device through the same depth-N inflight deque as the local
-    path. Rebuilt shards stream to `<base>.ecNN` with CRC32 folded in and
-    verified against the .eci record when present; any failure drains
-    inflight device work and unlinks the partial outputs.
-
-    Host work per batch (`_ShardLanes`): sources that say `lane_reads` (the
-    local files) are read on the lanes, all at once; the others (remote,
-    trace, projection sources, anything that does not say) on this thread,
-    one after another as before, while the lanes read; the dispatch waits
-    for all of them. Rebuilt rows are written and CRC'd on the lanes, one
-    shard's batches in order. A lane's exception is raised here at the next
-    join; before the partial outputs are unlinked every lane task of the run
-    has finished or been cancelled."""
+    identical regardless of where survivors live. Each batch is one flat
+    (survivors, width) slab of the staging ring, the width a whole number of
+    buffers rounded up to the encoder's `width_align`, decoded by ONE fused
+    survivors->missing matrix in ONE dispatch (`_decode_blocks`). Triple
+    overlap (`_run_rebuild`): remote sources are told to prefetch batch
+    k+`prefetch_batches` (network) while batch k+1 fills staging (disk /
+    prefetched-buffer copy) and batch k decodes on-device. Rebuilt shards
+    stream to `<base>.ecNN` with CRC32 folded in and verified against the
+    .eci record when present; any failure, on this thread or on a lane,
+    stops the lanes, drains inflight device work, unlinks the partial
+    outputs and is raised as it was."""
     enc = encoder or encoder_for_base(base_file_name)
     present = sorted(sources)
     if missing is None:
@@ -1374,111 +1553,38 @@ def rebuild_ec_files_from_sources(
         raise ValueError(
             f"cannot rebuild: only {len(present)} shards present, need {enc.data_shards}"
         )
-    depth = DEFAULT_PIPELINE_DEPTH if pipeline_depth is None else max(1, int(pipeline_depth))
-    ahead = (
-        DEFAULT_PREFETCH_BATCHES if prefetch_batches is None else max(1, int(prefetch_batches))
-    )
     survivors = present[: enc.data_shards]
     align = int(getattr(enc, "width_align", 1) or 1)
     chunks_per_batch = max(1, max_batch_bytes // (enc.data_shards * buffer_size))
     span = _aligned(chunks_per_batch * buffer_size, align)
-    ring = _StagingRing(depth + 1, (enc.data_shards, span))
-    crcs = {s: 0 for s in missing}
-    lanes = _ShardLanes(len(survivors) + len(missing))
-    trace_mod.annotate(lanes=lanes.n)  # on the caller's run span; 0 = inline
-    #: (offset, valid_bytes, staged_width) per batch, precomputed so the
-    #: prefetch cursor can run `ahead` batches past the read cursor
-    batches = []
-    off = 0
-    while off < shard_size:
-        valid = min(span, shard_size - off)
-        batches.append((off, valid, -(-valid // buffer_size) * buffer_size))
-        off += span
-    try:
-        with ExitStack() as stack:
-            outs = {
-                s: stack.enter_context(open(shard_file_name(base_file_name, s), "wb"))
-                for s in missing
-            }
-            inflight: deque = deque()  # FIFO of (decoded_handle, valid_bytes)
-            written = _LaneBatch()  # the last drain's writes; their args keep its array alive
-
-            def read_slab(src: SlabSource, off: int, out: np.ndarray) -> None:
-                with trace_mod.span("rebuild.read", bytes=out.size):
-                    src.read_into(off, out)
-
-            def put(s: int, row: np.ndarray) -> None:
-                with trace_mod.span("rebuild.write", bytes=row.size):
-                    outs[s].write(row)
-                with trace_mod.span("rebuild.crc", bytes=row.size):
-                    crcs[s] = zlib.crc32(row, crcs[s])
-
-            def drain_one() -> None:
-                lazy, valid = inflight.popleft()
-                with trace_mod.span("rebuild.drain", width=valid):
-                    with trace_mod.span("rebuild.sync", bytes=len(missing) * valid):
-                        # (len(missing), width) — sync point: device wait + D2H
-                        out = np.asarray(lazy)
-                    with trace_mod.span("rebuild.wait"):
-                        lanes.join(written)
-                    for k, s in enumerate(missing):
-                        lanes.submit(written, s, put, s, out[k, :valid])
-
-            def issue_prefetch(bi: int) -> None:
-                if bi < len(batches):
-                    o, _, wd = batches[bi]
-                    for s in survivors:
-                        sources[s].prefetch(o, wd)
-
-            try:
-                for j in range(min(ahead, len(batches))):
-                    issue_prefetch(j)
-                for bi, (off, valid, width) in enumerate(batches):
-                    issue_prefetch(bi + ahead)  # network runs ahead of reads
-                    while len(inflight) >= depth:
-                        drain_one()
-                    with trace_mod.span("rebuild.stage", batch=bi, width=width):
-                        staging = ring.take()
-                        reads = _LaneBatch()
-                        # sources that say they may be read on a lane first,
-                        # so that they run beside the calling thread's own
-                        for i, s in enumerate(survivors):
-                            if sources[s].lane_reads:
-                                lanes.submit(
-                                    reads, s, read_slab, sources[s], off, staging[i, :width]
-                                )
-                        for i, s in enumerate(survivors):
-                            if not sources[s].lane_reads:
-                                read_slab(sources[s], off, staging[i, :width])
-                        with trace_mod.span("rebuild.wait"):
-                            lanes.join(reads)
-                        aw = _aligned(width, align)  # <= span: roundup is monotone
-                        if aw > width:
-                            staging[:, width:aw] = 0  # tail: pad columns are zeros
-                    with trace_mod.span("rebuild.dispatch", bytes=len(survivors) * aw):
-                        decoded = enc.reconstruct_lazy(
-                            staging[:, :aw], survivors, missing, donate=True
-                        )  # async: H2D + launch
-                    inflight.append((decoded, valid))
-                while inflight:
-                    drain_one()
-                with trace_mod.span("rebuild.wait"):
-                    lanes.join()
-            except BaseException:
-                lanes.abort()
-                _discard_inflight(inflight)
-                raise
-        with trace_mod.span("rebuild.verify"):
-            _verify_rebuilt_crcs(base_file_name, crcs)
-    except BaseException:
-        for s in missing:
-            try:
-                os.unlink(shard_file_name(base_file_name, s))
-            except OSError:
-                pass
-        raise
-    # the caller's run span (rebuild_ec_files' or the RPC's rebuild.run)
-    trace_mod.annotate(bytes=len(missing) * shard_size, batches=len(batches))
+    batches = [
+        _Batch(
+            width,
+            valid,
+            width,
+            [
+                _Seg(
+                    0,
+                    [(sources[s], off, width, i, 0, width) for i, s in enumerate(survivors)],
+                    [(k, 0, valid, k) for k in range(len(missing))],
+                    {
+                        "encoder": enc,
+                        "survivors": survivors,
+                        "wanted": missing,
+                        "col_start": 0,
+                        "width": width,
+                    },
+                )
+            ],
+        )
+        for off, valid, width in _windows(shard_size, span, buffer_size)
+    ]
+    plan = _Plan(
+        (enc.data_shards, span), align, batches, [(base_file_name, 0, missing)], _decode_blocks
+    )
+    errors = _run_rebuild(plan, pipeline_depth, prefetch_batches)
+    if errors:
+        raise errors[0]
     return missing
 
 
@@ -1489,7 +1595,6 @@ def rebuild_ec_files_batch(
     max_batch_bytes: int = 64 * 1024 * 1024,
     pipeline_depth: Optional[int] = None,
     prefetch_batches: Optional[int] = None,
-    fuse: Optional[bool] = None,
 ) -> dict:
     """MANY volumes' rebuilds through SHARED device dispatches — the
     fleet-repair batch engine (and the PR 9 residual: dp used to shard
@@ -1506,21 +1611,22 @@ def rebuild_ec_files_batch(
     therefore ride full-width dispatches instead of one shallow dispatch
     per volume.
 
-    With `fuse` (default WEEDTPU_REBUILD_FUSE), DIFFERENT signatures
-    fuse too: every group becomes one BLOCK of a block-diagonal decode
-    (Encoder.reconstruct_block) and the whole heterogeneous cohort runs
-    through ONE staging-ring pipeline — dispatch_groups == 1 for any mix
-    of geometries and loss patterns. Groups keep insertion order, so the
-    caller's job order IS the block order. fuse=False restores one
-    pipeline per signature group (the bench baseline).
+    DIFFERENT signatures share batches too: the cohort runs group-major
+    through ONE `_run_rebuild` pipeline, each group's columns of a batch
+    consecutive, so a batch that holds several is one block-diagonal decode
+    (`Encoder.reconstruct_block`: the composite's zero blocks never
+    materialize) — dispatch_groups == 1 for any mix of geometries and loss
+    patterns. Groups keep insertion order, so the caller's job order IS the
+    block order.
 
-    Failure semantics are GROUP-scoped either way: a failure unlinks
-    every partial output of that signature group's members and records
-    the error per job; other groups still run/complete. Returns
+    Failure semantics are GROUP-scoped: a survivor read that fails unlinks
+    every partial output of that signature group's members and records the
+    error per job; a CRC mismatch does so for its volume; other groups still
+    complete. A failure of the run (dispatch, drain) fails every job.
+    Returns
       {"rebuilt": {base: [shard ids]}, "errors": {base: str},
        "dispatch_groups": int, "signature_groups": int,
        "volumes_fused": int, "block_order": [base, ...]}."""
-    enc_default = encoder
     groups: dict[tuple, list[dict]] = {}
     out: dict = {
         "rebuilt": {},
@@ -1531,7 +1637,7 @@ def rebuild_ec_files_batch(
         "block_order": [],
     }
     for job in jobs:
-        enc = job.get("encoder") or enc_default or encoder_for_base(job["base"])
+        enc = job.get("encoder") or encoder or encoder_for_base(job["base"])
         present = sorted(job["sources"])
         missing = job.get("missing")
         if missing is None:  # an explicit [] means "nothing to rebuild",
@@ -1557,308 +1663,75 @@ def rebuild_ec_files_batch(
         groups.setdefault(sig, []).append(
             {**job, "encoder": enc, "missing": missing, "survivors": survivors}
         )
-    depth = DEFAULT_PIPELINE_DEPTH if pipeline_depth is None else max(1, int(pipeline_depth))
-    ahead = (
-        DEFAULT_PREFETCH_BATCHES if prefetch_batches is None else max(1, int(prefetch_batches))
-    )
+    flat = [(gi, job) for gi, members in enumerate(groups.values()) for job in members]
     out["signature_groups"] = len(groups)
-    out["block_order"] = [job["base"] for members in groups.values() for job in members]
-    out["volumes_fused"] = len(out["block_order"])
-    if fuse is None:
-        fuse = config.env("WEEDTPU_REBUILD_FUSE") == "on"
-    if fuse and groups:
-        out["dispatch_groups"] = 1
-        glist = list(groups.values())
-        try:
-            rebuilt, errors = _rebuild_fused(
-                glist, depth, ahead, buffer_size, max_batch_bytes
-            )
-            out["rebuilt"].update(rebuilt)
-            out["errors"].update(errors)
-        except BaseException as e:
-            for members in glist:
-                for job in members:
-                    for s in job["missing"]:
-                        try:
-                            os.unlink(shard_file_name(job["base"], s))
-                        except OSError:
-                            pass
-                    out["errors"][job["base"]] = f"{type(e).__name__}: {e}"[:300]
-            if not isinstance(e, Exception):
-                raise
+    out["block_order"] = [job["base"] for _, job in flat]
+    out["volumes_fused"] = len(flat)
+    if not flat:
         return out
-    for sig, members in groups.items():
-        out["dispatch_groups"] += 1
-        try:
-            _rebuild_group(members, depth, ahead, buffer_size, max_batch_bytes)
-            for job in members:
-                out["rebuilt"][job["base"]] = list(job["missing"])
-        except BaseException as e:
-            for job in members:
-                for s in job["missing"]:
-                    try:
-                        os.unlink(shard_file_name(job["base"], s))
-                    except OSError:
-                        pass
-                out["errors"][job["base"]] = f"{type(e).__name__}: {e}"[:300]
-            if not isinstance(e, Exception):
-                # KeyboardInterrupt/SystemExit: partials are cleaned, but
-                # the interrupt must propagate, not be absorbed into a
-                # per-volume error string while later groups keep running
-                raise
+    out["dispatch_groups"] = 1
+    max_k = max(job["encoder"].data_shards for _, job in flat)
+    align = max(int(getattr(job["encoder"], "width_align", 1) or 1) for _, job in flat)
+    span = _aligned(max(1, max_batch_bytes // (max_k * buffer_size)) * buffer_size, align)
+    # width-packed, group-major: a group's columns of a batch are one segment
+    batches: list[_Batch] = []
+    segs: list[_Seg] = []
+    room = span
+    output = 0  # of this job's first missing shard
+    for gi, job in flat:
+        srcs, survivors, missing = job["sources"], job["survivors"], job["missing"]
+        size = int(job["shard_size"])
+        off = 0
+        while off < size:
+            take = min(room, size - off)
+            col = span - room
+            if not segs or segs[-1].group != gi:
+                block = {
+                    "encoder": job["encoder"],
+                    "survivors": survivors,
+                    "wanted": missing,
+                    "col_start": col,
+                    "width": 0,
+                }
+                segs.append(_Seg(gi, [], [], block))
+            seg = segs[-1]
+            seg.fills.extend(
+                (srcs[s], off, take, i, col, col + take) for i, s in enumerate(survivors)
+            )
+            seg.puts.extend((k, col, col + take, output + k) for k in range(len(missing)))
+            seg.block["width"] += take
+            off += take
+            room -= take
+            if room == 0:
+                batches.append(_Batch(span, span, span, segs))
+                segs, room = [], span
+        output += len(missing)
+    if segs:
+        used = span - room
+        batches.append(_Batch(used, used, used, segs))
+    plan = _Plan(
+        (max_k, span),
+        align,
+        batches,
+        [(job["base"], gi, job["missing"]) for gi, job in flat],
+        _decode_blocks,
+    )
+    try:
+        errors = _run_rebuild(plan, pipeline_depth, prefetch_batches)
+    except BaseException as e:
+        for _, job in flat:
+            out["errors"][job["base"]] = f"{type(e).__name__}: {e}"[:300]
+        if not isinstance(e, Exception):
+            # KeyboardInterrupt/SystemExit: partials are cleaned, but the
+            # interrupt must propagate, not be absorbed into error strings
+            raise
+        return out
+    for mi, (_, job) in enumerate(flat):
+        if mi in errors:
+            out["errors"][job["base"]] = f"{type(errors[mi]).__name__}: {errors[mi]}"[:300]
+        else:
+            out["rebuilt"][job["base"]] = list(job["missing"])
     return out
-
-
-def _rebuild_fused(
-    groups: list[list[dict]], depth: int, ahead: int, buffer_size: int,
-    max_batch_bytes: int,
-) -> tuple[dict, dict]:
-    """The heterogeneous cohort as ONE pipeline: every signature group is a
-    block of a block-diagonal decode, and each staging batch packs blocks'
-    survivor columns side by side — group g's segments stay consecutive
-    inside a batch, so each block is a contiguous column range and the
-    composite's zero blocks never materialize (reconstruct_block dispatches
-    per-block ranges).  Same depth-N inflight deque, per-volume CRC fold,
-    and triple overlap as `_rebuild_group`.
-
-    Group-scoped failure isolation: a survivor-read failure marks ONLY that
-    group failed — its later segments stop staging, its drains stop
-    writing, its partials are unlinked, its members get the error — while
-    every other block keeps flowing through the same dispatches.  Wholesale
-    failures (decode/drain) raise to the caller, which unlinks everything.
-
-    Returns ({base: [rebuilt shard ids]}, {base: error})."""
-    encs = [members[0]["encoder"] for members in groups]
-    base_enc = encs[0]
-    max_k = max(e.data_shards for e in encs)
-    align = max(int(getattr(e, "width_align", 1) or 1) for e in encs)
-    chunks_per_batch = max(1, max_batch_bytes // (max_k * buffer_size))
-    span = _aligned(chunks_per_batch * buffer_size, align)
-    ring = _StagingRing(depth + 1, (max_k, span))
-    flat = [(gi, job) for gi, members in enumerate(groups) for job in members]
-    crcs = [{s: 0 for s in job["missing"]} for _, job in flat]
-    failed: dict[int, str] = {}  # group index -> error string
-    # width-packed segments, (group, member, shard offset, take); iterating
-    # group-major keeps each group's columns consecutive within a batch
-    batches: list[list[tuple[int, int, int, int]]] = []
-    cur: list[tuple[int, int, int, int]] = []
-    room = span
-    for mi, (gi, job) in enumerate(flat):
-        off = 0
-        size = int(job["shard_size"])
-        while off < size:
-            take = min(room, size - off)
-            cur.append((gi, mi, off, take))
-            off += take
-            room -= take
-            if room == 0:
-                batches.append(cur)
-                cur, room = [], span
-    if cur:
-        batches.append(cur)
-    with ExitStack() as stack:
-        outs = [
-            {
-                s: stack.enter_context(open(shard_file_name(job["base"], s), "wb"))
-                for s in job["missing"]
-            }
-            for _, job in flat
-        ]
-        inflight: deque = deque()  # FIFO of (handle, segments)
-
-        def drain_one() -> None:
-            lazy, segs = inflight.popleft()
-            width = sum(t for _, _, _, t in segs)
-            with trace_mod.span("rebuild.drain", width=width):
-                with trace_mod.span("rebuild.sync"):
-                    dec = np.asarray(lazy)  # (max_m, span) — the sync point
-                col = 0
-                for gi, mi, off, length in segs:
-                    if gi not in failed:
-                        for k, s in enumerate(flat[mi][1]["missing"]):
-                            row = dec[k, col : col + length]
-                            outs[mi][s].write(row)
-                            crcs[mi][s] = zlib.crc32(row, crcs[mi][s])
-                    col += length
-
-        def issue_prefetch(bi: int) -> None:
-            if bi < len(batches):
-                for gi, mi, off, length in batches[bi]:
-                    if gi in failed:
-                        continue
-                    src = flat[mi][1]["sources"]
-                    for s in flat[mi][1]["survivors"]:
-                        src[s].prefetch(off, length)
-
-        try:
-            for j in range(min(ahead, len(batches))):
-                issue_prefetch(j)
-            for bi, segs in enumerate(batches):
-                issue_prefetch(bi + ahead)
-                while len(inflight) >= depth:
-                    drain_one()
-                width = sum(t for _, _, _, t in segs)
-                blocks: list[dict] = []
-                with trace_mod.span("rebuild.stage", batch=bi, width=width):
-                    staging = ring.take()
-                    col = 0
-                    for gi, mi, off, length in segs:
-                        job = flat[mi][1]
-                        if gi not in failed:
-                            try:
-                                src = job["sources"]
-                                for i, s in enumerate(job["survivors"]):
-                                    src[s].read_into(off, staging[i, col : col + length])
-                            except Exception as e:  # noqa: BLE001
-                                failed[gi] = f"{type(e).__name__}: {e}"[:300]
-                        if gi not in failed:
-                            enc = encs[gi]
-                            if blocks and blocks[-1]["_gi"] == gi:
-                                blocks[-1]["width"] += length
-                            else:
-                                blocks.append({
-                                    "_gi": gi,
-                                    "encoder": enc,
-                                    "survivors": job["survivors"],
-                                    "wanted": job["missing"],
-                                    "col_start": col,
-                                    "width": length,
-                                })
-                        col += length
-                # a read failure may land after its group's block opened:
-                # drop any block of a now-failed group before dispatching
-                blocks = [b for b in blocks if b["_gi"] not in failed]
-                if blocks:
-                    with trace_mod.span("rebuild.dispatch", blocks=len(blocks)):
-                        decoded = base_enc.reconstruct_block(staging, blocks)
-                    inflight.append((decoded, segs))
-            while inflight:
-                drain_one()
-        except BaseException:
-            _discard_inflight(inflight)
-            raise
-    rebuilt: dict = {}
-    errors: dict = {}
-    for mi, (gi, job) in enumerate(flat):
-        if gi in failed:
-            for s in job["missing"]:
-                try:
-                    os.unlink(shard_file_name(job["base"], s))
-                except OSError:
-                    pass
-            errors[job["base"]] = failed[gi]
-            continue
-        try:
-            with trace_mod.span("rebuild.verify"):
-                _verify_rebuilt_crcs(job["base"], crcs[mi])
-        except Exception as e:  # noqa: BLE001 — per-volume verify failure
-            # unlinks only that volume; the rest of the cohort is good
-            for s in job["missing"]:
-                try:
-                    os.unlink(shard_file_name(job["base"], s))
-                except OSError:
-                    pass
-            errors[job["base"]] = f"{type(e).__name__}: {e}"[:300]
-            continue
-        rebuilt[job["base"]] = list(job["missing"])
-    return rebuilt, errors
-
-
-def _rebuild_group(
-    members: list[dict], depth: int, ahead: int, buffer_size: int,
-    max_batch_bytes: int,
-) -> None:
-    """One same-signature group: a single depth-N pipeline whose batches
-    pack columns from consecutive volumes (see rebuild_ec_files_batch)."""
-    enc = members[0]["encoder"]
-    survivors = list(members[0]["survivors"])
-    missing = list(members[0]["missing"])
-    align = int(getattr(enc, "width_align", 1) or 1)
-    chunks_per_batch = max(1, max_batch_bytes // (enc.data_shards * buffer_size))
-    span = _aligned(chunks_per_batch * buffer_size, align)
-    ring = _StagingRing(depth + 1, (enc.data_shards, span))
-    crcs = [{s: 0 for s in missing} for _ in members]
-    # batches of width-packed segments: [(job index, offset, length), ...]
-    batches: list[list[tuple[int, int, int]]] = []
-    cur: list[tuple[int, int, int]] = []
-    room = span
-    for ji, job in enumerate(members):
-        off = 0
-        size = int(job["shard_size"])
-        while off < size:
-            take = min(room, size - off)
-            cur.append((ji, off, take))
-            off += take
-            room -= take
-            if room == 0:
-                batches.append(cur)
-                cur, room = [], span
-    if cur:
-        batches.append(cur)
-    with ExitStack() as stack:
-        outs = [
-            {
-                s: stack.enter_context(
-                    open(shard_file_name(job["base"], s), "wb")
-                )
-                for s in missing
-            }
-            for job in members
-        ]
-        inflight: deque = deque()  # FIFO of (handle, segments, valid)
-
-        def drain_one() -> None:
-            lazy, segs, valid = inflight.popleft()
-            with trace_mod.span("rebuild.drain", width=valid):
-                with trace_mod.span("rebuild.sync", bytes=len(missing) * valid):
-                    dec = np.asarray(lazy)  # (len(missing), width) — sync point
-                col = 0
-                for ji, off, length in segs:
-                    for k, s in enumerate(missing):
-                        row = dec[k, col : col + length]
-                        outs[ji][s].write(row)
-                        crcs[ji][s] = zlib.crc32(row, crcs[ji][s])
-                    col += length
-
-        def issue_prefetch(bi: int) -> None:
-            if bi < len(batches):
-                for ji, off, length in batches[bi]:
-                    src = members[ji]["sources"]
-                    for s in survivors:
-                        src[s].prefetch(off, length)
-
-        try:
-            for j in range(min(ahead, len(batches))):
-                issue_prefetch(j)
-            for bi, segs in enumerate(batches):
-                issue_prefetch(bi + ahead)
-                while len(inflight) >= depth:
-                    drain_one()
-                width = sum(length for _, _, length in segs)
-                with trace_mod.span("rebuild.stage", batch=bi, width=width):
-                    staging = ring.take()
-                    col = 0
-                    for ji, off, length in segs:
-                        src = members[ji]["sources"]
-                        for i, s in enumerate(survivors):
-                            src[s].read_into(off, staging[i, col : col + length])
-                        col += length
-                    aw = _aligned(width, align)
-                    if aw > width:
-                        staging[:, width:aw] = 0  # pad columns are zeros
-                with trace_mod.span("rebuild.dispatch", bytes=len(survivors) * aw):
-                    decoded = enc.reconstruct_lazy(
-                        staging[:, :aw], survivors, missing, donate=True
-                    )
-                inflight.append((decoded, segs, width))
-            while inflight:
-                drain_one()
-        except BaseException:
-            _discard_inflight(inflight)
-            raise
-    for ji, job in enumerate(members):
-        with trace_mod.span("rebuild.verify"):
-            _verify_rebuilt_crcs(job["base"], crcs[ji])
 
 
 def rebuild_ec_files(
@@ -1870,12 +1743,13 @@ def rebuild_ec_files(
 ) -> list[int]:
     """Reconstruct missing .ecNN files from >=10 survivors (RebuildEcFiles).
 
-    The device-first repair path: each batch is one flat
+    The device-first repair path (`rebuild_ec_files_from_sources` over the
+    local shard files): each batch is one flat
     (survivors, width) slab — one contiguous read per survivor straight
     into a reused staging ring (no chunk transpose, no per-batch host
     allocation) decoded by ONE fused survivors->missing matrix in ONE
-    device dispatch, with the same depth-N inflight pipeline as
-    `_encode_rows`: up to `pipeline_depth` batches decode on-device while
+    device dispatch, depth-N inflight like `_encode_rows`: up to
+    `pipeline_depth` batches decode on-device while
     the next batch's slab reads run; drains are FIFO so rebuilt files
     receive bytes in order. The ten survivor reads of a batch run at once
     on the shard lanes (positional reads of the local files) and the

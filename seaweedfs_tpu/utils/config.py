@@ -545,14 +545,6 @@ register_env(
     parse=_clamped_int(0),
 )
 register_env(
-    "WEEDTPU_REBUILD_FUSE", str, "on",
-    "Heterogeneous rebuild fusion in rebuild_ec_files_batch: 'on' fuses "
-    "ALL signature groups of a batch into one block-diagonal decode "
-    "dispatch (dispatch_groups == 1); 'off' restores the PR 16 "
-    "per-signature-group dispatches (the bench baseline).",
-    parse=_enum("on", "off"),
-)
-register_env(
     "WEEDTPU_REPAIR", str, "off",
     "Master-side fleet repair scheduler: `on` enumerates every stripe "
     "left under-replicated by a dead/quarantined holder, ranks by "

@@ -9,14 +9,10 @@ the heterogeneous `rebuild_ec_files_batch` path (mixed 10+4/12+3/20+4
 storm byte-identical to the serial per-volume oracle, 2-missing and
 1-missing in ONE batch, mid-batch failure unlinking only that block's
 partials), the per-block schedule-cache keying under a mixed-signature
-storm, the fusion fields on the wire contract, and the deterministic
-`BENCH_MODE=rebuild_batch --smoke` tier-1 gate.
+storm, and the fusion fields on the wire contract.
 """
 
-import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -26,7 +22,6 @@ from seaweedfs_tpu.ops import gf8, xorsched
 from seaweedfs_tpu.ops.rs_codec import Encoder
 from seaweedfs_tpu.utils import native
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LARGE, SMALL = 16384, 4096
 
 # encode with numpy-backend encoders so schedule-cache assertions below
@@ -288,26 +283,6 @@ def test_batch_mid_failure_unlinks_only_failed_block(tmp_path):
                 assert f.read() == golden[s]
 
 
-def test_fuse_off_restores_per_signature_dispatches(tmp_path):
-    """WEEDTPU_REBUILD_FUSE=off (here: fuse=False) is the PR 16 baseline:
-    one dispatch per signature group, same bytes."""
-    jobs, goldens = _storm_jobs(tmp_path, MIXED_SPECS)
-    try:
-        res = stripe.rebuild_ec_files_batch(
-            jobs, buffer_size=16384, max_batch_bytes=163_840, fuse=False
-        )
-    finally:
-        for job in jobs:
-            for src in job["sources"].values():
-                src.close()
-    assert not res["errors"], res["errors"]
-    assert res["dispatch_groups"] == res["signature_groups"] == len(MIXED_SPECS)
-    for base, (golden, missing, _) in goldens.items():
-        for s in missing:
-            with open(stripe.shard_file_name(base, s), "rb") as f:
-                assert f.read() == golden[s]
-
-
 def test_schedule_cache_keys_per_block_under_mixed_storm(tmp_path):
     """The small-fix satellite: the fused dispatch compiles ONE schedule
     per block sub-matrix (keyed individually in the LRU), not one giant
@@ -373,33 +348,3 @@ def test_wire_roundtrips_fusion_fields():
     got = c.to_dict(c.to_message(st, status_cls))
     assert got["batches"] == [batch]
     assert got["fused_volumes_total"] == 12
-
-
-# -- bench smoke (tier-1 gate) -------------------------------------------------
-
-
-def test_bench_rebuild_batch_smoke_deterministic():
-    """`BENCH_MODE=rebuild_batch bench.py --smoke`: deterministic byte
-    accounting + the homogeneous-vs-heterogeneous dispatch-count assert,
-    no timing fields, no timestamp."""
-    env = dict(os.environ, BENCH_MODE="rebuild_batch", JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "bench.py"), "--smoke"],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-        timeout=300,
-    )
-    out = None
-    for line in reversed(proc.stdout.decode(errors="replace").splitlines()):
-        if line.strip().startswith("{"):
-            out = json.loads(line)
-            break
-    assert out is not None, "no JSON from the smoke child"
-    assert out["ok"] is True
-    assert "when" not in out, "smoke output must be timestamp-free"
-    assert out["fused"]["dispatch_groups"] == 1
-    assert out["unfused"]["dispatch_groups"] == out["storm"]["signatures"] > 1
-    assert out["homogeneous_fused"]["dispatch_groups"] == 1
-    assert out["homogeneous_unfused"]["dispatch_groups"] == 1
-    assert out["verify"]["fused_bytes_match"] is True
-    assert out["verify"]["unfused_bytes_match"] is True
-    assert out["rebuilt_bytes"] > 0

@@ -4,7 +4,8 @@ path across geometries, every loss-pattern count (data/parity/mixed), and
 non-multiple tail chunks — while issuing ONE device dispatch per batch.
 `Encoder.reconstruct_batch`/`reconstruct_lazy` must match the per-call
 `reconstruct` oracle, and `EcVolume.read_intervals`' batched degraded
-recovery must match per-interval recovery."""
+recovery must match per-interval recovery. The last section drives the one
+pipelined loop (`stripe._run_rebuild`) through every entry point that plans it."""
 
 import os
 
@@ -13,6 +14,7 @@ import pytest
 
 from seaweedfs_tpu.ec import stripe
 from seaweedfs_tpu.ec.constants import TOTAL_SHARDS_COUNT
+from seaweedfs_tpu.obs import trace
 from seaweedfs_tpu.ops.rs_codec import Encoder
 
 ENC = Encoder(10, 4, backend="numpy")
@@ -251,3 +253,276 @@ def test_read_intervals_batched_recovery_matches_per_interval(tmp_path):
     assert any(b > 1 for b in batch_calls), (
         f"no multi-interval recovery was batched: {batch_calls}"
     )
+
+
+# -- the one pipelined loop through each of its planners -----------------------
+#
+# `stripe._run_rebuild` as the local rebuild, survivor sources with two the
+# lanes may not read, holder projections, a batch of one signature and a batch
+# of mixed signatures and geometries plan it. Each must leave the bytes
+# `rebuild_ec_files_serial` leaves, with shard lanes and inline, the last batch
+# shorter than a buffer; each must record the whole set of stage spans under its
+# run span; and a failure at a read, at a dispatch, at a lane's write and at the
+# CRC check must come out as itself, with no partial file and no lane task left.
+
+LARGE, SMALL = 16384, 4096
+BUFFER = 8192
+#: one buffer a batch, for 20+4 too; a 250,000-byte .dat of 10+4 gives shards
+#: of 28,672 bytes: three whole batches and a tail of half a buffer
+TUNING = dict(buffer_size=BUFFER, max_batch_bytes=10 * BUFFER)
+E10 = ENC
+E12 = Encoder(12, 3, backend="numpy", matrix_kind="cauchy")
+E20 = Encoder(20, 4, backend="numpy", matrix_kind="cauchy")
+ENTRY_POINTS = ["local", "sources", "projections", "batch_one_signature", "batch_mixed"]
+#: of eight cores: seven, or one per source and output where those are fewer
+LANES = {"projections": 2 + 4}
+
+
+class _Boom(Exception):
+    pass
+
+
+class _Plain(stripe.SlabSource):
+    """A survivor that says nothing of lanes: read on the pipeline's own thread."""
+
+    def __init__(self, path):
+        self._inner = stripe.LocalSlabSource(path)
+
+    def read_into(self, offset, out):
+        self._inner.read_into(offset, out)
+
+    def close(self):
+        self._inner.close()
+
+
+class _LanesSeen(stripe._ShardLanes):
+    made: list = []
+
+    def __init__(self, shards):
+        super().__init__(shards)
+        self.made.append(self)
+
+
+def _volume(tmp_path, vid, size, enc, missing):
+    base = os.path.join(str(tmp_path), str(vid))
+    rng = np.random.default_rng(vid)
+    with open(base + ".dat", "wb") as f:
+        f.write(rng.integers(0, 256, size, dtype=np.uint8).tobytes())
+    with open(base + ".idx", "wb"):
+        pass
+    stripe.write_ec_files(base, large_block_size=LARGE, small_block_size=SMALL, encoder=enc)
+    stripe.write_sorted_file_from_idx(base)
+    os.unlink(base + ".dat")
+    for s in missing:
+        os.unlink(stripe.shard_file_name(base, s))
+    return base, enc, missing
+
+
+def _survivors(base, enc, missing):
+    return [s for s in range(enc.total_shards) if s not in missing]
+
+
+def _scenario(entry, tmp_path):
+    """-> (volumes [(base, encoder, missing)], run): `run()` rebuilds them all
+    through `entry` and returns the batch's result (None for one volume)."""
+    if entry == "batch_one_signature":
+        volumes = [_volume(tmp_path, v, n, E10, [3, 12]) for v, n in ((1, 250_000), (2, 90_001))]
+    elif entry == "batch_mixed":
+        volumes = [
+            _volume(tmp_path, 1, 250_000, E10, [12, 13]),
+            _volume(tmp_path, 2, 90_001, E10, [3]),
+            _volume(tmp_path, 3, 120_003, E12, [0, 12]),
+            _volume(tmp_path, 4, 77_777, E20, [20, 23]),
+        ]
+    else:
+        volumes = [_volume(tmp_path, 1, 250_000, E10, [0, 3, 11, 13])]
+    base, enc, missing = volumes[0]
+    size = os.path.getsize(stripe.shard_file_name(base, _survivors(*volumes[0])[0]))
+    assert size % BUFFER == BUFFER // 2  # the tail batch is not a whole buffer
+
+    def local_sources(b, e, m, plain=()):
+        return {
+            s: (_Plain if s in plain else stripe.LocalSlabSource)(stripe.shard_file_name(b, s))
+            for s in _survivors(b, e, m)
+        }
+
+    def run():
+        if entry == "local":
+            stripe.rebuild_ec_files(base, encoder=enc, **TUNING)
+            return None
+        if entry == "sources":
+            sources = local_sources(base, enc, missing, plain=(2, 7))
+            try:
+                stripe.rebuild_ec_files_from_sources(
+                    base, sources, size, encoder=enc, missing=missing, **TUNING
+                )
+            finally:
+                for src in sources.values():
+                    src.close()
+            return None
+        if entry == "projections":
+            chosen = _survivors(base, enc, missing)[: enc.data_shards]
+            coeffs = enc.repair_projection_plan(chosen, missing)
+            groups = [
+                stripe.LocalProjectionSource(
+                    [stripe.shard_file_name(base, s) for s in part],
+                    np.stack([coeffs[s] for s in part], axis=1),
+                    enc,
+                )
+                for part in (chosen[:4], chosen[4:])
+            ]
+            try:
+                stripe.rebuild_ec_files_from_projections(
+                    base, groups, size, missing, encoder=enc, **TUNING
+                )
+            finally:
+                for g in groups:
+                    g.close()
+            return None
+        jobs = [
+            {
+                "base": b,
+                "sources": local_sources(b, e, m),
+                "shard_size": os.path.getsize(stripe.shard_file_name(b, _survivors(b, e, m)[0])),
+                "missing": m,
+                "encoder": e,
+            }
+            for b, e, m in volumes
+        ]
+        try:
+            return stripe.rebuild_ec_files_batch(jobs, **TUNING)
+        finally:
+            for job in jobs:
+                for src in job["sources"].values():
+                    src.close()
+
+    return volumes, run
+
+
+def _rebuilt_bytes(volumes):
+    out = {}
+    for base, _, missing in volumes:
+        for s in missing:
+            with open(stripe.shard_file_name(base, s), "rb") as f:
+                out[base, s] = f.read()
+    return out
+
+
+@pytest.mark.parametrize("cores", [8, 1], ids=["lanes", "inline"])
+@pytest.mark.parametrize("entry", ENTRY_POINTS)
+def test_every_entry_point_leaves_the_serial_rebuilds_bytes(tmp_path, monkeypatch, entry, cores):
+    monkeypatch.setattr(os, "cpu_count", lambda: cores)
+    monkeypatch.setattr(stripe, "_ShardLanes", _LanesSeen)
+    volumes, run = _scenario(entry, tmp_path)
+    _LanesSeen.made = []  # the encodes of the set-up made theirs
+    res = run()
+    if res is not None:
+        assert not res["errors"], res["errors"]
+        assert res["dispatch_groups"] == 1
+        assert res["signature_groups"] == (1 if entry == "batch_one_signature" else len(volumes))
+        assert res["rebuilt"] == {b: m for b, _, m in volumes}
+    (lanes,) = _LanesSeen.made
+    assert lanes.n == (LANES.get(entry, 7) if cores == 8 else 0)
+    got = _rebuilt_bytes(volumes)
+    for base, enc, missing in volumes:
+        for s in missing:
+            os.unlink(stripe.shard_file_name(base, s))
+        assert stripe.rebuild_ec_files_serial(base, encoder=enc, buffer_size=BUFFER) == missing
+    assert got == _rebuilt_bytes(volumes)
+
+
+@pytest.mark.parametrize("entry", ENTRY_POINTS)
+def test_every_entry_point_records_the_stage_spans_under_one_run(tmp_path, monkeypatch, entry):
+    monkeypatch.setenv("WEEDTPU_TRACE", "on")
+    monkeypatch.setattr(os, "cpu_count", lambda: 8)
+    volumes, run = _scenario(entry, tmp_path)
+    trace.RING.clear()
+    with trace.ensure("rebuild.run", klass="maint"):
+        run()
+    (t,) = trace.RING.snapshot(kind="rebuild.run")
+    root = t["root"]
+    spans = [s for s in trace.iter_spans(t) if s is not root]
+    count = {n: sum(1 for s in spans if s["name"] == n) for n in {s["name"] for s in spans}}
+    batches = root["attrs"]["batches"]
+    outputs = sum(len(m) for _, _, m in volumes)
+    assert batches >= 4 and root["attrs"]["lanes"] == LANES.get(entry, 7)
+    assert root["attrs"]["bytes"] == sum(len(b) for b in _rebuilt_bytes(volumes).values())
+    for name in ("stage", "dispatch", "drain", "sync"):
+        assert count[f"rebuild.{name}"] == batches, (name, count)
+    assert count["rebuild.wait"] == 2 * batches + 1  # a stage's reads, a drain's join, the end
+    assert count["rebuild.verify"] == len(volumes)
+    assert count["rebuild.read"] >= 2 * batches
+    assert count["rebuild.write"] == count["rebuild.crc"] >= outputs
+    assert set(count) <= set(trace.SPAN_NAMES)
+
+    def bytes_of(name):
+        return sum(s["attrs"]["bytes"] for s in spans if s["name"] == name)
+
+    assert bytes_of("rebuild.write") == bytes_of("rebuild.crc") == root["attrs"]["bytes"]
+    assert bytes_of("rebuild.sync") == root["attrs"]["bytes"]
+
+
+class _Fails:
+    """Stands in for a callable: passes the first `ok` calls through, then raises."""
+
+    def __init__(self, real, ok):
+        self._real, self._left = real, ok
+
+    def __call__(self, *a, **kw):
+        self._left -= 1
+        if self._left < 0:
+            raise _Boom("injected")
+        return self._real(*a, **kw)
+
+
+class _FailingWrites:
+    def __init__(self, f):
+        self._f, self.write = f, _Fails(f.write, 1)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self._f.close()
+
+
+@pytest.mark.parametrize("cores", [8, 1], ids=["lanes", "inline"])
+@pytest.mark.parametrize("where", ["read", "dispatch", "write", "verify"])
+@pytest.mark.parametrize("entry", ENTRY_POINTS)
+def test_a_failure_comes_out_as_itself_and_leaves_nothing(tmp_path, monkeypatch, entry, where, cores):
+    monkeypatch.setattr(os, "cpu_count", lambda: cores)
+    monkeypatch.setattr(stripe, "_ShardLanes", _LanesSeen)
+    volumes, run = _scenario(entry, tmp_path)
+    _LanesSeen.made = []  # the encodes of the set-up made theirs
+    if where == "read":  # every survivor read after the first batch's: every group fails
+        for cls in (stripe.LocalSlabSource, stripe.LocalProjectionSource):
+            fails = _Fails(cls.read_into, 3 if entry == "projections" else 12)
+            monkeypatch.setattr(cls, "read_into", lambda self, off, out, _f=fails: _f(self, off, out))
+    elif where == "dispatch":  # the second of its kind
+        for name in ("reconstruct_lazy", "reconstruct_block", "project_lazy"):
+            fails = _Fails(getattr(Encoder, name), 1)
+            monkeypatch.setattr(Encoder, name, lambda self, *a, _f=fails, **kw: _f(self, *a, **kw))
+    elif where == "write":  # the first output file's second batch
+        victim = stripe.shard_file_name(volumes[0][0], volumes[0][2][0])
+        real_open = open
+
+        def failing_open(path, mode="r", *a, **kw):
+            f = real_open(path, mode, *a, **kw)
+            return _FailingWrites(f) if (path == victim and "w" in mode) else f
+
+        monkeypatch.setattr(stripe, "open", failing_open, raising=False)
+    else:
+        monkeypatch.setattr(stripe, "_verify_rebuilt_crcs", _Fails(None, 0))
+    if entry.startswith("batch"):
+        res = run()
+        assert res["rebuilt"] == {}
+        assert set(res["errors"]) == {b for b, _, _ in volumes}
+        assert all(e.startswith("_Boom: injected") for e in res["errors"].values()), res
+    else:
+        with pytest.raises(_Boom, match="injected"):
+            run()
+    for base, enc, missing in volumes:
+        for s in range(enc.total_shards):
+            assert os.path.exists(stripe.shard_file_name(base, s)) == (s not in missing), (base, s)
+    (lanes,) = _LanesSeen.made
+    assert lanes._open == 0 and not lanes._queues

@@ -154,21 +154,25 @@ def test_slo_verdict_and_report_schema(tmp_path):
 # -- weedload smoke (tier-1 CI gate) ------------------------------------------
 
 
-def test_weedload_smoke_schema_and_zero_loss(tmp_path):
-    """The committed-artifact pipeline end to end on a tiny in-process
-    cluster: weedload --smoke must finish inside the CI budget, write a
-    schema-complete SLO artifact, observe all three traffic classes, and
-    lose zero bytes."""
+def _weedload_smoke(tmp_path) -> tuple[dict, float]:
+    """-> (the artifact `weedload --smoke` wrote, the seconds it took)."""
     weedload = _load_script("weedload")
     out = tmp_path / "SLO_smoke.json"
     t0 = time.monotonic()
     rc = weedload.main(["--smoke", "--out", str(out)])
     took = time.monotonic() - t0
     assert rc == 0, "weedload smoke lost bytes or crashed"
-    # 30 s: the original 20 s load budget plus the tracing-overhead
-    # gate's interleaved A/B phases (up to 3 damping attempts)
-    assert took < 30.0, f"smoke run must stay under the 30 s CI budget ({took:.1f}s)"
-    report = json.loads(out.read_text())
+    return json.loads(out.read_text()), took
+
+
+def test_weedload_smoke_schema_and_zero_loss(tmp_path):
+    """The committed-artifact pipeline end to end on a tiny in-process
+    cluster: weedload --smoke must write a schema-complete SLO artifact,
+    observe all three traffic classes, and lose zero bytes. What it asserts
+    of time is in `test_weedload_smoke_wall_clock_gates` (slow tier): on a
+    host shared with five other test workers neither the run's wall nor a
+    5% latency ratio says anything of the program."""
+    report, _ = _weedload_smoke(tmp_path)
     for key in slo.REPORT_SCHEMA_KEYS:
         assert key in report, f"artifact missing {key}"
     assert report["lost"] == [] and report["ok"]
@@ -194,13 +198,12 @@ def test_weedload_smoke_schema_and_zero_loss(tmp_path):
     assert len(attrib["slowest"]) >= 1
     assert all(t["root"].get("spans") is not None or t["kind"]
                for t in attrib["slowest"])
-    # the leave-tracing-ON design claim, measured: trace-on healthy
-    # p99/throughput within 5% of trace-off on the same live cluster, or
-    # within the absolute per-read floor (loopback reads are so cheap
-    # that tracing's fixed few-dozen-µs cost can exceed 5% relatively
-    # while staying invisible against any real ms-scale read)
+    # the tracing-overhead measurement ran and left its evidence; whether
+    # it held its bounds is the slow tier's to say
     overhead = report["trace_overhead"]
-    assert overhead["ok"], f"tracing overhead gate failed: {overhead}"
+    assert overhead["method"] == "interleaved-ABBA" and overhead["attempts"]
+    for attempt in overhead["attempts"]:
+        assert {"p99_ratio", "throughput_ratio", "mean_delta_us_per_read", "ok"} <= set(attempt)
     # hot-set serving: the decoded-interval cache must actually engage
     # under the zipf hot set (weedload itself exits 1 when hits == 0 —
     # these assertions pin the artifact evidence, not just the exit code)
@@ -210,6 +213,22 @@ def test_weedload_smoke_schema_and_zero_loss(tmp_path):
     # the read-class header routed cache hits into their own class, so
     # `degraded` in this artifact means reads that actually decoded
     assert report["overall"]["cached"]["count"] > 0
+
+
+@pytest.mark.slow
+def test_weedload_smoke_wall_clock_gates(tmp_path):
+    """The two statements about time, for a host with cores to spare: the
+    smoke stays inside its budget, and the leave-tracing-ON design claim
+    holds: trace-on healthy p99/throughput within 5% of trace-off on the
+    same live cluster, or within the absolute per-read floor (loopback reads
+    are so cheap that tracing's fixed few-dozen-µs cost can exceed 5%
+    relatively while staying invisible against any real ms-scale read)."""
+    report, took = _weedload_smoke(tmp_path)
+    # 30 s: the original 20 s load budget plus the tracing-overhead
+    # gate's interleaved A/B phases (up to 3 damping attempts)
+    assert took < 30.0, f"smoke run must stay under the 30 s CI budget ({took:.1f}s)"
+    overhead = report["trace_overhead"]
+    assert overhead["ok"], f"tracing overhead gate failed: {overhead}"
 
 
 def test_weedload_smoke_s3_front(tmp_path):
