@@ -6,7 +6,7 @@ lost. Window: repeat {untimed `VolumeEcShardsDelete` of the lost shards, wait
 until the master's topology has lost them; timed `shell -c "lock; ec.rebuild;
 unlock"`; each rebuilt shard compared by sha256} until `--seconds` have passed;
 an operation that has started is finished. Rate = bytes of lost shard restored
-over the seconds of the timed commands alone."""
+over the seconds of the timed commands alone, all of them (`common.bulk_rate`)."""
 
 from __future__ import annotations
 
@@ -87,10 +87,7 @@ def window(run) -> None:
             run.failed += 1
         if last:
             break
-    seconds = sum(run.timed)
-    if seconds > 0:
-        run.metrics["rebuild_MBps"] = len(run.timed) * len(run.lost) * run.shard_bytes / 1e6 / seconds
-    common.say(timed_ops=len(run.timed), timed_seconds=[round(t, 4) for t in run.timed])
+    common.bulk_rate(run, "rebuild", len(run.lost) * run.shard_bytes)
 
 
 def verify(run) -> None:

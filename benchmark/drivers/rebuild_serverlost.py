@@ -19,8 +19,8 @@ copies left behind; then the restore: the rebuilt shards deleted where they
 were put, the peer restarted over its untouched directory, the master listing
 its shards again. The last cycle of the window is not restored: `verify` finds
 the cluster as `ec.rebuild` left it, all 14 shards of both volumes on the three
-live servers. Rate = bytes of lost shard restored over the seconds of the timed
-commands alone."""
+live servers. Rate = bytes of lost shard restored (both volumes') over the seconds of
+the timed commands alone, all of them (`common.bulk_rate`)."""
 
 from __future__ import annotations
 
@@ -266,12 +266,8 @@ def window(run) -> None:
         if last:
             break
         _restore(run)
-    seconds = sum(run.timed)
     lost_bytes = sum(len(s) for s in run.lost.values()) * run.shard_bytes
-    if seconds > 0:
-        run.metrics["rebuild_MBps"] = len(run.timed) * lost_bytes / 1e6 / seconds
-    common.say(timed_ops=len(run.timed), timed_seconds=[round(t, 4) for t in run.timed],
-               lost_bytes_per_command=lost_bytes)
+    common.bulk_rate(run, "rebuild", lost_bytes)
 
 
 def _link_live_shards(run, vid: int, listed: dict[int, list[str]]) -> str:

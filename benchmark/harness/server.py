@@ -147,6 +147,13 @@ class Server:
                    {"volume_id": volume_id, "collection": "", "shard_ids": list(shard_ids)},
                    timeout=60)
 
+    def mount_volume(self, volume_id: int) -> None:
+        from seaweedfs_tpu import rpc
+        from seaweedfs_tpu.pb import VOLUME_SERVICE
+
+        with rpc.RpcClient(self.vs_grpc) as c:
+            c.call(VOLUME_SERVICE, "VolumeMount", {"volume_id": volume_id}, timeout=60)
+
     # -- the control thread of chip_server.py ---------------------------------
 
     def ask(self, request: str, reply: str, text: str = "", timeout: float = 120.0) -> str:

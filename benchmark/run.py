@@ -262,6 +262,8 @@ def main(argv=None) -> int:
         "correct": checks_ok and not run.rehearse,
         "attempted": run.attempted,
         "failed": run.failed,
+        # a fact, not a metric: the timed commands' count, median, longest, stalled (`common.timed_facts`)
+        "timed": run.facts.get("timed"),
         "metrics": out,
         "device": {
             "platform": device.get("platform"), "kind": device.get("kind"),
@@ -277,7 +279,12 @@ def main(argv=None) -> int:
     if run.rehearse:
         result["rehearse"] = True
         result["checks_ok"] = checks_ok
+    # every number compared beside its limit: last in the line, and last on stderr
+    result["checks"] = {c["check"]: {"value": c["value"], "limit": c["limit"]} for c in run.checks}
+    result["checks"]["failed_ops"] = {"value": run.failed, "limit": 0}
     print(json.dumps(result), flush=True)
+    for name, c in result["checks"].items():
+        print(f"check {name}: value {c['value']} limit {c['limit']}", file=sys.stderr, flush=True)
     return 0 if result["correct"] else 1
 
 
