@@ -461,11 +461,6 @@ def convert_ec_files(
 
     journal = _Journal(jpath)
     written_since_mark = 0
-    # one staging ring reused across every journal chunk of both row
-    # tiers — without it each _encode_rows call reallocates the multi-
-    # slot pinned ring (degenerate at small journal_bytes: one ring per
-    # chunk)
-    ring_cache: dict = {}
     try:
         if not resumed:
             journal.append(begin)
@@ -537,7 +532,6 @@ def convert_ec_files(
                             batch,
                             pipeline_depth,
                             crcs,
-                            ring_cache=ring_cache,
                         )
                         row += n
                         written_since_mark += n * row_bytes

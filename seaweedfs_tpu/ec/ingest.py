@@ -232,14 +232,13 @@ class InlineStripeBuilder:
         self._parts: list = []
         self._journal = None
         #: per-poll overhead killers (ROADMAP inline-EC follow-up 1): the
-        #: staging ring persists ACROSS polls (stripe._encode_rows reuses
-        #: it via ring_cache instead of re-allocating fresh buffers whose
-        #: first touch page-faults every poll), the .dat read handle
+        #: staging ring persists ACROSS polls (stripe._encode_rows leases
+        #: it from the process's pool instead of allocating fresh buffers
+        #: whose first touch page-faults every poll), the .dat read handle
         #: stays open for the builder's life (the file is append-only;
         #: compaction discards the whole builder), and watermark fsyncs
         #: run on a flusher thread so durability batching never stalls
         #: the encode lane
-        self._ring_cache: dict = {}
         self._dat = None
         self._flusher = None  # lazy single-worker executor
         #: optional parity-spread hook (shard_id, pos, length) — set by the
@@ -373,14 +372,13 @@ class InlineStripeBuilder:
             self._buffer,
             # right-size the staging ring to the work actually available:
             # an ingest poll usually encodes ONE row (so steady-state polls
-            # hit the SAME cached ring geometry every time), and allocating
+            # lease the SAME pooled buffers every time), and allocating
             # the warm path's full batch budget per poll would dominate the
             # amortized cost with dead buffer churn
             min(self._max_batch, max(self._buffer * DATA_SHARDS_COUNT,
                                      n_rows * self._large_row)),
             self._depth,
             self.crcs,
-            ring_cache=self._ring_cache,
         )
 
     def _journal_append(self, record: dict) -> None:
@@ -660,7 +658,6 @@ class InlineStripeBuilder:
                         self._max_batch,
                         self._depth,
                         self.crcs,
-                        ring_cache=self._ring_cache,
                     )
                 if not self.crc_valid:
                     self._recompute_crcs()
@@ -687,7 +684,6 @@ class InlineStripeBuilder:
             if self._flusher is not None:
                 self._flusher.shutdown(wait=False)
                 self._flusher = None
-            self._ring_cache.clear()
             try:
                 os.unlink(journal_path(self.base))
             except OSError:
@@ -742,7 +738,6 @@ class InlineStripeBuilder:
         if self._flusher is not None:
             self._flusher.shutdown(wait=False)
             self._flusher = None
-        self._ring_cache.clear()
 
     def abort(self) -> None:
         """Drop the in-progress state: close handles, unlink partials and

@@ -28,7 +28,11 @@ syncs; each shard's slab read, file write and CRC fold are tasks on the
 process's lane threads, one shard's tasks in submission order, different
 shards at once. Local files are read positionally (`pread_padded_into`); a
 source that is no file, or a `SlabSource` that does not say `lane_reads`,
-is read on the pipeline's own thread.
+is read on the pipeline's own thread. Staging runs ahead of the drain: a
+batch's lane reads are queued before the sync that makes room for it in the
+pipeline and run beside it. The staging ring is leased from a pool the
+process keeps (`_ring_for`, at most STAGING_POOL_MAX_BYTES between runs), so
+only a server's first bulk command faults its slots in.
 
 There are two pipelined loops. `_encode_rows` is the encode's (`write_ec_files`,
 the inline-ingest and conversion builders): its batches write data rows from
@@ -60,6 +64,7 @@ from typing import Callable, NamedTuple, Optional, Sequence
 
 import numpy as np
 
+from seaweedfs_tpu import stats
 from seaweedfs_tpu.ec.constants import (
     DATA_SHARDS_COUNT,
     EC_BUFFER_SIZE,
@@ -169,16 +174,41 @@ def _fd_of(f) -> Optional[int]:
         return None
 
 
+#: the most host memory the staging pool keeps between runs: three slots of the
+#: default 64 MiB batch budget, that is one ring at the default depth. The
+#: encode's `(10, 6553600)` slots (65.5 MB) and the rebuild's `(10, 4194304)`
+#: (41.9 MB) are views of the same three flat buffers, 196.6 MB on a server
+#: that has run a bulk command. What runs at once beyond that is allocated
+#: for its run and dropped after it.
+STAGING_POOL_MAX_BYTES = 3 * 64 * 1024 * 1024
+
+#: flat uint8 buffers that no run holds, smallest first
+_pool_free: list = []
+_pool_lock = threading.Lock()
+
+
 class _StagingRing:
-    """`slots` reused host staging buffers for a depth-N pipeline.
+    """`slots` host staging buffers for a depth-N pipeline, leased from the
+    process's pool (`_ring_for`) for one run and given back at its end.
 
-    A slot is pinned from fill until its batch drains; with slots =
-    pipeline_depth + 1 the round-robin take() never hands back a buffer
-    whose batch is still inflight (the pipeline drains to < depth before
-    every take)."""
+    A slot is pinned from the moment its batch's reads are queued until its
+    batch has drained. With slots = pipeline_depth + 1 the round-robin take()
+    never hands back a buffer whose batch is still inflight, although batch
+    i takes its slot BEFORE the drain that makes room for it: at most `depth`
+    batches are inflight then, i - depth .. i - 1, whose slots are the
+    `depth` others; the slot handed out was batch i - depth - 1's, drained
+    while batch i - 1 was staged (its parity or decode synced, so the device
+    has read the slot, and in the encode its data shards' writes joined).
 
-    def __init__(self, slots: int, shape: tuple):
-        self._bufs = [np.empty(shape, dtype=np.uint8) for _ in range(slots)]
+    The buffers are flat and handed out as `shape` views of their first
+    bytes, so that a run of any geometry can use a buffer that is large
+    enough. A slot may hold a longer batch's bytes from its last tenant:
+    nothing shows, because a batch dispatches and writes its own columns only
+    and its pad columns are zeroed by the pipeline."""
+
+    def __init__(self, flat: list, shape: tuple):
+        self._flat = flat
+        self._bufs = [b[: shape[0] * shape[1]].reshape(shape) for b in flat]
         self._next = 0
 
     def take(self) -> np.ndarray:
@@ -186,22 +216,42 @@ class _StagingRing:
         self._next = (self._next + 1) % len(self._bufs)
         return buf
 
+    def give_back(self) -> None:
+        """Return the buffers to the pool: the caller says that nothing can
+        read or write a slot any more (lanes joined or aborted, every inflight
+        dispatch synced or discarded). The pool keeps at most
+        STAGING_POOL_MAX_BYTES, the largest buffers, which serve every smaller
+        geometry; a ring that is never given back is ordinary garbage."""
+        flat, self._flat, self._bufs = self._flat, [], None
+        with _pool_lock:
+            _pool_free.extend(flat)
+            _pool_free.sort(key=lambda b: b.size)
+            over = sum(b.size for b in _pool_free) - STAGING_POOL_MAX_BYTES
+            while over > 0:
+                over -= _pool_free.pop(0).size
 
-def _ring_for(cache: Optional[dict], slots: int, shape: tuple) -> _StagingRing:
-    """A staging ring of the requested geometry, reused across calls when
-    the caller supplies a cache dict (the inline-ingest poll path: one
-    persistent ring per builder instead of fresh page-faulted buffers per
-    poll). The cache is bounded — geometry churn (a seal's bigger batch
-    after steady one-row polls) evicts the oldest entry."""
-    if cache is None:
-        return _StagingRing(slots, shape)
-    key = (slots, shape)
-    ring = cache.get(key)
-    if ring is None:
-        while len(cache) >= 2:
-            cache.pop(next(iter(cache)))
-        ring = cache[key] = _StagingRing(slots, shape)
-    return ring
+
+def _ring_for(slots: int, shape: tuple) -> _StagingRing:
+    """Lease a staging ring of `slots` buffers of `shape`: the one place a
+    bulk run gets its ring. Buffers come from the process's pool where it has
+    some that are large enough (the smallest such first), so that a server's
+    second bulk command does not fault three fresh 65 MB slots in again, and
+    are allocated where it has not; two runs at once never share a buffer,
+    because a leased buffer is out of the pool until `give_back`. Says what
+    happened: `weedtpu_staging_ring_leases_total{outcome}` and `ring=` on the
+    ambient run span, `reused` when every slot came from the pool, `allocated`
+    otherwise (and for the span, if any lease under it allocated)."""
+    need = shape[0] * shape[1]
+    with _pool_lock:
+        fit = [i for i, b in enumerate(_pool_free) if b.size >= need][:slots]
+        flat = [_pool_free.pop(i) for i in reversed(fit)]
+    outcome = "reused" if len(flat) == slots else "allocated"
+    flat += [np.empty(need, dtype=np.uint8) for _ in range(slots - len(flat))]
+    stats.StagingRingLeases.labels(outcome).inc()
+    run = trace_mod.current()
+    if run is not None and (run.attrs or {}).get("ring") != "allocated":
+        run.annotate(ring=outcome)
+    return _StagingRing(flat, shape)
 
 
 def _aligned(width: int, align: int) -> int:
@@ -381,7 +431,6 @@ def _encode_rows(
     max_batch_bytes: int,
     pipeline_depth: Optional[int] = None,
     crcs: Optional[list] = None,
-    ring_cache: Optional[dict] = None,
 ) -> int:
     """Encode `n_rows` rows of `block_size` blocks as a stream of flat
     (DATA_SHARDS, width) device dispatches over reused staging buffers.
@@ -393,6 +442,15 @@ def _encode_rows(
     the np.asarray in drain_one() is the per-batch synchronization point,
     and drains happen FIFO so parity files receive bytes in order.
 
+    Staging runs ahead of the drain. A batch takes its slot and queues its
+    lane reads first, then the oldest dispatches drain until fewer than
+    `depth` are inflight, then the calling thread waits for the reads, queues
+    the data shards' writes and dispatches: the reads run beside the sync
+    (device wait + D2H) and not after it. The slot is free before that drain
+    (`_StagingRing`). Reads that run on the calling thread (a source that is
+    no OS file, a host with no lanes) stay after the drain: before it they
+    would only delay it.
+
     Who does what (`_ShardLanes`). The calling thread lays out a batch,
     waits for its reads, dispatches it, and syncs its parity; the per-shard
     work runs on the lanes. Where `f` is a real OS file each data shard's
@@ -401,24 +459,28 @@ def _encode_rows(
     _VirtualDat`) is read on the calling thread, in row-run order, through
     `seek`/`readinto`. Each data shard's write and, when `crcs` is given,
     its CRC32 fold are queued behind its read and run beside the dispatch,
-    the device and the next batch's reads; the parity rows' follow their
-    sync. A batch's staging slot is pinned until its data shards are
-    written: drain_one() joins them before the slot can come round again,
-    and the parity array of a drain lives until the next drain (or the end)
-    has joined its writes. Data shards' bytes never cross the device, and
-    every byte is still touched once per stage: one read into staging, one
-    write from there, one CRC fold over the same memory; no second pass over
-    a finished file. On return every lane task of the call has finished; on
-    a failure the lanes are aborted before the inflight device work is
-    discarded, so the caller may unlink.
+    the device and the next batch's reads (a lane's order stays read i, put
+    i, read i + 1); the parity rows' follow their sync. A batch's staging
+    slot is pinned until its data shards are written: drain_one() joins them
+    before the slot can come round again, and the parity array of a drain
+    lives until the next drain (or the end) has joined its writes. Data
+    shards' bytes never cross the device, and every byte is still touched
+    once per stage: one read into staging, one write from there, one CRC
+    fold over the same memory; no second pass over a finished file. On
+    return every lane task of the call has finished; on a failure the lanes
+    are aborted before the inflight device work is discarded, so the caller
+    may unlink.
+
+    The staging ring is leased from the process's pool (`_ring_for`) and
+    given back when nothing can touch a slot any more: after the last drain
+    and the lanes' join, or on a failure after the lanes are aborted and the
+    inflight device work is discarded.
 
     On a mesh-backend encoder the staging span is rounded up to the
     encoder's `width_align` (dp*sp) and each dispatch covers the aligned
     width (the gap zero-filled, written/CRC'd only to the true width), so
     every batch's host->device transfer splits evenly across the chips
-    with no dispatcher-side pad copy. `ring_cache` (a caller-owned dict)
-    keeps the staging ring alive ACROSS calls — the inline-ingest
-    builder's per-poll path."""
+    with no dispatcher-side pad copy."""
     lanes = _ShardLanes(len(outputs))
     trace_mod.annotate(lanes=lanes.n)  # on the caller's run span; 0 = inline
     if n_rows <= 0:
@@ -434,8 +496,8 @@ def _encode_rows(
     # how many (k x buffer) segments fit the device-batch budget
     batch_cap = max(1, max_batch_bytes // (k * buffer_size))
     span = _aligned(batch_cap * buffer_size, align)
-    ring = _ring_for(ring_cache, depth + 1, (k, span))
     fd = _fd_of(f)
+    lane_reads = fd is not None and lanes.n > 0  # else they run on this thread
     inflight: deque = deque()  # FIFO of (parity_handle, width, the batch's data-shard tasks)
     parity_tasks = _LaneBatch()  # the last drain's parity writes; their args keep its array alive
     n_batches = 0
@@ -482,10 +544,8 @@ def _encode_rows(
             return
         n_batches += 1
         width = len(batch) * buffer_size
-        while len(inflight) >= depth:
-            drain_one()
         with trace_mod.span("encode.stage", width=width):
-            staging = ring.take()
+            staging = ring.take()  # free already: before the drain below
             # read runs of consecutive segments as one contiguous slab per
             # shard (k large sequential reads per row-run instead of one
             # seek per segment x shard — keeps readahead alive at 1 GiB
@@ -506,11 +566,21 @@ def _encode_rows(
                 )
                 i = j + 1
             data_tasks = _LaneBatch()
-            if fd is None:  # no OS file: here, run by run through seek/readinto
-                read_slabs(range(k), staging, runs)
-            else:
-                for d in range(k):
-                    lanes.submit(data_tasks, d, read_slabs, (d,), staging, runs)
+
+            def read_batch() -> None:
+                if fd is None:  # no OS file: here, run by run through seek/readinto
+                    read_slabs(range(k), staging, runs)
+                else:
+                    for d in range(k):
+                        lanes.submit(data_tasks, d, read_slabs, (d,), staging, runs)
+
+            if lane_reads:  # queued now, they run beside the sync
+                read_batch()
+            while len(inflight) >= depth:
+                drain_one()
+            if not lane_reads:
+                read_batch()
+            if fd is not None:
                 with trace_mod.span("encode.wait"):
                     lanes.join(data_tasks)
             view = staging[:, :width]
@@ -523,6 +593,7 @@ def _encode_rows(
             parity = enc.encode_parity_lazy(staging[:, :aw], donate=True)  # H2D + launch
         inflight.append((parity, width, data_tasks))
 
+    ring = _ring_for(depth + 1, (k, span))
     try:
         # iterate segments in global order (row-major, then segment in block)
         pending: list = []  # (row, seg)
@@ -540,7 +611,9 @@ def _encode_rows(
     except BaseException:
         lanes.abort()
         _discard_inflight(inflight)
+        ring.give_back()
         raise
+    ring.give_back()
     return n_batches
 
 
@@ -1298,22 +1371,29 @@ def _run_rebuild(
 ) -> dict:
     """THE pipelined rebuild: every entry point below plans, this runs.
 
-    Depth-N over a ring of `depth + 1` staging slots: while `depth` batches
-    decode on the device the next one is staged. Per batch: sources are told
-    to prefetch `ahead` batches in front of the read cursor (the network runs
-    ahead of the reads); the oldest dispatches drain until fewer than `depth`
-    are inflight; a slot is taken and filled, sources that say `lane_reads`
-    on the shard lanes, all at once, the others on this thread, one after
-    another, while the lanes read; the dispatch waits for all of them. A drain
-    syncs (np.asarray: device wait + D2H), joins the previous drain's writes
-    (their arguments keep its array alive) and queues each decoded row's write
-    and CRC fold on the lane of its output file, so a file receives its
-    batches in order whatever the other files do.
+    Depth-N over a ring of `depth + 1` staging slots, leased from the
+    process's pool (`_ring_for`): while `depth` batches decode on the device
+    the next one is staged, and its staging runs ahead of the drain. Per
+    batch: sources are told to prefetch `ahead` batches in front of the read
+    cursor (the network runs ahead of the reads); a slot is taken, free
+    already (`_StagingRing`), and the reads of sources that say `lane_reads`
+    are queued on the shard lanes, all at once; the oldest dispatches drain
+    until fewer than `depth` are inflight, the lanes reading beside the sync;
+    the other sources are read on this thread, one after another (before the
+    drain they would only delay it); the dispatch waits for all of them. A
+    drain syncs (np.asarray: device wait + D2H), joins the previous drain's
+    writes (their arguments keep its array alive) and queues each decoded
+    row's write and CRC fold on the lane of its output file, so a file
+    receives its batches in order whatever the other files do. The ring goes
+    back to the pool when nothing can touch a slot any more: after the last
+    drain and the lanes' join, or after the failure path below has aborted
+    the lanes and discarded the inflight device work.
 
     Failure is scoped. A survivor read that raises fails its GROUP: the read
     task records the exception against the group instead of raising, the
-    group's later segments stop staging, its drains stop writing, its
-    members' partial outputs are unlinked and each gets the exception; every
+    group's later segments stop staging, its drains stop writing (from the
+    drain that runs beside the failed read on), its members' partial outputs
+    are unlinked and each gets the exception; every
     other group flows on, and a run whose groups have all failed stops. A
     volume whose rebuilt CRCs disagree with its .eci record loses its own
     outputs only. Anything else (dispatch, sync, a lane's write) fails the
@@ -1331,7 +1411,6 @@ def _run_rebuild(
     groups = {group for _, group, _ in plan.members}
     failed: dict[int, Exception] = {}  # group -> what its read raised
     crcs = [0] * len(paths)
-    ring = _StagingRing(depth + 1, plan.shape)
     lanes = _ShardLanes(plan.shape[0] + len(paths))
     trace_mod.annotate(lanes=lanes.n)  # 0 = inline
     inflight: deque = deque()  # FIFO of (decoded handle, its batch)
@@ -1384,6 +1463,7 @@ def _run_rebuild(
     try:
         with ExitStack() as stack:
             files = [stack.enter_context(open(p, "wb")) for p in paths]
+            ring = _ring_for(depth + 1, plan.shape)
             try:
                 for j in range(min(ahead, len(batches))):
                     issue_prefetch(j)
@@ -1391,21 +1471,21 @@ def _run_rebuild(
                     if len(failed) == len(groups):
                         break
                     issue_prefetch(bi + ahead)  # network runs ahead of reads
-                    while len(inflight) >= depth:
-                        drain_one()
                     with trace_mod.span("rebuild.stage", batch=bi, width=batch.width):
-                        staging = ring.take()
+                        staging = ring.take()  # free already: before the drain below
                         reads = _LaneBatch()
                         fills = [(seg.group, f) for seg in batch.segs for f in seg.fills]
-                        # sources that say they may be read on a lane first,
-                        # so that they run beside the calling thread's own
+                        # sources read on a lane are queued now: they run beside the sync
                         for group, (src, off, _, row, first, end) in fills:
-                            if src.lane_reads:
+                            if lanes.n and src.lane_reads:
                                 lanes.submit(
                                     reads, src, read, group, src, off, staging[row, first:end]
                                 )
+                        while len(inflight) >= depth:
+                            drain_one()
+                        # ... and the calling thread's own after it, while the lanes read
                         for group, (src, off, _, row, first, end) in fills:
-                            if not src.lane_reads:
+                            if not (lanes.n and src.lane_reads):
                                 read(group, src, off, staging[row, first:end])
                         with trace_mod.span("rebuild.wait"):
                             lanes.join(reads)
@@ -1424,7 +1504,9 @@ def _run_rebuild(
             except BaseException:
                 lanes.abort()
                 _discard_inflight(inflight)
+                ring.give_back()
                 raise
+            ring.give_back()
         errors: dict[int, Exception] = {}
         o = 0
         for mi, (base, group, shards) in enumerate(plan.members):

@@ -71,7 +71,7 @@ SPAN_NAMES: dict[str, str] = {
     "ec.copy.fsync": "flush + fsync of one copied file, apart from its stream",
     "ec.copy.serve": "VolumeEcShardFileCopy on the source: one file read and streamed out (ext, bytes)",
     "rebuild.run": "one whole-volume rebuild (local or distributed)",
-    "rebuild.stage": "staging-ring fill for one rebuild batch (disk/wire)",
+    "rebuild.stage": "staging-ring fill for one rebuild batch (disk/wire): its lane reads queued, the drain it runs ahead of (nested), the wait for the reads",
     "rebuild.read": "one survivor's slab read into its staging row (child of rebuild.stage; on a lane thread where the source allows)",
     "rebuild.wait": "the calling thread blocked in a join of lane tasks (a batch's reads, the last drain's writes)",
     "rebuild.dispatch": "reconstruct_lazy: device_put (H2D) + the jit call, until it returns",
@@ -81,7 +81,7 @@ SPAN_NAMES: dict[str, str] = {
     "rebuild.crc": "zlib.crc32 fold over one rebuilt shard's bytes of one batch (on a lane thread)",
     "rebuild.verify": "rebuilt shards' CRC32s checked against the .eci record",
     "encode.run": "one whole-volume encode: .dat -> shard files + .eci (write_ec_files)",
-    "encode.stage": "staging-ring fill for one encode batch",
+    "encode.stage": "staging-ring fill for one encode batch: its lane reads queued, the drain it runs ahead of (nested), the wait for the reads",
     "encode.read": "one data shard's slabs of one batch read into its staging row (child of encode.stage; on a lane thread), or all ten on the calling thread where the source is no file",
     "encode.wait": "the calling thread blocked in a join of lane tasks (a batch's reads, its data shards' writes, the last drain's parity writes)",
     "encode.dispatch": "encode_parity_lazy: device_put (H2D) + the jit call, until it returns",

@@ -274,6 +274,14 @@ EcCopyBytes = REGISTRY.counter(
     "seconds are weedtpu_rpc_server_seconds{method} of those two RPCs",
     ("side",),
 )
+StagingRingLeases = REGISTRY.counter(
+    "weedtpu_staging_ring_leases_total",
+    "staging rings leased by bulk EC runs (an encode, a rebuild, an ingest "
+    "poll, a conversion chunk) from the process's pool: `reused` = every "
+    "slot was a buffer an earlier run gave back, `allocated` = at least one "
+    "slot was allocated, and page-faults in at its first fill",
+    ("outcome",),
+)
 EcRebuildRemoteBytes = REGISTRY.counter(
     "weedtpu_ec_rebuild_remote_bytes_total",
     "survivor bytes fetched from peer holders by distributed rebuilds",

@@ -465,8 +465,11 @@ def test_the_benchmark_cell_rehearses_to_its_end_and_leaves_no_process(tmp_path,
     assert result["correct"] is False and result["rehearse"] is True
     assert result["checks_ok"] is sound, p.stdout[-4000:]
     assert result["attempted"] >= 1
+    # every command begun is in the rate, unless it failed (and then it is in `failed`)
+    assert result["timed"]["ops"] == result["attempted"] - result["failed"]
     if sound:
-        assert result["failed"] == 0 and result["metrics"]["rebuild_MBps"]["value"] > 0
+        assert result["failed"] == 0 and result["timed"]["ops"] == result["attempted"]
+        assert result["metrics"]["rebuild_MBps"]["value"] > 0
     # every peer is gone: no process still names this run's directories
     left = subprocess.run(["pgrep", "-f", str(work)], capture_output=True, text=True).stdout.split()
     assert not left, f"processes left behind: {left}"

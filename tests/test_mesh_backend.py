@@ -431,11 +431,12 @@ def test_bench_mesh_smoke_schema_and_byte_verify(tmp_path):
 # -- ingest persistent staging ring (ROADMAP follow-up 1) ---------------------
 
 
-def test_inline_builder_reuses_staging_ring_across_polls(tmp_path):
-    """Steady-state polls must hit the SAME cached ring (no per-poll
+def test_inline_builder_reuses_staging_ring_across_polls(tmp_path, monkeypatch):
+    """Steady-state polls must lease the SAME pooled buffers (no per-poll
     buffer churn) and reuse the builder-lifetime .dat handle."""
-    from seaweedfs_tpu.ec import ingest
+    from seaweedfs_tpu.ec import ingest, stripe
 
+    monkeypatch.setattr(stripe, "_pool_free", [])
     large, small, buf = 64 * 1024, 16 * 1024, 16 * 1024
     base = str(tmp_path / "5")
     b = ingest.InlineStripeBuilder(base, _golden(), large, small, buffer_size=buf)
@@ -444,16 +445,16 @@ def test_inline_builder_reuses_staging_ring_across_polls(tmp_path):
         f.write(rng.integers(0, 256, large * 10 + 1, dtype=np.uint8).tobytes())
         f.flush()
         assert b.poll() == 1
-        ring_ids = {id(r) for r in b._ring_cache.values()}
+        ring_ids = {id(r) for r in stripe._pool_free}
         dat_handle = b._dat
-        assert len(ring_ids) == 1 and dat_handle is not None
+        assert len(ring_ids) == stripe.DEFAULT_PIPELINE_DEPTH + 1 and dat_handle is not None
         f.write(rng.integers(0, 256, large * 10, dtype=np.uint8).tobytes())
         f.flush()
         assert b.poll() == 1
-        assert {id(r) for r in b._ring_cache.values()} == ring_ids
+        assert {id(r) for r in stripe._pool_free} == ring_ids
         assert b._dat is dat_handle
     b.abort()
-    assert b._dat is None and not b._ring_cache
+    assert b._dat is None
 
 
 def test_inline_builder_async_watermark_lands_before_seal(tmp_path):

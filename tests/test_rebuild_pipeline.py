@@ -8,10 +8,13 @@ recovery must match per-interval recovery. The last section drives the one
 pipelined loop (`stripe._run_rebuild`) through every entry point that plans it."""
 
 import os
+import threading
+import time
 
 import numpy as np
 import pytest
 
+from seaweedfs_tpu import stats
 from seaweedfs_tpu.ec import stripe
 from seaweedfs_tpu.ec.constants import TOTAL_SHARDS_COUNT
 from seaweedfs_tpu.obs import trace
@@ -322,20 +325,23 @@ def _survivors(base, enc, missing):
     return [s for s in range(enc.total_shards) if s not in missing]
 
 
-def _scenario(entry, tmp_path):
+def _scenario(entry, tmp_path, longer=1):
     """-> (volumes [(base, encoder, missing)], run): `run()` rebuilds them all
-    through `entry` and returns the batch's result (None for one volume)."""
+    through `entry` and returns the batch's result (None for one volume).
+    `longer` = 2 makes every volume twice as long."""
     if entry == "batch_one_signature":
-        volumes = [_volume(tmp_path, v, n, E10, [3, 12]) for v, n in ((1, 250_000), (2, 90_001))]
+        volumes = [
+            _volume(tmp_path, v, longer * n, E10, [3, 12]) for v, n in ((1, 250_000), (2, 90_001))
+        ]
     elif entry == "batch_mixed":
         volumes = [
-            _volume(tmp_path, 1, 250_000, E10, [12, 13]),
-            _volume(tmp_path, 2, 90_001, E10, [3]),
-            _volume(tmp_path, 3, 120_003, E12, [0, 12]),
-            _volume(tmp_path, 4, 77_777, E20, [20, 23]),
+            _volume(tmp_path, 1, longer * 250_000, E10, [12, 13]),
+            _volume(tmp_path, 2, longer * 90_001, E10, [3]),
+            _volume(tmp_path, 3, longer * 120_003, E12, [0, 12]),
+            _volume(tmp_path, 4, longer * 77_777, E20, [20, 23]),
         ]
     else:
-        volumes = [_volume(tmp_path, 1, 250_000, E10, [0, 3, 11, 13])]
+        volumes = [_volume(tmp_path, 1, longer * 250_000, E10, [0, 3, 11, 13])]
     base, enc, missing = volumes[0]
     size = os.path.getsize(stripe.shard_file_name(base, _survivors(*volumes[0])[0]))
     assert size % BUFFER == BUFFER // 2  # the tail batch is not a whole buffer
@@ -526,3 +532,188 @@ def test_a_failure_comes_out_as_itself_and_leaves_nothing(tmp_path, monkeypatch,
             assert os.path.exists(stripe.shard_file_name(base, s)) == (s not in missing), (base, s)
     (lanes,) = _LanesSeen.made
     assert lanes._open == 0 and not lanes._queues
+
+
+# -- staging runs ahead of the drain, into a ring the process keeps -------------
+#
+# Every entry point again: a batch's lane reads start before the drain ahead of
+# it returns and its calling-thread reads after; a shorter cohort through the
+# buffers a longer one left is byte-exact; a failed run gives its ring back
+# only when no lane and no dispatch can touch it, and the next run through
+# those buffers is byte-exact.
+
+LAZY = ("reconstruct_lazy", "reconstruct_block", "project_lazy")
+
+
+def _wrap_lazy(monkeypatch, wrap):
+    """Every decode dispatch's handle, of every encoder, through `wrap`."""
+    for name in LAZY:
+        real = getattr(Encoder, name)
+        monkeypatch.setattr(
+            Encoder, name, lambda self, *a, _real=real, **kw: wrap(np.asarray(_real(self, *a, **kw)))
+        )
+
+
+def _poison_pool():
+    for buf in stripe._pool_free:
+        buf[: 1 << 20] = 0xA5  # more than any slot here, and no run of a volume's bytes
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+@pytest.mark.parametrize("entry", ENTRY_POINTS)
+def test_a_batchs_lane_reads_start_before_the_drain_ahead_of_it_returns(tmp_path, monkeypatch, entry, depth):
+    """The device held at the first drain (batch 0's, made while batch `depth`
+    is staged): every lane read of batches 0..depth has started by then and
+    none further ahead; the calling thread stands in the drain, so its own
+    reads of batch `depth` (sources that do not say `lane_reads`, projection
+    groups) have not. Released, the run leaves the serial rebuild's bytes."""
+    monkeypatch.setattr(os, "cpu_count", lambda: 8)
+    volumes, run = _scenario(entry, tmp_path)
+    syncing, release = threading.Event(), threading.Event()
+
+    class Held:
+        def __init__(self, value):
+            self._value = value
+
+        def __array__(self, *a, **kw):
+            syncing.set()
+            assert release.wait(20), "the test never released the device"
+            return self._value
+
+    _wrap_lazy(monkeypatch, Held)
+    seen = {"lane": 0, "own": 0}
+    for cls in (stripe.LocalSlabSource, stripe.LocalProjectionSource):
+
+        def read_into(self, off, out, _real=cls.read_into):
+            seen["lane" if threading.current_thread().name.startswith("ec-lane") else "own"] += 1
+            _real(self, off, out)
+
+        monkeypatch.setattr(cls, "read_into", read_into)
+    plans = []
+    real_run = stripe._run_rebuild
+    monkeypatch.setattr(
+        stripe, "_run_rebuild", lambda plan, _d, ahead: plans.append(plan) or real_run(plan, depth, ahead)
+    )
+    results = []
+    worker = threading.Thread(target=lambda: results.append(run()))
+    worker.start()
+    try:
+        assert syncing.wait(20), "no drain began"
+        (plan,) = plans
+        assert len(plan.batches) > depth + 1
+
+        def fills(upto, lane):
+            return sum(
+                1 for b in plan.batches[:upto] for seg in b.segs for f in seg.fills
+                if bool(f[0].lane_reads) == lane
+            )
+
+        assert fills(depth + 1, True) > fills(depth, True) or entry == "projections"
+        deadline = time.monotonic() + 10
+        while seen["lane"] < fills(depth + 1, True) and time.monotonic() < deadline:
+            time.sleep(0.005)
+        time.sleep(0.2)
+        assert seen == {"lane": fills(depth + 1, True), "own": fills(depth, False)}
+    finally:
+        release.set()
+        worker.join(30)
+    assert results and (results[0] is None or not results[0]["errors"])
+    got = _rebuilt_bytes(volumes)
+    for base, enc, missing in volumes:
+        for s in missing:
+            os.unlink(stripe.shard_file_name(base, s))
+        assert stripe.rebuild_ec_files_serial(base, encoder=enc, buffer_size=BUFFER) == missing
+    assert got == _rebuilt_bytes(volumes)
+
+
+@pytest.mark.parametrize("entry", ENTRY_POINTS)
+def test_a_shorter_cohort_through_a_kept_ring_leaves_the_serial_rebuilds_bytes(tmp_path, monkeypatch, entry):
+    """A cohort twice as long first, then the scenario's through the buffers it
+    left (overwritten with 0xA5 in between, so a stale column would show): the
+    second run allocates nothing and its bytes are those of a run with a fresh
+    ring and of the serial oracle."""
+    monkeypatch.setattr(os, "cpu_count", lambda: 8)
+    (tmp_path / "longer").mkdir()
+    (tmp_path / "kept").mkdir()
+    _, run_longer = _scenario(entry, tmp_path / "longer", longer=2)
+    volumes, run = _scenario(entry, tmp_path / "kept")
+    monkeypatch.setattr(stripe, "_pool_free", [])
+    counts = {o: stats.StagingRingLeases.labels(o) for o in ("allocated", "reused")}
+    before = {o: c.value for o, c in counts.items()}
+    run_longer()
+    _poison_pool()
+    run()
+    assert {o: c.value - before[o] for o, c in counts.items()} == {"allocated": 1, "reused": 1}
+    kept = _rebuilt_bytes(volumes)
+    for attempt in ("fresh", "serial"):
+        for base, enc, missing in volumes:
+            for s in missing:
+                os.unlink(stripe.shard_file_name(base, s))
+            if attempt == "serial":
+                assert stripe.rebuild_ec_files_serial(base, encoder=enc, buffer_size=BUFFER) == missing
+        if attempt == "fresh":
+            monkeypatch.setattr(stripe, "_pool_free", [])
+            run()
+        assert _rebuilt_bytes(volumes) == kept, attempt
+
+
+@pytest.mark.parametrize("where", ["read", "dispatch"])
+@pytest.mark.parametrize("entry", ENTRY_POINTS)
+def test_a_failed_run_gives_its_ring_back_when_nothing_can_touch_it(tmp_path, monkeypatch, entry, where):
+    """A survivor read that raises (its groups fail, the run ends by itself)
+    and a dispatch that raises (the run fails): the ring is given back once,
+    with no lane task open and every dispatched handle synced or discarded;
+    the next run through those buffers is the serial rebuild's bytes."""
+    monkeypatch.setattr(os, "cpu_count", lambda: 8)
+    volumes, run = _scenario(entry, tmp_path)
+    monkeypatch.setattr(stripe, "_pool_free", [])
+    monkeypatch.setattr(stripe, "_ShardLanes", _LanesSeen)
+    _LanesSeen.made = []
+    pending = set()
+
+    class Tracked:
+        def __init__(self, value):
+            self._value = value
+            pending.add(self)
+
+        def __array__(self, *a, **kw):
+            pending.discard(self)
+            return self._value
+
+    given_back = []
+    real_give_back = stripe._StagingRing.give_back
+
+    def give_back(ring):
+        lanes = _LanesSeen.made[-1]
+        given_back.append((lanes._open, len(lanes._queues), len(pending)))
+        real_give_back(ring)
+
+    monkeypatch.setattr(stripe._StagingRing, "give_back", give_back)
+    with monkeypatch.context() as failing:
+        _wrap_lazy(failing, Tracked)
+        if where == "read":  # every survivor read after the first batch's: every group fails
+            for cls in (stripe.LocalSlabSource, stripe.LocalProjectionSource):
+                fails = _Fails(cls.read_into, 3 if entry == "projections" else 12)
+                failing.setattr(cls, "read_into", lambda self, off, out, _f=fails: _f(self, off, out))
+        else:  # the third of its kind, with two inflight
+            for name in LAZY:
+                fails = _Fails(getattr(Encoder, name), 2)
+                failing.setattr(Encoder, name, lambda self, *a, _f=fails, **kw: _f(self, *a, **kw))
+        if entry.startswith("batch"):
+            assert set(run()["errors"]) == {b for b, _, _ in volumes}
+        else:
+            with pytest.raises(_Boom, match="injected"):
+                run()
+    assert given_back == [(0, 0, 0)]
+    kept = {id(b) for b in stripe._pool_free}
+    assert len(kept) == stripe.DEFAULT_PIPELINE_DEPTH + 1
+    _poison_pool()
+    res = run()
+    assert res is None or not res["errors"]
+    assert given_back == [(0, 0, 0)] * 2 and {id(b) for b in stripe._pool_free} == kept
+    got = _rebuilt_bytes(volumes)
+    for base, enc, missing in volumes:
+        for s in missing:
+            os.unlink(stripe.shard_file_name(base, s))
+        assert stripe.rebuild_ec_files_serial(base, encoder=enc, buffer_size=BUFFER) == missing
+    assert got == _rebuilt_bytes(volumes)

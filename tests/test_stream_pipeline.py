@@ -4,16 +4,19 @@ fused per-shard CRC recording/verification, exception-safety (a mid-stream
 failure must drain inflight device work and unlink partial shard files),
 decode-matrix cache boundedness, and the kernel_sweep --smoke CI gate."""
 
+import io
 import json
 import os
 import subprocess
 import sys
 import threading
+import time
 import zlib
 
 import numpy as np
 import pytest
 
+from seaweedfs_tpu import stats
 from seaweedfs_tpu.ec import stripe
 from seaweedfs_tpu.ec.constants import TOTAL_SHARDS_COUNT
 from seaweedfs_tpu.ops import gf8
@@ -736,10 +739,382 @@ def test_bulk_stage_spans_account_for_a_run(tmp_path, monkeypatch, pipeline):
     for drain in (s for s in spans if s["name"] == f"{pipeline}.drain"):
         assert [c["name"] for c in drain["spans"]][:2] == [f"{pipeline}.sync", f"{pipeline}.wait"]
         assert names(drain) == sorted(["sync", "wait"] + ["write", "crc"] * written)
-    # a stage holds its ten reads and the wait for them; an encode's also its
-    # data shards' writes and CRCs, which run on beside the dispatch
+    # a stage holds its ten reads and the wait for them, and between the two,
+    # from the batch that finds `depth` inflight on, the drain it runs ahead
+    # of; an encode's also its data shards' writes and CRCs, which run on
+    # beside the dispatch
     data = ["write", "crc"] * 10 if pipeline == "encode" else []
-    for stage in (s for s in spans if s["name"] == f"{pipeline}.stage"):
-        assert names(stage) == sorted(["read"] * 10 + ["wait"] + data)
+    stages = [s for s in spans if s["name"] == f"{pipeline}.stage"]
+    stages.sort(key=lambda s: s["t_ms"])
+    for i, stage in enumerate(stages):
+        ahead_of = ["drain"] if i >= stripe.DEFAULT_PIPELINE_DEPTH else []
+        assert names(stage) == sorted(["read"] * 10 + ["wait"] + ahead_of + data)
+        own = [c for c in stage["spans"] if c["name"].rsplit(".", 1)[-1] in ("drain", "wait")]
+        assert [c["name"].rsplit(".", 1)[-1] for c in own] == ahead_of + ["wait"]  # the join comes last
     calling = sum(_calling_self_ms(s) for s in spans if s["name"].rsplit(".", 1)[-1] in CALLING)
     assert calling >= 0.9 * root["dur_ms"], (calling, root["dur_ms"])
+
+
+# -- staging runs ahead of the drain, into a ring the process keeps -------------
+
+
+#: small rows only: one `_encode_rows` call, so one lease, an encode
+ROWS = dict(large_block_size=65536, small_block_size=4096, buffer_size=4096)
+
+
+class _Held:
+    """A dispatch's lazy handle whose sync (np.asarray of it) says that it has
+    begun and then blocks until the test lets every handle go."""
+
+    def __init__(self, value, gate):
+        self._value, self._gate = value, gate
+
+    def __array__(self, *a, **kw):
+        self._gate.syncing.set()
+        assert self._gate.release.wait(20), "the test never released the device"
+        return self._value
+
+
+class _Gate:
+    def __init__(self):
+        self.syncing, self.release = threading.Event(), threading.Event()
+
+
+class _HeldEncoder(Encoder):
+    def __init__(self, *a, gate, **kw):
+        super().__init__(*a, **kw)
+        self._gate = gate
+
+    def encode_parity_lazy(self, data, donate=False):
+        return _Held(np.asarray(super().encode_parity_lazy(data, donate=donate)), self._gate)
+
+    def reconstruct_lazy(self, stack, survivors, wanted, donate=False):
+        return _Held(np.asarray(super().reconstruct_lazy(stack, survivors, wanted, donate=donate)), self._gate)
+
+
+def _wait_until(cond, seconds):
+    deadline = time.monotonic() + seconds
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.005)
+    return cond()
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+@pytest.mark.parametrize("source", ["file", "shim"])
+def test_encode_reads_run_ahead_of_the_drain_where_a_lane_reads_them(tmp_path, monkeypatch, source, depth):
+    """Eight one-row batches, the device held: the first drain is batch 0's,
+    made while batch `depth` is staged. A real file's ten slab reads of that
+    batch are on the lanes before the drain returns; a source that is no file
+    is read on the calling thread, which stands in the drain, so after it. The
+    bytes are the golden run's either way."""
+    monkeypatch.setattr(os, "cpu_count", lambda: 8)
+    rows, block = 8, 4096
+    base = _write_dat(tmp_path, rows * 10 * block)
+    stripe.write_ec_files(
+        base, encoder=ENC, max_batch_bytes=10 * block,
+        large_block_size=65536, small_block_size=block, buffer_size=block,
+    )
+    golden = _shard_bytes(base)
+    gate = _Gate()
+    enc = _HeldEncoder(10, 4, backend="numpy", gate=gate)
+    batches_read = []  # the batch of every slab read, as it starts
+    real_pread = stripe.pread_padded_into
+
+    def pread(fd, offset, out):
+        batches_read.append(offset // (10 * block))
+        real_pread(fd, offset, out)
+
+    class Shim(_Shim):
+        def seek(self, pos):
+            batches_read.append(pos // (10 * block))
+            super().seek(pos)
+
+    monkeypatch.setattr(stripe, "pread_padded_into", pread)
+    f = open(base + ".dat", "rb") if source == "file" else Shim(base + ".dat")
+    outs = [io.BytesIO() for _ in range(TOTAL_SHARDS_COUNT)]
+    done = []
+    worker = threading.Thread(
+        target=lambda: done.append(stripe._encode_rows(f, enc, outs, 0, block, rows, block, 10 * block, depth))
+    )
+    worker.start()
+    try:
+        assert gate.syncing.wait(20), "no drain began"
+        if source == "file":
+            assert _wait_until(lambda: batches_read.count(depth) == 10, 10), sorted(set(batches_read))
+        else:
+            time.sleep(0.3)
+            assert depth not in batches_read
+        assert max(batches_read) == depth - (source == "shim")  # and nothing further ahead
+    finally:
+        gate.release.set()
+        worker.join(30)
+    (f if source == "file" else f._f).close()
+    assert done == [rows] and [o.getvalue() for o in outs] == golden
+    assert sorted(batches_read) == sorted(list(range(rows)) * 10)
+
+
+ENCODE_SHAPES = [
+    # (what, longer volume, the volume, batch budget): two large rows and small
+    # ones, batches of three 4 KiB segments that cut across the large rows and
+    # leave a tail batch; small rows only with a tail batch of three
+    ("tiers", 5 * 163_840 + 70_001, 2 * 163_840 + 50_000, 10 * 3 * 4096),
+    ("tail", 17 * 40_960 + 5, 6 * 40_960 + 123, 10 * 4 * 4096),
+    ("one-batch", 2 * 40_960, 4096 + 1, 10 * 4 * 4096),
+]
+
+
+def _poison_pool():
+    """Every buffer the pool holds, filled with a byte no volume here is made of
+    in runs (the first MiB: more than any slot of these tests)."""
+    for buf in stripe._pool_free:
+        buf[: 1 << 20] = 0xA5
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+@pytest.mark.parametrize("shape", ENCODE_SHAPES, ids=lambda s: s[0])
+def test_encode_through_a_kept_ring_after_a_longer_volume_is_byte_exact(tmp_path, monkeypatch, shape, depth):
+    """A longer volume first, a shorter one second, through the same pooled
+    buffers (whose last tenant's bytes are then overwritten with 0xA5): the
+    second run's 14 files, CRCs and rebuilt shards are those of a run with a
+    fresh ring and of the inline order on one core."""
+    _, longer, size, budget = shape
+    monkeypatch.setattr(stripe, "_pool_free", [])
+    reused = stats.StagingRingLeases.labels("reused")
+    first = _encode_then_rebuild(monkeypatch, tmp_path / "longer", longer, 8, depth, budget)
+    assert first[3] == first[2]
+    _poison_pool()
+    before = reused.value
+    kept = _encode_then_rebuild(monkeypatch, tmp_path / "kept", size, 8, depth, budget)
+    assert reused.value - before >= 2  # the encode's tiers and the rebuild, none allocated
+    monkeypatch.setattr(stripe, "_pool_free", [])
+    fresh = _encode_then_rebuild(monkeypatch, tmp_path / "fresh", size, 8, depth, budget)
+    monkeypatch.setattr(stripe, "_pool_free", [])
+    inline = _encode_then_rebuild(monkeypatch, tmp_path / "inline", size, 1, depth, budget)
+    assert kept[2:] == fresh[2:] == inline[2:]
+    assert kept[3] == kept[2] and kept[4] == [zlib.crc32(b) for b in kept[2]]
+
+
+def test_the_pool_keeps_no_more_than_its_bound_and_serves_both_geometries():
+    """The bound is the constant PERF.md states. A server that has run one encode
+    and one rebuild at the deployment's geometries holds the encode's three
+    slots and nothing else, and the rebuild's ring is views of the same three
+    buffers; geometry churn never leaves more than the bound behind, and what
+    is kept is the largest buffers."""
+    assert stripe.STAGING_POOL_MAX_BYTES == 3 * 64 * 1024 * 1024 == 201_326_592
+    saved, stripe._pool_free = stripe._pool_free, []
+    try:
+        encode = stripe._ring_for(3, (10, 6_553_600))
+        flat = {id(b) for b in encode._flat}
+        encode.give_back()
+        rebuild = stripe._ring_for(3, (10, 4_194_304))
+        assert {id(b) for b in rebuild._flat} == flat and not stripe._pool_free
+        assert rebuild.take().shape == (10, 4_194_304)
+        rebuild.give_back()
+        assert sum(b.size for b in stripe._pool_free) == 196_608_000 <= stripe.STAGING_POOL_MAX_BYTES
+        # the other order: the rebuild's smaller buffers make room for the encode's
+        stripe._pool_free = []
+        stripe._ring_for(3, (10, 4_194_304)).give_back()
+        stripe._ring_for(3, (10, 6_553_600)).give_back()
+        assert [b.size for b in stripe._pool_free] == [65_536_000] * 3  # the 41.9 MB ones went
+        for slots, shape in [(4, (10, 6_553_600)), (2, (14, 5_000_000)), (3, (2, 4 * 4_194_304)),
+                             (3, (10, 4096)), (1, (1, stripe.STAGING_POOL_MAX_BYTES + 1)), (5, (20, 1 << 20))]:
+            ring = stripe._ring_for(slots, shape)
+            assert ring.take().shape == shape
+            ring.give_back()
+            assert sum(b.size for b in stripe._pool_free) <= stripe.STAGING_POOL_MAX_BYTES
+            assert len({id(b) for b in stripe._pool_free}) == len(stripe._pool_free)
+        with pytest.raises(TypeError):
+            ring.take()  # given back: no slot to hand out
+    finally:
+        stripe._pool_free = saved
+
+
+@pytest.mark.parametrize("pipeline", ["encode", "rebuild"])
+def test_the_lease_counter_reads_allocated_once_and_reused_after(tmp_path, monkeypatch, pipeline):
+    """From an empty pool: the first run allocates, every later one reuses, on
+    the counter `/metrics` exposes and as `ring=` on the run's span."""
+    from seaweedfs_tpu.obs import trace
+
+    monkeypatch.setattr(stripe, "_pool_free", [])
+    monkeypatch.setattr(os, "cpu_count", lambda: 8)
+    seen = []
+    for i in range(3):
+        d = tmp_path / str(i)
+        d.mkdir()
+        base = _write_dat(d, 6 * 40_960 + 123)
+        if pipeline == "rebuild":  # its set-up's encode is not what is counted
+            stripe.write_ec_files(base, encoder=ENC, max_batch_bytes=10 * 4 * 4096, **ROWS)
+            for s in LOST:
+                os.unlink(stripe.shard_file_name(base, s))
+            if i == 0:
+                monkeypatch.setattr(stripe, "_pool_free", [])
+        counts = {o: stats.StagingRingLeases.labels(o).value for o in ("allocated", "reused")}
+        trace.RING.clear()
+        if pipeline == "encode":
+            stripe.write_ec_files(base, encoder=ENC, max_batch_bytes=10 * 4 * 4096, **ROWS)
+        else:
+            stripe.rebuild_ec_files(base, encoder=ENC, buffer_size=8192, max_batch_bytes=10 * 2 * 8192)
+        seen.append((
+            _run_attr(f"{pipeline}.run", "ring"),
+            *(stats.StagingRingLeases.labels(o).value - counts[o] for o in ("allocated", "reused")),
+        ))
+    assert seen == [("allocated", 1, 0), ("reused", 0, 1), ("reused", 0, 1)]
+    text = stats.REGISTRY.expose()
+    assert 'weedtpu_staging_ring_leases_total{outcome="reused"}' in text
+    assert 'weedtpu_staging_ring_leases_total{outcome="allocated"}' in text
+
+
+class _Tracked:
+    """A lazy handle that is in `pending` until it is synced."""
+
+    pending: set = set()
+
+    def __init__(self, value):
+        self._value = value
+        self.pending.add(self)
+
+    def __array__(self, *a, **kw):
+        self.pending.discard(self)
+        return self._value
+
+
+@pytest.mark.parametrize("where", ["read", "dispatch", "write"])
+def test_a_failed_encode_gives_its_ring_back_after_its_inflight_work_is_gone(tmp_path, monkeypatch, where):
+    """A read, a dispatch or a lane's write that raises at the third batch of
+    depth 2: the ring goes back once, after the lanes are aborted and every
+    dispatched handle is synced or discarded; the next encode runs through
+    those buffers and is byte-exact."""
+    monkeypatch.setattr(os, "cpu_count", lambda: 8)
+    monkeypatch.setattr(stripe, "_pool_free", [])
+    monkeypatch.setattr(stripe, "_ShardLanes", _LanesSeen)
+    size, budget = 6 * 40_960 + 123, 10 * 4096  # seven one-segment batches
+    d = tmp_path / "golden"
+    d.mkdir()
+    golden_base = _write_dat(d, size)
+    stripe.write_ec_files(golden_base, encoder=ENC, max_batch_bytes=budget, **ROWS)
+    golden = _shard_bytes(golden_base)
+    _LanesSeen.made, _Tracked.pending = [], set()
+    monkeypatch.setattr(stripe, "_pool_free", [])
+
+    class Enc(Encoder):
+        calls = 0
+
+        def encode_parity_lazy(self, data, donate=False):
+            Enc.calls += 1
+            if where == "dispatch" and Enc.calls == 3:
+                raise _Boom("dispatch")
+            return _Tracked(np.asarray(super().encode_parity_lazy(data, donate=donate)))
+
+    reads = []
+    real_pread = stripe.pread_padded_into
+
+    def pread(fd, offset, out):
+        reads.append(offset)
+        if where == "read" and len(reads) == 25:
+            raise _Boom("read")
+        real_pread(fd, offset, out)
+
+    monkeypatch.setattr(stripe, "pread_padded_into", pread)
+    if where == "write":
+        real_open = open
+
+        def failing_open(path, mode="r", *a, **kw):
+            f = real_open(path, mode, *a, **kw)
+            return _FailingWrites(f, 3) if (path.endswith(".ec05") and "w" in mode) else f
+
+        monkeypatch.setattr(stripe, "open", failing_open, raising=False)
+    given_back = []
+    real_give_back = stripe._StagingRing.give_back
+
+    def give_back(ring):
+        lanes = _LanesSeen.made[-1]
+        given_back.append((lanes._open, len(lanes._queues), len(_Tracked.pending)))
+        real_give_back(ring)
+
+    monkeypatch.setattr(stripe._StagingRing, "give_back", give_back)
+    base = _write_dat(tmp_path, size)
+    with pytest.raises(_Boom):
+        stripe.write_ec_files(base, encoder=Enc(10, 4, backend="numpy"), max_batch_bytes=budget,
+                              pipeline_depth=2, **ROWS)
+    assert given_back == [(0, 0, 0)]
+    kept = stripe._pool_free
+    assert len(kept) == 3
+    monkeypatch.undo()  # the failures; the pool stays the failed run's
+    monkeypatch.setattr(stripe, "_pool_free", kept)
+    before = stats.StagingRingLeases.labels("reused").value
+    _poison_pool()
+    stripe.write_ec_files(base, encoder=ENC, max_batch_bytes=budget, pipeline_depth=2, **ROWS)
+    assert stats.StagingRingLeases.labels("reused").value == before + 1
+    assert _shard_bytes(base) == golden
+
+
+@pytest.mark.parametrize("pair", ["encode+encode", "encode+rebuild", "rebuild+rebuild"])
+def test_two_runs_at_once_never_hold_the_same_buffer(tmp_path, monkeypatch, pair):
+    """Two bulk runs in one process, each held on the device until both have
+    their ring: no buffer is in both leases, although the pool had one whole
+    ring to give; afterwards every buffer is in the pool once, within the
+    bound, and both runs wrote the right bytes."""
+    monkeypatch.setattr(os, "cpu_count", lambda: 8)
+    monkeypatch.setattr(stripe, "_pool_free", [])
+    size, budget = 6 * 40_960 + 123, 10 * 4 * 4096
+    bases = []
+    for i in range(2):
+        d = tmp_path / str(i)
+        d.mkdir()
+        bases.append(_write_dat(d, size, seed=i + 1))
+        stripe.write_ec_files(bases[i], encoder=ENC, max_batch_bytes=budget, **ROWS)
+    golden = [_shard_bytes(b) for b in bases]
+    assert len(stripe._pool_free) == 3  # one ring to give, and two runs to want it
+    leases, both = [], threading.Barrier(2, timeout=20)
+    real_ring_for = stripe._ring_for
+
+    def ring_for(slots, shape):
+        ring = real_ring_for(slots, shape)
+        leases.append({id(b) for b in ring._flat})
+        return ring
+
+    class Meet(Encoder):
+        """The first dispatch of a run waits until the other run has its ring too."""
+
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            self._met = False
+
+        def _meet(self):
+            if not self._met:
+                self._met = True
+                both.wait()
+
+        def encode_parity_lazy(self, data, donate=False):
+            self._meet()
+            return super().encode_parity_lazy(data, donate=donate)
+
+        def reconstruct_lazy(self, stack, survivors, wanted, donate=False):
+            self._meet()
+            return super().reconstruct_lazy(stack, survivors, wanted, donate=donate)
+
+    monkeypatch.setattr(stripe, "_ring_for", ring_for)
+    errors = []
+
+    def run(kind, base):
+        try:
+            enc = Meet(10, 4, backend="numpy")
+            if kind == "encode":
+                stripe.write_ec_files(base, encoder=enc, max_batch_bytes=budget, **ROWS)
+            else:
+                for s in LOST:
+                    os.unlink(stripe.shard_file_name(base, s))
+                stripe.rebuild_ec_files(base, encoder=enc, buffer_size=8192, max_batch_bytes=10 * 2 * 8192)
+        except BaseException as e:  # noqa: BLE001 — for the test's thread to see
+            errors.append(e)
+
+    threads = [threading.Thread(target=run, args=(k, b)) for k, b in zip(pair.split("+"), bases)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(60)
+    assert not errors, errors
+    assert len(leases) == 2 and not (leases[0] & leases[1])
+    assert len({id(b) for b in stripe._pool_free}) == len(stripe._pool_free) >= 3
+    assert sum(b.size for b in stripe._pool_free) <= stripe.STAGING_POOL_MAX_BYTES
+    assert [_shard_bytes(b) for b in bases] == golden
