@@ -2048,15 +2048,19 @@ class VolumeServer:
 
     def _rpc_ec_rebuild_batch(self, req: dict, ctx) -> dict:
         """VolumeEcShardsRebuildBatch: this node rebuilds MANY volumes'
-        missing shards in one call — the fleet scheduler's dispatch unit.
+        missing shards in one call — the fleet scheduler's dispatch unit,
+        and `ec.rebuild`'s for a rebuilder's volumes that need no survivor
+        copy.
         Each volume is planned like a single remote rebuild (fresh holder
         map, survivor choice, shard-size preflight, slab sources through
-        the admission-gated bulk read), then same-signature volumes fuse
-        into shared width-packed decode pipelines
-        (`stripe.rebuild_ec_files_batch`). Rebuilt shards mount here and
-        the delta heartbeats immediately. Per-volume failures are soft
-        (reported in `results[].error`); the call only faults wholesale
-        on malformed requests."""
+        the admission-gated bulk read; a volume whose chosen survivors are
+        all local (what the shell sends: they never left) is
+        planned from its files alone, as `rebuild_ec_files` plans it, and
+        asks no holder anything), then the volumes run group-major through
+        ONE width-packed decode pipeline (`stripe.rebuild_ec_files_batch`).
+        Rebuilt shards mount here and the delta heartbeats immediately.
+        Per-volume failures are soft (reported in `results[].error`); the
+        call only faults wholesale on malformed requests."""
         vols = list(req.get("volumes") or [])
         if not vols:
             raise rpc.RpcFault(
@@ -2113,10 +2117,13 @@ class VolumeServer:
                         continue
                     holders = sorted({a for aa in locs.values() for a in aa})
                     self._ensure_ec_index_files(vid, collection, base, holders)
-                    shard_size, _caps = self._resolve_shard_size(
-                        vid, base, local, holders
-                    )
                     chosen = present[: enc.data_shards]
+                    # a decode none of whose survivors crosses the network
+                    # is planned from the local files' own lengths, which
+                    # have to agree: no holder is asked
+                    shard_size, _caps = self._resolve_shard_size(
+                        vid, base, local, [] if local.issuperset(chosen) else holders
+                    )
                     for s in chosen:
                         if s in local:
                             sources[s] = stripe.LocalSlabSource(
@@ -2146,6 +2153,7 @@ class VolumeServer:
                     errors[vid] = f"{type(e).__name__}: {e}"[:300]
             try:
                 res = stripe.rebuild_ec_files_batch(jobs, **tuning)
+                trace_mod.annotate(signature_groups=res["signature_groups"])
             finally:
                 for job in jobs:
                     for src in job["sources"].values():
@@ -2176,6 +2184,7 @@ class VolumeServer:
                     err = f"mount failed: {e}"[:300]
             if rebuilt:
                 stats.EcRebuildRuns.labels(self.store.encoder.backend).inc()
+                stats.EcRebuildBatchVolumes.inc()
             results.append(
                 {
                     "volume_id": m["vid"],

@@ -567,15 +567,14 @@ class RepairScheduler:
             for v, _, _ in batch:
                 self._inflight.add(v)
         stats.RepairInflight.set(len(self._inflight))
-        vols = [
-            {"volume_id": v, "collection": collections.get(v, "")}
-            for v, _, _ in batch
-        ]
-        return (target, batch, vols)
+        request = placement.rebuild_batch_request(
+            (v, collections.get(v, "")) for v, _, _ in batch
+        )
+        return (target, batch, request)
 
     # -- dispatch ------------------------------------------------------------
 
-    def _run_batch(self, target: dict, batch: list, vols: list) -> None:
+    def _run_batch(self, target: dict, batch: list, request: dict) -> None:
         addr = target["grpc"]
         seqs = {}
         n_missing_of = {v: n for v, _, n in batch}
@@ -589,7 +588,7 @@ class RepairScheduler:
                     resp = c.call(
                         VOLUME_SERVICE,
                         "VolumeEcShardsRebuildBatch",
-                        {"volumes": vols},
+                        request,
                         timeout=600,
                     )
             except grpc.RpcError as e:

@@ -243,6 +243,20 @@ def pick_rebuild_target(
     return min(pool, key=key)
 
 
+def rebuild_batch_request(volumes) -> dict:
+    """The `VolumeEcShardsRebuildBatch` request for (volume id, collection)
+    pairs: the one place it is spelled, for the master's scheduler and the
+    shell's `ec.rebuild` alike. The order given is the order the target plans
+    in, and so the block order of its packed batches (the scheduler sends
+    priority order, the shell volume-id order)."""
+    return {
+        "volumes": [
+            {"volume_id": int(vid), "collection": collection or ""}
+            for vid, collection in volumes
+        ]
+    }
+
+
 def plan_parity_targets(
     nodes: Sequence[dict],
     owner_url: str,

@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import io
 import json
+import math
 import os
 import threading
 import zlib
@@ -1324,7 +1325,9 @@ class _Seg(NamedTuple):
 
 
 class _Batch(NamedTuple):
-    width: int  # shard bytes staged per row: a volume's tail is rounded up to `buffer_size`
+    #: shard bytes staged per row: a volume's tail, and a packed plan's last
+    #: batch, are rounded up to `buffer_size`
+    width: int
     valid: int  # ... of them the shards': what is written
     cols: int  # staging columns in use (projections fold `rows` shard bytes into each)
     segs: list
@@ -1355,7 +1358,8 @@ def _decode_blocks(staging: np.ndarray, cols: int, blocks: list):
     """The decode of a batch of survivor slabs. One signature from column 0 on
     (every batch of a single-volume rebuild) is one `reconstruct_lazy` over the
     dispatched columns, donated; several signatures side by side are one
-    block-diagonal `reconstruct_block`."""
+    block-diagonal `reconstruct_block` over the same columns (on the jax
+    backend one program too: the plan starts every block on its tile grid)."""
     first = blocks[0]
     enc = first["encoder"]
     if len(blocks) == 1 and first["col_start"] == 0:
@@ -1363,7 +1367,7 @@ def _decode_blocks(staging: np.ndarray, cols: int, blocks: list):
             staging[: enc.data_shards, :cols], first["survivors"], first["wanted"], donate=True
         )  # async: H2D + launch
     trace_mod.annotate(blocks=len(blocks))
-    return enc.reconstruct_block(staging, blocks)
+    return enc.reconstruct_block(staging[:, :cols], blocks)
 
 
 def _run_rebuild(
@@ -1489,7 +1493,9 @@ def _run_rebuild(
                                 read(group, src, off, staging[row, first:end])
                         with trace_mod.span("rebuild.wait"):
                             lanes.join(reads)
-                        cols = _aligned(batch.cols, plan.align)  # <= the slot's: monotone
+                        # <= the slot's: monotone. A packed tail dispatches its
+                        # rounded width, so that widths repeat from run to run
+                        cols = _aligned(max(batch.cols, batch.width), plan.align)
                         if cols > batch.cols:
                             staging[:, batch.cols:cols] = 0  # tail: pad columns are zeros
                     # a read may have failed its group after an earlier segment staged
@@ -1755,6 +1761,10 @@ def rebuild_ec_files_batch(
     max_k = max(job["encoder"].data_shards for _, job in flat)
     align = max(int(getattr(job["encoder"], "width_align", 1) or 1) for _, job in flat)
     span = _aligned(max(1, max_batch_bytes // (max_k * buffer_size)) * buffer_size, align)
+    # where a new signature's block may start: the codec's tile grid, so that
+    # a batch of several blocks is one device program whose shape does not
+    # depend on where a volume's tail fell (1 on host backends: packed tight)
+    grid = max(job["encoder"].block_tile(span) for _, job in flat)
     # width-packed, group-major: a group's columns of a batch are one segment
     batches: list[_Batch] = []
     segs: list[_Seg] = []
@@ -1765,6 +1775,11 @@ def rebuild_ec_files_batch(
         size = int(job["shard_size"])
         off = 0
         while off < size:
+            if segs and segs[-1].group != gi:
+                room -= -(span - room) % grid  # the columns skipped are never read
+                if room == 0:
+                    batches.append(_Batch(span, span, span, segs))
+                    segs, room = [], span
             take = min(room, size - off)
             col = span - room
             if not segs or segs[-1].group != gi:
@@ -1790,7 +1805,10 @@ def rebuild_ec_files_batch(
         output += len(missing)
     if segs:
         used = span - room
-        batches.append(_Batch(used, used, used, segs))
+        # the tail dispatches a whole number of buffers, as a volume's does
+        # (`_windows`): the widths of a run's programs repeat in the next run
+        width = min(span, _aligned(used, math.lcm(buffer_size, align, grid)))
+        batches.append(_Batch(width, used, used, segs))
     plan = _Plan(
         (max_k, span),
         align,

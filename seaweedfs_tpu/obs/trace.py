@@ -54,7 +54,7 @@ SPAN_NAMES: dict[str, str] = {
     "http.read": "volume-server HTTP GET of one needle (the serving path)",
     "http.write": "volume-server HTTP POST/PUT of one needle",
     "master.http": "master HTTP facade route (/dir/assign, /dir/lookup, ...)",
-    "shell.command": "one weed-shell command execution",
+    "shell.command": "one weed-shell command execution (command, modules loaded at its start, rpcs it made)",
     "rpc.server": "server side of one gRPC method (method name in attrs)",
     "ec.lookup": "master LookupEcVolume round-trip (shard-location cache miss)",
     "ec.recover": "degraded interval reconstruction, client-facing wall time",
@@ -70,11 +70,11 @@ SPAN_NAMES: dict[str, str] = {
     "ec.copy.file": "one file of a copy: its stream from the source written to `.cpy`, then fsync + rename (ext, bytes); self time is the stream",
     "ec.copy.fsync": "flush + fsync of one copied file, apart from its stream",
     "ec.copy.serve": "VolumeEcShardFileCopy on the source: one file read and streamed out (ext, bytes)",
-    "rebuild.run": "one whole-volume rebuild (local or distributed)",
+    "rebuild.run": "one rebuild pipeline: a whole volume's (local or distributed), or a VolumeEcShardsRebuildBatch's over many (batch= volumes, signature_groups=); ring= says whether its staging ring was reused",
     "rebuild.stage": "staging-ring fill for one rebuild batch (disk/wire): its lane reads queued, the drain it runs ahead of (nested), the wait for the reads",
     "rebuild.read": "one survivor's slab read into its staging row (child of rebuild.stage; on a lane thread where the source allows)",
     "rebuild.wait": "the calling thread blocked in a join of lane tasks (a batch's reads, the last drain's writes)",
-    "rebuild.dispatch": "reconstruct_lazy: device_put (H2D) + the jit call, until it returns",
+    "rebuild.dispatch": "reconstruct_lazy, or reconstruct_block where a packed batch holds several signature groups (blocks=): device_put (H2D) + the jit call, until it returns",
     "rebuild.drain": "device sync + shard write-out + CRC for one rebuild batch",
     "rebuild.sync": "np.asarray of one batch's decode: device wait + D2H, nothing else",
     "rebuild.write": "one rebuilt shard's bytes of one batch written to its file (on a lane thread)",

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import os
 import threading
 from typing import Optional, Sequence
@@ -271,6 +272,20 @@ class Encoder:
         if self.backend != "mesh":
             return 1
         return self._mesh_dispatch().width_align
+
+    #: columns of one tile of the jax backend's packed-batch program
+    BLOCK_TILE = 65536
+
+    def block_tile(self, width: int) -> int:
+        """The column grid on which a packed batch of `width` columns has to
+        start its blocks for `reconstruct_block` to run it as ONE device
+        program (one decode matrix per tile, `rs_jax.apply_matrix`): the part
+        of `BLOCK_TILE` that divides `width` on the jax backend; 1 elsewhere,
+        where a block may start at any column (xorsched stitches blocks of
+        any width into one pass; the other backends apply block by block)."""
+        if self.backend != "jax":
+            return 1
+        return math.gcd(int(width), self.BLOCK_TILE)
 
     def _count_dispatch(self) -> None:
         try:
@@ -638,8 +653,8 @@ class Encoder:
         staging: np.ndarray,
         blocks: Sequence[dict],
     ):
-        """Block-diagonal fused decode: ONE dispatch over a staging batch
-        that packs MANY signature groups' survivor columns side by side.
+        """Block-diagonal decode of a staging batch that packs MANY signature
+        groups' survivor columns side by side: one call, one sync point.
 
         `staging` is a (max_k, W) uint8 matrix; block g is a dict with
         `survivors` / `wanted` (shard-id sequences), `col_start` / `width`
@@ -649,17 +664,36 @@ class Encoder:
         occupy staging[:k_g, col_start:col_start+width] and its decoded
         shards land at the same columns of the returned (max_m, W) array,
         rows [0, len(wanted_g)).  Rows past len(wanted_g) inside a block's
-        columns are UNSPECIFIED (never zeroed — the composite's zero blocks
-        are structural, not materialized).
+        columns, and columns that no block covers, are UNSPECIFIED (never
+        zeroed — the composite's zero blocks are structural, not
+        materialized).
 
         GF matmul is column-independent, so packing different volumes'
         columns into one batch is byte-exact; each block keeps its own
-        LRU'd decode matrix and (on the xorsched backend) its own compiled
-        XOR program — the stitched pass is dispatched as per-block column
-        ranges, never as one giant composite matrix.  Host backends return
-        the materialized ndarray; device backends return a lazy handle
-        whose np.asarray() is the synchronization point, like
-        reconstruct_lazy."""
+        LRU'd decode matrix. What runs, by backend:
+
+        - jax: ONE device program over the whole (max_k, W) batch, which
+          crosses to the device once, donated: W is cut into tiles of
+          `block_tile(W)` columns and every tile is decoded by the matrix
+          of the block it lies in (`rs_jax.apply_matrix` of the stack of
+          them, padded with zeros to the batch's largest geometry). The
+          program's shape
+          is (W / tile, max_m, max_k, W): it does not change with where
+          blocks begin and end, so a pipeline of such batches compiles
+          once. That needs every block to start on the tile grid (a block
+          covers the columns up to the next block's start, so padding
+          before a start decodes with its left neighbour's matrix and is
+          never read); blocks that do not are applied one by one, as below.
+        - xorsched: one stitched native (or interpreter) pass over the flat
+          (block, width-tile) task list, each block its own compiled XOR
+          program.
+        - pallas, mesh, native, numpy: one apply per block over its column
+          range (on the device backends asynchronous, so the blocks overlap
+          in flight; each a program of its own width).
+
+        Host backends return the materialized ndarray; device backends
+        return a lazy handle whose np.asarray() is the synchronization
+        point, like reconstruct_lazy."""
         staging = np.asarray(staging, dtype=np.uint8)
         if staging.ndim != 2:
             raise ValueError(f"want a 2-D (max_k, W) staging batch, got {staging.shape}")
@@ -687,6 +721,20 @@ class Encoder:
         max_m = max(m.shape[0] for _, m, _, _ in spans)
         if self.backend == "xorsched":
             return self._reconstruct_block_xorsched(staging, spans, max_m)
+        tile = self.block_tile(width_total)
+        if self.backend == "jax" and all(c0 % tile == 0 for _, _, c0, _ in by_col):
+            # one program: a block's matrix from its first tile up to the next
+            # block's (the first block's from tile 0: nobody reads what lies
+            # left of a block), each padded with zeros to the batch's largest
+            from seaweedfs_tpu.ops import rs_jax
+
+            self._count_dispatch()
+            tiles = np.zeros((width_total // tile, max_m, staging.shape[0]), dtype=np.uint8)
+            starts = [0] + [c0 // tile for _, _, c0, _ in by_col[1:]]
+            for (_, m, _, _), first in zip(by_col, starts):
+                tiles[first:] = 0
+                tiles[first:, : m.shape[0], : m.shape[1]] = m
+            return rs_jax.apply_matrix(tiles, staging, donate=True)
         # other backends: per-block dispatches (async on device backends,
         # so blocks overlap in flight; _apply_lazy counts each), one sync
         # point for the whole batch via the lazy wrapper

@@ -84,6 +84,49 @@ def gf_apply(b_bits: jax.Array, data: jax.Array) -> jax.Array:
 _gf_apply_donated = jax.jit(_gf_apply_impl, donate_argnums=(1,))
 
 
+def _gf_apply_tiled_impl(b_tiles: jax.Array, data: jax.Array) -> jax.Array:
+    """One lifted matrix per column tile: b_tiles (T, R*8, C*8) int8, data
+    (C, T*w) uint8 -> (R, T*w) uint8, tile t of the output being matrix t
+    applied to tile t of the input. What a packed rebuild batch runs: its
+    signature groups' decode matrices side by side in one program, whose
+    shape does not say where one group's columns end."""
+    tiles, r8, _ = b_tiles.shape
+    c, n = data.shape
+    # tiles lead, as the matrices' do: the v5e compiler then fuses the
+    # unpack into the batched matmul, as it does in the flat program
+    by_tile = jnp.moveaxis(data.reshape(c, tiles, n // tiles), 1, 0)  # (T, C, w)
+    acc = jax.lax.dot_general(
+        b_tiles,
+        bytes_to_bits(by_tile),
+        (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.int32,
+    )  # (T, R*8, w)
+    out = bits_to_bytes(acc & 1)  # (T, R, w)
+    return jnp.moveaxis(out, 0, 1).reshape(r8 // 8, n)
+
+
+gf_apply_tiled = jax.jit(_gf_apply_tiled_impl)
+_gf_apply_tiled_donated = jax.jit(_gf_apply_tiled_impl, donate_argnums=(1,))
+
+
+def _run_counted(fn, *args) -> jax.Array:
+    """Call a jitted program; a call that grew its jit cache traced and
+    compiled (or loaded) a program for a shape this process had not run:
+    `weedtpu_codec_programs_compiled_total` counts those. (A jax whose
+    jitted functions do not say their cache's size counts nothing.)"""
+    size = getattr(fn, "_cache_size", None)
+    if size is None:
+        return fn(*args)
+    before = size()
+    out = fn(*args)
+    grew = size() - before
+    if grew > 0:
+        from seaweedfs_tpu import stats
+
+        stats.CodecProgramsCompiled.inc(grew)
+    return out
+
+
 @functools.lru_cache(maxsize=1)
 def donation_supported() -> bool:
     """Buffer donation is a no-op (plus a warning per dispatch) on the XLA
@@ -92,15 +135,24 @@ def donation_supported() -> bool:
 
 
 @functools.lru_cache(maxsize=256)
-def _lifted(matrix_key) -> jax.Array:
+def _lifted_host(matrix_key) -> np.ndarray:
     rows = np.array(matrix_key, dtype=np.uint8)
-    return jnp.asarray(gf8.gf_matrix_to_bits(rows), dtype=jnp.int8)
+    return gf8.gf_matrix_to_bits(rows).astype(np.int8)
+
+
+@functools.lru_cache(maxsize=256)
+def _lifted(matrix_key) -> jax.Array:
+    return jnp.asarray(_lifted_host(matrix_key), dtype=jnp.int8)
+
+
+def _matrix_key(m: np.ndarray) -> tuple:
+    m = np.asarray(m, dtype=np.uint8)
+    return tuple(tuple(int(v) for v in row) for row in m)
 
 
 def lifted_matrix(m: np.ndarray) -> jax.Array:
     """Device int8 binary lift of a GF(2^8) matrix, cached by value."""
-    m = np.asarray(m, dtype=np.uint8)
-    return _lifted(tuple(tuple(int(v) for v in row) for row in m))
+    return _lifted(_matrix_key(m))
 
 
 def encode_parity(data: jax.Array, parity_m: np.ndarray) -> jax.Array:
@@ -109,7 +161,13 @@ def encode_parity(data: jax.Array, parity_m: np.ndarray) -> jax.Array:
 
 
 def apply_matrix(m: np.ndarray, shards: jax.Array, donate: bool = False) -> jax.Array:
-    """Apply an arbitrary GF(2^8) matrix (e.g. a cached decode matrix).
+    """Apply an arbitrary GF(2^8) matrix (e.g. a cached decode matrix) — or
+    a (T, R, C) stack of them, matrix t to the t-th of T equal column tiles
+    of the (C, N) shards: ONE program and one crossing of the shards to the
+    device, whatever the matrices are and wherever one gives way to the next
+    (what a packed rebuild batch runs; a matrix padded with zeros ignores
+    the rows it has no survivor in). Every apply of the codec on the device
+    comes through here.
 
     donate=True routes through the donated jit so the input's device buffer
     is released the moment the dispatch consumes it (streaming pipelines
@@ -119,7 +177,12 @@ def apply_matrix(m: np.ndarray, shards: jax.Array, donate: bool = False) -> jax.
     a release hint, not output aliasing). The host array is explicitly
     device_put first so the donated buffer is one jax owns — never a
     zero-copy alias of caller memory."""
-    b = lifted_matrix(m)
+    m = np.asarray(m, dtype=np.uint8)
+    if m.ndim == 3:
+        b = jnp.asarray(np.stack([_lifted_host(_matrix_key(t)) for t in m]))
+        plain, donated = gf_apply_tiled, _gf_apply_tiled_donated
+    else:
+        b, plain, donated = lifted_matrix(m), gf_apply, _gf_apply_donated
     if donate and donation_supported():
-        return _gf_apply_donated(b, jax.device_put(jnp.asarray(shards)))
-    return gf_apply(b, shards)
+        return _run_counted(donated, b, jax.device_put(jnp.asarray(shards)))
+    return _run_counted(plain, b, shards)

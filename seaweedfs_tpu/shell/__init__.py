@@ -116,6 +116,10 @@ class CommandEnv:
         self.client_name = client_name
         self.cwd = "/"  # fs.cd/fs.pwd REPL state; fs.* paths resolve against it
         self._lock_token = 0
+        #: RPCs made through `master_call` and `vs_call` since the env was
+        #: made; `run_command` writes a command's share on its root span
+        self.rpcs = 0
+        self._rpcs_lock = threading.Lock()
         self._renew_stop: Optional[threading.Event] = None
         self._renew_thread: Optional[threading.Thread] = None
 
@@ -141,7 +145,12 @@ class CommandEnv:
     def master_call(self, method: str, req: dict, timeout: float = 30) -> dict:
         """Master RPC via MasterClient's single failover/redirect path
         (thread-safe: the lock renewer calls this concurrently)."""
+        self._count_rpc()
         return self.client.master_call(method, req, timeout=timeout)
+
+    def _count_rpc(self) -> None:
+        with self._rpcs_lock:  # a command's copies call from a pool
+            self.rpcs += 1
 
     def resolve(self, path: str) -> str:
         """Resolve an fs.* path argument against the REPL's working
@@ -184,6 +193,7 @@ class CommandEnv:
         return out
 
     def vs_call(self, grpc_address: str, method: str, req: dict, timeout: float = 300) -> dict:
+        self._count_rpc()
         with rpc.RpcClient(grpc_address) as c:
             return c.call(VOLUME_SERVICE, method, req, timeout=timeout)
 
@@ -347,7 +357,13 @@ def run_command(env: CommandEnv, line: str, writer: TextIO) -> None:
         # modules: how much this process had loaded when the command began
         # its work (for a `-c` child's first command, what its start cost)
         _trace.annotate(command=name, modules=len(sys.modules))
-        cmd.do(args, env, writer)
+        before = getattr(env, "rpcs", 0)
+        try:
+            cmd.do(args, env, writer)
+        finally:
+            # how many RPCs the command made (the lock renewer's, if one
+            # fell inside it, included): a per-volume loop shows here
+            _trace.annotate(rpcs=getattr(env, "rpcs", 0) - before)
 
 
 def run_script(env: CommandEnv, script: str, writer: TextIO) -> None:

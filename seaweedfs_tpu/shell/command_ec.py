@@ -457,6 +457,15 @@ def pick_rebuilder(
 
 
 def do_ec_rebuild(args: list[str], env: CommandEnv, w: TextIO) -> None:
+    """Plan every EC volume of the selection (what is missing, who holds
+    what, the geometry, the rebuilder), then rebuild rebuilder by
+    rebuilder. The volumes whose survivors are ALL on their rebuilder
+    already go together in ONE `VolumeEcShardsRebuildBatch` (a lone one
+    too), whose packed pipeline fills and drains once for all of them. A
+    volume that needs survivor copies is rebuilt on its own (copy,
+    single-volume RPC, copies dropped), so that the rebuilder's disk never
+    holds more than one volume's temporary copies. `-remote` stays volume
+    by volume: its options are the single RPC's."""
     fl = parse_flags(args, collection="", remote=False, trace="auto")
     trace_mode = str(fl.trace).strip().lower()
     if trace_mode not in ("on", "off", "auto"):
@@ -469,8 +478,8 @@ def do_ec_rebuild(args: list[str], env: CommandEnv, w: TextIO) -> None:
     )
     if fl.collection:
         ec_vids = [v for v in ec_vids if colls.get(v, "") == fl.collection]
+    by_rebuilder: dict[str, list[dict]] = {}  # url -> its volumes, in id order
     for vid in ec_vids:
-        collection = colls.get(vid, "")
         holders = _shard_holders(nodes, vid)
         # geometry-flexible volumes (ec.convert targets) record their own
         # (k, k+m): missing-shard detection over the legacy 14 would never
@@ -497,74 +506,141 @@ def do_ec_rebuild(args: list[str], env: CommandEnv, w: TextIO) -> None:
             )
             continue
         rebuilder = pick_rebuilder(nodes, holders, missing, max(1, total - k))
-        addr = grpc_addr(rebuilder)
+        by_rebuilder.setdefault(rebuilder["url"], []).append(
+            {
+                "vid": vid,
+                "collection": colls.get(vid, ""),
+                "holders": holders,
+                "rebuilder": rebuilder,
+                # nothing to copy: every survivor is on the rebuilder already
+                "local": set(holders) <= set(_node_shards_of(rebuilder, vid)),
+            }
+        )
+    failed: list[int] = []
+    for plans in by_rebuilder.values():
         if fl.remote:
-            # distributed path: NO bulk survivor pre-copy. The rebuilder
-            # streams survivor input from peer holders while decoding —
-            # trace-repair projections when the holders speak them
-            # (-trace auto/on), full slabs otherwise — writes +
-            # CRC-verifies the missing .ecNN files, and mounts only those.
-            resp = env.vs_call(
-                addr,
-                "VolumeEcShardsRebuild",
-                {
-                    "volume_id": vid,
-                    "collection": collection,
-                    "remote": True,
-                    "trace_mode": trace_mode,
-                },
-                timeout=600,
-            )
-            rebuilt = resp.get("rebuilt_shard_ids", [])
-            if rebuilt:
-                env.vs_call(
-                    addr,
-                    "VolumeEcShardsMount",
-                    {"volume_id": vid, "collection": collection, "shard_ids": rebuilt},
-                )
-            detail = ""
-            if resp.get("remote_survivors"):
-                detail = f" (remote survivors {resp['remote_survivors']}"
-                if resp.get("failed_over"):
-                    detail += f", failed over {resp['failed_over']}"
-                if resp.get("mode"):
-                    detail += f", {resp['mode']} mode"
-                    if resp.get("wire_bytes") is not None:
-                        detail += f" moved {resp['wire_bytes']} bytes"
-                    if resp.get("trace_fallback"):
-                        detail += f", trace fell back: {resp['trace_fallback']}"
-                detail += ")"
-            w.write(
-                f"ec.rebuild volume {vid}: rebuilt {rebuilt} on "
-                f"{rebuilder['url']}{detail}\n"
-            )
+            for plan in plans:
+                _rebuild_remote(env, plan, trace_mode, w)
             continue
-        copied = _copy_missing_to(env, rebuilder, vid, collection, holders)
+        batch = [p for p in plans if p["local"]]
+        if batch:
+            failed += _rebuild_many(env, batch, w)
+        for plan in plans:
+            if not plan["local"]:
+                _rebuild_one(env, plan, w)
+    if failed:
+        raise ShellError(f"ec.rebuild: volumes {failed} were not rebuilt")
+
+
+def _rebuild_remote(env: CommandEnv, plan: dict, trace_mode: str, w: TextIO) -> None:
+    """The distributed path: NO bulk survivor pre-copy. The rebuilder
+    streams survivor input from peer holders while decoding —
+    trace-repair projections when the holders speak them
+    (-trace auto/on), full slabs otherwise — writes +
+    CRC-verifies the missing .ecNN files, and mounts only those."""
+    vid, collection, rebuilder = plan["vid"], plan["collection"], plan["rebuilder"]
+    addr = grpc_addr(rebuilder)
+    resp = env.vs_call(
+        addr,
+        "VolumeEcShardsRebuild",
+        {
+            "volume_id": vid,
+            "collection": collection,
+            "remote": True,
+            "trace_mode": trace_mode,
+        },
+        timeout=600,
+    )
+    rebuilt = resp.get("rebuilt_shard_ids", [])
+    if rebuilt:
+        env.vs_call(
+            addr,
+            "VolumeEcShardsMount",
+            {"volume_id": vid, "collection": collection, "shard_ids": rebuilt},
+        )
+    detail = ""
+    if resp.get("remote_survivors"):
+        detail = f" (remote survivors {resp['remote_survivors']}"
+        if resp.get("failed_over"):
+            detail += f", failed over {resp['failed_over']}"
+        if resp.get("mode"):
+            detail += f", {resp['mode']} mode"
+            if resp.get("wire_bytes") is not None:
+                detail += f" moved {resp['wire_bytes']} bytes"
+            if resp.get("trace_fallback"):
+                detail += f", trace fell back: {resp['trace_fallback']}"
+        detail += ")"
+    w.write(
+        f"ec.rebuild volume {vid}: rebuilt {rebuilt} on "
+        f"{rebuilder['url']}{detail}\n"
+    )
+
+
+def _rebuild_one(env: CommandEnv, plan: dict, w: TextIO) -> None:
+    """Upstream's copy-then-rebuild of one volume on its rebuilder; the
+    copies go whether or not the rebuild came back."""
+    vid, collection, rebuilder = plan["vid"], plan["collection"], plan["rebuilder"]
+    addr = grpc_addr(rebuilder)
+    copied = _copy_missing_to(env, rebuilder, vid, collection, plan["holders"])
+    try:
         resp = env.vs_call(
             addr, "VolumeEcShardsRebuild", {"volume_id": vid, "collection": collection}
         )
-        rebuilt = resp.get("rebuilt_shard_ids", [])
+    finally:
         # drop the temp survivor copies; delete remounts local = original+rebuilt
+        # (never with an empty list: the server reads that as every shard)
         if copied:
             env.vs_call(
                 addr,
                 "VolumeEcShardsDelete",
                 {"volume_id": vid, "collection": collection, "shard_ids": copied},
             )
+    rebuilt = resp.get("rebuilt_shard_ids", [])
+    w.write(f"ec.rebuild volume {vid}: rebuilt {rebuilt} on {rebuilder['url']}\n")
+
+
+def _rebuild_many(env: CommandEnv, plans: list[dict], w: TextIO) -> list[int]:
+    """One rebuilder's volumes that need no survivor copy, as ONE batch:
+    one `VolumeEcShardsRebuildBatch` rebuilds and mounts them all, in the
+    order of the plan. A volume whose part of the batch failed prints its
+    error and the others complete. -> the volumes that were not rebuilt."""
+    from seaweedfs_tpu.ec import placement
+
+    rebuilder = plans[0]["rebuilder"]
+    resp = env.vs_call(
+        grpc_addr(rebuilder),
+        "VolumeEcShardsRebuildBatch",
+        placement.rebuild_batch_request((p["vid"], p["collection"]) for p in plans),
+        timeout=300 * len(plans),
+    )
+    results = {int(r["volume_id"]): r for r in resp.get("results", [])}
+    w.write(
+        f"ec.rebuild batch on {rebuilder['url']}: {len(plans)} volumes in "
+        f"{int(resp.get('signature_groups', 0))} signature groups\n"
+    )
+    failed: list[int] = []
+    for plan in plans:
+        vid = plan["vid"]
+        r = results.get(vid) or {}
+        error = r.get("error") or ("" if r else "no result")
+        if error:
+            failed.append(vid)
+            w.write(f"ec.rebuild volume {vid}: NOT rebuilt on {rebuilder['url']}: {error}\n")
         else:
-            env.vs_call(
-                addr,
-                "VolumeEcShardsMount",
-                {"volume_id": vid, "collection": collection, "shard_ids": rebuilt},
+            w.write(
+                f"ec.rebuild volume {vid}: rebuilt "
+                f"{[int(s) for s in r.get('rebuilt_shard_ids', [])]} on {rebuilder['url']}\n"
             )
-        w.write(f"ec.rebuild volume {vid}: rebuilt {rebuilt} on {rebuilder['url']}\n")
+    return failed
 
 
 register(
     ShellCommand(
         "ec.rebuild",
         "ec.rebuild [-collection <name>] [-remote] [-trace on|off|auto]\n\tfind "
-        "EC volumes with lost shards and reconstruct them on a rebuilder node;\n"
+        "EC volumes with lost shards and reconstruct them on a rebuilder node\n"
+        "\t(those with every survivor on their rebuilder already, in ONE "
+        "batch);\n"
         "\t-remote streams survivors from their holders through the network-\n"
         "\toverlapped rebuild pipeline instead of bulk-copying shard files "
         "first;\n\t-trace (with -remote) controls repair-bandwidth projections: "

@@ -274,6 +274,13 @@ EcCopyBytes = REGISTRY.counter(
     "seconds are weedtpu_rpc_server_seconds{method} of those two RPCs",
     ("side",),
 )
+EcRebuildBatchVolumes = REGISTRY.counter(
+    "weedtpu_ec_rebuild_batch_volumes_total",
+    "volumes THIS server rebuilt inside a VolumeEcShardsRebuildBatch, whoever "
+    "sent it (the repair scheduler, or ec.rebuild for a rebuilder's volumes "
+    "that need no survivor copy): the scheduler's own count of what it sent is "
+    "weedtpu_repair_fused_volumes_total, on the master",
+)
 StagingRingLeases = REGISTRY.counter(
     "weedtpu_staging_ring_leases_total",
     "staging rings leased by bulk EC runs (an encode, a rebuild, an ingest "
@@ -443,6 +450,13 @@ EcBackendSelected = REGISTRY.gauge(
     "env:WEEDTPU_BACKEND, explicit)",
     ("backend", "source"),
 )
+CodecProgramsCompiled = REGISTRY.counter(
+    "weedtpu_codec_programs_compiled_total",
+    "device programs the XLA codec (ops/rs_jax) has traced and compiled, or "
+    "loaded from the persistent cache, since boot: one per new (program, "
+    "shapes) of a jit cache. It stands still once a server has run each of "
+    "its shapes; a rise under steady traffic is a compile on the hot path",
+)
 XorschedCache = REGISTRY.gauge(
     "weedtpu_xorsched_schedule_cache",
     "compiled XOR-schedule LRU counters by event (hits/misses/evictions/"
@@ -482,9 +496,11 @@ RepairFusedVolumes = REGISTRY.counter(
 )
 RepairDispatchGroups = REGISTRY.gauge(
     "weedtpu_repair_dispatch_groups",
-    "decode dispatch groups the most recent repair batch ran: 1 means "
-    "the whole cohort fused into one block-diagonal dispatch, higher "
-    "values mean per-signature-group dispatches (fusion off or absent)",
+    "pipelines the most recent repair batch ran: 1 means the whole cohort "
+    "rode one width-packed pipeline, each of whose batches is one codec "
+    "call over all the signature groups it holds (one device program on "
+    "the jax backend, one stitched pass on xorsched; the pallas, mesh, "
+    "native and numpy backends run one apply per group inside it)",
 )
 PlacementViolations = REGISTRY.gauge(
     "weedtpu_placement_violations",

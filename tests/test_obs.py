@@ -676,7 +676,9 @@ def test_shell_command_root_says_how_much_its_process_had_loaded(cluster):
     """`run_command` annotates its root with the command's name and
     `modules=len(sys.modules)` at the command's first line: for a `shell -c`
     child's first command that is what the child's start loaded, and
-    `render_trace` (`ec.trace`, `/debug/traces`) prints it with the root."""
+    `render_trace` (`ec.trace`, `/debug/traces`) prints it with the root. At
+    the command's end it adds `rpcs`, the calls the command made through the
+    env (`volume.list`: the master's VolumeList)."""
     import sys
 
     _master, _servers, _client, env = cluster
@@ -684,9 +686,9 @@ def test_shell_command_root_says_how_much_its_process_had_loaded(cluster):
     assert "volume" in _shell(env, "volume.list").lower()
     (root,) = [t for t in trace.RING.snapshot(limit=100000) if t["kind"] == "shell.command"]
     attrs = root["root"]["attrs"]
-    assert set(attrs) == {"command", "modules"} and attrs["command"] == "volume.list"
-    assert 50 <= attrs["modules"] <= len(sys.modules)
-    assert f"command=volume.list modules={attrs['modules']}" in trace.render_trace(root)
+    assert set(attrs) == {"command", "modules", "rpcs"} and attrs["command"] == "volume.list"
+    assert 50 <= attrs["modules"] <= len(sys.modules) and attrs["rpcs"] == 1
+    assert f"command=volume.list modules={attrs['modules']} rpcs=1" in trace.render_trace(root)
 
 
 # -- the profiler mirror (PR 25): the program's spans on another clock ---------

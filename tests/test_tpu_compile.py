@@ -57,6 +57,12 @@ XLA_PROGRAMS = {
     "small_read_smallest_bucket": (rs_jax.gf_apply, 1, 10, Encoder.RECONSTRUCT_BUCKETS[0]),
     "small_read_largest_bucket": (rs_jax.gf_apply, 1, 10, Encoder.RECONSTRUCT_BUCKETS[-1]),
     "encode_cauchy_12_3_flat": (rs_jax._gf_apply_donated, 3, 12, _encode_width(12)),
+    # many10p4.rebuild-1lost-each: a packed batch of one signature is the flat
+    # program with one row out; one that holds a seam is the tiled program
+    "reconstruct_1from10_rebuild": (rs_jax._gf_apply_donated, 1, 10, _rebuild_width(10)),
+    "reconstruct_1from10_packed": (rs_jax._gf_apply_tiled_donated, 1, 10, _rebuild_width(10)),
+    # spread10p4's seam batch: a 3-lost volume's tail beside a 4-lost volume's head
+    "reconstruct_4from10_packed": (rs_jax._gf_apply_tiled_donated, 4, 10, _rebuild_width(10)),
 }
 
 # shape classes the storage engine hits, per fused variant (the table
@@ -131,8 +137,11 @@ def test_xla_main_path_compiles_and_fits(one_chip, name):
     """Each program compiles for the v5e, and a pipeline of it — depth
     batches in flight plus the one being staged — fits the chip's 16 GB."""
     fn, rows, cols, width = XLA_PROGRAMS[name]
+    matrix = (rows * 8, cols * 8)
+    if fn is rs_jax._gf_apply_tiled_donated:  # a matrix for each tile of the slot
+        matrix = (width // Encoder(cols, 4, backend="jax").block_tile(width),) + matrix
     compiled = fn.lower(
-        _shape((rows * 8, cols * 8), jnp.int8, one_chip),
+        _shape(matrix, jnp.int8, one_chip),
         _shape((cols, width), jnp.uint8, one_chip),
     ).compile()
     mem = compiled.memory_analysis()
