@@ -74,7 +74,7 @@ SPAN_NAMES: dict[str, str] = {
     "rebuild.stage": "staging-ring fill for one rebuild batch (disk/wire): its lane reads queued, the drain it runs ahead of (nested), the wait for the reads",
     "rebuild.read": "one survivor's slab read into its staging row (child of rebuild.stage; on a lane thread where the source allows)",
     "rebuild.wait": "the calling thread blocked in a join of lane tasks (a batch's reads, the last drain's writes)",
-    "rebuild.dispatch": "reconstruct_lazy, or reconstruct_block where a packed batch holds several signature groups (blocks=): device_put (H2D) + the jit call, until it returns",
+    "rebuild.dispatch": "reconstruct_lazy, or reconstruct_block where a packed batch holds several signature groups (blocks=): device_put (H2D) + the jit call, until it returns; form= says how the slot crossed on the jax backend (exact | as_is: rs_jax.apply_matrix)",
     "rebuild.drain": "device sync + shard write-out + CRC for one rebuild batch",
     "rebuild.sync": "np.asarray of one batch's decode: device wait + D2H, nothing else",
     "rebuild.write": "one rebuilt shard's bytes of one batch written to its file (on a lane thread)",
@@ -84,7 +84,7 @@ SPAN_NAMES: dict[str, str] = {
     "encode.stage": "staging-ring fill for one encode batch: its lane reads queued, the drain it runs ahead of (nested), the wait for the reads",
     "encode.read": "one data shard's slabs of one batch read into its staging row (child of encode.stage; on a lane thread), or all ten on the calling thread where the source is no file",
     "encode.wait": "the calling thread blocked in a join of lane tasks (a batch's reads, its data shards' writes, the last drain's parity writes)",
-    "encode.dispatch": "encode_parity_lazy: device_put (H2D) + the jit call, until it returns",
+    "encode.dispatch": "encode_parity_lazy: device_put (H2D) + the jit call, until it returns; form= as on rebuild.dispatch",
     "encode.drain": "device sync + shard write-out + CRC for one encode batch",
     "encode.sync": "np.asarray of one batch's parity: device wait + D2H, nothing else",
     "encode.write": "one shard's bytes of one batch written to its file (data under its stage, parity under its drain; on a lane thread)",

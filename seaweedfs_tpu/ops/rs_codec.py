@@ -185,6 +185,23 @@ class _FusedBlocks:
         return self._out
 
 
+class _Crossed:
+    """Lazy handle for an apply of the jax backend: the device array stays
+    in the shape it crossed in (`rs_jax.apply_matrix`: `(R * k, N / k)` for
+    the exact crossing) until np.asarray(), the one sync point, which gives
+    `shape`, the `(R, N)` of the matrix's rows and the input's width, back:
+    a reshape of the synced array (a view; of another size, an error)."""
+
+    def __init__(self, out, shape: tuple):
+        self._out, self._shape = out, shape
+
+    def __array__(self, dtype=None, copy=None):
+        self._out = np.asarray(self._out).reshape(self._shape)
+        if dtype is not None and dtype != self._out.dtype:
+            return self._out.astype(dtype)
+        return self._out
+
+
 class Encoder:
     """RS(d+p) encoder/reconstructor over GF(2^8).
 
@@ -316,7 +333,8 @@ class Encoder:
         if self.backend == "jax":
             from seaweedfs_tpu.ops import rs_jax
 
-            return rs_jax.apply_matrix(m, shards, donate=donate)
+            out = rs_jax.apply_matrix(m, shards, donate=donate)
+            return _Crossed(out, (*shards.shape[:-2], m.shape[0], shards.shape[-1]))
         if self.backend == "native":
             out = self._apply_native(m, shards)
             if out is not None:
@@ -734,7 +752,7 @@ class Encoder:
             for (_, m, _, _), first in zip(by_col, starts):
                 tiles[first:] = 0
                 tiles[first:, : m.shape[0], : m.shape[1]] = m
-            return rs_jax.apply_matrix(tiles, staging, donate=True)
+            return _Crossed(rs_jax.apply_matrix(tiles, staging, donate=True), (max_m, width_total))
         # other backends: per-block dispatches (async on device backends,
         # so blocks overlap in flight; _apply_lazy counts each), one sync
         # point for the whole batch via the lazy wrapper

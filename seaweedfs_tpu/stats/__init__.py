@@ -457,6 +457,17 @@ CodecProgramsCompiled = REGISTRY.counter(
     "shapes) of a jit cache. It stands still once a server has run each of "
     "its shapes; a rise under steady traffic is a compile on the hot path",
 )
+CodecCrossings = REGISTRY.counter(
+    "weedtpu_codec_crossings_total",
+    "applies of the XLA codec (ops/rs_jax) by the shape their shards crossed "
+    "to the device in: `exact` = as the (rows * k, width / k) view of the "
+    "contiguous slot, which the TPU stores and ships without padding (ten "
+    "uint8 rows are held as sixteen otherwise, one row back as four), "
+    "`as_is` = as handed over (a strided column range, an odd width, one "
+    "row in, a batch axis, a result of two rows or more). The steady batches "
+    "of a bulk decode of ONE lost shard read `exact`",
+    ("form",),
+)
 XorschedCache = REGISTRY.gauge(
     "weedtpu_xorsched_schedule_cache",
     "compiled XOR-schedule LRU counters by event (hits/misses/evictions/"

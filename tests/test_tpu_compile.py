@@ -49,7 +49,10 @@ def _rebuild_width(k: int) -> int:
     return max(1, _default(stripe.rebuild_ec_files, "max_batch_bytes") // (k * per)) * per
 
 
-# name -> (fn, out rows, in rows, width): the XLA main path as the smoke drives it
+# name -> (fn, out rows, in rows, width[, "exact"]): the XLA main path as the
+# smoke drives it. "exact": the slot in the exact crossing's (rows * k, width / k)
+# view (`rs_jax.apply_matrix`), what a steady batch is handed as where its result
+# is one row
 XLA_PROGRAMS = {
     "encode_10p4_flat": (rs_jax.gf_apply, 4, 10, _encode_width(10)),
     "encode_10p4_flat_donated": (rs_jax._gf_apply_donated, 4, 10, _encode_width(10)),
@@ -63,6 +66,11 @@ XLA_PROGRAMS = {
     "reconstruct_1from10_packed": (rs_jax._gf_apply_tiled_donated, 1, 10, _rebuild_width(10)),
     # spread10p4's seam batch: a 3-lost volume's tail beside a 4-lost volume's head
     "reconstruct_4from10_packed": (rs_jax._gf_apply_tiled_donated, 4, 10, _rebuild_width(10)),
+    "reconstruct_1from10_exact": (rs_jax._gf_apply_donated, 1, 10, _rebuild_width(10), "exact"),
+    "reconstruct_1from10_packed_exact": (rs_jax._gf_apply_tiled_donated, 1, 10, _rebuild_width(10), "exact"),
+    "reconstruct_1from12_exact": (rs_jax._gf_apply_donated, 1, 12, _rebuild_width(12), "exact"),
+    "small_read_smallest_bucket_exact": (rs_jax.gf_apply, 1, 10, Encoder.RECONSTRUCT_BUCKETS[0], "exact"),
+    "small_read_largest_bucket_exact": (rs_jax.gf_apply, 1, 10, Encoder.RECONSTRUCT_BUCKETS[-1], "exact"),
 }
 
 # shape classes the storage engine hits, per fused variant (the table
@@ -136,15 +144,26 @@ def _shape(shape, dtype, sharding):
 def test_xla_main_path_compiles_and_fits(one_chip, name):
     """Each program compiles for the v5e, and a pipeline of it — depth
     batches in flight plus the one being staged — fits the chip's 16 GB."""
-    fn, rows, cols, width = XLA_PROGRAMS[name]
+    fn, rows, cols, width, *exact = XLA_PROGRAMS[name]
     matrix = (rows * 8, cols * 8)
     if fn is rs_jax._gf_apply_tiled_donated:  # a matrix for each tile of the slot
         matrix = (width // Encoder(cols, 4, backend="jax").block_tile(width),) + matrix
+    k = rs_jax.crossing_chunks(cols) if exact else 1
     compiled = fn.lower(
         _shape(matrix, jnp.int8, one_chip),
-        _shape((cols, width), jnp.uint8, one_chip),
+        _shape((cols * k, width // k), jnp.uint8, one_chip),
     ).compile()
     mem = compiled.memory_analysis()
+    if exact:
+        # the device holds the slot and the result in the bytes they have (41,943,040
+        # of a rebuild slot, 4,194,304 of its one-row decode) plus the lifted matrices:
+        # the (10, N) slot's 1.6 x and the (1, N) result's 4 x of padding cannot come
+        # back unseen; and a one-row program keeps no temporaries, which is what the
+        # crossing's rule rests on (`rs_jax._exact_chunks`)
+        lifted = (matrix[0] if len(matrix) == 3 else 1) * 32 * 1024  # <= a padded (32, 96) int8 each
+        assert cols * width <= mem.argument_size_in_bytes <= cols * width + lifted
+        assert mem.output_size_in_bytes == rows * width
+        assert mem.temp_size_in_bytes == 0
     per_batch = (
         mem.temp_size_in_bytes + mem.argument_size_in_bytes + mem.output_size_in_bytes
     )
