@@ -1040,6 +1040,7 @@ class VolumeServer:
         add("WriteNeedle", self._rpc_write_needle)
         add("DeleteNeedle", self._rpc_delete_needle)
         add("VolumeEcShardsGenerate", self._rpc_ec_generate)
+        add("VolumeEcShardsGenerateBatch", self._rpc_ec_generate_batch)
         add("VolumeEcShardsCopy", self._rpc_ec_copy)
         add("VolumeEcShardsRebuild", self._rpc_ec_rebuild)
         add("VolumeEcShardsRebuildBatch", self._rpc_ec_rebuild_batch)
@@ -1485,6 +1486,50 @@ class VolumeServer:
 
     # EC surface (SURVEY.md §2.4)
 
+    @staticmethod
+    def _ec_block_sizes(req: dict) -> dict:
+        """The block sizes a generate request names, as `stripe`'s keywords."""
+        return {
+            key: int(req[key])
+            for key in ("large_block_size", "small_block_size")
+            if req.get(key)
+        }
+
+    def _encode_warm(self, bases: dict[int, str], block_sizes: dict) -> tuple[dict, int]:
+        """Warm-encode the volumes {vid: base path}, whose maintenance locks
+        the caller holds, through ONE pipeline, in that order: each volume's
+        14 shards + .eci (`stripe.write_ec_files_batch`), then its .ecx.
+        -> ({vid: the exception that failed it}, device dispatches)."""
+        enc = self.store.encoder
+        if self._ingest is not None:
+            # a warm generate supersedes any inline partial state:
+            # leftovers must not shadow the fresh shard set — base
+            # included, so journaled state from before a restart
+            # (no live builder) is scrubbed from disk too
+            for vid, base in bases.items():
+                self._ingest.discard(vid, base)
+        # the run span is write_ec_files_batch's own (batch=, bytes=,
+        # batches=, ring=); opened here so that it names the volumes
+        with trace_mod.ensure("encode.run", klass="maint"):
+            trace_mod.annotate(volumes=",".join(map(str, bases)))
+            res = stripe.write_ec_files_batch(
+                list(bases.values()), encoder=enc, **block_sizes
+            )
+        errors: dict[int, BaseException] = {}
+        for vid, base in bases.items():
+            e = res["errors"].get(base)
+            if e is None:
+                try:
+                    stripe.write_sorted_file_from_idx(base)
+                except Exception as e2:  # noqa: BLE001 — this volume's alone
+                    e = e2
+            if e is not None:
+                errors[vid] = e
+                continue
+            stats.EcEncodeRuns.labels(enc.backend).inc()
+            stats.EcEncodeBytes.inc(os.path.getsize(base + ".dat"))
+        return errors, int(res["batches"])
+
     def _rpc_ec_generate(self, req: dict, ctx) -> dict:
         """VolumeEcShardsGenerate: local .dat+.idx -> 14 shards + .ecx.
 
@@ -1494,17 +1539,13 @@ class VolumeServer:
         identical output, but the bulk of the encode already happened at
         ingest time. Any unusable inline state (policy off, geometry
         mismatch, broken/un-vouchable journal) falls back to the warm
-        conversion inside the same call; the response's `mode` says which
-        path actually produced the shards."""
+        conversion inside the same call (the batch of one: `_encode_warm`);
+        the response's `mode` says which path actually produced the shards."""
         vid = int(req["volume_id"])
         v = self.store.get_volume(vid)
         if v is None:
             raise rpc.NotFoundFault(f"volume {vid} not found")
-        kwargs = {}
-        if req.get("large_block_size"):
-            kwargs["large_block_size"] = int(req["large_block_size"])
-        if req.get("small_block_size"):
-            kwargs["small_block_size"] = int(req["small_block_size"])
+        kwargs = self._ec_block_sizes(req)
         t0 = time.monotonic()
         info: dict = {"mode": "warm"}
         with self.maintenance_lock(vid):  # never interleave with compact/copy
@@ -1514,23 +1555,13 @@ class VolumeServer:
                 # spreads from here): discard any pre-spread partials so
                 # its allocation starts from the full local set
                 self._finalize_spread(vid, v.base_path, "shell")
+                stripe.write_sorted_file_from_idx(v.base_path)
+                stats.EcEncodeBytes.inc(os.path.getsize(v.base_path + ".dat"))
             else:
-                if self._ingest is not None:
-                    # a warm generate supersedes any inline partial state:
-                    # leftovers must not shadow the fresh shard set — base
-                    # included, so journaled state from before a restart
-                    # (no live builder) is scrubbed from disk too
-                    self._ingest.discard(vid, v.base_path)
-                # the run span is write_ec_files' own (it adds bytes and
-                # batches); opened here so that it carries the volume id
-                with trace_mod.ensure("encode.run", klass="maint"):
-                    trace_mod.annotate(volume=vid)
-                    stripe.write_ec_files(
-                        v.base_path, encoder=self.store.encoder, **kwargs
-                    )
-            stripe.write_sorted_file_from_idx(v.base_path)
+                errors, _ = self._encode_warm({vid: v.base_path}, kwargs)
+                if errors:
+                    raise errors[vid]
         stats.EcEncodeSeconds.observe(time.monotonic() - t0)
-        stats.EcEncodeBytes.inc(os.path.getsize(v.base_path + ".dat"))
         total = stripe.geometry_from_info(
             stripe.read_ec_info(v.base_path)
         ).total_shards
@@ -1539,6 +1570,59 @@ class VolumeServer:
             "mode": info.get("mode", "warm"),
             "inline_rows": int(info.get("rows_inline", 0)),
             "delta_updates": int(info.get("delta_updates", 0)),
+        }
+
+    def _rpc_ec_generate_batch(self, req: dict, ctx) -> dict:
+        """VolumeEcShardsGenerateBatch: MANY local volumes' .dat+.idx -> 14
+        shards + .eci + .ecx each, warm, in one call: `ec.encode`'s unit for
+        the volumes of a sweep that live on this server. The volumes run in
+        request order through ONE encode pipeline (`_encode_warm`: their
+        rows packed into the same device batches), under every volume's
+        maintenance lock; each volume's files are what
+        `VolumeEcShardsGenerate` writes for it alone. Nothing is mounted and
+        no volume is touched beyond its new files: the cut-over stays the
+        caller's, volume by volume.
+        Per-volume failures are soft (reported in `results[].error`: an
+        unknown volume, a .dat that cannot be opened, an .ecx that cannot be
+        written; a failure of the run itself fails every volume of it that
+        was not finished, their partial shards unlinked); the call only
+        faults wholesale on malformed requests."""
+        vids = list(dict.fromkeys(int(v["volume_id"]) for v in req.get("volumes") or []))
+        if not vids:
+            raise rpc.RpcFault(
+                "volumes required", code=grpc.StatusCode.INVALID_ARGUMENT
+            )
+        t0 = time.monotonic()
+        errors: dict[int, str] = {}
+        batches = 0
+        with ExitStack() as locks:
+            # vid-sorted, so that two batches can never deadlock on each other
+            for vid in sorted(vids):
+                locks.enter_context(self.maintenance_lock(vid))
+            bases: dict[int, str] = {}  # in request order: the order of the packed rows
+            for vid in vids:
+                v = self.store.get_volume(vid)
+                if v is None:
+                    errors[vid] = f"volume {vid} not found"
+                else:
+                    bases[vid] = v.base_path
+            if bases:
+                failed, batches = self._encode_warm(bases, self._ec_block_sizes(req))
+                for vid, e in failed.items():
+                    errors[vid] = f"{type(e).__name__}: {e}"[:300]
+                stats.EcEncodeBatchVolumes.inc(len(bases) - len(failed))
+        stats.EcEncodeSeconds.observe(time.monotonic() - t0)
+        total = self.store.encoder.total_shards
+        return {
+            "results": [
+                {
+                    "volume_id": vid,
+                    "shard_ids": [] if vid in errors else list(range(total)),
+                    "error": errors.get(vid, ""),
+                }
+                for vid in sorted(vids)
+            ],
+            "batches": batches,
         }
 
     def _inline_usable(self, kwargs: dict) -> bool:

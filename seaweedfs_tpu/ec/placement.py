@@ -248,7 +248,8 @@ def rebuild_batch_request(volumes) -> dict:
     pairs: the one place it is spelled, for the master's scheduler and the
     shell's `ec.rebuild` alike. The order given is the order the target plans
     in, and so the block order of its packed batches (the scheduler sends
-    priority order, the shell volume-id order)."""
+    priority order, the shell volume-id order). `ec.encode`'s
+    `VolumeEcShardsGenerateBatch` names its volumes the same way."""
     return {
         "volumes": [
             {"volume_id": int(vid), "collection": collection or ""}

@@ -34,9 +34,11 @@ pipeline and run beside it. The staging ring is leased from a pool the
 process keeps (`_ring_for`, at most STAGING_POOL_MAX_BYTES between runs), so
 only a server's first bulk command faults its slots in.
 
-There are two pipelined loops. `_encode_rows` is the encode's (`write_ec_files`,
-the inline-ingest and conversion builders): its batches write data rows from
-staging and parity rows from the device. `_run_rebuild` is every pipelined
+There are two pipelined loops. `_encode_parts` is the encode's
+(`write_ec_files_batch`, of which `write_ec_files` is the batch of one; the
+inline-ingest and conversion builders through `_encode_rows`): its batches
+write data rows from staging and parity rows from the device, and hold the
+rows of as many volumes as fit. `_run_rebuild` is every pipelined
 rebuild's: `rebuild_ec_files`, `rebuild_ec_files_from_sources`,
 `rebuild_ec_files_from_projections` and `rebuild_ec_files_batch` each lay
 their work out as a `_Plan` (which columns a batch holds, what fills a staging
@@ -421,6 +423,23 @@ class _ShardLanes:
                 self._cv.wait()
 
 
+class _Rows(NamedTuple):
+    """`n_rows` block rows of one source, as the encode loop takes them: row r's
+    shard d is the `block_size` bytes at `start_offset + (r * k + d) *
+    block_size` of `f`; shard s's bytes go to `outputs[s]`, in row order, and
+    are folded into `crcs[s]` where `crcs` is given. `done`, where given, is
+    called once every output has the part's last byte, on a lane of its own
+    beside the batches of the parts that follow (never for a part of no rows)."""
+
+    f: object
+    outputs: Sequence
+    start_offset: int
+    block_size: int
+    n_rows: int
+    crcs: Optional[list]
+    done: Optional[Callable[[], None]] = None
+
+
 def _encode_rows(
     f,
     enc: Encoder,
@@ -433,10 +452,30 @@ def _encode_rows(
     pipeline_depth: Optional[int] = None,
     crcs: Optional[list] = None,
 ) -> int:
-    """Encode `n_rows` rows of `block_size` blocks as a stream of flat
-    (DATA_SHARDS, width) device dispatches over reused staging buffers.
-    Output files receive bytes in row-major order. Returns the number of
-    batches dispatched.
+    """`_encode_parts` over the rows of ONE source (the inline-ingest and
+    conversion builders' entry; `buffer_size` is capped at the block)."""
+    part = _Rows(f, outputs, start_offset, block_size, n_rows, crcs)
+    return _encode_parts(
+        [part], enc, min(buffer_size, block_size), max_batch_bytes, pipeline_depth
+    )
+
+
+def _encode_parts(
+    parts: Sequence[_Rows],
+    enc: Encoder,
+    buffer_size: int,
+    max_batch_bytes: int,
+    pipeline_depth: Optional[int] = None,
+) -> int:
+    """Encode the rows of `parts`, in their order, as ONE stream of flat
+    (DATA_SHARDS, width) device dispatches over reused staging buffers: the
+    `buffer_size` segments of all the parts are packed into the batches one
+    after the other, so a batch may hold the end of one part and the start of
+    the next (of another volume: the GF matmul is column-independent, which
+    source a column came from only decides which files it is read from and
+    written to), and only the last batch of the call is narrower than the
+    rest. Every part's output files receive its bytes in row-major order.
+    Returns the number of batches dispatched.
 
     Depth-N pipeline: up to `pipeline_depth` batches' parity computes
     on-device (async dispatch) while the next batch's disk reads run;
@@ -454,9 +493,10 @@ def _encode_rows(
 
     Who does what (`_ShardLanes`). The calling thread lays out a batch,
     waits for its reads, dispatches it, and syncs its parity; the per-shard
-    work runs on the lanes. Where `f` is a real OS file each data shard's
-    slabs are read on a lane, positionally (`pread_padded_into`: no seek on
-    a handle that two threads touch); a source that is no file (`convert.
+    work runs on the lanes. Where every part's `f` is a real OS file each
+    data shard's slabs are read on a lane, positionally (`pread_padded_into`:
+    no seek on a handle that two threads touch), from the file of the part
+    each run of columns belongs to; a source that is no file (`convert.
     _VirtualDat`) is read on the calling thread, in row-run order, through
     `seek`/`readinto`. Each data shard's write and, when `crcs` is given,
     its CRC32 fold are queued behind its read and run beside the dispatch,
@@ -482,61 +522,81 @@ def _encode_rows(
     width (the gap zero-filled, written/CRC'd only to the true width), so
     every batch's host->device transfer splits evenly across the chips
     with no dispatcher-side pad copy."""
-    lanes = _ShardLanes(len(outputs))
-    trace_mod.annotate(lanes=lanes.n)  # on the caller's run span; 0 = inline
-    if n_rows <= 0:
+    lanes = _ShardLanes(len(parts[0].outputs))
+    run_span = trace_mod.current()  # the caller's
+    trace_mod.annotate(lanes=lanes.n)  # 0 = inline
+    parts = [p for p in parts if p.n_rows > 0]
+    if not parts:
         return 0
-    if buffer_size > block_size:
-        buffer_size = block_size
-    if block_size % buffer_size:
-        raise ValueError(f"block size {block_size} not a multiple of buffer {buffer_size}")
+    for p in parts:
+        if p.block_size % buffer_size:
+            raise ValueError(f"block size {p.block_size} not a multiple of buffer {buffer_size}")
     depth = DEFAULT_PIPELINE_DEPTH if pipeline_depth is None else max(1, int(pipeline_depth))
     align = int(getattr(enc, "width_align", 1) or 1)
     k = enc.data_shards  # geometry-flexible: the encoder owns (k, m)
-    segs_per_row = block_size // buffer_size
+    n_out = len(parts[0].outputs)
     # how many (k x buffer) segments fit the device-batch budget
     batch_cap = max(1, max_batch_bytes // (k * buffer_size))
     span = _aligned(batch_cap * buffer_size, align)
-    fd = _fd_of(f)
-    lane_reads = fd is not None and lanes.n > 0  # else they run on this thread
-    inflight: deque = deque()  # FIFO of (parity_handle, width, the batch's data-shard tasks)
+    fds = [_fd_of(p.f) for p in parts]
+    on_lanes = None not in fds  # else every read runs on this thread, in run order
+    lane_reads = on_lanes and lanes.n > 0
+    inflight: deque = deque()  # FIFO of (parity_handle, width, the batch's data-shard tasks, its pieces)
     parity_tasks = _LaneBatch()  # the last drain's parity writes; their args keep its array alive
+    done_tasks = _LaneBatch()  # the parts' `done` calls, on the lane after the shards'
+    unwritten = {id(p): n_out for p in parts if p.done}  # outputs that lack the part's last bytes
+    unwritten_lock = threading.Lock()
     n_batches = 0
 
     def read_slabs(shards: Sequence[int], staging: np.ndarray, runs: list) -> None:
         # the runs' columns are 0..width: one span for what `shards` get of a batch
-        with trace_mod.span("encode.read", bytes=len(shards) * runs[-1][2]):
-            for off, lo, hi in runs:
+        with trace_mod.span("encode.read", bytes=len(shards) * runs[-1][3]):
+            for pi, off, lo, hi in runs:
+                fd, block = fds[pi], parts[pi].block_size
                 for d in shards:
                     if fd is None:
-                        read_padded_into(f, off + d * block_size, staging[d, lo:hi])
+                        read_padded_into(parts[pi].f, off + d * block, staging[d, lo:hi])
                     else:
-                        pread_padded_into(fd, off + d * block_size, staging[d, lo:hi])
+                        pread_padded_into(fd, off + d * block, staging[d, lo:hi])
 
-    def put(s: int, row: np.ndarray) -> None:
+    def put(s: int, row: np.ndarray, pieces: list) -> None:
+        # `pieces`: the columns each part has of the batch, (part, first, end,
+        # whether they are the part's last)
         with trace_mod.span("encode.write", bytes=row.size):
-            outputs[s].write(row)
-        if crcs is not None:
+            for p, lo, hi, _ in pieces:
+                p.outputs[s].write(row[lo:hi])
+        if any(p.crcs is not None for p, *_ in pieces):
             with trace_mod.span("encode.crc", bytes=row.size):
-                crcs[s] = zlib.crc32(row, crcs[s])
+                for p, lo, hi, _ in pieces:
+                    if p.crcs is not None:
+                        p.crcs[s] = zlib.crc32(row[lo:hi], p.crcs[s])
+        for p, _, _, last in pieces:
+            if last and p.done is not None:
+                with unwritten_lock:
+                    unwritten[id(p)] -= 1
+                    whole = not unwritten[id(p)]
+                if whole:  # this shard was the last to get the part's end
+                    with trace_mod.attach(run_span):  # a child of the run, not of this drain
+                        lanes.submit(done_tasks, n_out, p.done)
 
     def drain_one() -> None:
-        parity, width, data_tasks = inflight.popleft()
+        parity, width, data_tasks, pieces = inflight.popleft()
         with trace_mod.span("encode.drain", width=width):
-            with trace_mod.span("encode.sync", bytes=(len(outputs) - k) * width):
+            with trace_mod.span("encode.sync", bytes=(n_out - k) * width):
                 parity_np = np.asarray(parity)  # sync point: device wait + D2H
-            if k + parity_np.shape[0] != len(outputs):
+            if k + parity_np.shape[0] != n_out:
                 # a geometry-mismatched encoder must fail loudly, not leave
                 # trailing .ecNN files silently empty
                 raise ValueError(
                     f"encoder produced {parity_np.shape[0]} parity shards; "
-                    f"layout wants {len(outputs) - k}"
+                    f"layout wants {n_out - k}"
                 )
             with trace_mod.span("encode.wait"):
                 lanes.join(data_tasks, parity_tasks)
             for p in range(parity_np.shape[0]):
                 lanes.submit(
-                    parity_tasks, k + p, put, k + p, np.ascontiguousarray(parity_np[p, :width])
+                    parity_tasks, k + p, put, k + p,
+                    np.ascontiguousarray(parity_np[p, :width]), pieces,
                 )
 
     def flush(batch: list) -> None:
@@ -550,26 +610,35 @@ def _encode_rows(
             # read runs of consecutive segments as one contiguous slab per
             # shard (k large sequential reads per row-run instead of one
             # seek per segment x shard — keeps readahead alive at 1 GiB
-            # block strides): (shard 0's offset in f, first column, end)
+            # block strides): (part, shard 0's offset in its f, first
+            # column, end); a part's runs are one piece of the batch
             runs = []
+            pieces = []
             i = 0
             while i < len(batch):
-                row, seg0 = batch[i]
+                pi, row, seg0 = batch[i]
                 j = i
-                while j + 1 < len(batch) and batch[j + 1] == (row, batch[j][1] + 1):
+                while j + 1 < len(batch) and batch[j + 1] == (pi, row, batch[j][2] + 1):
                     j += 1
+                p = parts[pi]
                 runs.append(
                     (
-                        start_offset + row * block_size * k + seg0 * buffer_size,
+                        pi,
+                        p.start_offset + row * p.block_size * k + seg0 * buffer_size,
                         i * buffer_size,
                         (j + 1) * buffer_size,
                     )
                 )
+                last = row == p.n_rows - 1 and batch[j][2] == p.block_size // buffer_size - 1
+                if pieces and pieces[-1][0] is p:
+                    pieces[-1] = (p, pieces[-1][1], (j + 1) * buffer_size, last)
+                else:
+                    pieces.append((p, i * buffer_size, (j + 1) * buffer_size, last))
                 i = j + 1
             data_tasks = _LaneBatch()
 
             def read_batch() -> None:
-                if fd is None:  # no OS file: here, run by run through seek/readinto
+                if not on_lanes:  # no OS file: here, run by run through seek/readinto
                     read_slabs(range(k), staging, runs)
                 else:
                     for d in range(k):
@@ -581,29 +650,30 @@ def _encode_rows(
                 drain_one()
             if not lane_reads:
                 read_batch()
-            if fd is not None:
+            if on_lanes:
                 with trace_mod.span("encode.wait"):
                     lanes.join(data_tasks)
             view = staging[:, :width]
             for d in range(k):
-                lanes.submit(data_tasks, d, put, d, view[d])
+                lanes.submit(data_tasks, d, put, d, view[d], pieces)
             aw = _aligned(width, align)  # <= span: roundup is monotone
             if aw > width:
                 staging[:, width:aw] = 0  # tail batch: pad columns are zeros
         with trace_mod.span("encode.dispatch", bytes=k * aw):
             parity = enc.encode_parity_lazy(staging[:, :aw], donate=True)  # H2D + launch
-        inflight.append((parity, width, data_tasks))
+        inflight.append((parity, width, data_tasks, pieces))
 
     ring = _ring_for(depth + 1, (k, span))
     try:
-        # iterate segments in global order (row-major, then segment in block)
-        pending: list = []  # (row, seg)
-        for row in range(n_rows):
-            for seg in range(segs_per_row):
-                pending.append((row, seg))
-                if len(pending) >= batch_cap:
-                    flush(pending)
-                    pending = []
+        # iterate segments in global order (part, then row-major, then segment in block)
+        pending: list = []  # (part, row, seg)
+        for pi, p in enumerate(parts):
+            for row in range(p.n_rows):
+                for seg in range(p.block_size // buffer_size):
+                    pending.append((pi, row, seg))
+                    if len(pending) >= batch_cap:
+                        flush(pending)
+                        pending = []
         flush(pending)
         while inflight:
             drain_one()
@@ -645,6 +715,107 @@ def stripe_layout(
     return n_large, n_small
 
 
+def write_ec_files_batch(
+    base_file_names: Sequence[str],
+    large_block_size: int = ERASURE_CODING_LARGE_BLOCK_SIZE,
+    small_block_size: int = ERASURE_CODING_SMALL_BLOCK_SIZE,
+    buffer_size: int = EC_BUFFER_SIZE,
+    encoder: Optional[Encoder] = None,
+    max_batch_bytes: int = 64 * 1024 * 1024,
+    pipeline_depth: Optional[int] = None,
+) -> dict:
+    """<base>.dat -> <base>.ec00 .. .ec13 + <base>.eci for MANY volumes of
+    one geometry through ONE encode pipeline (`_encode_parts`): the volumes'
+    rows are packed into the batches in the order given, so the pipeline
+    fills and drains once and only the last batch of the call is narrower
+    than the slot, where a loop over the volumes ends each in a tail batch of
+    its own width. Each volume's shard files, CRC32s and .eci are its own and
+    byte-identical to what `write_ec_files` writes for it alone.
+
+    Each shard's CRC32 is folded over its staged bytes as they are written
+    (on the shard's lane, in batch order — no read-back pass over a
+    finished file) and recorded in the .eci sidecar for later shard
+    verification.
+
+    A volume is finished (its 15 files closed, its .eci written and fsynced)
+    as soon as its last bytes are written, on a lane of its own beside the
+    batches of the volumes after it (`encode.finish`): only the last volume's
+    finish follows the pipeline.
+
+    Failure semantics: a volume whose .dat cannot be opened is left out and
+    the others run. A mid-stream failure, on this thread or on a lane, stops
+    the lanes, drains the inflight device work and unlinks every partial
+    .ecNN file of every volume that was not finished — a crashed encode never
+    leaves a truncated shard set that a later rebuild would mistake for
+    truth — and fails those (a finished volume is whole and stays); an
+    interrupt is raised again after that clean-up.
+    Returns {"errors": {base: the exception}, "batches": int}."""
+    enc = encoder or new_encoder()
+    k, total = enc.data_shards, enc.total_shards
+    errors: dict[str, BaseException] = {}
+    finished: dict[str, int] = {}  # base -> its .dat's bytes
+    batches = 0
+    with trace_mod.ensure("encode.run", klass="maint"), ExitStack() as stack:
+        parts: list[_Rows] = []
+        opened: list[str] = []
+        for base in base_file_names:
+            try:
+                f = stack.enter_context(open(base + ".dat", "rb"))
+                dat_size = os.fstat(f.fileno()).st_size
+            except OSError as e:
+                errors[base] = e
+                continue
+            opened.append(base)
+            outputs = [
+                stack.enter_context(open(shard_file_name(base, s), "wb")) for s in range(total)
+            ]
+            crcs = [0] * total
+
+            def finish(base=base, files=(f, *outputs), dat_size=dat_size, crcs=crcs) -> None:
+                with trace_mod.span("encode.finish"):
+                    for h in files:  # every shard file, before its .eci says it is whole
+                        h.close()
+                    write_ec_info(
+                        base, large_block_size, small_block_size, dat_size,
+                        shard_crcs=crcs, geometry=geometry_of(enc),
+                    )
+                finished[base] = dat_size
+
+            n_large, n_small = stripe_layout(dat_size, large_block_size, small_block_size, k)
+            small_at = n_large * large_block_size * k
+            parts += [
+                _Rows(f, outputs, 0, large_block_size, n_large, crcs),
+                _Rows(f, outputs, small_at, small_block_size, n_small, crcs, finish),
+            ]
+            if not n_small:  # an empty .dat: no row will ever end, it is whole already
+                finish()
+        try:
+            # one run where the buffer fits both block sizes (every published
+            # geometry); a block under the buffer is cut finer and runs after
+            # the coarser parts, which are the same volumes' earlier rows
+            for buf in sorted({min(buffer_size, p.block_size) for p in parts}, reverse=True):
+                batches += _encode_parts(
+                    [p for p in parts if min(buffer_size, p.block_size) == buf],
+                    enc, buf, max_batch_bytes, pipeline_depth,
+                )
+        except BaseException as e:
+            stack.close()
+            for base in opened:
+                if base not in finished:
+                    errors[base] = e
+                    for s in range(total):
+                        try:
+                            os.unlink(shard_file_name(base, s))
+                        except OSError:
+                            pass
+            if not isinstance(e, Exception):
+                raise
+        trace_mod.annotate(
+            batch=len(base_file_names), bytes=sum(finished.values()), batches=batches
+        )
+    return {"errors": errors, "batches": batches}
+
+
 def write_ec_files(
     base_file_name: str,
     large_block_size: int = ERASURE_CODING_LARGE_BLOCK_SIZE,
@@ -654,60 +825,14 @@ def write_ec_files(
     max_batch_bytes: int = 64 * 1024 * 1024,
     pipeline_depth: Optional[int] = None,
 ) -> None:
-    """<base>.dat -> <base>.ec00 .. .ec13 (WriteEcFiles semantics).
-
-    Each shard's CRC32 is folded over its staged bytes as they are written
-    (on the shard's lane, in batch order — no read-back pass over a
-    finished file) and recorded in the .eci sidecar for later shard
-    verification. A mid-stream failure, on this thread or on a lane, stops
-    the lanes, drains the inflight device work and unlinks every partial
-    .ecNN file — a crashed encode never leaves a truncated shard set that a
-    later rebuild would mistake for truth."""
-    enc = encoder or new_encoder()
-    dat_path = base_file_name + ".dat"
-    dat_size = os.path.getsize(dat_path)
-    large_row = large_block_size * enc.data_shards
-    n_large, n_small = stripe_layout(
-        dat_size, large_block_size, small_block_size, enc.data_shards
+    """<base>.dat -> <base>.ec00 .. .ec13 (WriteEcFiles semantics): the
+    batch of one (`write_ec_files_batch`), whose failure is raised."""
+    res = write_ec_files_batch(
+        [base_file_name], large_block_size, small_block_size, buffer_size,
+        encoder, max_batch_bytes, pipeline_depth,
     )
-
-    crcs = [0] * enc.total_shards
-    with trace_mod.ensure("encode.run", klass="maint"):
-        try:
-            with ExitStack() as stack:
-                f = stack.enter_context(open(dat_path, "rb"))
-                outputs = [
-                    stack.enter_context(open(shard_file_name(base_file_name, s), "wb"))
-                    for s in range(enc.total_shards)
-                ]
-                batches = _encode_rows(
-                    f, enc, outputs, 0, large_block_size, n_large, buffer_size,
-                    max_batch_bytes, pipeline_depth, crcs,
-                )
-                batches += _encode_rows(
-                    f,
-                    enc,
-                    outputs,
-                    n_large * large_row,
-                    small_block_size,
-                    n_small,
-                    min(buffer_size, small_block_size),
-                    max_batch_bytes,
-                    pipeline_depth,
-                    crcs,
-                )
-        except BaseException:
-            for s in range(enc.total_shards):
-                try:
-                    os.unlink(shard_file_name(base_file_name, s))
-                except OSError:
-                    pass
-            raise
-        write_ec_info(
-            base_file_name, large_block_size, small_block_size, dat_size,
-            shard_crcs=crcs, geometry=geometry_of(enc),
-        )
-        trace_mod.annotate(bytes=dat_size, batches=batches)
+    if res["errors"]:
+        raise res["errors"][base_file_name]
 
 
 def geometry_of(enc: Encoder) -> CodeGeometry:

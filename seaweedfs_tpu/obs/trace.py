@@ -80,7 +80,8 @@ SPAN_NAMES: dict[str, str] = {
     "rebuild.write": "one rebuilt shard's bytes of one batch written to its file (on a lane thread)",
     "rebuild.crc": "zlib.crc32 fold over one rebuilt shard's bytes of one batch (on a lane thread)",
     "rebuild.verify": "rebuilt shards' CRC32s checked against the .eci record",
-    "encode.run": "one whole-volume encode: .dat -> shard files + .eci (write_ec_files)",
+    "encode.run": "one encode pipeline: .dat -> shard files + .eci of one volume (write_ec_files), or of a VolumeEcShardsGenerateBatch's many, their rows packed into the same batches (batch= volumes of the run, volumes= their ids, batches= device dispatches the whole run took, bytes= of .dat); ring= says whether its staging ring was reused",
+    "encode.finish": "one volume of an encode run made whole: its 15 files closed, its .eci written and fsynced (on a lane of its own, beside the batches of the volumes after it)",
     "encode.stage": "staging-ring fill for one encode batch: its lane reads queued, the drain it runs ahead of (nested), the wait for the reads",
     "encode.read": "one data shard's slabs of one batch read into its staging row (child of encode.stage; on a lane thread), or all ten on the calling thread where the source is no file",
     "encode.wait": "the calling thread blocked in a join of lane tasks (a batch's reads, its data shards' writes, the last drain's parity writes)",

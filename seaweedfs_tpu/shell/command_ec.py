@@ -134,64 +134,144 @@ def _parallel(work: list) -> None:
 # -- ec.encode ---------------------------------------------------------------
 
 
-def _do_ec_encode(
+#: a batch of a sweep closes at this many volumes or bytes of .dat, whichever
+#: comes first (a volume over the bytes is a batch of one): until a batch's
+#: cut-overs run, its server holds every volume's shards beside its .dat, and
+#: the checkpoint marks nothing of it
+ENCODE_BATCH_MAX_VOLUMES = 16
+ENCODE_BATCH_MAX_BYTES = 16 << 30
+
+
+def _encode_batches(plans: list[dict]) -> list[list[dict]]:
+    """The selected volumes as batches: source server by source server (the
+    first replica holder, in the order of each server's first volume), cut
+    at ENCODE_BATCH_MAX_VOLUMES / ENCODE_BATCH_MAX_BYTES."""
+    by_source: dict[str, list[dict]] = {}
+    for plan in plans:
+        by_source.setdefault(plan["locations"][0]["url"], []).append(plan)
+    out: list[list[dict]] = []
+    for group in by_source.values():
+        batch: list[dict] = []
+        for plan in group:
+            if batch and (
+                len(batch) >= ENCODE_BATCH_MAX_VOLUMES
+                or sum(p["size"] for p in batch) + plan["size"] > ENCODE_BATCH_MAX_BYTES
+            ):
+                out.append(batch)
+                batch = []
+            batch.append(plan)
+        out.append(batch)
+    return out
+
+
+def _encode_batch(
     env: CommandEnv,
     nodes: list[dict],
-    vid: int,
-    collection: str,
+    plans: list[dict],
     w: TextIO,
     large_block_size: int = 0,
     small_block_size: int = 0,
     inline: bool = False,
-) -> None:
-    locations = _volume_locations(nodes, vid)
-    if not locations:
-        raise ShellError(f"volume {vid} not found on any node")
-    # 1. freeze writes on every replica (SURVEY.md §3.1); roll the freeze
-    # back if anything later fails, or the volume is stuck readonly forever
-    for loc in locations:
-        env.vs_call(grpc_addr(loc), "VolumeMarkReadonly", {"volume_id": vid})
-    try:
-        _encode_spread_cutover(
-            env, nodes, locations, vid, collection, w, large_block_size,
-            small_block_size, inline,
-        )
-    except Exception:
-        for loc in locations:
+    on_done=None,
+) -> list[int]:
+    """One source server's volumes of a sweep (a lone `-volumeId` too).
+    Each is frozen on every replica (SURVEY.md §3.1); the server generates
+    them all in ONE `VolumeEcShardsGenerateBatch`, their rows sharing one
+    pipeline's batches (`-inline` finalizes a volume's own encode-on-write
+    state, so there each volume keeps its `VolumeEcShardsGenerate`); then,
+    volume by volume, spread, mount, delete of the original, `on_done(vid)`.
+    A volume that fails anywhere is made writable again and reported, and
+    the others complete. -> the volumes that were not encoded."""
+    src_addr = grpc_addr(plans[0]["locations"][0])
+    error: dict[int, str] = {}
+    mode: dict[int, str] = {}
+
+    def fail(plan: dict, e) -> None:
+        error[plan["vid"]] = str(e) if isinstance(e, ShellError) else f"{type(e).__name__}: {e}"
+
+    # 1. freeze writes on every replica; a freeze is rolled back below if
+    # anything later fails, or the volume is stuck readonly forever
+    for plan in plans:
+        try:
+            for loc in plan["locations"]:
+                env.vs_call(grpc_addr(loc), "VolumeMarkReadonly", {"volume_id": plan["vid"]})
+        except Exception as e:  # noqa: BLE001 — this volume's alone
+            fail(plan, e)
+    # 2. generate all 14 shards + .ecx of each on the first replica holder
+    block_sizes = {}
+    if large_block_size:
+        block_sizes["large_block_size"] = large_block_size
+    if small_block_size:
+        block_sizes["small_block_size"] = small_block_size
+    frozen = [p for p in plans if p["vid"] not in error]
+    if inline:
+        # finalize from the server's encode-on-write stripe state —
+        # byte-identical shards, the encode already amortized into ingest;
+        # the server falls back to the warm conversion when no usable inline
+        # state exists and reports which path ran
+        for plan in frozen:
+            req = {"volume_id": plan["vid"], "collection": plan["collection"], "inline": True}
             try:
-                env.vs_call(grpc_addr(loc), "VolumeMarkWritable", {"volume_id": vid})
-            except Exception:  # noqa: BLE001 — best-effort rollback
-                pass
-        raise
+                resp = env.vs_call(src_addr, "VolumeEcShardsGenerate", {**req, **block_sizes})
+                mode[plan["vid"]] = resp.get("mode") or ""
+            except Exception as e:  # noqa: BLE001
+                fail(plan, e)
+    elif frozen:
+        from seaweedfs_tpu.ec import placement
+
+        req = placement.rebuild_batch_request((p["vid"], p["collection"]) for p in frozen)
+        try:
+            resp = env.vs_call(
+                src_addr, "VolumeEcShardsGenerateBatch", {**req, **block_sizes},
+                timeout=600 * len(frozen),
+            )
+            results = {int(r["volume_id"]): r for r in resp.get("results", [])}
+            for plan in frozen:
+                r = results.get(plan["vid"])
+                if r is None or r.get("error"):
+                    error[plan["vid"]] = (r or {}).get("error") or "no result"
+            w.write(
+                f"ec.encode batch on {plans[0]['locations'][0]['url']}: {len(frozen)} "
+                f"volumes in {int(resp.get('batches', 0))} batches\n"
+            )
+        except Exception as e:  # noqa: BLE001 — the call itself: every volume of it
+            for plan in frozen:
+                fail(plan, e)
+    # 3.-5. each volume's own cut-over, or its freeze rolled back
+    for plan in plans:
+        vid = plan["vid"]
+        if vid not in error:
+            try:
+                _spread_cutover(
+                    env, nodes, plan["locations"], vid, plan["collection"], w, mode.get(vid)
+                )
+                if on_done is not None:
+                    on_done(vid)
+            except Exception as e:  # noqa: BLE001
+                fail(plan, e)
+        if vid in error:
+            for loc in plan["locations"]:
+                try:
+                    env.vs_call(grpc_addr(loc), "VolumeMarkWritable", {"volume_id": vid})
+                except Exception:  # noqa: BLE001 — best-effort rollback
+                    pass
+            w.write(f"ec.encode volume {vid}: NOT encoded: {error[vid]}\n")
+    return sorted(error)
 
 
-def _encode_spread_cutover(
+def _spread_cutover(
     env: CommandEnv,
     nodes: list[dict],
     locations: list[dict],
     vid: int,
     collection: str,
     w: TextIO,
-    large_block_size: int,
-    small_block_size: int,
-    inline: bool = False,
+    gen_mode: Optional[str] = None,
 ) -> None:
-    # 2. generate all 14 shards + .ecx on the first replica holder
-    # (-inline: finalize from the server's encode-on-write stripe state —
-    # byte-identical shards, the encode already amortized into ingest;
-    # the server falls back to the warm conversion when no usable inline
-    # state exists and reports which path ran)
+    """One generated volume's cut-over: its shards spread and mounted, then
+    the original and its replicas deleted."""
     source = locations[0]
     src_addr = grpc_addr(source)
-    gen_req = {"volume_id": vid, "collection": collection}
-    if large_block_size:
-        gen_req["large_block_size"] = large_block_size
-    if small_block_size:
-        gen_req["small_block_size"] = small_block_size
-    if inline:
-        gen_req["inline"] = True
-    gen_resp = env.vs_call(src_addr, "VolumeEcShardsGenerate", gen_req)
-    gen_mode = gen_resp.get("mode") if inline else None
     # 3. spread: balanced, rack-aware allocation; targets pull from source
     alloc = allocate_shards(nodes)
 
@@ -269,9 +349,11 @@ def do_ec_encode(args: list[str], env: CommandEnv, w: TextIO) -> None:
     # each volume's real collection comes from the topology, not the flag —
     # the flag only SELECTS volumes
     coll_of: dict[int, str] = {}
+    size_of: dict[int, int] = {}
     for n in nodes:
         for v in n.get("volumes", []):
             coll_of[int(v["id"])] = v.get("collection", "")
+            size_of[int(v["id"])] = max(size_of.get(int(v["id"]), 0), int(v.get("size", 0)))
     vids: list[int] = []
     if fl.volumeId:
         if fl.volumeId not in coll_of:
@@ -323,23 +405,39 @@ def do_ec_encode(args: list[str], env: CommandEnv, w: TextIO) -> None:
         done = ckpt.load_done()
         if done:
             w.write(f"ec.encode: resuming, {len(done)} volume(s) already done\n")
+    # plan first: what is left of the selection, where each volume lives
+    plans: list[dict] = []
     for vid in vids:
         if vid in done:
             w.write(f"ec.encode volume {vid}: skip (checkpointed)\n")
             continue
-        _do_ec_encode(
+        locations = _volume_locations(nodes, vid)
+        if not locations:
+            raise ShellError(f"volume {vid} not found on any node")
+        plans.append(
+            {"vid": vid, "collection": coll_of[vid], "locations": locations, "size": size_of[vid]}
+        )
+
+    def on_done(vid: int) -> None:
+        # a volume is done when ITS cut-over is complete, batch or no batch
+        if ckpt is not None:
+            done.add(vid)
+            ckpt.mark_done(done)
+
+    failed: list[int] = []
+    for batch in _encode_batches(plans):
+        failed += _encode_batch(
             env,
             nodes,
-            vid,
-            coll_of[vid],
+            batch,
             w,
             large_block_size=fl.largeBlockSize,
             small_block_size=fl.smallBlockSize,
             inline=bool(fl.inline),
+            on_done=on_done,
         )
-        if ckpt is not None:
-            done.add(vid)
-            ckpt.mark_done(done)
+    if failed:
+        raise ShellError(f"ec.encode: volumes {failed} were not encoded")
     if ckpt is not None:
         ckpt.finish()  # batch complete: a future batch starts fresh
 
@@ -350,7 +448,13 @@ register(
         "ec.encode -volumeId <id> | -collection <name> [-fullPercent 95] "
         "[-quietFor <secs>] [-force] [-inline] [-checkpoint <file>]\n"
         "\tencode a volume into 14 EC shards, spread them, delete the original;\n"
-        "\tbatch runs checkpoint per-volume progress and resume on rerun;\n"
+        "\twithout -volumeId a sweep: every selected volume, source server by\n"
+        "\tsource server in ONE VolumeEcShardsGenerateBatch (their rows share one\n"
+        "\tpipeline's device batches; at most 16 volumes or 16 GiB a batch), then\n"
+        "\teach volume's own cut-over; a volume that fails is made writable again\n"
+        "\tand reported (NOT encoded), the others complete, the command ends in an\n"
+        "\terror naming it; sweeps checkpoint a volume when its cut-over is\n"
+        "\tcomplete and resume on rerun;\n"
         "\t-inline finalizes from the server's encode-on-write stripe state\n"
         "\t(WEEDTPU_INLINE_EC=on) instead of re-encoding the sealed .dat —\n"
         "\tbyte-identical shards, warm fallback when no usable inline state",

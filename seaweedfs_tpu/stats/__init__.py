@@ -251,6 +251,19 @@ EcEncodeBytes = REGISTRY.counter(
 EcEncodeSeconds = REGISTRY.histogram(
     "weedtpu_ec_encode_seconds", "wall time of volume EC encodes"
 )
+EcEncodeRuns = REGISTRY.counter(
+    "weedtpu_ec_encode_runs_total",
+    "volumes THIS server turned into EC shards by a warm encode, by the codec "
+    "backend its store runs (one per VolumeEcShardsGenerate, one per volume of "
+    "a VolumeEcShardsGenerateBatch): where a cluster's encodes really ran",
+    ("backend",),
+)
+EcEncodeBatchVolumes = REGISTRY.counter(
+    "weedtpu_ec_encode_batch_volumes_total",
+    "volumes THIS server encoded inside a VolumeEcShardsGenerateBatch (ec.encode "
+    "sends one per source server for the volumes of a sweep): their rows shared "
+    "one pipeline's batches",
+)
 EcReconstructSeconds = REGISTRY.histogram(
     "weedtpu_ec_reconstruct_seconds",
     "latency of shard-interval reconstructions (p50 is the north-star)",

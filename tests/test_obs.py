@@ -632,7 +632,7 @@ def test_trace_id_round_trips_shell_rebuild_trace_and_slab(cluster):
 
 def test_ec_trace_renders_the_bulk_stages_of_one_shell_encode_and_rebuild(cluster):
     """One `ec.encode` through the shell: `ec.trace -traceId <its id>` shows
-    the generate RPC's run span (volume id, bytes, batches) and the per-batch
+    the generate RPC's run span (volume ids, bytes, batches) and the per-batch
     stages under the shell's id; the local rebuild RPC's run span likewise."""
     master, servers, client, env = cluster
     trace.RING.clear()
@@ -647,11 +647,12 @@ def test_ec_trace_renders_the_bulk_stages_of_one_shell_encode_and_rebuild(cluste
         (run_span,) = [s for s in trace.iter_spans(rpc_leg) if s["name"] == run]
         return shell["trace_id"], run_span, {s["name"] for s in trace.iter_spans(rpc_leg)}
 
-    tid, run, names = leg("ec.encode", "VolumeEcShardsGenerate", "encode.run")
-    assert run["attrs"]["volume"] == vid and run["attrs"]["batches"] >= 1 and run["attrs"]["bytes"] > 0
+    tid, run, names = leg("ec.encode", "VolumeEcShardsGenerateBatch", "encode.run")
+    assert run["attrs"]["volumes"] == str(vid) and run["attrs"]["batch"] == 1
+    assert run["attrs"]["batches"] >= 1 and run["attrs"]["bytes"] > 0
     assert {f"encode.{s}" for s in ("stage", "read", "write", "crc", "dispatch", "drain", "sync")} <= names
     rendered = _shell(env, f"ec.trace -traceId {tid}")
-    for want in (tid, f"encode.run volume={vid}", "encode.read bytes=", "encode.sync bytes=", "encode.crc bytes="):
+    for want in (tid, f"encode.run volumes={vid}", "encode.read bytes=", "encode.sync bytes=", "encode.crc bytes="):
         assert want in rendered, (want, rendered)
 
     # the local rebuild RPC, on a second volume whose 14 shards stay on its server

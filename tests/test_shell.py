@@ -276,18 +276,19 @@ def test_ec_encode_batch_resume_after_interrupt(cluster, tmp_path, monkeypatch):
     ckpt = str(tmp_path / "enc.ckpt")
     run(env, "lock")
 
-    # simulated kill: the encode of the SECOND volume dies at its start —
-    # after the first volume completed and was checkpointed
-    real = cec._do_ec_encode
+    # simulated kill: the cut-over of the SECOND volume dies at its start —
+    # after the first volume's completed and was checkpointed (a volume is
+    # done when ITS cut-over is, whether or not a batch generated both)
+    real = cec._spread_cutover
     calls = {"n": 0}
 
-    def dying(env_, nodes, vid, coll, w, **kw):
+    def dying(env_, nodes, locations, vid, coll, w, *a):
         calls["n"] += 1
         if calls["n"] >= 2:
             raise KeyboardInterrupt("simulated operator kill")
-        return real(env_, nodes, vid, coll, w, **kw)
+        return real(env_, nodes, locations, vid, coll, w, *a)
 
-    monkeypatch.setattr(cec, "_do_ec_encode", dying)
+    monkeypatch.setattr(cec, "_spread_cutover", dying)
     with pytest.raises(KeyboardInterrupt):
         run(env, f"ec.encode -collection '' -force -checkpoint {ckpt} "
                  f"-largeBlockSize {LARGE} -smallBlockSize {SMALL}")
@@ -299,7 +300,7 @@ def test_ec_encode_batch_resume_after_interrupt(cluster, tmp_path, monkeypatch):
 
     # rerun (no kill): the checkpointed volume is skipped even though the
     # master's topology may still show it (stale heartbeat window)
-    monkeypatch.setattr(cec, "_do_ec_encode", real)
+    monkeypatch.setattr(cec, "_spread_cutover", real)
     out = run(env, f"ec.encode -collection '' -force -checkpoint {ckpt} "
                    f"-largeBlockSize {LARGE} -smallBlockSize {SMALL}")
     if f"volume {vid_a}" in out:

@@ -716,6 +716,8 @@ def test_bulk_stage_spans_account_for_a_run(tmp_path, monkeypatch, pipeline):
     want[f"{pipeline}.wait"] = 2 * batches + 1  # a stage's reads, a drain's join, the run's end
     if pipeline == "rebuild":
         want["rebuild.verify"] = 1
+    else:
+        want["encode.finish"] = 1  # the volume's files closed, its .eci written
     assert count == want
     assert set(want) <= set(trace.SPAN_NAMES)
 
