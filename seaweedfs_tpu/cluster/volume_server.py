@@ -26,7 +26,7 @@ import urllib.error
 import urllib.parse
 import urllib.request
 from concurrent import futures
-from contextlib import ExitStack
+from contextlib import ExitStack, contextmanager
 from typing import Optional
 
 import time
@@ -157,6 +157,10 @@ class VolumeServer:
         # closes the race no matter which actor (timer or operator) fires.
         self._maint_locks: dict[int, threading.Lock] = {}
         self._maint_mu = threading.Lock()
+        # (begun since start, in flight now) of VolumeEcShardsRebuild, swapped
+        # whole under _maint_mu: a VolumeEcShardsCopy reads it as it begins and
+        # ends to say whether it ran beside one (EcCopyBesideRebuild)
+        self._ec_rebuilds = (0, 0)
         # degraded-read plumbing: LookupEcVolume answers are cached per vid
         # with expiry (the reference caches ShardLocations on the EcVolume)
         # and peer channels are pooled — an uncached lookup + fresh dial per
@@ -1694,6 +1698,7 @@ class VolumeServer:
         src = req["source_data_node"]  # grpc address host:port
         base = self._base_path_for(vid, collection)
         pulled = 0
+        rebuilds = self._ec_rebuilds  # (begun, in flight) as this copy begins
         with trace_mod.span(
             "ec.copy", source=src, shards=",".join(map(str, shard_ids))
         ) as sp, rpc.RpcClient(src) as c:
@@ -1709,6 +1714,8 @@ class VolumeServer:
                     raise
             if sp is not None:
                 sp.annotate(bytes=pulled)
+        if rebuilds[1] or self._ec_rebuilds[0] != rebuilds[0]:
+            stats.EcCopyBesideRebuild.inc()
         return {}
 
     @staticmethod
@@ -1938,7 +1945,7 @@ class VolumeServer:
         collection = req.get("collection", "")
         base = self._base_path_for(vid, collection)
         t0 = time.monotonic()
-        with trace_mod.ensure("rebuild.run", klass="maint"):
+        with self._ec_rebuild_in_flight(), trace_mod.ensure("rebuild.run", klass="maint"):
             trace_mod.annotate(volume=vid, remote=bool(req.get("remote")))
             if not req.get("remote"):
                 rebuilt = stripe.rebuild_ec_files(
@@ -1954,6 +1961,19 @@ class VolumeServer:
         if resp.get("rebuilt_shard_ids"):
             stats.EcRebuildRuns.labels(self.store.encoder.backend).inc()
         return resp
+
+    @contextmanager
+    def _ec_rebuild_in_flight(self):
+        """One VolumeEcShardsRebuild, counted in `_ec_rebuilds` while it runs."""
+        with self._maint_mu:
+            begun, running = self._ec_rebuilds
+            self._ec_rebuilds = (begun + 1, running + 1)
+        try:
+            yield
+        finally:
+            with self._maint_mu:
+                begun, running = self._ec_rebuilds
+                self._ec_rebuilds = (begun, running - 1)
 
     def _ec_rebuild_remote(
         self, vid: int, collection: str, base: str, req: dict

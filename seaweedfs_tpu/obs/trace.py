@@ -54,7 +54,7 @@ SPAN_NAMES: dict[str, str] = {
     "http.read": "volume-server HTTP GET of one needle (the serving path)",
     "http.write": "volume-server HTTP POST/PUT of one needle",
     "master.http": "master HTTP facade route (/dir/assign, /dir/lookup, ...)",
-    "shell.command": "one weed-shell command execution (command, modules loaded at its start, rpcs it made)",
+    "shell.command": "one weed-shell command execution (command, modules loaded at its start, rpcs it made; ec.rebuild without -remote: overlapped= gathers that ran beside the rebuild of the volume before)",
     "rpc.server": "server side of one gRPC method (method name in attrs)",
     "ec.lookup": "master LookupEcVolume round-trip (shard-location cache miss)",
     "ec.recover": "degraded interval reconstruction, client-facing wall time",

@@ -474,12 +474,21 @@ def _shard_holders(nodes: list[dict], vid: int) -> dict[int, list[dict]]:
     return out
 
 
+class CopiesFailed(ShellError):
+    """Some pull of a gather failed. `landed`: the shard ids that are on the
+    node now, or may be: what the caller has to drop."""
+
+    def __init__(self, message: str, landed: list[int]):
+        super().__init__(message)
+        self.landed = landed
+
+
 def _copy_missing_to(env: CommandEnv, node: dict, vid: int, collection: str,
                      holders: dict[int, list[dict]],
                      only: Optional[set] = None) -> list[int]:
     """Pull every survivor shard `node` lacks onto it (restricted to the
     `only` set when given); returns the shard ids temporarily copied (for
-    cleanup)."""
+    cleanup). Where a pull fails, `CopiesFailed` carries the same list."""
     local = set(_node_shards_of(node, vid))
     by_source: dict[str, list[int]] = {}
     for sid, hs in holders.items():
@@ -489,7 +498,6 @@ def _copy_missing_to(env: CommandEnv, node: dict, vid: int, collection: str,
         if src is None:
             continue
         by_source.setdefault(grpc_addr(src), []).append(sid)
-    copied: list[int] = []
     first = not local  # no local shards: also pull the index files
     # Pull from every source in parallel (command_ec_rebuild.go's
     # prepareDataToRecover analog): each source writes disjoint .ecNN files
@@ -499,6 +507,9 @@ def _copy_missing_to(env: CommandEnv, node: dict, vid: int, collection: str,
     for src_addr, sids in sorted(by_source.items()):
         jobs.append((src_addr, sids, first))
         first = False
+    # on the node once its pull has ended, and in part where the pull failed
+    # (the files before the one that broke are renamed already)
+    copied = [sid for _, sids, _ in jobs for sid in sids]
     errs: list[str] = []
     with futures.ThreadPoolExecutor(max_workers=min(_POOL, max(1, len(jobs)))) as pool:
         futs = {
@@ -513,18 +524,18 @@ def _copy_missing_to(env: CommandEnv, node: dict, vid: int, collection: str,
                     "source_data_node": src_addr,
                     "copy_ecx_file": with_ecx,
                 },
-            ): (src_addr, sids)
+            ): src_addr
             for src_addr, sids, with_ecx in jobs
         }
         for fut in futures.as_completed(futs):
-            src_addr, sids = futs[fut]
             try:
                 fut.result()
-                copied.extend(sids)
             except Exception as e:  # noqa: BLE001
-                errs.append(f"{src_addr}: {e}")
+                errs.append(f"{futs[fut]}: {e}")
     if errs:
-        raise ShellError(f"shard copies failed: {'; '.join(errs)}")
+        raise CopiesFailed(
+            f"volume {vid}: shard copies to {node['url']} failed: {'; '.join(errs)}", copied
+        )
     return copied
 
 
@@ -562,14 +573,16 @@ def pick_rebuilder(
 
 def do_ec_rebuild(args: list[str], env: CommandEnv, w: TextIO) -> None:
     """Plan every EC volume of the selection (what is missing, who holds
-    what, the geometry, the rebuilder), then rebuild rebuilder by
-    rebuilder. The volumes whose survivors are ALL on their rebuilder
+    what, the geometry, the rebuilder), then rebuild. First, rebuilder by
+    rebuilder, the volumes whose survivors are ALL on their rebuilder
     already go together in ONE `VolumeEcShardsRebuildBatch` (a lone one
-    too), whose packed pipeline fills and drains once for all of them. A
-    volume that needs survivor copies is rebuilt on its own (copy,
-    single-volume RPC, copies dropped), so that the rebuilder's disk never
-    holds more than one volume's temporary copies. `-remote` stays volume
-    by volume: its options are the single RPC's."""
+    too), whose packed pipeline fills and drains once for all of them.
+    Then the volumes that need survivor copies, in plan order, through
+    `_rebuild_pipelined`: each keeps its own copy, single-volume RPC and
+    drop of the copies, and the next volume's copies are gathered while
+    this one is rebuilt, so that a rebuilder's disk holds at most TWO
+    volumes' temporary copies at any moment. `-remote` stays volume by
+    volume: its options are the single RPC's."""
     fl = parse_flags(args, collection="", remote=False, trace="auto")
     trace_mode = str(fl.trace).strip().lower()
     if trace_mode not in ("on", "off", "auto"):
@@ -629,9 +642,10 @@ def do_ec_rebuild(args: list[str], env: CommandEnv, w: TextIO) -> None:
         batch = [p for p in plans if p["local"]]
         if batch:
             failed += _rebuild_many(env, batch, w)
-        for plan in plans:
-            if not plan["local"]:
-                _rebuild_one(env, plan, w)
+    if by_rebuilder and not fl.remote:
+        _rebuild_pipelined(
+            env, [p for plans in by_rebuilder.values() for p in plans if not p["local"]], w
+        )
     if failed:
         raise ShellError(f"ec.rebuild: volumes {failed} were not rebuilt")
 
@@ -680,27 +694,87 @@ def _rebuild_remote(env: CommandEnv, plan: dict, trace_mode: str, w: TextIO) -> 
     )
 
 
-def _rebuild_one(env: CommandEnv, plan: dict, w: TextIO) -> None:
-    """Upstream's copy-then-rebuild of one volume on its rebuilder; the
-    copies go whether or not the rebuild came back."""
-    vid, collection, rebuilder = plan["vid"], plan["collection"], plan["rebuilder"]
-    addr = grpc_addr(rebuilder)
-    copied = _copy_missing_to(env, rebuilder, vid, collection, plan["holders"])
-    try:
-        resp = env.vs_call(
-            addr, "VolumeEcShardsRebuild", {"volume_id": vid, "collection": collection}
-        )
-    finally:
-        # drop the temp survivor copies; delete remounts local = original+rebuilt
-        # (never with an empty list: the server reads that as every shard)
+def _rebuild_pipelined(env: CommandEnv, plans: list[dict], w: TextIO) -> None:
+    """The volumes that need survivor copies, in plan order, each upstream's
+    copy-then-rebuild on its rebuilder (copies, `VolumeEcShardsRebuild`,
+    `VolumeEcShardsDelete` of the copies), as a pipeline of two stages: the
+    gather of volume n+1 runs on a worker beside the rebuild and the drop of
+    volume n. A gather starts when the one before it has ENDED (two gathers
+    never share the sockets, the receiver and the disk) and the volume
+    before that has been DROPPED: a rebuilder's disk holds at most two
+    volumes' temporary copies. Rebuilds and drops stay on the calling
+    thread, in plan order, never two at once, never before all of a
+    volume's copies have landed. A lone volume is a pipeline of one.
+
+    Whatever fails, nothing temporary is left when the error goes up: a
+    volume's copies go whether or not its rebuild came back (what a gather
+    landed before one of its pulls failed, too), and a gather in flight is
+    awaited and what it landed is dropped."""
+    from seaweedfs_tpu.obs import trace as trace_obs
+
+    def gather(plan: dict) -> tuple[list[int], Optional[CopiesFailed]]:
+        try:
+            return _copy_missing_to(
+                env, plan["rebuilder"], plan["vid"], plan["collection"], plan["holders"]
+            ), None
+        except CopiesFailed as e:
+            return e.landed, e
+
+    def drop(plan: dict, copied: list[int]) -> None:
+        # delete remounts local = original + rebuilt (never with an empty
+        # list: the server reads that as every shard)
         if copied:
             env.vs_call(
-                addr,
+                grpc_addr(plan["rebuilder"]),
                 "VolumeEcShardsDelete",
-                {"volume_id": vid, "collection": collection, "shard_ids": copied},
+                {"volume_id": plan["vid"], "collection": plan["collection"], "shard_ids": copied},
             )
-    rebuilt = resp.get("rebuilt_shard_ids", [])
-    w.write(f"ec.rebuild volume {vid}: rebuilt {rebuilt} on {rebuilder['url']}\n")
+
+    def rebuild(plan: dict) -> list[int]:
+        vid, rebuilder = plan["vid"], plan["rebuilder"]
+        try:
+            resp = env.vs_call(
+                grpc_addr(rebuilder),
+                "VolumeEcShardsRebuild",
+                {"volume_id": vid, "collection": plan["collection"]},
+            )
+        except Exception as e:  # noqa: BLE001 — the error names its volume
+            raise ShellError(
+                f"ec.rebuild volume {vid}: NOT rebuilt on {rebuilder['url']}: {e}"
+            ) from e
+        return resp.get("rebuilt_shard_ids", [])
+
+    overlapped = 0  # gathers that ran beside the rebuild of the volume before
+    try:
+        with futures.ThreadPoolExecutor(max_workers=1) as pool:
+            ahead = pool.submit(_in_trace(gather), plans[0]) if plans else None
+            for n, plan in enumerate(plans):
+                copied, gather_failed = ahead.result()
+                ahead = None
+                try:
+                    try:
+                        if gather_failed is not None:
+                            raise gather_failed
+                        if n + 1 < len(plans):
+                            ahead = pool.submit(_in_trace(gather), plans[n + 1])
+                            overlapped += 1
+                        rebuilt = rebuild(plan)
+                    finally:
+                        drop(plan, copied)
+                except BaseException:
+                    if ahead is not None:
+                        drop(plans[n + 1], ahead.result()[0])
+                    raise
+                w.write(
+                    f"ec.rebuild volume {plan['vid']}: rebuilt {rebuilt} on "
+                    f"{plan['rebuilder']['url']}\n"
+                )
+    finally:
+        w.write(
+            f"ec.rebuild: {len(plans)} volumes with copies, "
+            f"{overlapped} gathered beside a rebuild\n"
+        )
+        trace_obs.annotate(overlapped=overlapped)
 
 
 def _rebuild_many(env: CommandEnv, plans: list[dict], w: TextIO) -> list[int]:
@@ -744,7 +818,10 @@ register(
         "ec.rebuild [-collection <name>] [-remote] [-trace on|off|auto]\n\tfind "
         "EC volumes with lost shards and reconstruct them on a rebuilder node\n"
         "\t(those with every survivor on their rebuilder already, in ONE "
-        "batch);\n"
+        "batch;\n\tthose that need survivor copies one rebuild at a time, the "
+        "next volume's\n\tcopies gathered meanwhile: a rebuilder's disk holds at "
+        "most two volumes'\n\ttemporary copies, and none when the command "
+        "returns);\n"
         "\t-remote streams survivors from their holders through the network-\n"
         "\toverlapped rebuild pipeline instead of bulk-copying shard files "
         "first;\n\t-trace (with -remote) controls repair-bandwidth projections: "

@@ -294,6 +294,14 @@ EcRebuildBatchVolumes = REGISTRY.counter(
     "that need no survivor copy): the scheduler's own count of what it sent is "
     "weedtpu_repair_fused_volumes_total, on the master",
 )
+EcCopyBesideRebuild = REGISTRY.counter(
+    "weedtpu_ec_copy_beside_rebuild_total",
+    "VolumeEcShardsCopy calls on THIS server during which a single-volume "
+    "VolumeEcShardsRebuild ran on it too (one was in flight when the copy "
+    "began, or began before it ended): ec.rebuild gathering the next "
+    "volume's survivors while this one is rebuilt; the shell's own count is "
+    "overlapped= on its shell.command span",
+)
 StagingRingLeases = REGISTRY.counter(
     "weedtpu_staging_ring_leases_total",
     "staging rings leased by bulk EC runs (an encode, a rebuild, an ingest "

@@ -17,6 +17,7 @@ import re
 import shutil
 import subprocess
 import sys
+import threading
 import time
 
 import numpy as np
@@ -31,7 +32,7 @@ from seaweedfs_tpu.ec.fleet import RepairScheduler
 from seaweedfs_tpu.obs import trace
 from seaweedfs_tpu.ops.rs_codec import new_encoder
 from seaweedfs_tpu.pb import Heartbeat
-from seaweedfs_tpu.shell import CommandEnv, command_ec, run_script
+from seaweedfs_tpu.shell import CommandEnv, ShellError, command_ec, run_script
 from seaweedfs_tpu.storage.needle import Needle
 from seaweedfs_tpu.storage.volume import Volume
 
@@ -91,15 +92,16 @@ class Cluster:
     codec: "jax" (a real jax encoder, on the CPU here), "host" (numpy) or
     "nothing" (a server that predates the report)."""
 
-    def __init__(self, tmp_path, reports):
+    def __init__(self, tmp_path, reports, vids=VIDS):
+        self.vids = vids
         self.master = MasterServer(port=0, reap_interval=3600)
         self.master.start()
         self.dirs = [str(tmp_path / f"srv{i}") for i in range(4)]
         for d in self.dirs:
             os.makedirs(d)
-        self.needles = {vid: _write_volume(self.dirs[0], vid, seed=28) for vid in VIDS}
+        self.needles = {vid: _write_volume(self.dirs[0], vid, seed=28) for vid in vids}
         self.reference = {}
-        for vid in VIDS:
+        for vid in vids:
             with open(os.path.join(self.dirs[0], f"{vid}.dat"), "rb") as f:
                 self.reference[vid] = _reference_shards(f.read())
         self.servers = [self._server(i, r) for i, r in enumerate(reports)]
@@ -126,7 +128,7 @@ class Cluster:
     def encode_and_spread(self):
         self.shell("lock; " + "; ".join(
             f"ec.encode -volumeId {v} -force -largeBlockSize {LARGE} -smallBlockSize {SMALL}"
-            for v in VIDS) + "; unlock")
+            for v in self.vids) + "; unlock")
 
     def held(self, vid):
         """url -> shard ids, as the master lists them."""
@@ -139,7 +141,8 @@ class Cluster:
     def lose(self, i):
         victim = self.servers[i]
         victim.stop()
-        _wait_for(lambda: all(victim.url not in self.held(v) for v in VIDS), msg="the master dropped the lost server")
+        _wait_for(lambda: victim.url not in self.master.topology.nodes
+                  and all(victim.url not in self.held(v) for v in self.vids), msg="the master dropped the lost server")
 
     def close(self):
         self.env.close()
@@ -158,8 +161,8 @@ def make_cluster(tmp_path, monkeypatch):
     monkeypatch.setenv("WEEDTPU_TRACE_SAMPLE", "1.0")
     made = []
 
-    def make(reports):
-        c = Cluster(tmp_path, reports)
+    def make(reports, vids=VIDS):
+        c = Cluster(tmp_path, reports, vids)
         made.append(c)
         return c
 
@@ -266,6 +269,315 @@ def test_without_a_device_report_the_choice_is_the_old_one(make_cluster, reports
             base = os.path.join(c.dirs[[vs.url for vs in c.servers].index(chosen[vid])], str(vid))
             with open(stripe.shard_file_name(base, s), "rb") as f:
                 assert f.read() == c.reference[vid][s]
+
+
+# -- the two-volume pipeline of the volumes that need copies ----------------------
+
+
+class Recorded:
+    """`env.vs_call`, recording [start, end, method, volume, address] of every
+    call the shell makes; `before(method, volume)` runs first (it may sleep or
+    raise), `watch()` at each call's start and end."""
+
+    def __init__(self, env, before=None, watch=None):
+        self.calls, self._send, self._before, self._watch = [], env.vs_call, before, watch
+        self._lock = threading.Lock()
+
+    def __call__(self, addr, method, req, timeout=300):
+        row = [time.monotonic(), None, method, int(req.get("volume_id", 0)), addr]
+        with self._lock:
+            self.calls.append(row)
+        try:
+            if self._watch:
+                self._watch()
+            if self._before:
+                self._before(method, row[3])
+            return self._send(addr, method, req, timeout=timeout)
+        finally:
+            row[1] = time.monotonic()
+            if self._watch:
+                self._watch()
+
+    def of(self, method, vid=None):
+        """-> [(start, end, volume)] of the calls of `method`, by start."""
+        return sorted((a, b, v) for a, b, m, v, _ in self.calls if m == method and vid in (None, v))
+
+
+def _slow_rebuilds(monkeypatch, seconds=0.15):
+    """Every server-side whole-volume rebuild takes `seconds` longer, so that
+    what the shell sends beside it arrives while it runs."""
+    real = stripe.rebuild_ec_files
+
+    def slow(*a, **kw):
+        time.sleep(seconds)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(stripe, "rebuild_ec_files", slow)
+
+
+def _shell_root(command="ec.rebuild"):
+    (root,) = [t["root"] for t in trace.RING.snapshot(kind="shell.command", limit=1000)
+               if t["root"]["attrs"].get("command") == command]
+    return root
+
+
+def _nothing_temporary(c, rebuilder_dir, own):
+    """No `.cpy` on any server; on the rebuilder, of each volume, its own
+    shards and what was rebuilt there, never a survivor's copy."""
+    assert not [n for d in c.dirs for n in os.listdir(d) if n.endswith(".cpy")]
+    for vid, (mine, lost) in own.items():
+        assert set(stripe.find_local_shards(os.path.join(rebuilder_dir, str(vid)))) - lost == mine, vid
+
+
+def _lost_server_setup(c, device=1, lost=3):
+    """Encode, spread, lose server `lost`. -> (the rebuilder's directory,
+    {volume: (the rebuilder's own shards, the lost ones)})."""
+    c.encode_and_spread()
+    spread = {vid: c.held(vid) for vid in c.vids}
+    lost_url, device_url = c.servers[lost].url, c.servers[device].url
+    c.lose(lost)
+    return c.dirs[device], {vid: (spread[vid][device_url], spread[vid][lost_url]) for vid in c.vids}
+
+
+def test_two_volumes_the_second_is_gathered_beside_the_first_rebuild(make_cluster, monkeypatch):
+    """Both volumes need copies: volume 2's `VolumeEcShardsCopy` calls start
+    before volume 1's `VolumeEcShardsRebuild` ends, and after volume 1's own
+    copies have all landed; the shell and the rebuilder both say so."""
+    c = make_cluster(["host", "jax", "host", "host"])
+    rebuilder_dir, own = _lost_server_setup(c)
+    _slow_rebuilds(monkeypatch)
+    rec = Recorded(c.env)
+    monkeypatch.setattr(c.env, "vs_call", rec)
+    beside0 = stats.EcCopyBesideRebuild.value
+    trace.RING.clear()
+
+    out = c.shell("lock; ec.rebuild; unlock")
+
+    assert _rebuilders(out) == {vid: c.servers[1].url for vid in VIDS}, out
+    assert out.endswith("ec.rebuild: 2 volumes with copies, 1 gathered beside a rebuild\ncluster unlocked\n"), out
+    assert _shell_root()["attrs"]["overlapped"] == 1
+    (r1_start, r1_end, _), (r2_start, _, _) = rec.of("VolumeEcShardsRebuild")
+    assert [v for _, _, v in rec.of("VolumeEcShardsRebuild")] == [1, 2]
+    copies1, copies2 = rec.of("VolumeEcShardsCopy", 1), rec.of("VolumeEcShardsCopy", 2)
+    assert len(copies1) >= 2 and len(copies2) >= 2
+    assert max(end for _, end, _ in copies1) <= min(start for start, _, _ in copies2)  # one gather at a time
+    assert max(end for _, end, _ in copies1) <= r1_start
+    assert max(start for start, _, _ in copies2) < r1_end, "volume 2 was gathered after volume 1's rebuild"
+    assert max(end for _, end, _ in copies2) <= r2_start
+    assert stats.EcCopyBesideRebuild.value - beside0 == len(copies2)
+    assert "\nweedtpu_ec_copy_beside_rebuild_total " in stats.REGISTRY.expose()
+    for vid, (_, lost) in own.items():
+        for s in lost:
+            with open(stripe.shard_file_name(os.path.join(rebuilder_dir, str(vid)), s), "rb") as f:
+                assert f.read() == c.reference[vid][s], f"volume {vid} shard {s} differs from the reference"
+        assert sorted(c.master.topology.lookup_ec_shards(vid)) == list(range(14))
+    _nothing_temporary(c, rebuilder_dir, own)
+
+
+def test_four_volumes_never_more_than_two_volumes_copies_on_the_rebuilder(make_cluster, monkeypatch):
+    """Four volumes that need copies: whenever the shell sends or ends an RPC,
+    at most two volumes have temporary copies (or a `.cpy`) on the rebuilder,
+    and at some moment two have; the rebuilds run in plan order, one at a
+    time, each after all its volume's copies; a volume's gather starts only
+    once the volume two before it has been dropped."""
+    vids = (1, 2, 3, 4)
+    c = make_cluster(["host", "jax", "host", "host"], vids)
+    rebuilder_dir, own = _lost_server_setup(c)
+    _slow_rebuilds(monkeypatch, 0.1)
+    seen = []
+
+    def watch():
+        names = os.listdir(rebuilder_dir)
+        holding = set()
+        for vid, (mine, lost) in own.items():
+            there = {int(n[-2:]) for n in names if re.fullmatch(rf"{vid}\.ec\d\d", n)}
+            if there - mine - lost or any(n.startswith(f"{vid}.") and n.endswith(".cpy") for n in names):
+                holding.add(vid)
+        seen.append(holding)
+
+    rec = Recorded(c.env, watch=watch)
+    monkeypatch.setattr(c.env, "vs_call", rec)
+    trace.RING.clear()
+
+    out = c.shell("lock; ec.rebuild; unlock")
+
+    assert _rebuilders(out) == {vid: c.servers[1].url for vid in vids}, out
+    assert out.endswith("ec.rebuild: 4 volumes with copies, 3 gathered beside a rebuild\ncluster unlocked\n"), out
+    assert _shell_root()["attrs"]["overlapped"] == 3
+    assert max(len(h) for h in seen) == 2, sorted(map(sorted, seen))
+    assert all(max(h) - min(h) <= 1 for h in seen if h)  # and only ever neighbours of the plan
+    rebuilds = rec.of("VolumeEcShardsRebuild")
+    assert [v for _, _, v in rebuilds] == list(vids)
+    assert all(a[1] <= b[0] for a, b in zip(rebuilds, rebuilds[1:])), "two rebuilds at once"
+    drops = {v: (a, b) for a, b, v in rec.of("VolumeEcShardsDelete")}
+    for start, end, vid in rebuilds:
+        copies = rec.of("VolumeEcShardsCopy", vid)
+        assert max(e for _, e, _ in copies) <= start and end <= drops[vid][0]
+        if vid + 1 in own:  # the next volume's gather starts beside this rebuild, never earlier
+            first = min(s for s, _, _ in rec.of("VolumeEcShardsCopy", vid + 1))
+            assert max(e for _, e, _ in copies) <= first < end
+        if vid - 2 in own:
+            assert drops[vid - 2][1] <= min(s for s, _, _ in copies)
+    for vid, (_, lost) in own.items():
+        for s in lost:
+            with open(stripe.shard_file_name(os.path.join(rebuilder_dir, str(vid)), s), "rb") as f:
+                assert f.read() == c.reference[vid][s]
+        assert sorted(c.master.topology.lookup_ec_shards(vid)) == list(range(14))
+    _nothing_temporary(c, rebuilder_dir, own)
+
+
+@pytest.mark.parametrize("case", ["rebuild_fails_with_the_next_gather_in_flight", "a_pull_fails_half_way"])
+def test_a_failure_in_the_pipeline_leaves_nothing_temporary(make_cluster, monkeypatch, case):
+    """Volume 1's rebuild fails while volume 2's gather is in flight: the
+    gather is awaited and what it landed is dropped before the error, which
+    names volume 1, goes up; volume 2 is as it was. Or one pull of volume 2's
+    gather fails after its first file landed: volume 1 completes and is
+    dropped, then the error names volume 2, whose landed copies are gone."""
+    c = make_cluster(["host", "jax", "host", "host"])
+    rebuilder_dir, own = _lost_server_setup(c)
+    _slow_rebuilds(monkeypatch)
+    if case == "rebuild_fails_with_the_next_gather_in_flight":
+        def before(method, vid):
+            if method == "VolumeEcShardsCopy" and vid == 2:
+                time.sleep(0.3)  # still on its way when the rebuild has failed
+            if method == "VolumeEcShardsRebuild" and vid == 1:
+                time.sleep(0.05)
+                raise RuntimeError("the rebuilder refused")
+        failed, whole = 1, 2
+    else:
+        before = None
+        pulls = []
+        real = VolumeServer._pull_ec_file
+
+        def pull(client, vid, collection, base, ext):
+            if vid == 2:
+                pulls.append(ext)
+                if len(pulls) == 2:
+                    raise OSError("the stream broke")
+            return real(client, vid, collection, base, ext)
+
+        monkeypatch.setattr(VolumeServer, "_pull_ec_file", staticmethod(pull))
+        failed, whole = 2, 1
+    rec = Recorded(c.env, before=before)
+    monkeypatch.setattr(c.env, "vs_call", rec)
+    trace.RING.clear()
+    out = io.StringIO()
+
+    with pytest.raises(ShellError, match=rf"volume {failed}\b") as err:
+        run_script(c.env, "lock; ec.rebuild", out)
+    c.shell("unlock")
+
+    out = out.getvalue()
+    assert f"volume {whole}" not in str(err.value)
+    assert out.endswith("ec.rebuild: 2 volumes with copies, 1 gathered beside a rebuild\n"), out
+    assert _shell_root()["attrs"]["overlapped"] == 1
+    # every copy asked for was dropped after it had ended, landed or not
+    for vid in VIDS:
+        copies, (drop,) = rec.of("VolumeEcShardsCopy", vid), rec.of("VolumeEcShardsDelete", vid)
+        assert max(e for _, e, _ in copies) <= drop[0]
+    _nothing_temporary(c, rebuilder_dir, own)
+    assert not set(stripe.find_local_shards(os.path.join(rebuilder_dir, str(failed)))) & own[failed][1]
+    assert _rebuilders(out) == ({} if failed == 1 else {1: c.servers[1].url})
+    if whole == 1:  # rebuilt, byte for byte, and listed
+        for s in own[1][1]:
+            with open(stripe.shard_file_name(os.path.join(rebuilder_dir, "1"), s), "rb") as f:
+                assert f.read() == c.reference[1][s]
+        assert sorted(c.master.topology.lookup_ec_shards(1)) == list(range(14))
+    else:  # as it was: its survivors listed where they were, the rebuilder serving its own
+        assert sorted(c.master.topology.lookup_ec_shards(2)) == sorted(set(range(14)) - own[2][1])
+        assert c.held(2)[c.servers[1].url] == own[2][0]
+    for vid in VIDS:  # what is lost still decodes from what is there
+        for fid, payload in c.needles[vid][:6]:
+            assert c.client.read(fid) == payload
+    # the command can be given again, and then succeeds
+    monkeypatch.undo()
+    assert _rebuilders(c.shell("lock; ec.rebuild; unlock")) == (
+        {1: c.servers[1].url, 2: c.servers[1].url} if failed == 1 else {2: c.servers[1].url})
+    assert all(sorted(c.master.topology.lookup_ec_shards(v)) == list(range(14)) for v in VIDS)
+
+
+@pytest.mark.parametrize("case", ["a_lone_volume_with_copies", "all_local"])
+def test_the_routes_the_pipeline_leaves_alone(make_cluster, monkeypatch, case):
+    """A lone volume that needs copies is a pipeline of one: the RPCs of
+    before in the order of before (its copies, the single-volume rebuild, the
+    drop). Volumes whose survivors are all on their rebuilder still share ONE
+    `VolumeEcShardsRebuildBatch`, with nothing copied and nothing beside."""
+    c = make_cluster(["host", "jax", "host", "host"])
+    if case == "all_local":
+        for i in (1, 2, 3):  # only server 0 is there when the volumes are encoded
+            c.lose(i)
+        c.encode_and_spread()
+        assert all(c.held(v) == {c.servers[0].url: set(range(14))} for v in VIDS)
+        victim, lost = c.servers[0], {1: [0, 3, 11], 2: [5]}
+    else:
+        c.encode_and_spread()
+        victim = c.servers[3]
+        lost = {1: sorted(c.held(1)[victim.url])}
+    for vid, shards in lost.items():
+        c.env.vs_call(victim.grpc_address, "VolumeEcShardsDelete",
+                      {"volume_id": vid, "collection": "", "shard_ids": shards})
+    _wait_for(lambda: all(victim.url not in c.held(v).get(s, ()) and s not in c.master.topology.lookup_ec_shards(v)
+                          for v, ss in lost.items() for s in ss), msg="the master dropped the lost shards")
+    rec = Recorded(c.env)
+    monkeypatch.setattr(c.env, "vs_call", rec)
+    beside0 = stats.EcCopyBesideRebuild.value
+    trace.RING.clear()
+
+    out = c.shell("lock; ec.rebuild; unlock")
+
+    sent = [m for _, _, m, _, _ in sorted(rec.calls) if m != "VolumeStatus"]
+    if case == "all_local":
+        assert sent == ["VolumeEcShardsRebuildBatch"], sent
+        assert f"ec.rebuild batch on {victim.url}: 2 volumes in 2 signature groups\n" in out
+        assert out.endswith("ec.rebuild: 0 volumes with copies, 0 gathered beside a rebuild\ncluster unlocked\n"), out
+        rebuilder = 0
+    else:
+        copies = sent.count("VolumeEcShardsCopy")
+        assert copies >= 2 and sent == ["VolumeEcShardsCopy"] * copies + ["VolumeEcShardsRebuild", "VolumeEcShardsDelete"]
+        (rebuild,), (drop,) = rec.of("VolumeEcShardsRebuild"), rec.of("VolumeEcShardsDelete")
+        assert max(e for _, e, _ in rec.of("VolumeEcShardsCopy")) <= rebuild[0] and rebuild[1] <= drop[0]
+        assert out.endswith("ec.rebuild: 1 volumes with copies, 0 gathered beside a rebuild\ncluster unlocked\n"), out
+        rebuilder = [vs.url for vs in c.servers].index(_rebuilders(out)[1])  # wherever the rule puts it
+    assert _shell_root()["attrs"]["overlapped"] == 0
+    assert stats.EcCopyBesideRebuild.value == beside0
+    assert _rebuilders(out) == {vid: c.servers[rebuilder].url for vid in lost}, out
+    for vid, shards in lost.items():
+        for s in shards:
+            with open(stripe.shard_file_name(os.path.join(c.dirs[rebuilder], str(vid)), s), "rb") as f:
+                assert f.read() == c.reference[vid][s]
+        assert sorted(c.master.topology.lookup_ec_shards(vid)) == list(range(14))
+    assert not [n for d in c.dirs for n in os.listdir(d) if n.endswith(".cpy")]
+
+
+def test_the_rebuilds_in_flight_are_counted_under_many_callers(make_cluster):
+    """What `VolumeEcShardsCopy` reads to say it ran beside a rebuild: sixteen
+    threads of failing rebuilds (a volume nobody has) leave every one begun
+    and none in flight."""
+    c = make_cluster(["host"] * 4)
+    vs = c.servers[0]
+    begun0 = vs._ec_rebuilds[0]
+    failures = []
+
+    def hammer():
+        for _ in range(25):
+            try:
+                vs._rpc_ec_rebuild({"volume_id": 999}, None)
+            except Exception:  # noqa: BLE001 — no such volume: the count must still come down
+                failures.append(1)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=hammer) for _ in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert len(failures) == 16 * 25
+    assert vs._ec_rebuilds == (begun0 + 16 * 25, 0)
 
 
 # -- the rule itself ------------------------------------------------------------
