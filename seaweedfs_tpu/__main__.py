@@ -7,10 +7,15 @@ exit."""
 
 from __future__ import annotations
 
-import argparse
-import sys
+import time
 
-from seaweedfs_tpu.command import COMMAND_TABLE, commands, load_command
+_FIRST_LINE = time.monotonic()  # before any import of the package's own: `shell.start` reads it
+
+import argparse  # noqa: E402
+import sys  # noqa: E402
+
+from seaweedfs_tpu import command  # noqa: E402
+from seaweedfs_tpu.command import COMMAND_TABLE, commands, load_command  # noqa: E402
 
 
 def main(argv=None) -> int:
@@ -38,6 +43,8 @@ def main(argv=None) -> int:
     if not getattr(args, "_run", None):
         parser.print_help()
         return 2
+    if __name__ == "__main__":  # this process IS the command: its birth is the command's
+        command.STARTED = (_FIRST_LINE,)
     # process-wide TLS from security.toml [grpc]: activated before any
     # command binds a socket or dials a peer, so every server AND tool
     # (shell, upload, sync, ...) in this process speaks TLS uniformly

@@ -55,7 +55,7 @@ _METRIC_SUFFIX = re.compile(
 _SERIES_SUFFIXES = ("_count", "_sum", "_bucket")
 #: trace call spellings the package uses: module-qualified (any alias
 #: containing "trace") or the bare contextmanager name
-_SPAN_FNS = ("span", "start", "ensure", "continue_trace")
+_SPAN_FNS = ("span", "start", "ensure", "continue_trace", "record", "mark")
 
 
 def _parse_metric_decls(path: str):

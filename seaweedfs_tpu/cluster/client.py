@@ -196,6 +196,13 @@ class MasterClient:
             return resp
         raise ClusterError(f"no usable master ({tried}): {last_err}")
 
+    def call_current(self, method: str, req: dict, timeout: float) -> dict:
+        """ONE unary call to the master that answered last, on the channel
+        that is open: no failover, no redirect (a best-effort report)."""
+        return self._client_for(self._current).call(
+            MASTER_SERVICE, method, req, timeout=timeout
+        )
+
     def close(self) -> None:
         with self._lock:
             for c in self._clients.values():

@@ -322,7 +322,32 @@ class MasterServer:
         svc.add("VolumeGrow", self._rpc_volume_grow)
         svc.add("CollectionDelete", self._rpc_collection_delete)
         svc.add("RepairStatus", self._rpc_repair_status)
+        svc.add("ReportTrace", self._rpc_report_trace)
         return svc
+
+    def _rpc_report_trace(self, req: dict, ctx) -> dict:
+        """A `shell -c` child's finished `shell.script` trace, handed over as
+        it ends so that it outlives the child: offered to this master's ring
+        as any root of its own (`/debug/traces?kind=shell.script`, `ec.trace`),
+        folded into `weedtpu_shell_command_seconds`, and, where this process
+        mirrors its spans into the profiler, put there flat as ONE short
+        `shell.trace` annotation, on the wall clock the mirrored roots'
+        `unix_ns` ties to the profiler's."""
+        import json as _json
+
+        try:
+            if len(req["trace"]) > trace_mod.REPORT_MAX_BYTES:
+                raise ValueError(f"over {trace_mod.REPORT_MAX_BYTES} bytes")
+            trace = _json.loads(req["trace"])
+            kept = trace_mod.offer_received(trace)
+        except (KeyError, TypeError, ValueError) as e:
+            raise rpc.RpcFault(
+                f"ReportTrace: {e}", code=grpc.StatusCode.INVALID_ARGUMENT
+            ) from e
+        for command, phase, seconds in trace_mod.script_phases(trace):
+            stats.ShellCommandSeconds.labels(command, phase).observe(seconds)
+        trace_mod.mark("shell.trace", **trace_mod.flatten(trace))
+        return {"kept": kept}
 
     def _rpc_repair_status(self, req: dict, ctx) -> dict:
         """Fleet-repair view for `ec.status` and the chaos gates: queue
@@ -461,7 +486,12 @@ class MasterServer:
 
     def _rpc_list_cluster_nodes(self, req: dict, ctx) -> dict:
         now = time.monotonic()
-        out: dict[str, list] = {"filers": [], "brokers": []}
+        out: dict[str, list] = {"filers": [], "brokers": [], "masters": []}
+        if self.http_port:
+            host = self.address.rsplit(":", 1)[0]
+            out["masters"].append(
+                {"http_address": f"{host}:{self.http_port}", "grpc_address": self.address}
+            )
         with self._admin_lock_mu:
             for (node_type, url), (grpc_addr, seen) in getattr(
                 self, "_cluster_nodes", {}

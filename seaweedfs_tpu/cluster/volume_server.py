@@ -371,7 +371,10 @@ class VolumeServer:
     def heartbeat_once(self) -> None:
         if self._stop.is_set():  # left the cluster: never re-register
             return
-        self._masters_fanout("Heartbeat", self._make_heartbeat().to_dict(), timeout=10)
+        # under an RPC that waits for it (a mount, a deletion), a span of
+        # that RPC's trace; from the heartbeat loop, nothing
+        with trace_mod.span("vs.heartbeat"):
+            self._masters_fanout("Heartbeat", self._make_heartbeat().to_dict(), timeout=10)
 
     def _master_query(self, method: str, req: dict, timeout: float = 5.0) -> dict:
         """Read query against any reachable master (soft state is on all)."""
@@ -1083,12 +1086,13 @@ class VolumeServer:
         return {}
 
     def _rpc_volume_delete(self, req: dict, ctx) -> dict:
-        if self._ingest is not None:  # partial stripe state dies with the .dat
-            v = self.store.get_volume(int(req["volume_id"]))
-            self._ingest.discard(
-                int(req["volume_id"]), v.base_path if v is not None else None
-            )
-        self.store.remove_volume(int(req["volume_id"]))
+        with trace_mod.span("volume.remove", volume=int(req["volume_id"])):
+            if self._ingest is not None:  # partial stripe state dies with the .dat
+                v = self.store.get_volume(int(req["volume_id"]))
+                self._ingest.discard(
+                    int(req["volume_id"]), v.base_path if v is not None else None
+                )
+            self.store.remove_volume(int(req["volume_id"]))
         self.heartbeat_once()  # push the deletion to the master now
         return {}
 
@@ -1524,7 +1528,8 @@ class VolumeServer:
             e = res["errors"].get(base)
             if e is None:
                 try:
-                    stripe.write_sorted_file_from_idx(base)
+                    with trace_mod.span("ec.ecx", volume=vid):
+                        stripe.write_sorted_file_from_idx(base)
                 except Exception as e2:  # noqa: BLE001 — this volume's alone
                     e = e2
             if e is not None:
@@ -1559,7 +1564,8 @@ class VolumeServer:
                 # spreads from here): discard any pre-spread partials so
                 # its allocation starts from the full local set
                 self._finalize_spread(vid, v.base_path, "shell")
-                stripe.write_sorted_file_from_idx(v.base_path)
+                with trace_mod.span("ec.ecx", volume=vid):
+                    stripe.write_sorted_file_from_idx(v.base_path)
                 stats.EcEncodeBytes.inc(os.path.getsize(v.base_path + ".dat"))
             else:
                 errors, _ = self._encode_warm({vid: v.base_path}, kwargs)
@@ -1946,7 +1952,7 @@ class VolumeServer:
         base = self._base_path_for(vid, collection)
         t0 = time.monotonic()
         with self._ec_rebuild_in_flight(), trace_mod.ensure("rebuild.run", klass="maint"):
-            trace_mod.annotate(volume=vid, remote=bool(req.get("remote")))
+            trace_mod.annotate(volume=vid)
             if not req.get("remote"):
                 rebuilt = stripe.rebuild_ec_files(
                     base, encoder=stripe.encoder_for_base(base, self.store.encoder)
@@ -2068,7 +2074,6 @@ class VolumeServer:
                             for g in groups:
                                 g.close()
                         stats.EcRepairNetworkBytes.labels("trace").inc(wire)
-                        stats.EcRebuildRemoteBytes.inc(wire)
                         resp.update(
                             rebuilt_shard_ids=rebuilt,
                             wire_bytes=wire,
@@ -2088,7 +2093,6 @@ class VolumeServer:
                             stats.EcRepairNetworkBytes.labels("trace").inc(
                                 trace_wasted
                             )
-                            stats.EcRebuildRemoteBytes.inc(trace_wasted)
             # full-slab path: the capability/chaos fallback and the
             # trace_mode=off shape — striped RemoteSlabSource per survivor.
             # fetch workers are RTT/IO-bound (they sleep on peer streams),
@@ -2130,7 +2134,6 @@ class VolumeServer:
                 executor.shutdown(wait=False, cancel_futures=True)
             if wire:
                 stats.EcRepairNetworkBytes.labels("slab").inc(wire)
-                stats.EcRebuildRemoteBytes.inc(wire)
             failed_over = [
                 f"{src.shard_id}:{addr}"
                 for src in sources.values()
@@ -2278,12 +2281,13 @@ class VolumeServer:
             err = res["errors"].get(base, "")
             if rebuilt and not err:
                 try:
-                    ev = self.store.get_ec_volume(m["vid"])
-                    if ev is not None:
-                        for s in rebuilt:
-                            ev.mount_local_shard(s)
-                    else:
-                        self.store.mount_ec_volume(m["vid"], base)
+                    with trace_mod.span("ec.mount", volume=m["vid"]):
+                        ev = self.store.get_ec_volume(m["vid"])
+                        if ev is not None:
+                            for s in rebuilt:
+                                ev.mount_local_shard(s)
+                        else:
+                            self.store.mount_ec_volume(m["vid"], base)
                 except Exception as e:  # noqa: BLE001 — rebuilt but dark
                     err = f"mount failed: {e}"[:300]
             if rebuilt:
@@ -2304,7 +2308,6 @@ class VolumeServer:
             )
         if total_wire:
             stats.EcRepairNetworkBytes.labels("slab").inc(total_wire)
-            stats.EcRebuildRemoteBytes.inc(total_wire)
         stats.EcRebuildSeconds.observe(time.monotonic() - t0)
         try:
             self.heartbeat_once()  # rebuilt shards are holders NOW
@@ -2729,10 +2732,11 @@ class VolumeServer:
 
     def _rpc_ec_mount(self, req: dict, ctx) -> dict:
         vid = int(req["volume_id"])
-        base = self._base_path_for(vid, req.get("collection", ""))
-        if not stripe.find_local_shards(base):
-            raise rpc.NotFoundFault(f"no local shards for volume {vid}")
-        self.store.mount_ec_volume(vid, base)
+        with trace_mod.span("ec.mount", volume=vid):
+            base = self._base_path_for(vid, req.get("collection", ""))
+            if not stripe.find_local_shards(base):
+                raise rpc.NotFoundFault(f"no local shards for volume {vid}")
+            self.store.mount_ec_volume(vid, base)
         self.heartbeat_once()  # push the shard delta to the master now
         return {}
 
@@ -2942,20 +2946,23 @@ class VolumeServer:
     def _rpc_ec_delete(self, req: dict, ctx) -> dict:
         vid = int(req["volume_id"])
         shard_ids = [int(s) for s in req.get("shard_ids", [])]
-        base = self._base_path_for(vid, req.get("collection", ""))
-        self.store.unmount_ec_volume(vid)
-        for s in shard_ids or stripe.find_local_shards(base):
-            p = stripe.shard_file_name(base, s)
-            if os.path.exists(p):
-                os.remove(p)
-            if os.path.exists(p + ".bad"):  # quarantined original, kept
-                os.remove(p + ".bad")       # for forensics until deletion
-        if not stripe.find_local_shards(base):
-            for ext in _EC_EXTS:
-                if os.path.exists(base + ext):
-                    os.remove(base + ext)
-        elif stripe.find_local_shards(base):
-            self.store.mount_ec_volume(vid, base)
+        with trace_mod.span("ec.unmount", volume=vid, shards=len(shard_ids)):
+            base = self._base_path_for(vid, req.get("collection", ""))
+            self.store.unmount_ec_volume(vid)
+            for s in shard_ids or stripe.find_local_shards(base):
+                p = stripe.shard_file_name(base, s)
+                if os.path.exists(p):
+                    os.remove(p)
+                if os.path.exists(p + ".bad"):  # quarantined original, kept
+                    os.remove(p + ".bad")       # for forensics until deletion
+            left = stripe.find_local_shards(base)
+            if not left:
+                for ext in _EC_EXTS:
+                    if os.path.exists(base + ext):
+                        os.remove(base + ext)
+        if left:
+            with trace_mod.span("ec.mount", volume=vid):
+                self.store.mount_ec_volume(vid, base)
         self.heartbeat_once()
         return {}
 

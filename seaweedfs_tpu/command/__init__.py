@@ -20,6 +20,11 @@ class Command:
 
 _REGISTRY: dict[str, Command] = {}
 
+#: `time.monotonic()` at the first line of `__main__`, set by `__main__` in a
+#: process that was started AS the command (`python -m seaweedfs_tpu ...`) and
+#: nowhere else: where the shell's `shell.start` span takes its marks from
+STARTED: tuple = ()
+
 # Every command: name -> (module under this package that registers it, its
 # one-line help), in the order `-h` lists them. A table, so that a process
 # imports the module of the command its command line names and not its

@@ -505,11 +505,19 @@ def _shell_conf(p: argparse.ArgumentParser) -> None:
 
 
 def _shell_run(args: argparse.Namespace) -> int:
+    import time
+
+    from seaweedfs_tpu import command
+    from seaweedfs_tpu.obs import trace
     from seaweedfs_tpu.shell import CommandEnv, repl, run_script
 
+    imported = time.monotonic()  # grpc and the shell's own modules are loaded (the TLS configuration began it)
     with CommandEnv(args.master) as env:
         if args.script:
-            run_script(env, args.script, sys.stdout)
+            started = command.STARTED
+            if started:  # a `-c` child: its script's trace begins at its birth
+                started = (trace.process_birth(started[0]), started[0], imported, time.monotonic())
+            run_script(env, args.script, sys.stdout, started=started)
         else:
             repl(env, sys.stdin, sys.stdout)
     return 0

@@ -1600,7 +1600,7 @@ def _run_rebuild(
                     if len(failed) == len(groups):
                         break
                     issue_prefetch(bi + ahead)  # network runs ahead of reads
-                    with trace_mod.span("rebuild.stage", batch=bi, width=batch.width):
+                    with trace_mod.span("rebuild.stage", width=batch.width):
                         staging = ring.take()  # free already: before the drain below
                         reads = _LaneBatch()
                         fills = [(seg.group, f) for seg in batch.segs for f in seg.fills]

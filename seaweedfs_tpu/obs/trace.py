@@ -24,7 +24,9 @@ Design constraints, in order:
    METADATA, so the pinned proto contracts are untouched) and the
    `X-Weedtpu-Trace` HTTP header, and come back on the response so a
    client can grep every process's glog lines / trace rings for one
-   slow request.
+   slow request. A `shell -c` script is one id from the birth of its
+   process to its last command (`shell.script`); its tree is handed to
+   the master when it ends (`ReportTrace`), so it outlives the child.
 
 Span names are a closed catalog (`SPAN_NAMES`): weedlint's obs-drift
 family asserts every `span("...")` call site in the package names a
@@ -54,7 +56,12 @@ SPAN_NAMES: dict[str, str] = {
     "http.read": "volume-server HTTP GET of one needle (the serving path)",
     "http.write": "volume-server HTTP POST/PUT of one needle",
     "master.http": "master HTTP facade route (/dir/assign, /dir/lookup, ...)",
-    "shell.command": "one weed-shell command execution (command, modules loaded at its start, rpcs it made; ec.rebuild without -remote: overlapped= gathers that ran beside the rebuild of the volume before)",
+    "shell.script": "one `shell -c` script, from the BIRTH of its process to the end of its last command: the root every command of the script nests under (script= its text, cut to 200 characters); handed to the master by ReportTrace when it ends",
+    "shell.start": "first child of shell.script, birth of the process to the first command: interp_ms (birth to the first line of __main__), import_ms (from there until grpc and the shell's own modules are loaded: the command line parsed and security.toml read on the way), connect_ms (CommandEnv, the channel, until the script starts), modules=",
+    "shell.command": "one weed-shell command execution (command, modules loaded at its start, rpcs it made; ec.rebuild without -remote: overlapped= gathers that ran beside the rebuild of the volume before); a root in the REPL, a child of shell.script in a -c script",
+    "shell.plan": "ec.encode / ec.rebuild before their first state-changing RPC: selection, VolumeList, a VolumeStatus a volume, pick_rebuilder (volumes= planned, rpcs= made)",
+    "shell.trace": "the receipt of a ReportTrace on a server whose spans are mirrored into the profiler: one short annotation whose attributes carry the child's tree flat (names, what, t_ns from birth_unix_ns, dur_ns, depth, thread)",
+    "rpc.client": "the shell's side of one RPC through CommandEnv.master_call / vs_call (method=, target=; thread= where another thread than the command's made it); as many under a shell.command as its rpcs=",
     "rpc.server": "server side of one gRPC method (method name in attrs)",
     "ec.lookup": "master LookupEcVolume round-trip (shard-location cache miss)",
     "ec.recover": "degraded interval reconstruction, client-facing wall time",
@@ -98,6 +105,11 @@ SPAN_NAMES: dict[str, str] = {
     "convert.run": "one whole-volume geometry conversion",
     "convert.chunk": "one journaled chunk of a geometry conversion",
     "heal.verify": "verify-on-read culprit hunt after a body-CRC failure",
+    "ec.ecx": "one volume's sorted index (.ecx) written from its .idx after an encode (VolumeEcShardsGenerate, VolumeEcShardsGenerateBatch)",
+    "ec.mount": "an EC volume's local shards found and mounted (VolumeEcShardsMount, a rebuild batch's rebuilt shards, the remount of VolumeEcShardsDelete): files opened, the codec's small-read programs warmed",
+    "ec.unmount": "VolumeEcShardsDelete's unmount and unlinks (shards= named)",
+    "volume.remove": "VolumeDelete: the volume closed and its files unlinked",
+    "vs.heartbeat": "a full-state heartbeat sent NOW and awaited (heartbeat_once under an RPC: the master must know of a mount or a deletion before the RPC answers)",
 }
 
 _ID_RE = re.compile(r"^[0-9a-fA-F][0-9a-fA-F-]{0,63}$")
@@ -154,21 +166,28 @@ class _TraceState:
 
     __slots__ = ("trace_id", "kind", "klass", "wall0", "t0")
 
-    def __init__(self, trace_id: str, kind: str, klass: str):
+    def __init__(self, trace_id: str, kind: str, klass: str, t0: Optional[float] = None):
         self.trace_id = trace_id
         self.kind = kind
         self.klass = klass
         self.wall0 = time.time()
         self.t0 = time.monotonic()
+        if t0 is not None:
+            # a root that began before anyone could open it (a process's
+            # birth): both clocks go back by the same stretch
+            self.wall0 -= self.t0 - t0
+            self.t0 = t0
 
 
 class Span:
     __slots__ = ("name", "attrs", "t0", "dur", "children", "error", "trace")
 
-    def __init__(self, name: str, attrs: Optional[dict], trace: _TraceState):
+    def __init__(
+        self, name: str, attrs: Optional[dict], trace: _TraceState, t0: Optional[float] = None
+    ):
         self.name = name
         self.attrs = attrs
-        self.t0 = time.monotonic()
+        self.t0 = time.monotonic() if t0 is None else t0
         self.dur = 0.0
         self.children: Optional[list] = None
         self.error: Optional[str] = None
@@ -224,6 +243,9 @@ class _Completed:
             "kind": self.state.kind,
             "class": self.state.klass,
             "start": round(self.state.wall0, 3),
+            # the same instant, whole: what joins this root with another
+            # process's spans of the same id (ec.trace, command_reduce)
+            "unix_ns": int(self.state.wall0 * 1e9),
             "duration_s": round(self.dur, 6),
             "error": self.error,
             "root": self.root.to_dict(),
@@ -279,21 +301,29 @@ class TraceRing:
             key = (done.state.kind, done.state.klass)
             row = self._slowest.setdefault(key, [])
             if len(row) < self.slowest_n or done.dur > row[0].dur:
-                # insert sorted ascending; evict the least-slow
+                # insert sorted ascending; the least slow leaves the row for
+                # the sample gate, as if it had never been among the slowest
+                # (or the first few of a kind, fast ones too, would be lost
+                # the moment slower ones came)
                 bisect.insort(row, done, key=lambda c: c.dur)
-                if len(row) > self.slowest_n:
-                    del row[0]
                 kept = True
+                if len(row) > self.slowest_n:
+                    self._sample_in(row.pop(0))
             if not kept:
-                rate = self._sample_rate()
-                if rate >= 1.0 or self._rng.random() < rate:
-                    self._sampled.append(done)
-                    if len(self._sampled) > self.capacity:
-                        del self._sampled[0]
-                    kept = True
+                kept = self._sample_in(done)
             if kept:
                 self.kept += 1
         return kept
+
+    def _sample_in(self, done: _Completed) -> bool:
+        """Through the sample gate into the FIFO (the caller holds the lock)."""
+        rate = self._sample_rate()
+        if rate < 1.0 and self._rng.random() >= rate:
+            return False
+        self._sampled.append(done)
+        if len(self._sampled) > self.capacity:
+            del self._sampled[0]
+        return True
 
     def snapshot(
         self,
@@ -416,8 +446,13 @@ class _RootCtx:
 
     def __enter__(self) -> Span:
         if _mirror is not None:
-            self._mirrored = _mirror(self._state.kind, self._attrs)
-        root = Span(self._state.kind, self._attrs, self._state)
+            # unix_ns: any one mirrored root of a profiler session yields the
+            # offset between the profiler's clock and the wall clock
+            self._mirrored = _mirror(
+                self._state.kind,
+                {**(self._attrs or {}), "trace_id": self._state.trace_id, "unix_ns": time.time_ns()},
+            )
+        root = Span(self._state.kind, self._attrs, self._state, self._state.t0)
         self._root = root
         self._tok = _cv.set(root)
         return root
@@ -436,15 +471,25 @@ class _RootCtx:
         return False
 
 
-def start(kind: str, klass: str = "healthy", trace_id=None, ring: Optional[TraceRing] = None):
+def start(
+    kind: str,
+    klass: str = "healthy",
+    trace_id=None,
+    ring: Optional[TraceRing] = None,
+    t0: Optional[float] = None,
+    **attrs,
+):
     """Begin a root trace (the HTTP fronts, the shell, background
     maintenance). `trace_id` adopts a propagated id (sanitized); absent
-    or invalid ids mint a fresh one. Returns a context manager yielding
-    the root Span — or a no-op when tracing is off."""
+    or invalid ids mint a fresh one. `t0` back-dates the root to a
+    `time.monotonic()` reading of before (a `-c` script's root begins at
+    its process's birth); `attrs` are the root span's from its start.
+    Returns a context manager yielding the root Span — or a no-op when
+    tracing is off."""
     if not enabled():
         return _NULL
     tid = valid_id(trace_id) or new_trace_id()
-    return _RootCtx(_TraceState(tid, kind, klass), ring or RING)
+    return _RootCtx(_TraceState(tid, kind, klass, t0), ring or RING, attrs)
 
 
 def continue_trace(
@@ -475,6 +520,26 @@ def ensure(kind: str, klass: str = "maint"):
     if cur.name == kind:
         return attach(cur)
     return span(kind)
+
+
+def record(_name: str, t0: float, t1: float, **attrs) -> None:
+    """A span that is over already, `time.monotonic()` readings `t0` to
+    `t1`, as a child of the ambient span: what ran before anything could
+    open a span around it (`shell.start`)."""
+    parent = _cv.get()
+    if parent is not None:
+        sp = Span(_name, attrs or None, parent.trace, t0)
+        sp.dur = t1 - t0
+        parent.add_child(sp)
+
+
+def mark(_name: str, **attrs) -> bool:
+    """One short event in the profiler mirror alone, where one is installed
+    (no span, no ring): `attrs` are what it is there to carry."""
+    if _mirror is None or not enabled():
+        return False
+    _mirror(_name, attrs).__exit__(None, None, None)
+    return True
 
 
 def current() -> Optional[Span]:
@@ -527,6 +592,191 @@ class attach:  # noqa: N801 — `with attach(parent):` in worker threads
         return False
 
 
+# -- a trace that outlives its process (the shell child's, ReportTrace) --------
+
+#: spans a handed-over tree may hold; the deepest are cut first
+REPORT_MAX_SPANS = 2000
+#: bytes its JSON may have (2,000 spans are some 0.4 MB): wire input, kept in a ring
+REPORT_MAX_BYTES = 1 << 20
+#: what a command's name looks like: it becomes a label of a metric family
+_COMMAND_RE = re.compile(r"^[A-Za-z][\w.]{0,47}$")
+
+
+def process_birth(fallback: float) -> float:
+    """When this process was born, as a `time.monotonic()` reading, from the
+    kernel: the start time of `/proc/self/stat` (clock ticks after boot:
+    10 ms steps, rounded down) against CLOCK_BOOTTIME. Where `/proc` is
+    missing: `fallback` (the first line of `__main__`)."""
+    mono = time.monotonic()
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            # the fields after the command's name, which may hold spaces
+            ticks = int(f.read().rsplit(b")", 1)[1].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - ticks / os.sysconf("SC_CLK_TCK")
+        return min(fallback, mono - age)
+    except (OSError, ValueError, IndexError, AttributeError):
+        return fallback
+
+
+def _depths(root: dict) -> Iterator[tuple[dict, int]]:
+    stack = [(root, 0)]
+    while stack:
+        sp, depth = stack.pop()
+        yield sp, depth
+        stack.extend((c, depth + 1) for c in reversed(sp.get("spans", ())))
+
+
+def cap_spans(root: dict, limit: int = REPORT_MAX_SPANS) -> int:
+    """Cut a serialized tree to `limit` spans in place, the deepest first
+    (a level goes whole or not at all, but for the one that straddles the
+    limit, which keeps its earliest); -> how many were cut."""
+    by_depth: dict[int, int] = {}
+    for _, depth in _depths(root):
+        by_depth[depth] = by_depth.get(depth, 0) + 1
+    total = sum(by_depth.values())
+    if total <= limit:
+        return 0
+    kept, room = 0, {}
+    for depth in sorted(by_depth):
+        room[depth] = max(0, min(by_depth[depth], limit - kept))
+        kept += room[depth]
+    level, depth = [root], 0
+    while level:
+        below = []
+        for sp in level:
+            children = sp.get("spans")
+            if not children:
+                continue
+            take = min(len(children), room.get(depth + 1, 0))
+            room[depth + 1] = room.get(depth + 1, 0) - take
+            if take:
+                sp["spans"] = children[:take]
+                below.extend(sp["spans"])
+            else:
+                del sp["spans"]
+        level, depth = below, depth + 1
+    return total - kept
+
+
+def flatten(trace: dict) -> dict:
+    """A serialized trace as parallel lists, pre-order, for a profiler
+    annotation's attributes (`shell.trace`): `names`, `what` (the attribute
+    that tells one span of a name from another: an RPC's method, a command's
+    name), `t_ns` after the root's start, `dur_ns`, `depth`, `thread` (0: the
+    thread that ran the commands; n: the n-th other thread seen, from the
+    spans' `thread=`, inherited by what nests under them), each joined into
+    one string, beside `trace_id`, `birth_unix_ns` and `start_ms` (what
+    `shell.start` says of itself: interpreter, imports, connect)."""
+    names, what, t_ns, dur_ns, depth_of, thread_of = [], [], [], [], [], []
+    start_ms = ""
+    threads: dict[str, int] = {}
+    stack = [(trace["root"], 0, 0)]
+    while stack:
+        sp, depth, thread = stack.pop()
+        attrs = sp.get("attrs") or {}
+        if "thread" in attrs:
+            thread = threads.setdefault(str(attrs["thread"]), len(threads) + 1)
+        if sp["name"] == "shell.start":
+            start_ms = ";".join(str(attrs.get(k, 0)) for k in ("interp_ms", "import_ms", "connect_ms"))
+        names.append(sp["name"])
+        what.append(str(attrs.get("method") or attrs.get("command") or ""))
+        t_ns.append(int(round(sp["t_ms"] * 1e6)))
+        dur_ns.append(int(round(sp["dur_ms"] * 1e6)))
+        depth_of.append(depth)
+        thread_of.append(thread)
+        stack.extend((c, depth + 1, thread) for c in reversed(sp.get("spans", ())))
+    join = lambda xs: ";".join(map(str, xs))  # noqa: E731
+    return {
+        "trace_id": trace["trace_id"],
+        "birth_unix_ns": int(trace.get("birth_unix_ns") or trace.get("unix_ns") or 0),
+        "names": join(names), "what": join(what), "t_ns": join(t_ns),
+        "dur_ns": join(dur_ns), "depth": join(depth_of), "thread": join(thread_of),
+        "start_ms": start_ms,
+    }
+
+
+class _Received:
+    """A finished trace another process handed over (`ReportTrace`), held in
+    the ring as its own are: the serialized form is all there is of it."""
+
+    __slots__ = ("state", "dur", "error", "_dict")
+
+    def __init__(self, trace: dict):
+        self.state = _TraceState(trace["trace_id"], trace["kind"], trace["class"])
+        self.dur = float(trace["duration_s"])
+        self.error = trace.get("error")
+        self._dict = trace
+
+    def to_dict(self) -> dict:
+        return self._dict
+
+
+def offer_received(trace: dict, ring: Optional[TraceRing] = None) -> bool:
+    """Offer a handed-over trace (`_Completed.to_dict()`'s shape, checked
+    and cut to REPORT_MAX_SPANS here: wire input) to this process's ring,
+    as any root of its own. Raises ValueError on a malformed one."""
+    try:
+        tid = valid_id(trace["trace_id"])
+        kind, root = trace["kind"], trace["root"]
+        ok = (
+            tid is not None and kind in SPAN_NAMES and isinstance(trace["class"], str)
+            and isinstance(root, dict) and root["name"] == kind
+            and float(trace["duration_s"]) >= 0 and float(root["dur_ms"]) >= 0
+        )
+    except (KeyError, TypeError, ValueError):
+        ok = False
+    if not ok:
+        raise ValueError("not a serialized trace")
+    trace["trace_id"] = tid
+    cap_spans(root)
+    return (ring or RING).offer(_Received(trace))
+
+
+def script_phases(trace: dict) -> list[tuple[str, str, float]]:
+    """A `shell.script` trace as (command, phase, seconds) rows, what the
+    master folds into `weedtpu_shell_command_seconds`: per `shell.command`
+    its `plan` (`shell.plan`), its `rpc` (the union of the `rpc.client`
+    spans its own thread made outside the plan: what the command WAITED
+    for, not what ran beside it) and its `other` (the rest of its wall);
+    and the script's `start` (`shell.start`), under the first command,
+    the one that waited for it."""
+    rows: list[tuple[str, str, float]] = []
+    start_ms = sum(c["dur_ms"] for c in trace["root"].get("spans", ()) if c["name"] == "shell.start")
+    for cmd in trace["root"].get("spans", ()):
+        if cmd["name"] != "shell.command":
+            continue
+        name = str((cmd.get("attrs") or {}).get("command", ""))
+        if not _COMMAND_RE.match(name):
+            name = "other"  # wire input: never a label of its own
+        if start_ms:
+            rows.append((name, "start", start_ms / 1e3))
+            start_ms = 0.0
+        plan_ms, waited = 0.0, []
+        stack = list(cmd.get("spans", ()))
+        while stack:
+            sp = stack.pop()
+            if sp["name"] == "shell.plan":
+                plan_ms += sp["dur_ms"]
+            elif "thread" in (sp.get("attrs") or {}):
+                pass  # another thread's, with all under it
+            elif sp["name"] == "rpc.client":
+                waited.append((sp["t_ms"], sp["t_ms"] + sp["dur_ms"]))
+            else:
+                stack.extend(sp.get("spans", ()))
+        rpc_ms, end = 0.0, None
+        for a, b in sorted(waited):
+            if end is None or a > end:
+                rpc_ms, end = rpc_ms + (b - a), b
+            elif b > end:
+                rpc_ms, end = rpc_ms + (b - end), b
+        rows += [
+            (name, "plan", plan_ms / 1e3),
+            (name, "rpc", rpc_ms / 1e3),
+            (name, "other", max(0.0, cmd["dur_ms"] - plan_ms - rpc_ms) / 1e3),
+        ]
+    return rows
+
+
 # -- the /debug/traces surface -------------------------------------------------
 
 
@@ -565,7 +815,17 @@ def debug_payload(request_path: str, ring: Optional[TraceRing] = None) -> dict:
 # -- rendering (ec.trace / tests) ---------------------------------------------
 
 
-def render_trace(trace: dict) -> str:
+#: how far outside its `rpc.client` span a served root may begin and still be
+#: its answer: two processes' wall clocks, each read beside a monotonic one
+_JOIN_SLACK_NS = 2_000_000
+
+
+def identity(trace: dict) -> tuple:
+    """What tells one retained trace from another, wherever it was fetched."""
+    return trace["trace_id"], trace["kind"], trace.get("unix_ns"), trace["start"], trace["duration_s"]
+
+
+def render_trace(trace: dict, served: Optional[list] = None) -> str:
     """Human span tree with wall times — the `ec.trace` output format.
 
     trace=4f1d... http.read class=degraded 812.4ms
@@ -574,13 +834,37 @@ def render_trace(trace: dict) -> str:
       ...
 
     The root's own attributes (an RPC's method, the shell's command)
-    follow its class on the first line."""
+    follow its class on the first line. `served`: (server, trace) pairs of
+    the same id from the servers' rings; each `rpc.server` root among them
+    is printed under the `rpc.client` span that sent it (the same method,
+    begun inside the client's span by the wall clock, the target's own ring
+    first), marked `@ <server>`, its times after its own start, and is
+    taken out of the list: what is left there found no caller."""
     root_attrs = "".join(f" {k}={v}" for k, v in (trace["root"].get("attrs") or {}).items())
     lines = [
         f"trace={trace['trace_id']} {trace['kind']} "
         f"class={trace['class']}{root_attrs} {trace['duration_s'] * 1e3:.1f}ms"
         + (f" ERROR={trace['error']}" if trace.get("error") else "")
     ]
+
+    def answer(sp: dict):
+        """The served root that `sp`, an rpc.client span, waited for."""
+        attrs = sp.get("attrs") or {}
+        t0 = trace.get("unix_ns", trace["start"] * 1e9) + sp["t_ms"] * 1e6
+        t1 = t0 + sp["dur_ms"] * 1e6
+        found = [
+            (server != attrs.get("target"), t["unix_ns"], i)
+            for i, (server, t) in enumerate(served)
+            if t["kind"] == "rpc.server" and t["trace_id"] == trace["trace_id"]
+            and (t["root"].get("attrs") or {}).get("method") == attrs.get("method")
+            and t0 - _JOIN_SLACK_NS <= t.get("unix_ns", t["start"] * 1e9) <= t1 + _JOIN_SLACK_NS
+        ]
+        if not found:
+            return None
+        got = served[min(found)[2]]
+        # servers of one process (tests, `server`) share a ring: one root, once
+        served[:] = [pair for pair in served if identity(pair[1]) != identity(got[1])]
+        return got
 
     def walk(sp: dict, depth: int) -> None:
         attrs = " ".join(f"{k}={v}" for k, v in (sp.get("attrs") or {}).items())
@@ -589,6 +873,16 @@ def render_trace(trace: dict) -> str:
             f"{'|  ' * depth}+- {sp['t_ms']:8.1f}ms {sp['dur_ms']:9.1f}ms "
             f"{sp['name']}" + (f" {attrs}" if attrs else "") + err
         )
+        if served and sp["name"] == "rpc.client":
+            got = answer(sp)
+            if got is not None:
+                server, t = got
+                lines.append(
+                    f"{'|  ' * (depth + 1)}@ {server} rpc.server {t['duration_s'] * 1e3:.1f}ms"
+                    + (f" ERROR={t['error']}" if t.get("error") else "")
+                )
+                for c in t["root"].get("spans", ()):
+                    walk(c, depth + 2)
         for c in sp.get("spans", ()):
             walk(c, depth + 1)
 

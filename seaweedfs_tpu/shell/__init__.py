@@ -12,9 +12,11 @@ Each command is a `ShellCommand(name, help, do)` where
 
 from __future__ import annotations
 
+import json
 import shlex
 import sys
 import threading
+import time
 from dataclasses import dataclass
 from typing import Callable, Iterable, Optional, TextIO
 
@@ -22,10 +24,14 @@ import grpc
 
 from seaweedfs_tpu import rpc
 from seaweedfs_tpu.cluster.client import MasterClient
-from seaweedfs_tpu.pb import MASTER_SERVICE, VOLUME_SERVICE
+from seaweedfs_tpu.obs import trace as _trace
+from seaweedfs_tpu.pb import VOLUME_SERVICE
 
 LOCK_NAME = "admin"
 _RENEW_INTERVAL = 10.0
+#: how long a `-c` script waits for the master to take its trace when it
+#: ends; past it the trace is lost and nothing else changes
+_HAND_OVER_TIMEOUT = 0.2
 
 
 class ShellError(Exception):
@@ -117,9 +123,14 @@ class CommandEnv:
         self.cwd = "/"  # fs.cd/fs.pwd REPL state; fs.* paths resolve against it
         self._lock_token = 0
         #: RPCs made through `master_call` and `vs_call` since the env was
-        #: made; `run_command` writes a command's share on its root span
+        #: made; `run_command` writes a command's share on its span, and
+        #: each is an `rpc.client` span under the span that queued it
         self.rpcs = 0
         self._rpcs_lock = threading.Lock()
+        #: the span a call from a thread of the env's own (the lock renewer)
+        #: goes under: the running command's, else the script's
+        self.trace_parent = None
+        self._thread = threading.get_ident()  # the one that runs the commands
         self._renew_stop: Optional[threading.Event] = None
         self._renew_thread: Optional[threading.Thread] = None
 
@@ -145,12 +156,28 @@ class CommandEnv:
     def master_call(self, method: str, req: dict, timeout: float = 30) -> dict:
         """Master RPC via MasterClient's single failover/redirect path
         (thread-safe: the lock renewer calls this concurrently)."""
-        self._count_rpc()
-        return self.client.master_call(method, req, timeout=timeout)
+        return self._rpc(
+            method, self.master_address,
+            lambda: self.client.master_call(method, req, timeout=timeout),
+        )
 
-    def _count_rpc(self) -> None:
+    def _rpc(self, method: str, target: str, call, **attrs):
+        """One RPC of a command, counted and timed: an `rpc.client` span
+        under the ambient span (a pool thread's: the span that queued it,
+        through `trace.attach`), `thread=` where another thread than the
+        command's makes it."""
         with self._rpcs_lock:  # a command's copies call from a pool
             self.rpcs += 1
+        attrs = {"method": method, "target": target, **attrs}
+        if threading.get_ident() != self._thread:
+            attrs["thread"] = threading.current_thread().name
+        with _trace.span("rpc.client", **attrs) as sp:
+            try:
+                return call()
+            except grpc.RpcError as e:
+                if sp is not None:
+                    sp.error = e.code().name
+                raise
 
     def resolve(self, path: str) -> str:
         """Resolve an fs.* path argument against the REPL's working
@@ -193,9 +220,13 @@ class CommandEnv:
         return out
 
     def vs_call(self, grpc_address: str, method: str, req: dict, timeout: float = 300) -> dict:
-        self._count_rpc()
-        with rpc.RpcClient(grpc_address) as c:
-            return c.call(VOLUME_SERVICE, method, req, timeout=timeout)
+        def call():
+            with rpc.RpcClient(grpc_address) as c:
+                return c.call(VOLUME_SERVICE, method, req, timeout=timeout)
+
+        # volume=: which volume of a command's many a call belongs to
+        named = {"volume": req["volume_id"]} if "volume_id" in req else {}
+        return self._rpc(method, grpc_address, call, **named)
 
     # -- exclusive lock (SURVEY.md §3.1 "acquire cluster exclusive lock") ----
 
@@ -262,8 +293,10 @@ class CommandEnv:
     def _renew_loop(self) -> None:
         stop = self._renew_stop
         while not stop.wait(_RENEW_INTERVAL):
-            if not self._lock_token or not self._renew_once():
-                return
+            # under whatever runs now: the renewal carries the script's id
+            with _trace.attach(self.trace_parent):
+                if not self._lock_token or not self._renew_once():
+                    return
 
 
 # -- argument helpers (flag.FlagSet analog for `-name=value` style) ----------
@@ -345,32 +378,92 @@ def run_command(env: CommandEnv, line: str, writer: TextIO) -> None:
             for c in sorted(cmds):
                 writer.write(f"  {c:<28} {cmds[c].help.splitlines()[0]}\n")
         return
-    cmd = find_command(name)
-    if cmd is None:
-        raise ShellError(f"unknown command {name!r} (try `help`)")
-    # the shell is a trace ROOT: every RPC a command fans out carries
-    # this id in its metadata, so one ec.rebuild/ec.convert run can be
-    # reconstructed across every server it touched (ec.trace, glog grep)
-    from seaweedfs_tpu.obs import trace as _trace
-
-    with _trace.start("shell.command", klass="shell"):
+    # every RPC a command fans out carries its trace's id, so one
+    # ec.rebuild/ec.convert run can be reconstructed across every server it
+    # touched (ec.trace, glog grep): the script's where a `-c` script runs
+    # the command, else (the REPL, an in-process caller) a root of its own
+    with _trace.ensure("shell.command", klass="shell"):
+        _trace.annotate(command=name)
+        cmd = find_command(name)  # inside the span: a family's import is the command's time
+        if cmd is None:
+            raise ShellError(f"unknown command {name!r} (try `help`)")
         # modules: how much this process had loaded when the command began
         # its work (for a `-c` child's first command, what its start cost)
-        _trace.annotate(command=name, modules=len(sys.modules))
+        _trace.annotate(modules=len(sys.modules))
         before = getattr(env, "rpcs", 0)
+        outer = getattr(env, "trace_parent", None)
+        if env is not None:  # (`help` of a test runs without one)
+            env.trace_parent, env._thread = _trace.current(), threading.get_ident()
         try:
             cmd.do(args, env, writer)
         finally:
+            if env is not None:
+                env.trace_parent = outer
             # how many RPCs the command made (the lock renewer's, if one
             # fell inside it, included): a per-volume loop shows here
             _trace.annotate(rpcs=getattr(env, "rpcs", 0) - before)
 
 
-def run_script(env: CommandEnv, script: str, writer: TextIO) -> None:
-    """Run `;`-separated commands (the `weed shell -c` path)."""
-    for line in script.split(";"):
-        if line.strip():
-            run_command(env, line, writer)
+class _Outbox:
+    """Where a script's root lands when it ends, in the place of this
+    process's ring, which dies with a `-c` child: the master keeps it."""
+
+    done = None
+
+    def offer(self, done) -> bool:
+        self.done = done
+        return True
+
+
+def run_script(env: CommandEnv, script: str, writer: TextIO, started: tuple = ()) -> None:
+    """Run `;`-separated commands (the `weed shell -c` path) as ONE trace:
+    a `shell.script` root every command nests under, handed to the master
+    when the script ends, whatever its end was. `started`: the
+    `time.monotonic()` readings (birth of the process, first line of
+    `__main__`, the shell's modules imported, env connected) of a process
+    that IS the script; the root then begins at the birth and `shell.start`
+    is its first child."""
+    outbox = _Outbox()
+    try:
+        with _trace.start(
+            "shell.script", klass="shell", ring=outbox,
+            t0=started[0] if started else None, script=script[:200],
+        ) as root:
+            if root is not None and started:
+                born, main, imported, connected = started
+                _trace.record(
+                    "shell.start", born, time.monotonic(),
+                    interp_ms=round((main - born) * 1e3, 3),
+                    import_ms=round((imported - main) * 1e3, 3),
+                    connect_ms=round((connected - imported) * 1e3, 3),
+                    modules=len(sys.modules),
+                )
+            env.trace_parent = root
+            for line in script.split(";"):
+                if line.strip():
+                    run_command(env, line, writer)
+    finally:
+        env.trace_parent = None
+        if outbox.done is not None:
+            _hand_over(env, outbox.done)
+
+
+def _hand_over(env: CommandEnv, done) -> None:
+    """A finished script's tree to the master, in ONE call on the channel
+    that is open (`ReportTrace`; gRPC, not HTTP: a tool child never imports
+    `http.client`), so that it outlives this process. A master that does not
+    take it in time, or at all, costs the trace and nothing else: then this
+    process's ring has it, for as long as the process lives."""
+    trace = done.to_dict()
+    trace["birth_unix_ns"] = trace["unix_ns"]
+    _trace.cap_spans(trace["root"])
+    try:
+        env.client.call_current(
+            "ReportTrace", {"trace": json.dumps(trace, separators=(",", ":"))},
+            timeout=_HAND_OVER_TIMEOUT,
+        )
+    except Exception:  # noqa: BLE001 — the command's output and exit code are not this call's
+        _trace.RING.offer(done)
 
 
 def repl(env: CommandEnv, stdin, writer: TextIO) -> None:

@@ -13,6 +13,7 @@ from typing import Optional, TextIO
 
 from seaweedfs_tpu.ec.constants import DATA_SHARDS_COUNT, TOTAL_SHARDS_COUNT
 from seaweedfs_tpu.ec.shard_bits import ShardBits
+from seaweedfs_tpu.obs import trace as trace_obs
 from seaweedfs_tpu.shell import (
     CommandEnv,
     ShellCommand,
@@ -111,8 +112,6 @@ def _in_trace(fn):
     """`fn` for a pool thread, under the submitting thread's ambient span:
     ContextVars do not cross a pool's submission, and an RPC sent without
     the command's trace id is recorded by no server."""
-    from seaweedfs_tpu.obs import trace as trace_obs
-
     parent = trace_obs.current()
 
     def run(*args, **kw):
@@ -343,6 +342,44 @@ def do_ec_encode(args: list[str], env: CommandEnv, w: TextIO) -> None:
         checkpoint=".ec_encode.checkpoint",
     )
     env.confirm_locked()
+    before = env.rpcs
+    with trace_obs.span("shell.plan") as planning:
+        planned = _plan_encode(fl, env, w)
+        if planning is not None:
+            planning.annotate(volumes=len(planned[1]) if planned else 0, rpcs=env.rpcs - before)
+    if planned is None:
+        return
+    nodes, plans, ckpt, done = planned
+
+    def on_done(vid: int) -> None:
+        # a volume is done when ITS cut-over is complete, batch or no batch
+        if ckpt is not None:
+            done.add(vid)
+            ckpt.mark_done(done)
+
+    failed: list[int] = []
+    for batch in _encode_batches(plans):
+        failed += _encode_batch(
+            env,
+            nodes,
+            batch,
+            w,
+            large_block_size=fl.largeBlockSize,
+            small_block_size=fl.smallBlockSize,
+            inline=bool(fl.inline),
+            on_done=on_done,
+        )
+    if failed:
+        raise ShellError(f"ec.encode: volumes {failed} were not encoded")
+    if ckpt is not None:
+        ckpt.finish()  # batch complete: a future batch starts fresh
+
+
+def _plan_encode(fl, env: CommandEnv, w: TextIO):
+    """`ec.encode` before its first freeze (the `shell.plan` span): the
+    topology, the selection, the checkpoint, where each volume lives.
+    -> (nodes, plans, checkpoint or None, the volumes it holds as done), or
+    None where nothing matches."""
     topo = env.volume_list()
     nodes = env.topology_nodes()
     limit = int(topo.get("volume_size_limit", 0)) or 1
@@ -385,7 +422,7 @@ def do_ec_encode(args: list[str], env: CommandEnv, w: TextIO) -> None:
         )
     if not vids:
         w.write("ec.encode: no matching volumes\n")
-        return
+        return None
     # batch resume: single -volumeId runs don't checkpoint (nothing to skip)
     ckpt = None
     done: set[int] = set()
@@ -417,29 +454,7 @@ def do_ec_encode(args: list[str], env: CommandEnv, w: TextIO) -> None:
         plans.append(
             {"vid": vid, "collection": coll_of[vid], "locations": locations, "size": size_of[vid]}
         )
-
-    def on_done(vid: int) -> None:
-        # a volume is done when ITS cut-over is complete, batch or no batch
-        if ckpt is not None:
-            done.add(vid)
-            ckpt.mark_done(done)
-
-    failed: list[int] = []
-    for batch in _encode_batches(plans):
-        failed += _encode_batch(
-            env,
-            nodes,
-            batch,
-            w,
-            large_block_size=fl.largeBlockSize,
-            small_block_size=fl.smallBlockSize,
-            inline=bool(fl.inline),
-            on_done=on_done,
-        )
-    if failed:
-        raise ShellError(f"ec.encode: volumes {failed} were not encoded")
-    if ckpt is not None:
-        ckpt.finish()  # batch complete: a future batch starts fresh
+    return nodes, plans, ckpt, done
 
 
 register(
@@ -588,6 +603,35 @@ def do_ec_rebuild(args: list[str], env: CommandEnv, w: TextIO) -> None:
     if trace_mode not in ("on", "off", "auto"):
         raise ShellError(f"-trace must be on|off|auto, got {fl.trace!r}")
     env.confirm_locked()
+    before = env.rpcs
+    with trace_obs.span("shell.plan") as planning:
+        by_rebuilder = _plan_rebuild(fl, env, w)
+        if planning is not None:
+            planning.annotate(
+                volumes=sum(map(len, by_rebuilder.values())), rpcs=env.rpcs - before
+            )
+    failed: list[int] = []
+    for plans in by_rebuilder.values():
+        if fl.remote:
+            for plan in plans:
+                _rebuild_remote(env, plan, trace_mode, w)
+            continue
+        batch = [p for p in plans if p["local"]]
+        if batch:
+            failed += _rebuild_many(env, batch, w)
+    if by_rebuilder and not fl.remote:
+        _rebuild_pipelined(
+            env, [p for plans in by_rebuilder.values() for p in plans if not p["local"]], w
+        )
+    if failed:
+        raise ShellError(f"ec.rebuild: volumes {failed} were not rebuilt")
+
+
+def _plan_rebuild(fl, env: CommandEnv, w: TextIO) -> dict[str, list[dict]]:
+    """`ec.rebuild` before its first copy or rebuild (the `shell.plan`
+    span): every EC volume of the selection that misses shards, with who
+    holds what, its geometry (a `VolumeStatus` a volume) and its rebuilder.
+    -> rebuilder url -> its volumes' plans, in id order."""
     nodes = env.topology_nodes()
     colls = _ec_collections(env)
     ec_vids = sorted(
@@ -633,21 +677,7 @@ def do_ec_rebuild(args: list[str], env: CommandEnv, w: TextIO) -> None:
                 "local": set(holders) <= set(_node_shards_of(rebuilder, vid)),
             }
         )
-    failed: list[int] = []
-    for plans in by_rebuilder.values():
-        if fl.remote:
-            for plan in plans:
-                _rebuild_remote(env, plan, trace_mode, w)
-            continue
-        batch = [p for p in plans if p["local"]]
-        if batch:
-            failed += _rebuild_many(env, batch, w)
-    if by_rebuilder and not fl.remote:
-        _rebuild_pipelined(
-            env, [p for plans in by_rebuilder.values() for p in plans if not p["local"]], w
-        )
-    if failed:
-        raise ShellError(f"ec.rebuild: volumes {failed} were not rebuilt")
+    return by_rebuilder
 
 
 def _rebuild_remote(env: CommandEnv, plan: dict, trace_mode: str, w: TextIO) -> None:
@@ -710,7 +740,6 @@ def _rebuild_pipelined(env: CommandEnv, plans: list[dict], w: TextIO) -> None:
     volume's copies go whether or not its rebuild came back (what a gather
     landed before one of its pulls failed, too), and a gather in flight is
     awaited and what it landed is dropped."""
-    from seaweedfs_tpu.obs import trace as trace_obs
 
     def gather(plan: dict) -> tuple[list[int], Optional[CopiesFailed]]:
         try:
@@ -1433,16 +1462,20 @@ def do_ec_trace(args: list[str], env: CommandEnv, w: TextIO) -> None:
         traceId="",     # one specific id (post-incident grep)
     )
     # the master's ring too (master.http roots, its rpc.server
-    # continuations) — "cluster-wide" must include every process that
-    # retains traces, not just the volume servers
-    nodes = [{"url": env.master_address}] + env.topology_nodes()
+    # continuations, the `shell.script` trees `-c` children handed over):
+    # "cluster-wide" must include every process that retains traces
+    masters = env.master_call("ListClusterNodes", {}).get("masters") or [
+        {"http_address": env.master_address, "grpc_address": env.master_address}
+    ]  # a master that predates the field: its gRPC address, as before
+    nodes = [{"url": m["http_address"], "grpc": m["grpc_address"]} for m in masters] + [
+        dict(n, grpc=grpc_addr(n)) for n in env.topology_nodes()
+    ]
     if fl.server:
         nodes = [n for n in nodes if fl.server in n["url"]]
     if not nodes:
         raise ShellError("no matching servers")
-    from seaweedfs_tpu.obs import trace as trace_obs
-
     shown = 0
+    found: list[tuple[dict, dict]] = []  # -traceId: (node, trace), joined below
     for n in sorted(nodes, key=lambda n: n["url"]):
         q = f"?limit={1000000 if fl.traceId else int(fl.limit)}"
         if fl.klass:
@@ -1466,8 +1499,28 @@ def do_ec_trace(args: list[str], env: CommandEnv, w: TextIO) -> None:
             f"offered; tracing "
             f"{'on' if payload.get('enabled') else 'OFF'})\n"
         )
+        if fl.traceId:
+            found += [(n, t) for t in traces]
+            continue
         for t in traces:
             w.write(trace_obs.render_trace(t) + "\n")
+            shown += 1
+    # one id, cluster-wide: a `-c` script's own tree first, each server's half
+    # of an RPC under the `rpc.client` span that waited for it; then, server
+    # by server, what had no caller among the script's spans. Servers of one
+    # process share a ring: a trace is printed once.
+    served = [(n["grpc"], t) for n, t in found if t["kind"] != "shell.script"]
+    seen: set[tuple] = set()
+    for n, t in found:
+        if t["kind"] == "shell.script" and trace_obs.identity(t) not in seen:
+            seen.add(trace_obs.identity(t))
+            w.write(trace_obs.render_trace(t, served) + "\n")
+            shown += 1
+    url_of = {n["grpc"]: n["url"] for n, _ in found}
+    for server, t in served:
+        if trace_obs.identity(t) not in seen:
+            seen.add(trace_obs.identity(t))
+            w.write(f"# {url_of[server]}:\n" + trace_obs.render_trace(t) + "\n")
             shown += 1
     if not shown:
         w.write("ec.trace: no retained traces matched\n")
@@ -1478,10 +1531,13 @@ register(
         "ec.trace",
         "ec.trace [-server <url-substr>] [-klass <class>] [-kind <kind>] "
         "[-minMs <ms>] [-limit <n>] [-traceId <id>]\n"
-        "\trender retained weedtrace span trees from the volume servers' "
-        "/debug/traces\n\trings, slowest first — per-stage wall times "
-        "(lookup/fetch/hedge/coalesce/\n\tdecode) for tail requests; "
-        "-traceId finds one specific request cluster-wide",
+        "\trender retained weedtrace span trees from the master's and the volume "
+        "servers'\n\t/debug/traces rings, slowest first — per-stage wall times "
+        "(lookup/fetch/hedge/\n\tcoalesce/decode) for tail requests; -traceId "
+        "finds one request cluster-wide\n\tand joins it: the `shell.script` tree a "
+        "`shell -c` child handed to the master\n\t(start, plan, every RPC as "
+        "`rpc.client`), and under each `rpc.client` the\n\t`rpc.server` tree of the "
+        "same id from whichever server's ring holds it",
         do_ec_trace,
     )
 )

@@ -310,10 +310,6 @@ StagingRingLeases = REGISTRY.counter(
     "slot was allocated, and page-faults in at its first fill",
     ("outcome",),
 )
-EcRebuildRemoteBytes = REGISTRY.counter(
-    "weedtpu_ec_rebuild_remote_bytes_total",
-    "survivor bytes fetched from peer holders by distributed rebuilds",
-)
 EcRepairNetworkBytes = REGISTRY.counter(
     "weedtpu_ec_repair_network_bytes_total",
     "survivor payload bytes a rebuild target pulled over the network, by "
@@ -546,6 +542,15 @@ RpcServerSeconds = REGISTRY.histogram(
     "recorded at the generic dispatch seam, so every registered RPC is "
     "covered without per-handler wiring",
     ("method",),
+)
+ShellCommandSeconds = REGISTRY.histogram(
+    "weedtpu_shell_command_seconds",
+    "where the wall of one command of a `shell -c` script went, by command "
+    "and phase, from the script's own trace as the master received it "
+    "(ReportTrace): start (the child's birth to its first command, under the "
+    "script's first command), plan (before the first state-changing RPC), rpc "
+    "(the RPCs the command's own thread waited for, outside the plan), other",
+    ("command", "phase"),
 )
 RpcInflight = REGISTRY.gauge(
     "weedtpu_rpc_inflight",

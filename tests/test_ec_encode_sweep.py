@@ -378,8 +378,9 @@ def test_a_sweep_of_eight_volumes_is_one_batch(make_sweep, backend):
     assert run["attrs"]["batch"] == 8 and run["attrs"]["batches"] == 1
     assert run["attrs"]["volumes"] == "1,2,3,4,5,6,7,8" and run["attrs"]["ring"] in ("reused", "allocated")
     assert run["attrs"]["bytes"] == sum(len(d) for d in c.dats.values())
-    (root,) = [t["root"] for t in trace.RING.snapshot(kind="shell.command", limit=1000)
-               if t["root"]["attrs"].get("command") == "ec.encode"]
+    # the command's span, under the script's root (`shell -c` is ONE trace)
+    (root,) = [s for t in trace.RING.snapshot(kind="shell.script", limit=1000) for s in trace.iter_spans(t)
+               if s["name"] == "shell.command" and s["attrs"].get("command") == "ec.encode"]
     # VolumeList twice (the size limit, the nodes), eight freezes, the one
     # batch, and a mount and a delete a volume
     assert root["attrs"]["rpcs"] == 2 + 8 + 1 + 8 + 8

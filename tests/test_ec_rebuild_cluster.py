@@ -316,8 +316,9 @@ def _slow_rebuilds(monkeypatch, seconds=0.15):
 
 
 def _shell_root(command="ec.rebuild"):
-    (root,) = [t["root"] for t in trace.RING.snapshot(kind="shell.command", limit=1000)
-               if t["root"]["attrs"].get("command") == command]
+    """The command's own span: under the script's root (`shell -c` is ONE trace)."""
+    (root,) = [s for t in trace.RING.snapshot(kind="shell.script", limit=1000) for s in trace.iter_spans(t)
+               if s["name"] == "shell.command" and s["attrs"].get("command") == command]
     return root
 
 
@@ -637,7 +638,21 @@ def test_pick_rebuild_target_prefers_a_device_codec(case):
 NOT_IN_THE_CHILD = (
     "numpy", "jax", "sqlite3", "seaweedfs_tpu.ec.stripe", "seaweedfs_tpu.ops.rs_codec",
     "seaweedfs_tpu.command.local", "seaweedfs_tpu.filer", "seaweedfs_tpu.s3api", "seaweedfs_tpu.mq",
+    # the hand-over of a script's trace is gRPC on the open channel for this: no HTTP client in the child
+    "http.client", "urllib.request",
 )
+# the package's own modules in a `lock; ec.rebuild; unlock` child: the trace it
+# hands over (PR 42) brought none (the installation's own add to them: 241
+# modules in all on the chip's host, 227 here, as before)
+PACKAGE_MODULES_IN_THE_EC_CHILD = {
+    "seaweedfs_tpu", "seaweedfs_tpu.cluster", "seaweedfs_tpu.cluster.client", "seaweedfs_tpu.command",
+    "seaweedfs_tpu.command.servers", "seaweedfs_tpu.ec", "seaweedfs_tpu.ec.constants", "seaweedfs_tpu.ec.placement",
+    "seaweedfs_tpu.ec.shard_bits", "seaweedfs_tpu.obs", "seaweedfs_tpu.obs.trace", "seaweedfs_tpu.pb",
+    "seaweedfs_tpu.pb.wire", "seaweedfs_tpu.rpc", "seaweedfs_tpu.security", "seaweedfs_tpu.security.guard",
+    "seaweedfs_tpu.security.jwt", "seaweedfs_tpu.security.tls", "seaweedfs_tpu.shell",
+    "seaweedfs_tpu.shell.command_cluster", "seaweedfs_tpu.shell.command_ec", "seaweedfs_tpu.stats",
+    "seaweedfs_tpu.utils", "seaweedfs_tpu.utils.config", "seaweedfs_tpu.utils.glog",
+}
 # `python -m seaweedfs_tpu <argv>` is `__main__.main(argv)`. The master is real
 # and has no volume server: `ec.encode` / `ec.decode` of a volume nobody holds
 # get past their first RPCs and fail there, which is far enough.
@@ -692,6 +707,10 @@ def test_a_tool_child_imports_what_its_command_line_names(case):
     assert "seaweedfs_tpu" in loaded
     assert not [m for m in loaded for bad in NOT_IN_THE_CHILD if m == bad or m.startswith(bad + ".")]
     assert {m.rsplit(".", 1)[1] for m in loaded if m.startswith("seaweedfs_tpu.shell.command_")} == families
+    if case == "ec.rebuild":
+        ours = {m for m in loaded if m.split(".")[0] == "seaweedfs_tpu" and m != "seaweedfs_tpu.__main__"}
+        # `ec.placement` loads when a volume is planned: this master has none
+        assert ours | {"seaweedfs_tpu.ec.placement"} == PACKAGE_MODULES_IN_THE_EC_CHILD
 
 
 def test_the_domain_cap_still_comes_before_the_device():
@@ -786,3 +805,194 @@ def test_the_benchmark_cell_rehearses_to_its_end_and_leaves_no_process(tmp_path,
     left = subprocess.run(["pgrep", "-f", str(work)], capture_output=True, text=True).stdout.split()
     assert not left, f"processes left behind: {left}"
     shutil.rmtree(work, ignore_errors=True)
+
+
+# -- one command, one trace, kept (PR 42) -----------------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_script(tmp_path_factory):
+    """`lock; ec.rebuild; unlock` as a `-c` child runs it (the start marks of
+    a process that IS the script), against four servers in this process, one
+    lost, two volumes with copies. -> what the command left behind."""
+    import types
+    import urllib.request
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("WEEDTPU_TRACE", "on")
+        mp.setenv("WEEDTPU_TRACE_SAMPLE", "1.0")
+        mp.setenv("WEEDTPU_TRACE_RING", "100000")
+        c = Cluster(tmp_path_factory.mktemp("one_script"), ["host", "jax", "host", "host"])
+        try:
+            _lost_server_setup(c)
+            _slow_rebuilds(mp)
+            trace.RING.clear()
+            phases0 = stats.ShellCommandSeconds.labels("ec.rebuild", "rpc").total
+            out = io.StringIO()
+            now = time.monotonic()
+            run_script(c.env, "lock; ec.rebuild; unlock", out,
+                       started=(now - 0.30, now - 0.20, now - 0.12, now - 0.02))
+            snap = trace.RING.snapshot(limit=100000)
+            (script,) = [t for t in snap if t["kind"] == "shell.script"]
+            joined = io.StringIO()
+            run_script(c.env, f"ec.trace -traceId {script['trace_id']}", joined)
+            http = f"127.0.0.1:{c.master.http_port}"
+            with urllib.request.urlopen(f"http://{http}/debug/traces?kind=shell.script&limit=1000", timeout=10) as r:
+                served = json.loads(r.read().decode())
+            with urllib.request.urlopen(f"http://{http}/metrics", timeout=10) as r:
+                metrics = r.read().decode()
+            yield types.SimpleNamespace(
+                c=c, out=out.getvalue(), snap=snap, script=script, joined=joined.getvalue(),
+                served=served, metrics=metrics, phases0=phases0)
+        finally:
+            c.close()
+
+
+def _commands(script):
+    return [s for s in script["root"]["spans"] if s["name"] == "shell.command"]
+
+
+def _descendants(span, name):
+    return [s for s in trace.iter_spans({"root": span}) if s["name"] == name and s is not span]
+
+
+def test_a_script_is_one_trace_under_one_id(one_script):
+    """`lock`, `ec.rebuild` and `unlock` of one `shell -c` are ONE trace: one
+    `shell.script` root, no `shell.command` root, and every RPC any server
+    recorded while it ran carries its id."""
+    kinds = [t["kind"] for t in one_script.snap]
+    assert kinds.count("shell.script") == 1 and "shell.command" not in kinds
+    assert {t["trace_id"] for t in one_script.snap} == {one_script.script["trace_id"]}
+    methods = {t["root"]["attrs"]["method"] for t in one_script.snap if t["kind"] == "rpc.server"}
+    assert {"LeaseAdminToken", "VolumeList", "VolumeStatus", "VolumeEcShardsCopy", "VolumeEcShardFileCopy",
+            "VolumeEcShardsRebuild", "VolumeEcShardsDelete", "ReleaseAdminToken"} <= methods
+    assert "ReportTrace" not in methods  # the hand-over is outside the trace it carries
+
+
+def test_the_masters_ring_holds_the_scripts_tree(one_script):
+    """`shell.script` > `shell.start` + three `shell.command`, from the birth
+    of the process; the plan is a span of its own; what the spans leave
+    uncovered of the script's wall is under 5%."""
+    script, root = one_script.script, one_script.script["root"]
+    assert script["class"] == "shell" and root["attrs"] == {"script": "lock; ec.rebuild; unlock"}
+    assert script["birth_unix_ns"] == script["unix_ns"] and abs(script["unix_ns"] / 1e9 - script["start"]) < 0.01
+    assert [s["name"] for s in root["spans"]] == ["shell.start"] + ["shell.command"] * 3
+    start = root["spans"][0]
+    assert start["t_ms"] == 0 and 280 <= start["dur_ms"] <= 400
+    assert (start["attrs"]["interp_ms"], start["attrs"]["import_ms"], start["attrs"]["connect_ms"]) == (
+        pytest.approx(100, abs=0.01), pytest.approx(80, abs=0.01), pytest.approx(100, abs=0.01))
+    assert start["attrs"]["modules"] == _commands(script)[0]["attrs"]["modules"]
+    assert [s["attrs"]["command"] for s in _commands(script)] == ["lock", "ec.rebuild", "unlock"]
+    rebuild = _commands(script)[1]
+    (plan,) = [s for s in rebuild["spans"] if s["name"] == "shell.plan"]
+    # VolumeList, the collections, a VolumeStatus a volume: all before the first copy
+    assert plan["attrs"] == {"volumes": 2, "rpcs": 4} and len(_descendants(plan, "rpc.client")) == 4
+    first_copy = min(s["t_ms"] for s in _descendants(rebuild, "rpc.client")
+                     if s["attrs"]["method"] == "VolumeEcShardsCopy")
+    assert plan["t_ms"] + plan["dur_ms"] <= first_copy
+    covered = sum(s["dur_ms"] for s in root["spans"])
+    assert covered >= 0.95 * script["duration_s"] * 1e3
+
+
+def test_a_command_has_as_many_rpc_client_spans_as_its_rpcs_says(one_script):
+    for cmd, want in zip(_commands(one_script.script), (1, 12, 1)):
+        clients = _descendants(cmd, "rpc.client")
+        assert len(clients) == cmd["attrs"]["rpcs"] == want, cmd["attrs"]
+        assert all(set(s["attrs"]) >= {"method", "target"} for s in clients)
+
+
+def test_the_gather_workers_calls_say_their_volume_and_run_beside_the_rebuild(one_script):
+    """The pool threads' copies attach to the command that queued them, say
+    `thread=` and `volume=`; volume 2's begin with volume 1's rebuild and end
+    before volume 2's own; the command's own thread made every other call."""
+    rebuild = _commands(one_script.script)[1]
+    clients = [s for s in rebuild["spans"] if s["name"] == "rpc.client"]  # direct children: the queuing span
+    by = lambda method, vid: [s for s in clients if s["attrs"]["method"] == method  # noqa: E731
+                              and s["attrs"].get("volume") == vid]
+    copies1, copies2 = by("VolumeEcShardsCopy", 1), by("VolumeEcShardsCopy", 2)
+    assert len(copies1) == 2 and len(copies2) == 2
+    assert all("thread" in s["attrs"] for s in copies1 + copies2)
+    assert not [s for s in _descendants(rebuild, "rpc.client")
+                if "thread" in s["attrs"] and s["attrs"]["method"] != "VolumeEcShardsCopy"]
+    (r1,), (r2,) = by("VolumeEcShardsRebuild", 1), by("VolumeEcShardsRebuild", 2)
+    assert max(s["t_ms"] + s["dur_ms"] for s in copies1) <= r1["t_ms"]
+    assert all(r1["t_ms"] - 5 <= s["t_ms"] < r1["t_ms"] + r1["dur_ms"] for s in copies2)
+    assert max(s["t_ms"] + s["dur_ms"] for s in copies2) <= r2["t_ms"]
+    assert rebuild["attrs"]["overlapped"] == 1
+
+
+def test_every_rpc_server_root_lies_inside_its_rpc_client_by_the_wall_clock(one_script):
+    """A root says `unix_ns`, the wall clock at its start; the script's spans
+    are offsets from its own. Mapped so, each server's half of an RPC lies
+    inside the shell's half of the same method, on every server."""
+    script = one_script.script
+    clients = {}
+    for s in _descendants(script["root"], "rpc.client"):
+        t0 = script["unix_ns"] + s["t_ms"] * 1e6
+        clients.setdefault(s["attrs"]["method"], []).append((t0, t0 + s["dur_ms"] * 1e6))
+    served = [t for t in one_script.snap if t["kind"] == "rpc.server"
+              and t["root"]["attrs"]["method"] in clients]  # (a peer's VolumeEcShardFileCopy has no shell half)
+    assert len(served) == 14
+    for t in served:
+        t0, t1 = t["unix_ns"], t["unix_ns"] + t["duration_s"] * 1e9
+        sticks_out = min(max(a - t0, t1 - b, 0) for a, b in clients[t["root"]["attrs"]["method"]])
+        assert sticks_out < 1e6, (t["root"]["attrs"]["method"], sticks_out)  # nanoseconds: under 1 ms
+
+
+def test_ec_trace_prints_the_scripts_tree_with_each_servers_half_in_place(one_script):
+    """`ec.trace -traceId`: the shell's tree once, and under each `rpc.client`
+    the `rpc.server` tree of the same id, each printed exactly once."""
+    text, tid = one_script.joined, one_script.script["trace_id"]
+    lines = text.splitlines()
+    assert sum(line.startswith(f"trace={tid} shell.script class=shell") for line in lines) == 1
+    rebuilder = one_script.c.servers[1].grpc_address
+    at = [i for i, line in enumerate(lines) if "rpc.client method=VolumeEcShardsRebuild " in line]
+    assert len(at) == 2
+    for i in at:
+        assert lines[i + 1].strip("| ").startswith(f"@ {rebuilder} rpc.server ")
+        assert " rebuild.run volume=" in lines[i + 2] and lines[i + 2].startswith(lines[i + 1].split("@")[0] + "|  +-")
+    assert text.count(" rebuild.run volume=") == 2 and text.count(" ec.copy source=") == 4
+    assert text.count("@ ") == 14  # every RPC of the script that a server recorded, in its place
+    # what had no caller among the script's spans comes after, under its server's name
+    tail = text[text.rindex("@ "):]
+    assert tail.count("rpc.server class=rpc method=VolumeEcShardFileCopy") == text.count("method=VolumeEcShardFileCopy") > 0
+
+
+def test_the_master_serves_the_script_and_counts_its_phases(one_script):
+    (served,) = [t for t in one_script.served["traces"] if t["trace_id"] == one_script.script["trace_id"]]
+    assert served == one_script.script
+    rebuild = _commands(one_script.script)[1]
+    rows = {(c, p): s for c, p, s in trace.script_phases(one_script.script)}
+    assert set(rows) == {("lock", "start")} | {(c, p) for c in ("lock", "ec.rebuild", "unlock")
+                                               for p in ("plan", "rpc", "other")}
+    assert rows[("lock", "start")] == pytest.approx(one_script.script["root"]["spans"][0]["dur_ms"] / 1e3)
+    assert sum(rows[("ec.rebuild", p)] for p in ("plan", "rpc", "other")) == pytest.approx(rebuild["dur_ms"] / 1e3)
+    # rpc: what the command's own thread waited for: not the worker's copies beside the rebuild
+    own = [s for s in rebuild["spans"] if s["name"] == "rpc.client" and "thread" not in s["attrs"]]
+    assert rows[("ec.rebuild", "rpc")] == pytest.approx(sum(s["dur_ms"] for s in own) / 1e3)
+    assert stats.ShellCommandSeconds.labels("ec.rebuild", "rpc").total == one_script.phases0 + 1
+    for phase in ("plan", "rpc", "other"):
+        assert f'weedtpu_shell_command_seconds_count{{command="ec.rebuild",phase="{phase}"}} ' in one_script.metrics
+    assert 'weedtpu_shell_command_seconds_count{command="lock",phase="start"} ' in one_script.metrics
+
+
+def test_with_tracing_off_nothing_is_recorded_or_handed_over_and_the_shards_are_the_same(tmp_path, monkeypatch):
+    """`WEEDTPU_TRACE=off`: no root, no span, no `ReportTrace` call; the same
+    commands write the same bytes (the reference's, as with tracing on)."""
+    monkeypatch.setenv("WEEDTPU_TRACE", "off")
+    c = Cluster(tmp_path, ["host", "jax", "host", "host"])
+    try:
+        rebuilder_dir, own = _lost_server_setup(c)
+        trace.RING.clear()
+        reports0 = stats.RpcServerSeconds.labels("ReportTrace").total
+        out = c.shell("lock; ec.rebuild; unlock")
+        assert out.endswith("ec.rebuild: 2 volumes with copies, 1 gathered beside a rebuild\ncluster unlocked\n")
+        assert stats.RpcServerSeconds.labels("ReportTrace").total == reports0
+        assert trace.RING.snapshot() == [] and trace.RING.stats()["offered"] == 0
+        for vid, (mine, lost) in own.items():
+            for s in mine | lost:
+                with open(stripe.shard_file_name(os.path.join(rebuilder_dir, str(vid)), s), "rb") as f:
+                    assert f.read() == c.reference[vid][s], f"volume {vid} shard {s} differs from the reference"
+        _nothing_temporary(c, rebuilder_dir, own)
+    finally:
+        c.close()

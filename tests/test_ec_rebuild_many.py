@@ -196,8 +196,9 @@ def test_one_shard_of_each_of_eight_volumes_is_one_batch(make_many, backend):
               for s in trace.iter_spans(t) if s["name"] == "rebuild.run"]
     assert run["attrs"]["batch"] == 8 and run["attrs"]["signature_groups"] == 7
     assert run["attrs"]["ring"] in ("reused", "allocated")
-    (root,) = [t["root"] for t in trace.RING.snapshot(kind="shell.command", limit=1000)
-               if t["root"]["attrs"].get("command") == "ec.rebuild"]
+    # the command's span, under the script's root (`shell -c` is ONE trace)
+    (root,) = [s for t in trace.RING.snapshot(kind="shell.script", limit=1000) for s in trace.iter_spans(t)
+               if s["name"] == "shell.command" and s["attrs"].get("command") == "ec.rebuild"]
     # VolumeList, the collections, a VolumeStatus a volume, and the one batch
     assert root["attrs"]["rpcs"] == 2 + 8 + 1
 

@@ -743,6 +743,10 @@ def test_mirror_gets_enter_exit_in_pairs_on_the_recording_thread(mirror):
     assert len(by_thread) == 2 and threading.get_ident() not in by_thread
     for events in by_thread.values():
         method = events[0][2]["method"]
+        # a mirrored ROOT also says its id and the wall clock at its start:
+        # one of them ties a profiler session's clock to every other process's
+        unix_ns = events[0][2].pop("unix_ns")
+        assert abs(unix_ns - time.time_ns()) < 60e9 and events[0][2].pop("trace_id") == "abc123"
         assert events == [
             ("enter", "rpc.server", {"method": method}), ("enter", "encode.run", {}),
             ("enter", "encode.read", {"bytes": 7}), ("exit", "encode.read", None),
@@ -818,3 +822,207 @@ def test_obs_trace_imports_no_jax():
     code = ("import sys; from seaweedfs_tpu.obs import trace; trace.set_mirror(None); "
             "assert 'jax' not in sys.modules and 'jaxlib' not in sys.modules, sorted(m for m in sys.modules if 'jax' in m)")
     subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
+# -- one command, one trace, kept (PR 42): the pieces -------------------------
+
+
+def test_a_root_can_begin_before_it_was_opened_and_take_finished_spans(on):
+    """`start(t0=...)` back-dates a root on both clocks (a script's begins at
+    its process's birth); `record` adds a span that is over already."""
+    ring = trace.TraceRing(capacity=8, slowest_n=2, sample=1.0, seed=1)
+    born = time.monotonic() - 0.5
+    with trace.start("shell.script", klass="shell", ring=ring, t0=born, script="lock; unlock") as root:
+        trace.record("shell.start", born, born + 0.4, interp_ms=100.0, modules=7)
+        with trace.span("rpc.client", method="LeaseAdminToken"):
+            pass
+    (t,) = ring.snapshot()
+    assert t["root"]["attrs"] == {"script": "lock; unlock"} and root.t0 == born
+    assert 0.5 <= t["duration_s"] < 0.6 and abs(t["unix_ns"] / 1e9 - (time.time() - t["duration_s"])) < 0.05
+    start, call = t["root"]["spans"]
+    assert (start["name"], start["t_ms"], start["dur_ms"]) == ("shell.start", 0.0, pytest.approx(400.0))
+    assert start["attrs"] == {"interp_ms": 100.0, "modules": 7}
+    assert call["t_ms"] >= 500.0
+    trace.record("shell.start", born, born + 0.1)  # no ambient trace: nothing, and no error
+
+
+def test_mark_reaches_the_mirror_alone(mirror, monkeypatch):
+    assert trace.mark("shell.trace", names="a;b", depth="0;1") is True
+    assert [(e, n, a) for e, n, a, _ in mirror] == [
+        ("enter", "shell.trace", {"names": "a;b", "depth": "0;1"}), ("exit", "shell.trace", None)]
+    assert trace.RING.snapshot() == []
+    del mirror[:]
+    monkeypatch.setenv("WEEDTPU_TRACE", "off")
+    assert trace.mark("shell.trace", names="a") is False and mirror == []
+    trace.set_mirror(None)
+    monkeypatch.setenv("WEEDTPU_TRACE", "on")
+    assert trace.mark("shell.trace", names="a") is False
+
+
+def test_process_birth_is_read_from_the_kernel_and_falls_back():
+    now = time.monotonic()
+    born = trace.process_birth(now)
+    assert born <= now and now - born < 24 * 3600  # this interpreter started before this line, today
+    assert trace.process_birth(now) == pytest.approx(born, abs=0.02)  # the tick is 10 ms
+    assert trace.process_birth(born - 5.0) == born - 5.0  # never later than the first line of __main__
+
+
+def _tree(fan, depth, t=0.0):
+    sp = {"name": "rpc.client", "t_ms": t, "dur_ms": 1.0}
+    if depth:
+        sp["spans"] = [_tree(fan, depth - 1, t + i) for i in range(fan)]
+    return sp
+
+
+def test_a_handed_over_tree_is_cut_to_2000_spans_the_deepest_first():
+    root = _tree(5, 5)  # 1 + 5 + 25 + 125 + 625 + 3125
+    count = lambda sp: sum(1 for _ in trace.iter_spans({"root": sp}))  # noqa: E731
+    assert count(root) == 3906 and trace.cap_spans(_tree(5, 4)) == 0
+    assert trace.cap_spans(root) == 1906 and count(root) == trace.REPORT_MAX_SPANS
+    by_depth = {}
+    for _, depth in trace._depths(root):
+        by_depth[depth] = by_depth.get(depth, 0) + 1
+    assert by_depth == {0: 1, 1: 5, 2: 25, 3: 125, 4: 625, 5: 1219}  # every level above the deepest is whole
+    assert trace.cap_spans(root, 31) == 1969 and max(d for _, d in trace._depths(root)) == 2
+
+
+SCRIPT_TRACE = {
+    "trace_id": "ab12", "kind": "shell.script", "class": "shell", "start": 1000.0, "unix_ns": 1000 * 10**9,
+    "birth_unix_ns": 1000 * 10**9, "duration_s": 1.0, "error": None,
+    "root": {"name": "shell.script", "t_ms": 0.0, "dur_ms": 1000.0, "attrs": {"script": "lock; ec.rebuild"}, "spans": [
+        {"name": "shell.start", "t_ms": 0.0, "dur_ms": 300.0,
+         "attrs": {"interp_ms": 170.0, "import_ms": 90.0, "connect_ms": 40.0, "modules": 241}},
+        {"name": "shell.command", "t_ms": 300.0, "dur_ms": 10.0, "attrs": {"command": "lock", "rpcs": 1}, "spans": [
+            {"name": "rpc.client", "t_ms": 301.0, "dur_ms": 8.0, "attrs": {"method": "LeaseAdminToken", "target": "m:1"}}]},
+        {"name": "shell.command", "t_ms": 310.0, "dur_ms": 690.0, "attrs": {"command": "ec.rebuild", "rpcs": 4}, "spans": [
+            {"name": "shell.plan", "t_ms": 310.0, "dur_ms": 50.0, "attrs": {"volumes": 2, "rpcs": 1}, "spans": [
+                {"name": "rpc.client", "t_ms": 311.0, "dur_ms": 40.0, "attrs": {"method": "VolumeList", "target": "m:1"}}]},
+            {"name": "rpc.client", "t_ms": 400.0, "dur_ms": 300.0,
+             "attrs": {"method": "VolumeEcShardsRebuild", "target": "v:2", "volume": 1}},
+            {"name": "rpc.client", "t_ms": 400.0, "dur_ms": 500.0,
+             "attrs": {"method": "VolumeEcShardsCopy", "target": "v:2", "volume": 2, "thread": "pool_0"}},
+            {"name": "rpc.client", "t_ms": 650.0, "dur_ms": 100.0,
+             "attrs": {"method": "VolumeEcShardsDelete", "target": "v:2", "volume": 1}}]},
+    ]},
+}
+
+
+def test_a_script_trace_flat_for_the_profiler():
+    flat = trace.flatten(SCRIPT_TRACE)
+    assert flat["trace_id"] == "ab12" and flat["birth_unix_ns"] == 1000 * 10**9
+    assert flat["names"].split(";") == ["shell.script", "shell.start", "shell.command", "rpc.client",
+                                        "shell.command", "shell.plan", "rpc.client"] + ["rpc.client"] * 3
+    assert flat["what"].split(";") == ["", "", "lock", "LeaseAdminToken", "ec.rebuild", "", "VolumeList",
+                                       "VolumeEcShardsRebuild", "VolumeEcShardsCopy", "VolumeEcShardsDelete"]
+    assert flat["depth"] == "0;1;1;2;1;2;3;2;2;2" and flat["thread"] == "0;0;0;0;0;0;0;0;1;0"
+    assert flat["t_ns"].split(";")[6] == "311000000" and flat["dur_ns"].split(";")[1] == "300000000"
+    assert flat["start_ms"] == "170.0;90.0;40.0"
+    assert all(isinstance(v, (str, int)) for v in flat.values())  # what an annotation's attributes can carry
+
+
+def test_a_script_trace_as_phases_of_its_commands():
+    import copy
+
+    odd = copy.deepcopy(SCRIPT_TRACE)
+    odd["root"]["spans"][1]["attrs"]["command"] = 'x"} 1\nweedtpu_injected{a="'
+    assert {c for c, _, _ in trace.script_phases(odd)} == {"other", "ec.rebuild"}  # a label is never wire input as it came
+    rows = {(c, p): s for c, p, s in trace.script_phases(SCRIPT_TRACE)}
+    assert rows == {
+        ("lock", "start"): pytest.approx(0.300), ("lock", "plan"): 0.0,
+        ("lock", "rpc"): pytest.approx(0.008), ("lock", "other"): pytest.approx(0.002),
+        ("ec.rebuild", "plan"): pytest.approx(0.050),
+        # the union of what the command's own thread waited for outside the plan:
+        # 400-700 and 650-750; the worker's copy ran beside them
+        ("ec.rebuild", "rpc"): pytest.approx(0.350), ("ec.rebuild", "other"): pytest.approx(0.290),
+    }
+
+
+def test_a_received_trace_is_kept_as_a_root_of_the_rings_own(on):
+    import copy
+
+    ring = trace.TraceRing(capacity=8, slowest_n=2, sample=1.0, seed=1)
+    sent = copy.deepcopy(SCRIPT_TRACE)
+    assert trace.offer_received(sent, ring) is True
+    assert ring.snapshot(kind="shell.script", klass="shell", min_duration=0.5) == [SCRIPT_TRACE]
+    assert ring.snapshot(kind="shell.script", min_duration=1.5) == []
+    slow = dict(copy.deepcopy(SCRIPT_TRACE), duration_s=3.0, trace_id="AB13")
+    trace.offer_received(slow, ring)
+    assert [t["trace_id"] for t in ring.snapshot()] == ["ab13", "ab12"]  # slowest first, the id sanitized
+
+
+@pytest.mark.parametrize("bad", [
+    {}, {"trace_id": "ab12"}, dict(SCRIPT_TRACE, trace_id="<script>"), dict(SCRIPT_TRACE, kind="nosuch.kind"),
+    dict(SCRIPT_TRACE, root="x"), dict(SCRIPT_TRACE, root={"name": "rpc.server", "t_ms": 0, "dur_ms": 1}),
+    dict(SCRIPT_TRACE, duration_s="long"), dict(SCRIPT_TRACE, duration_s=-1.0), [SCRIPT_TRACE],
+], ids=["empty", "id-alone", "bad-id", "unknown-kind", "root-no-tree", "root-of-another-kind",
+        "duration-no-number", "duration-negative", "a-list"])
+def test_a_malformed_received_trace_is_refused(on, bad):
+    ring = trace.TraceRing(capacity=8, slowest_n=2, sample=1.0, seed=1)
+    with pytest.raises(ValueError):
+        trace.offer_received(bad, ring)
+    assert ring.snapshot() == []
+
+
+def test_report_trace_refuses_what_is_no_trace_and_keeps_what_is(on):
+    import grpc
+
+    from seaweedfs_tpu import rpc, stats
+    from seaweedfs_tpu.pb import MASTER_SERVICE, wire
+
+    master = MasterServer(port=0, reap_interval=3600)
+    master.start()
+    try:
+        with rpc.RpcClient(master.address) as c:
+            for body in ({}, {"trace": "not json"}, {"trace": json.dumps({"trace_id": "ab12"})},
+                         {"trace": json.dumps(dict(SCRIPT_TRACE, pad="x" * trace.REPORT_MAX_BYTES))}):
+                with pytest.raises(grpc.RpcError) as e:
+                    c.call(MASTER_SERVICE, "ReportTrace", body)
+                assert e.value.code() == grpc.StatusCode.INVALID_ARGUMENT
+            assert trace.RING.snapshot(kind="shell.script") == []
+            before = stats.ShellCommandSeconds.labels("ec.rebuild", "plan").total
+            assert c.call(MASTER_SERVICE, "ReportTrace", {"trace": json.dumps(SCRIPT_TRACE)}) == {"kept": True}
+        assert trace.RING.snapshot(kind="shell.script") == [SCRIPT_TRACE]
+        assert stats.ShellCommandSeconds.labels("ec.rebuild", "plan").total == before + 1
+        assert stats.ShellCommandSeconds.labels("ec.rebuild", "plan").sum >= 0.05
+    finally:
+        master.stop()
+    # and on the protobuf wire: a tree of any depth travels as one string
+    ser, de = wire.codec().request_serdes(MASTER_SERVICE, "ReportTrace")
+    assert json.loads(de(ser({"trace": json.dumps(SCRIPT_TRACE)}))["trace"]) == SCRIPT_TRACE
+
+
+def test_the_first_few_of_a_kind_are_not_lost_when_slower_ones_come():
+    """A root that leaves the slowest row goes through the sample gate like
+    any other: before PR 42 it was dropped, so the first (fast) RPCs of a
+    command vanished from the ring the moment its long ones ended."""
+    ring = trace.TraceRing(capacity=8, slowest_n=2, sample=1.0, seed=1)
+    for dur in (0.001, 0.002, 0.5, 0.7, 0.003):
+        ring.offer(_mk(dur, kind="rpc.server", klass="rpc"))
+    assert sorted(t["duration_s"] for t in ring.snapshot()) == [0.001, 0.002, 0.003, 0.5, 0.7]
+    assert ring.stats()["kept"] == 5 and ring.stats()["sampled"] == 3
+    none = trace.TraceRing(capacity=8, slowest_n=2, sample=0.0, seed=1)
+    for dur in (0.001, 0.002, 0.5, 0.7, 0.003):
+        none.offer(_mk(dur, kind="rpc.server", klass="rpc"))
+    assert sorted(t["duration_s"] for t in none.snapshot()) == [0.5, 0.7]  # at sample 0: the slowest alone
+
+
+def test_render_trace_puts_each_servers_half_under_the_call_that_waited_for_it():
+    def served(method, at_ms, dur_s, inner):
+        return {"trace_id": "ab12", "kind": "rpc.server", "class": "rpc", "start": 1000.0 + at_ms / 1e3,
+                "unix_ns": 1000 * 10**9 + int(at_ms * 1e6), "duration_s": dur_s, "error": None,
+                "root": {"name": "rpc.server", "t_ms": 0.0, "dur_ms": dur_s * 1e3, "attrs": {"method": method},
+                         "spans": [{"name": inner, "t_ms": 0.1, "dur_ms": dur_s * 1e3 - 0.2}]}}
+
+    rebuild, copy_ = served("VolumeEcShardsRebuild", 401.0, 0.298, "rebuild.run"), served(
+        "VolumeEcShardsCopy", 400.5, 0.499, "ec.copy")
+    stray = served("VolumeEcShardFileCopy", 420.0, 0.1, "ec.copy.serve")  # a peer's: the shell never called it
+    other = dict(served("VolumeEcShardsRebuild", 402.0, 0.2, "rebuild.run"), trace_id="ffff")
+    pairs = [("v:9", rebuild), ("v:2", rebuild), ("v:2", copy_), ("v:3", stray), ("v:2", other)]
+    text = trace.render_trace(SCRIPT_TRACE, pairs)
+    lines = text.splitlines()
+    at = lines.index("|  +-    400.0ms     300.0ms rpc.client method=VolumeEcShardsRebuild target=v:2 volume=1")
+    assert lines[at + 1] == "|  |  @ v:2 rpc.server 298.0ms"  # the target's own ring first
+    assert lines[at + 2] == "|  |  |  +-      0.1ms     297.8ms rebuild.run"
+    assert text.count("@ ") == 2 and "ec.copy.serve" not in text
+    assert pairs == [("v:3", stray), ("v:2", other)]  # what found no caller is left for the caller to print
+    assert trace.render_trace(SCRIPT_TRACE) == trace.render_trace(SCRIPT_TRACE, [])

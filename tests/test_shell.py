@@ -947,3 +947,149 @@ def test_orphans_after_cutoff_chunks_and_classifies(monkeypatch):
     )
     assert fresh == {2} and 7 not in fresh
     assert undatable == {7, 8, 9, 10}  # failed chunk + fast-failed remainder
+
+
+# -- the script's trace, handed to the master when the script ends (PR 42) -----
+
+
+@pytest.fixture
+def traced(monkeypatch):
+    from seaweedfs_tpu.obs import trace
+
+    monkeypatch.setenv("WEEDTPU_TRACE", "on")
+    monkeypatch.setenv("WEEDTPU_TRACE_SAMPLE", "1.0")
+    trace.RING.clear()
+    yield trace
+    trace.RING.clear()
+
+
+def _report_calls():
+    from seaweedfs_tpu import stats
+
+    return stats.RpcServerSeconds.labels("ReportTrace").total
+
+
+@pytest.mark.parametrize("master_is", ["there", "down", "late"])
+def test_the_hand_over_never_changes_what_a_script_prints_or_raises(traced, monkeypatch, master_is):
+    """The script's trace goes to the master in ONE call when the script
+    ends, a failed script's too. A master that is down at that moment, or
+    answers after the 0.2 s the child gives it, costs the trace its place in
+    the master's ring (this process's ring then has it) and nothing else."""
+    import time
+
+    import grpc
+
+    if master_is == "late":
+        real = MasterServer._rpc_report_trace
+        monkeypatch.setattr(MasterServer, "_rpc_report_trace",
+                            lambda self, req, ctx: (time.sleep(1.5), real(self, req, ctx))[1])
+    master = MasterServer(port=0, reap_interval=3600)
+    master.start()
+    env = CommandEnv(master.address)
+    try:
+        if master_is == "down":
+            def down(method, req, timeout):
+                assert method == "ReportTrace" and timeout == shell._HAND_OVER_TIMEOUT == 0.2
+                raise grpc.RpcError("the master went away")
+
+            monkeypatch.setattr(env.client, "call_current", down)
+        calls0 = _report_calls()
+        out = io.StringIO()
+        t0 = time.monotonic()
+        run_script(env, "lock; unlock", out)
+        took = time.monotonic() - t0
+        assert out.getvalue() == "cluster locked\ncluster unlocked\n"
+        assert took < 1.2  # the script and 0.2 s at most: never the late master's 1.5 s
+        (script,) = traced.RING.snapshot(kind="shell.script")
+        assert [s["attrs"]["command"] for s in script["root"]["spans"]] == ["lock", "unlock"]
+        if master_is == "there":
+            assert _report_calls() == calls0 + 1 and script["birth_unix_ns"] == script["unix_ns"]
+        else:
+            assert "birth_unix_ns" not in script  # this process's own ring took it, as it is
+        # a script that fails hands its trace over too, and raises what it raised
+        traced.RING.clear()
+        out = io.StringIO()
+        with pytest.raises(ShellError, match="lock the cluster first"):
+            run_script(env, "ec.rebuild; lock", out)
+        (failed,) = traced.RING.snapshot(kind="shell.script")
+        assert failed["error"].startswith("ShellError: lock the cluster first") and not env.is_locked
+        assert failed["root"]["spans"][0]["error"] == "ShellError" and out.getvalue() == ""
+        if master_is == "late":
+            time.sleep(1.6)  # let the late answers land before the ring is cleared
+    finally:
+        env.close()
+        master.stop()
+
+
+@pytest.mark.parametrize("master_is", ["there", "late"])
+def test_a_child_exits_zero_with_the_same_output_whatever_the_master_does_with_its_trace(
+        traced, monkeypatch, master_is):
+    """The real `-c` child, `python -m seaweedfs_tpu shell`: exit code, stdout
+    and stderr are the same against a master that takes the trace and one
+    that sits on it; the one that takes it holds the child's whole life, from
+    its birth, after the child has gone."""
+    import time
+
+    if master_is == "late":
+        real = MasterServer._rpc_report_trace
+        monkeypatch.setattr(MasterServer, "_rpc_report_trace",
+                            lambda self, req, ctx: (time.sleep(1.5), real(self, req, ctx))[1])
+    master = MasterServer(port=0, reap_interval=3600)
+    master.start()
+    try:
+        t0 = time.time()
+        done = subprocess.run(
+            [sys.executable, "-m", "seaweedfs_tpu", "shell", "-master", master.address, "-c", "lock; unlock"],
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))), timeout=120,
+            capture_output=True, text=True, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+        wall = time.time() - t0
+        assert (done.returncode, done.stdout, done.stderr) == (0, "cluster locked\ncluster unlocked\n", "")
+        scripts = traced.RING.snapshot(kind="shell.script")
+        if master_is == "late":
+            assert scripts == []  # the child did not wait for it
+            time.sleep(1.6)
+            return
+        (script,) = scripts
+        start, lock, unlock = script["root"]["spans"]
+        assert start["name"] == "shell.start" and start["t_ms"] == 0.0
+        assert set(start["attrs"]) == {"interp_ms", "import_ms", "connect_ms", "modules"}
+        parts = sum(start["attrs"][k] for k in ("interp_ms", "import_ms", "connect_ms"))
+        assert 0 < parts <= start["dur_ms"] + 0.01 and 50 <= start["attrs"]["modules"] <= lock["attrs"]["modules"]
+        # born after this test spawned it (the kernel's tick is 10 ms), gone before it returned
+        assert t0 - 0.02 <= script["unix_ns"] / 1e9 <= t0 + wall
+        assert script["duration_s"] <= wall + 0.02
+        covered = sum(s["dur_ms"] for s in script["root"]["spans"])
+        assert covered >= 0.95 * script["duration_s"] * 1e3
+        # every RPC of the child carried the script's id: lock and unlock are one trace
+        ids = {t["trace_id"] for t in traced.RING.snapshot(kind="rpc.server")
+               if t["root"]["attrs"]["method"] in ("LeaseAdminToken", "ReleaseAdminToken")}
+        assert ids == {script["trace_id"]}
+    finally:
+        master.stop()
+
+
+def test_the_lock_renewer_carries_the_running_commands_trace(traced, monkeypatch):
+    """A renewal that falls inside a command is one of its `rpcs=` and an
+    `rpc.client` span under it, `thread=` saying it was not the command's own
+    thread; the master records it under the script's id."""
+    import time
+
+    monkeypatch.setattr(shell, "_RENEW_INTERVAL", 0.05)
+    master = MasterServer(port=0, reap_interval=3600)
+    master.start()
+    env = CommandEnv(master.address)
+    slow = shell.register(shell.ShellCommand("slow.command", "sleeps", lambda a, e, w: time.sleep(0.3)))
+    try:
+        run_script(env, "lock; slow.command; unlock", io.StringIO())
+    finally:
+        del shell._REGISTRY[slow.name]
+        env.close()
+        master.stop()
+    (script,) = traced.RING.snapshot(kind="shell.script")
+    command = script["root"]["spans"][1]
+    renewals = [s for s in command["spans"] if s["name"] == "rpc.client"]
+    assert command["attrs"]["command"] == "slow.command" and command["attrs"]["rpcs"] == len(renewals) >= 2
+    assert all(s["attrs"]["method"] == "LeaseAdminToken" and "thread" in s["attrs"] for s in renewals)
+    leases = [t for t in traced.RING.snapshot(kind="rpc.server", limit=1000)
+              if t["root"]["attrs"]["method"] == "LeaseAdminToken"]
+    assert len(leases) >= 3 and {t["trace_id"] for t in leases} == {script["trace_id"]}
