@@ -53,6 +53,7 @@ from seaweedfs_tpu.storage.store import Store
 from seaweedfs_tpu.storage.volume import VolumeReadOnly
 from seaweedfs_tpu.security import tls
 from seaweedfs_tpu.utils import config
+from seaweedfs_tpu.utils.door import Door
 
 _COPY_CHUNK = 1024 * 1024
 _EC_EXTS = [".ecx", ".ecj", ".eci"]
@@ -130,6 +131,7 @@ class VolumeServer:
         self.max_volume_count = max_volume_count
         self._hb_interval = heartbeat_interval
         self._stop = threading.Event()
+        self._hb_door = Door(self._send_heartbeat)  # heartbeat_once
 
         self._grpc = rpc.RpcServer(port=grpc_port, host=host)
         self._grpc.add_service(self._build_service())
@@ -269,6 +271,7 @@ class VolumeServer:
         gates heartbeat_once(): an admin RPC landing after leave must not
         re-register the drained node."""
         self._stop.set()
+        self._hb_door.close()  # whoever waits for a heartbeat: none will come
         try:
             self._masters_fanout("LeaveCluster", {"url": self.url}, timeout=2)
         except Exception:  # noqa: BLE001 — masters may already be gone
@@ -369,11 +372,26 @@ class VolumeServer:
         return ok[0]
 
     def heartbeat_once(self) -> None:
+        """Returns once the masters have answered a full-state heartbeat
+        composed AFTER this call began (a mount, a deletion: the master knows
+        of it before the RPC answers). Every heartbeat, an RPC's or the
+        loop's, leaves through one door (`utils/door.py`), composed and sent
+        one at a time: the master never processes an older state after a
+        newer one (`process_heartbeat` unregisters whatever a heartbeat
+        lacks). A caller that arrives while one is on its way waits for the
+        NEXT, composed after it came, and shares it with whoever else waits
+        (`waiters=` on the span); a lone caller composes and sends at once."""
         if self._stop.is_set():  # left the cluster: never re-register
             return
         # under an RPC that waits for it (a mount, a deletion), a span of
         # that RPC's trace; from the heartbeat loop, nothing
-        with trace_mod.span("vs.heartbeat"):
+        with trace_mod.span("vs.heartbeat") as sp:
+            served = self._hb_door.through()
+            if sp is not None:
+                sp.annotate(waiters=served)
+
+    def _send_heartbeat(self, _callers: list) -> None:
+        if not self._stop.is_set():
             self._masters_fanout("Heartbeat", self._make_heartbeat().to_dict(), timeout=10)
 
     def _master_query(self, method: str, req: dict, timeout: float = 5.0) -> dict:

@@ -77,6 +77,24 @@ class EcGeometryError(ValueError):
         self.details = dict(details or {})
 
 
+#: a mount folds a `.ecj` of at least this many bytes into the `.ecx`
+ECJ_COMPACT_THRESHOLD = 1 << 20
+
+
+def ecj_compaction_due(base_file_name: str, threshold: int = ECJ_COMPACT_THRESHOLD) -> bool:
+    """Whether `EcVolume(base_file_name)` would fold the deletion journal into
+    the `.ecx` (`stripe.compact_ecj`: it unlinks the `.ecj`, and with it
+    whatever was appended after it was read, so no mount of the volume may be
+    taking deletes meanwhile: `Store.mount_ec_volume`)."""
+    ecj_path = base_file_name + ".ecj"
+    return bool(threshold) and os.path.exists(ecj_path) and os.path.getsize(ecj_path) >= threshold
+
+
+class EcVolumeClosed(KeyError):
+    """A delete reached a mount that was closed (unmounted, or replaced by a
+    remount): nothing was journaled; look the volume up again."""
+
+
 class EcVolume:
     def __init__(
         self,
@@ -88,7 +106,7 @@ class EcVolume:
         version: int = 3,
         shard_size: Optional[int] = None,
         warm_on_mount: bool = True,
-        ecj_compact_threshold: int = 1 << 20,
+        ecj_compact_threshold: int = ECJ_COMPACT_THRESHOLD,
         recover_fetch_parallelism: int = 8,
         recover_fetch_deadline: float = 30.0,
         recover_holder_timeout: float = 30.0,
@@ -154,18 +172,18 @@ class EcVolume:
         # mount-time journal compaction: a delete-heavy volume's .ecj is
         # folded into .ecx tombstones once it crosses the threshold, so the
         # journal (and its replay cost) stays bounded over the volume's life
-        ecj_path = base_file_name + ".ecj"
-        if (
-            ecj_compact_threshold
-            and os.path.exists(ecj_path)
-            and os.path.getsize(ecj_path) >= ecj_compact_threshold
-        ):
+        if ecj_compaction_due(base_file_name, ecj_compact_threshold):
             stripe.compact_ecj(base_file_name)
 
         with open(base_file_name + ".ecx", "rb") as f:
             self._index = idx_mod.index_entries_array(f.read())
         self._keys = self._index["key"]
         self._deleted = set(stripe.read_ecj(base_file_name))
+        # close() against delete_needle(): a closed mount journals nothing,
+        # and close() returns only after the appends it found under way
+        self._journal = threading.Condition()
+        self._journaling = 0
+        self._closed = False
 
         self._shard_files = {}
         # shards pulled out of serving by failed integrity verification:
@@ -280,6 +298,10 @@ class EcVolume:
             pass
 
     def close(self) -> None:
+        with self._journal:
+            self._closed = True
+            while self._journaling:
+                self._journal.wait()
         for f in self._shard_files.values():
             f.close()
         self._shard_files.clear()
@@ -493,6 +515,14 @@ class EcVolume:
 
     # -- deletes -------------------------------------------------------------
 
+    def inherit_deletes(self, replaced: "EcVolume") -> None:
+        """Take over the deletions of the mount this one replaces: one it
+        journaled after this mount read the `.ecj` is otherwise unknown here
+        until the next mount. The two share ONE set from here on, so a delete
+        that still reaches `replaced` before it is closed is seen here too."""
+        replaced._deleted |= self._deleted
+        self._deleted = replaced._deleted
+
     def delete_needle(self, needle_id: int) -> bool:
         """Append to the deletion journal (VolumeEcBlobDelete semantics).
         Returns False (and journals nothing) when the needle is absent or
@@ -501,6 +531,15 @@ class EcVolume:
             self.find_needle_from_ecx(needle_id)
         except (NeedleNotFound, NeedleDeleted):
             return False
-        stripe.append_ecj(self.base, needle_id)
-        self._deleted.add(needle_id)
+        with self._journal:
+            if self._closed:
+                raise EcVolumeClosed(f"{self.base}: this mount was closed")
+            self._journaling += 1
+        try:
+            stripe.append_ecj(self.base, needle_id)
+            self._deleted.add(needle_id)
+        finally:
+            with self._journal:
+                self._journaling -= 1
+                self._journal.notify_all()
         return True

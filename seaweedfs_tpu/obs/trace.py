@@ -58,7 +58,7 @@ SPAN_NAMES: dict[str, str] = {
     "master.http": "master HTTP facade route (/dir/assign, /dir/lookup, ...)",
     "shell.script": "one `shell -c` script, from the BIRTH of its process to the end of its last command: the root every command of the script nests under (script= its text, cut to 200 characters); handed to the master by ReportTrace when it ends",
     "shell.start": "first child of shell.script, birth of the process to the first command: interp_ms (birth to the first line of __main__), import_ms (from there until grpc and the shell's own modules are loaded: the command line parsed and security.toml read on the way), connect_ms (CommandEnv, the channel, until the script starts), modules=",
-    "shell.command": "one weed-shell command execution (command, modules loaded at its start, rpcs it made; ec.rebuild without -remote: overlapped= gathers that ran beside the rebuild of the volume before); a root in the REPL, a child of shell.script in a -c script",
+    "shell.command": "one weed-shell command execution (command, modules loaded at its start, rpcs it made; ec.rebuild without -remote: overlapped= gathers that ran beside the rebuild of the volume before; ec.encode: overlapped= cut-overs that began while another volume's was running, ckpt_writes= checkpoint files written); a root in the REPL, a child of shell.script in a -c script",
     "shell.plan": "ec.encode / ec.rebuild before their first state-changing RPC: selection, VolumeList, a VolumeStatus a volume, pick_rebuilder (volumes= planned, rpcs= made)",
     "shell.trace": "the receipt of a ReportTrace on a server whose spans are mirrored into the profiler: one short annotation whose attributes carry the child's tree flat (names, what, t_ns from birth_unix_ns, dur_ns, depth, thread)",
     "rpc.client": "the shell's side of one RPC through CommandEnv.master_call / vs_call (method=, target=; thread= where another thread than the command's made it); as many under a shell.command as its rpcs=",
@@ -109,7 +109,7 @@ SPAN_NAMES: dict[str, str] = {
     "ec.mount": "an EC volume's local shards found and mounted (VolumeEcShardsMount, a rebuild batch's rebuilt shards, the remount of VolumeEcShardsDelete): files opened, the codec's small-read programs warmed",
     "ec.unmount": "VolumeEcShardsDelete's unmount and unlinks (shards= named)",
     "volume.remove": "VolumeDelete: the volume closed and its files unlinked",
-    "vs.heartbeat": "a full-state heartbeat sent NOW and awaited (heartbeat_once under an RPC: the master must know of a mount or a deletion before the RPC answers)",
+    "vs.heartbeat": "a full-state heartbeat composed after the call began, sent and awaited (heartbeat_once under an RPC: the master must know of a mount or a deletion before the RPC answers); heartbeats leave one at a time, so the span holds the wait for the one on its way; waiters= callers the heartbeat served (above 1: shared)",
 }
 
 _ID_RE = re.compile(r"^[0-9a-fA-F][0-9a-fA-F-]{0,63}$")

@@ -6,8 +6,10 @@ thread pool (errgroup analog)."""
 
 from __future__ import annotations
 
+import functools
 import json
 import os
+import threading
 from concurrent import futures
 from typing import Optional, TextIO
 
@@ -22,22 +24,32 @@ from seaweedfs_tpu.shell import (
     parse_flags,
     register,
 )
+from seaweedfs_tpu.utils.door import Door
 
+#: thunks `_parallel` runs at once: a volume's spread copies, a rebuild's
+#: pulls, and the volumes of one `ec.encode` batch whose freezes, and later
+#: whose cut-overs, run side by side (`_encode_batch`)
 _POOL = 8
 
 
 class EncodeCheckpoint:
     """Persisted ec.encode work-list (SURVEY §5: "encode of 10k volumes
     resumes"): a batch over many volumes survives interruption — the rerun
-    skips completed vids. One JSON file, fsync'd after every finished
-    volume, keyed by the volume-selection criteria so a checkpoint from a
-    different selection is never misapplied.
+    skips completed vids. One JSON file, keyed by the volume-selection
+    criteria so a checkpoint from a different selection is never misapplied.
+    `mark(vid)` returns only after a file that holds `vid` has been written,
+    fsync'd and renamed; volumes that finish while another's write is running
+    share the next write (`utils/door.py`: a group commit, nothing deferred).
     [ref: weed/shell/command_ec_encode.go — mount empty; upstream restarts
     from scratch, this is the resume SURVEY §5 calls out as required.]"""
 
     def __init__(self, path: str, selector: dict):
         self.path = path
         self.selector = selector
+        #: the volumes the file on disk holds, and the files written so far
+        self.done: set[int] = set()
+        self.writes = 0
+        self._door = Door(self._write)
 
     def load_done(self) -> set[int]:
         try:
@@ -47,15 +59,22 @@ class EncodeCheckpoint:
             return set()
         if data.get("selector") != self.selector:
             return set()  # different batch criteria: ignore, will overwrite
-        return {int(v) for v in data.get("done", [])}
+        self.done = {int(v) for v in data.get("done", [])}
+        return self.done
 
-    def mark_done(self, done: set[int]) -> None:
-        tmp = self.path + ".tmp"
+    def mark(self, vid: int) -> None:
+        self._door.through(vid)
+
+    def _write(self, vids: list[int]) -> None:
+        done = self.done | set(vids)
+        tmp = self.path + ".tmp"  # the door lets one writer through at a time
         with open(tmp, "w", encoding="utf-8") as f:
             json.dump({"selector": self.selector, "done": sorted(done)}, f)
             f.flush()
             os.fsync(f.fileno())
         os.replace(tmp, self.path)
+        self.done = done
+        self.writes += 1
 
     def finish(self) -> None:
         try:
@@ -130,13 +149,27 @@ def _parallel(work: list) -> None:
             f.result()
 
 
+class _WholeLines:
+    """A command's output where several threads write it: every write (a
+    line, whole) under one lock."""
+
+    def __init__(self, w: TextIO):
+        self._w = w
+        self._mu = threading.Lock()
+
+    def write(self, text: str) -> int:
+        with self._mu:
+            return self._w.write(text)
+
+
 # -- ec.encode ---------------------------------------------------------------
 
 
 #: a batch of a sweep closes at this many volumes or bytes of .dat, whichever
 #: comes first (a volume over the bytes is a batch of one): until a batch's
-#: cut-overs run, its server holds every volume's shards beside its .dat, and
-#: the checkpoint marks nothing of it
+#: cut-overs run (after its one generate RPC, `_POOL` volumes side by
+#: side), its server holds every volume's shards beside its .dat, and the
+#: checkpoint marks nothing of it
 ENCODE_BATCH_MAX_VOLUMES = 16
 ENCODE_BATCH_MAX_BYTES = 16 << 30
 
@@ -172,30 +205,46 @@ def _encode_batch(
     small_block_size: int = 0,
     inline: bool = False,
     on_done=None,
-) -> list[int]:
+) -> tuple[list[int], int]:
     """One source server's volumes of a sweep (a lone `-volumeId` too).
     Each is frozen on every replica (SURVEY.md §3.1); the server generates
     them all in ONE `VolumeEcShardsGenerateBatch`, their rows sharing one
     pipeline's batches (`-inline` finalizes a volume's own encode-on-write
-    state, so there each volume keeps its `VolumeEcShardsGenerate`); then,
-    volume by volume, spread, mount, delete of the original, `on_done(vid)`.
-    A volume that fails anywhere is made writable again and reported, and
-    the others complete. -> the volumes that were not encoded."""
+    state, so there each volume keeps its `VolumeEcShardsGenerate`); then
+    each volume's own cut-over: spread, mount, delete of the original,
+    `on_done(vid)`, in that order. The freezes, and later the cut-overs, of
+    the batch's volumes run side by side, `_POOL` volumes at once (a
+    batch of one on this thread); all of them together keep at most `_POOL`
+    `VolumeEcShardsCopy` in flight against the source server, what one
+    volume's spread alone may put there. A volume that fails anywhere is made
+    writable again and reported, and the others complete.
+    -> (the volumes that were not encoded, the cut-overs that began while
+    another's was running)."""
     src_addr = grpc_addr(plans[0]["locations"][0])
     error: dict[int, str] = {}
     mode: dict[int, str] = {}
+    w = _WholeLines(w)
 
     def fail(plan: dict, e) -> None:
         error[plan["vid"]] = str(e) if isinstance(e, ShellError) else f"{type(e).__name__}: {e}"
 
+    def side_by_side(step, of: list[dict]) -> None:
+        if len(of) > 1:
+            _parallel([functools.partial(step, plan) for plan in of])
+        else:
+            for plan in of:
+                step(plan)
+
     # 1. freeze writes on every replica; a freeze is rolled back below if
     # anything later fails, or the volume is stuck readonly forever
-    for plan in plans:
+    def freeze(plan: dict) -> None:
         try:
             for loc in plan["locations"]:
                 env.vs_call(grpc_addr(loc), "VolumeMarkReadonly", {"volume_id": plan["vid"]})
         except Exception as e:  # noqa: BLE001 — this volume's alone
             fail(plan, e)
+
+    side_by_side(freeze, plans)
     # 2. generate all 14 shards + .ecx of each on the first replica holder
     block_sizes = {}
     if large_block_size:
@@ -237,17 +286,29 @@ def _encode_batch(
             for plan in frozen:
                 fail(plan, e)
     # 3.-5. each volume's own cut-over, or its freeze rolled back
-    for plan in plans:
+    copy_gate = threading.BoundedSemaphore(_POOL)
+    count = threading.Lock()
+    running = overlapped = 0
+
+    def cutover(plan: dict) -> None:
+        nonlocal running, overlapped
         vid = plan["vid"]
         if vid not in error:
+            with count:
+                overlapped += running > 0
+                running += 1
             try:
                 _spread_cutover(
-                    env, nodes, plan["locations"], vid, plan["collection"], w, mode.get(vid)
+                    env, nodes, plan["locations"], vid, plan["collection"], w, mode.get(vid),
+                    copy_gate,
                 )
                 if on_done is not None:
                     on_done(vid)
             except Exception as e:  # noqa: BLE001
                 fail(plan, e)
+            finally:
+                with count:
+                    running -= 1
         if vid in error:
             for loc in plan["locations"]:
                 try:
@@ -255,7 +316,9 @@ def _encode_batch(
                 except Exception:  # noqa: BLE001 — best-effort rollback
                     pass
             w.write(f"ec.encode volume {vid}: NOT encoded: {error[vid]}\n")
-    return sorted(error)
+
+    side_by_side(cutover, plans)
+    return sorted(error), overlapped
 
 
 def _spread_cutover(
@@ -265,10 +328,12 @@ def _spread_cutover(
     vid: int,
     collection: str,
     w: TextIO,
-    gen_mode: Optional[str] = None,
+    gen_mode: Optional[str],
+    copy_gate: threading.BoundedSemaphore,
 ) -> None:
     """One generated volume's cut-over: its shards spread and mounted, then
-    the original and its replicas deleted."""
+    the original and its replicas deleted. `copy_gate` bounds the copies that
+    the cut-overs of one source server's volumes pull from it at once."""
     source = locations[0]
     src_addr = grpc_addr(source)
     # 3. spread: balanced, rack-aware allocation; targets pull from source
@@ -278,17 +343,18 @@ def _spread_cutover(
         def run():
             addr = grpc_addr(node)
             if node["url"] != source["url"]:
-                env.vs_call(
-                    addr,
-                    "VolumeEcShardsCopy",
-                    {
-                        "volume_id": vid,
-                        "collection": collection,
-                        "shard_ids": sids,
-                        "source_data_node": src_addr,
-                        "copy_ecx_file": True,
-                    },
-                )
+                with copy_gate:
+                    env.vs_call(
+                        addr,
+                        "VolumeEcShardsCopy",
+                        {
+                            "volume_id": vid,
+                            "collection": collection,
+                            "shard_ids": sids,
+                            "source_data_node": src_addr,
+                            "copy_ecx_file": True,
+                        },
+                    )
                 env.vs_call(
                     addr,
                     "VolumeEcShardsMount",
@@ -349,17 +415,12 @@ def do_ec_encode(args: list[str], env: CommandEnv, w: TextIO) -> None:
             planning.annotate(volumes=len(planned[1]) if planned else 0, rpcs=env.rpcs - before)
     if planned is None:
         return
-    nodes, plans, ckpt, done = planned
-
-    def on_done(vid: int) -> None:
-        # a volume is done when ITS cut-over is complete, batch or no batch
-        if ckpt is not None:
-            done.add(vid)
-            ckpt.mark_done(done)
+    nodes, plans, ckpt = planned
 
     failed: list[int] = []
+    overlapped = 0
     for batch in _encode_batches(plans):
-        failed += _encode_batch(
+        not_encoded, beside = _encode_batch(
             env,
             nodes,
             batch,
@@ -367,8 +428,12 @@ def do_ec_encode(args: list[str], env: CommandEnv, w: TextIO) -> None:
             large_block_size=fl.largeBlockSize,
             small_block_size=fl.smallBlockSize,
             inline=bool(fl.inline),
-            on_done=on_done,
+            # a volume is done when ITS cut-over is complete, batch or no batch
+            on_done=ckpt.mark if ckpt is not None else None,
         )
+        failed += not_encoded
+        overlapped += beside
+    trace_obs.annotate(overlapped=overlapped, ckpt_writes=ckpt.writes if ckpt is not None else 0)
     if failed:
         raise ShellError(f"ec.encode: volumes {failed} were not encoded")
     if ckpt is not None:
@@ -378,8 +443,7 @@ def do_ec_encode(args: list[str], env: CommandEnv, w: TextIO) -> None:
 def _plan_encode(fl, env: CommandEnv, w: TextIO):
     """`ec.encode` before its first freeze (the `shell.plan` span): the
     topology, the selection, the checkpoint, where each volume lives.
-    -> (nodes, plans, checkpoint or None, the volumes it holds as done), or
-    None where nothing matches."""
+    -> (nodes, plans, checkpoint or None), or None where nothing matches."""
     topo = env.volume_list()
     nodes = env.topology_nodes()
     limit = int(topo.get("volume_size_limit", 0)) or 1
@@ -454,7 +518,7 @@ def _plan_encode(fl, env: CommandEnv, w: TextIO):
         plans.append(
             {"vid": vid, "collection": coll_of[vid], "locations": locations, "size": size_of[vid]}
         )
-    return nodes, plans, ckpt, done
+    return nodes, plans, ckpt
 
 
 register(
@@ -466,10 +530,13 @@ register(
         "\twithout -volumeId a sweep: every selected volume, source server by\n"
         "\tsource server in ONE VolumeEcShardsGenerateBatch (their rows share one\n"
         "\tpipeline's device batches; at most 16 volumes or 16 GiB a batch), then\n"
-        "\teach volume's own cut-over; a volume that fails is made writable again\n"
-        "\tand reported (NOT encoded), the others complete, the command ends in an\n"
-        "\terror naming it; sweeps checkpoint a volume when its cut-over is\n"
-        "\tcomplete and resume on rerun;\n"
+        "\teach volume's own cut-over (spread, mount, delete of the original, in\n"
+        "\tthat order), eight volumes of the batch side by side, so their lines\n"
+        "\tcome in the order they finish; a volume that fails is made writable\n"
+        "\tagain and reported (NOT encoded), the others complete, the command ends\n"
+        "\tin an error naming it; sweeps checkpoint a volume when its cut-over is\n"
+        "\tcomplete (the file fsynced before it counts; volumes that finish\n"
+        "\ttogether share a write) and resume on rerun;\n"
         "\t-inline finalizes from the server's encode-on-write stripe state\n"
         "\t(WEEDTPU_INLINE_EC=on) instead of re-encoding the sealed .dat —\n"
         "\tbyte-identical shards, warm fallback when no usable inline state",

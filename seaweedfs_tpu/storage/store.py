@@ -11,7 +11,7 @@ import re
 import threading
 from typing import Optional
 
-from seaweedfs_tpu.ec.ec_volume import EcVolume
+from seaweedfs_tpu.ec.ec_volume import ECJ_COMPACT_THRESHOLD, EcVolume, ecj_compaction_due
 from seaweedfs_tpu.ec.shard_bits import EcVolumeInfo, ShardBits
 from seaweedfs_tpu.utils import glog
 from seaweedfs_tpu.ec import stripe
@@ -88,6 +88,8 @@ class Store:
         # sorted_file binary-searches a persistent .sdx sidecar
         self.needle_map_kind = needle_map_kind
         self._lock = threading.RLock()
+        #: vid -> the lock its EC mounts and unmounts take (mount_ec_volume)
+        self._ec_mounting: dict[int, threading.Lock] = {}
         #: optional post-append hook `callback(vid)`, fired after every
         #: acked needle write/delete (both are .dat appends) — the inline-EC
         #: ingest manager polls its stripe builders through this seam. Must
@@ -195,23 +197,53 @@ class Store:
             raise PermissionError(f"needle {needle_id:x}: cookie mismatch")
         return n
 
-    def mount_ec_volume(self, vid: int, base_path: str) -> EcVolume:
+    def _ec_mount_lock(self, vid: int) -> threading.Lock:
         with self._lock:
-            loc = next(
-                (l for l in self.locations if os.path.dirname(base_path) == l.directory),
-                self.locations[0],
+            return self._ec_mounting.setdefault(vid, threading.Lock())
+
+    def mount_ec_volume(self, vid: int, base_path: str) -> EcVolume:
+        """(Re)mount an EC volume from its files. EcVolume() opens the local
+        shards and loads the `.ecx`, so it runs OUTSIDE the store lock (same
+        discipline as mount_volume): mounts of different volumes run side by
+        side. Mounts (and unmounts) of ONE vid go one at a time. A remount
+        is built while the mount it replaces still serves; the store lock
+        covers the swap into the map, and what was replaced is closed after
+        it. The one remount that takes the old mount out of serving FIRST is
+        the one whose constructor will fold the deletion journal into the
+        `.ecx` (`ecj_compaction_due`): that unlinks the `.ecj`, and a delete
+        the old mount journaled meanwhile, fsynced and acknowledged, would
+        be unlinked with it. A mount built beside a serving one leaves the
+        journal alone."""
+        loc = next(
+            (l for l in self.locations if os.path.dirname(base_path) == l.directory),
+            self.locations[0],
+        )
+        with self._ec_mount_lock(vid):
+            old = loc.ec_volumes.get(vid)
+            if old is not None and ecj_compaction_due(base_path):
+                with self._lock:
+                    del loc.ec_volumes[vid]
+                old.close()  # returns after the deletes it was journaling
+                old = None
+            ev = EcVolume(
+                base_path,
+                encoder=self.encoder,
+                ecj_compact_threshold=ECJ_COMPACT_THRESHOLD if old is None else 0,
             )
-            old = loc.ec_volumes.pop(vid, None)
-            if old is not None:
-                old.close()
-            ev = EcVolume(base_path, encoder=self.encoder)
-            loc.ec_volumes[vid] = ev
-            return ev
+            with self._lock:
+                if old is not None:
+                    # a delete the old mount took after `ev` read the journal
+                    ev.inherit_deletes(old)
+                loc.ec_volumes[vid] = ev
+        if old is not None:
+            old.close()
+        return ev
 
     def unmount_ec_volume(self, vid: int) -> None:
-        with self._lock:
-            for loc in self.locations:
-                ev = loc.ec_volumes.pop(vid, None)
+        with self._ec_mount_lock(vid):
+            with self._lock:
+                gone = [loc.ec_volumes.pop(vid, None) for loc in self.locations]
+            for ev in gone:
                 if ev is not None:
                     ev.close()
 
@@ -322,7 +354,9 @@ class Store:
     def volume_infos(self) -> list[dict]:
         out = []
         for loc in self.locations:
-            for vid, v in loc.volumes.items():
+            # list(): heartbeats are composed beside mounts and deletes of
+            # other volumes, and a dict that changes size under an iteration raises
+            for vid, v in list(loc.volumes.items()):
                 # lock-free snapshot: the heartbeat must not block behind a
                 # long-running compaction's volume lock
                 size, count, garbage = v.stats_snapshot()
@@ -347,7 +381,7 @@ class Store:
     def ec_volume_infos(self) -> list[EcVolumeInfo]:
         out = []
         for loc in self.locations:
-            for vid, ev in loc.ec_volumes.items():
+            for vid, ev in list(loc.ec_volumes.items()):
                 parsed = parse_base_name(os.path.basename(ev.base))
                 out.append(
                     EcVolumeInfo(

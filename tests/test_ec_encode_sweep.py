@@ -274,7 +274,7 @@ class Sweep:
     """master + one server that holds `vids` as sealed volumes, and a twin of
     each volume's files encoded alone (`generate_ec_files`) to compare with."""
 
-    def __init__(self, tmp_path, backend, vids=VIDS):
+    def __init__(self, tmp_path, backend, vids=VIDS, heartbeat_interval=0.2):
         self.vids = list(vids)
         self.master = MasterServer(port=0, reap_interval=3600)
         self.master.start()
@@ -290,7 +290,7 @@ class Sweep:
             self.dats[vid] = _read(self.base(vid) + ".dat")
             stripe.generate_ec_files(os.path.join(self.twin, str(vid)), large_block_size=LARGE,
                                      small_block_size=SMALL, encoder=Encoder(10, 4, backend="numpy"))
-        self.server = VolumeServer([self.dir], self.master.address, heartbeat_interval=0.2,
+        self.server = VolumeServer([self.dir], self.master.address, heartbeat_interval=heartbeat_interval,
                                    max_volume_count=40, encoder=new_encoder(backend=backend))
         self.server.start()
         self.client = MasterClient(self.master.address)
@@ -337,8 +337,8 @@ def make_sweep(tmp_path, monkeypatch):
     monkeypatch.chdir(tmp_path)  # the sweep's default checkpoint lands here
     made = []
 
-    def make(backend, vids=VIDS):
-        made.append(Sweep(tmp_path, backend, vids))
+    def make(backend, vids=VIDS, **kw):
+        made.append(Sweep(tmp_path, backend, vids, **kw))
         return made[-1]
 
     yield make
@@ -415,11 +415,14 @@ def test_inline_keeps_the_single_volume_rpc(make_sweep):
 
 
 def test_every_volume_is_frozen_before_it_is_read_and_deleted_after_its_own_mount(make_sweep, monkeypatch):
-    """The loop's order, per volume, inside the batch: when the pipeline
-    starts every volume of the batch is read-only; a volume's `VolumeDelete`
-    goes out only when its 14 shards, `.ecx` and `.eci` are on disk and its
-    EC volume is mounted, while later volumes still have their `.dat`."""
-    c = make_sweep("numpy", vids=[1, 2, 3])
+    """The per-volume rule, in whatever order the volumes' cut-overs run side
+    by side: when the pipeline starts every volume of the batch is read-only;
+    a volume's `VolumeDelete` goes out only when its own 14 shards, `.ecx` and
+    `.eci` are on disk beside its `.dat` and its EC volume is mounted; and at
+    least two cut-overs overlapped (`overlapped=` on the command's span). The
+    server's own loop never beats here: that the master lists every shard when
+    the command returns is the doing of the heartbeats the RPCs waited for."""
+    c = make_sweep("numpy", vids=[1, 2, 3], heartbeat_interval=3600)
     frozen = []
     real_batch = stripe.write_ec_files_batch
 
@@ -435,15 +438,25 @@ def test_every_volume_is_frozen_before_it_is_read_and_deleted_after_its_own_moun
         if method == "VolumeDelete":
             vid = int(req["volume_id"])
             seen.append((vid, all(os.path.exists(c.base(vid) + ext) for ext in EXTS + [".ecx", ".dat"]),
-                         c.server.store.get_ec_volume(vid) is not None,
-                         [v for v in (1, 2, 3) if os.path.exists(c.base(v) + ".dat")]))
+                         c.server.store.get_ec_volume(vid) is not None))
         return real_call(addr, method, req, **kw)
 
     monkeypatch.setattr(c.env, "vs_call", vs_call)
+    trace.RING.clear()
     out, err = c.shell(f"lock; ec.encode {FLAGS}; unlock")
     assert err is None, out
     assert frozen == [True, True, True]
-    assert seen == [(1, True, True, [1, 2, 3]), (2, True, True, [2, 3]), (3, True, True, [3])]
+    assert sorted(seen) == [(1, True, True), (2, True, True), (3, True, True)]
+    (root,) = [s for t in trace.RING.snapshot(kind="shell.script", limit=1000) for s in trace.iter_spans(t)
+               if s["name"] == "shell.command" and s["attrs"].get("command") == "ec.encode"]
+    assert 1 <= root["attrs"]["overlapped"] <= 2
+    assert 1 <= root["attrs"]["ckpt_writes"] <= 3
+    # every line whole: a line a volume and the batch's, whatever the order
+    assert sorted(ln for ln in out.splitlines() if ln.startswith("ec.encode")) == sorted(
+        [f"ec.encode batch on {c.server.url}: 3 volumes in 1 batches"]
+        + [f"ec.encode volume {v}: spread {c.server.url}={','.join(map(str, range(14)))}" for v in (1, 2, 3)])
+    for vid in (1, 2, 3):
+        c.encoded_as_alone(vid)
 
 
 @pytest.mark.parametrize("where", ["generate", "cutover"])

@@ -642,8 +642,8 @@ NOT_IN_THE_CHILD = (
     "http.client", "urllib.request",
 )
 # the package's own modules in a `lock; ec.rebuild; unlock` child: the trace it
-# hands over (PR 42) brought none (the installation's own add to them: 241
-# modules in all on the chip's host, 227 here, as before)
+# hands over (PR 42) brought none, the door of the checkpoint's marks (PR 43) one
+# (the installation's own add to them: 242 modules in all on the chip's host)
 PACKAGE_MODULES_IN_THE_EC_CHILD = {
     "seaweedfs_tpu", "seaweedfs_tpu.cluster", "seaweedfs_tpu.cluster.client", "seaweedfs_tpu.command",
     "seaweedfs_tpu.command.servers", "seaweedfs_tpu.ec", "seaweedfs_tpu.ec.constants", "seaweedfs_tpu.ec.placement",
@@ -651,7 +651,7 @@ PACKAGE_MODULES_IN_THE_EC_CHILD = {
     "seaweedfs_tpu.pb.wire", "seaweedfs_tpu.rpc", "seaweedfs_tpu.security", "seaweedfs_tpu.security.guard",
     "seaweedfs_tpu.security.jwt", "seaweedfs_tpu.security.tls", "seaweedfs_tpu.shell",
     "seaweedfs_tpu.shell.command_cluster", "seaweedfs_tpu.shell.command_ec", "seaweedfs_tpu.stats",
-    "seaweedfs_tpu.utils", "seaweedfs_tpu.utils.config", "seaweedfs_tpu.utils.glog",
+    "seaweedfs_tpu.utils", "seaweedfs_tpu.utils.config", "seaweedfs_tpu.utils.door", "seaweedfs_tpu.utils.glog",
 }
 # `python -m seaweedfs_tpu <argv>` is `__main__.main(argv)`. The master is real
 # and has no volume server: `ec.encode` / `ec.decode` of a volume nobody holds
