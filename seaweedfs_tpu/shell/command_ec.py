@@ -106,24 +106,28 @@ def allocate_shards(
     nodes: list[dict],
     total: int = TOTAL_SHARDS_COUNT,
     data_shards: int = DATA_SHARDS_COUNT,
+    given: Optional[dict[str, int]] = None,
 ) -> dict[str, list[int]]:
     """Balanced, FAILURE-DOMAIN-CAPPED spread of `total` shard ids over
     nodes — the shared `ec/placement.py` planner: each shard goes to the
     least-loaded node whose rack still has headroom under the
     no-domain-holds-more-than-m cap (the invariant that makes a whole-
     rack loss survivable by construction); on topologies with too few
-    racks the cap relaxes minimally instead of failing."""
+    racks the cap relaxes minimally instead of failing. `given` (url ->
+    shards) is what the caller has planned onto a node since `nodes` was
+    read, counted into its load: the earlier volumes of a sweep."""
     if not nodes:
         raise ShellError("no volume servers available")
     from seaweedfs_tpu.ec import placement
     from seaweedfs_tpu.utils import config as _config
 
+    given = given or {}
     return placement.plan_spread(
         nodes,
         total,
         max(1, total - data_shards),
         cap_override=int(_config.env("WEEDTPU_PLACEMENT_MAX_PER_DOMAIN")),
-        load_of=_node_ec_load,
+        load_of=lambda n: _node_ec_load(n) + given.get(n["url"], 0),
     )
 
 
@@ -211,8 +215,9 @@ def _encode_batch(
     them all in ONE `VolumeEcShardsGenerateBatch`, their rows sharing one
     pipeline's batches (`-inline` finalizes a volume's own encode-on-write
     state, so there each volume keeps its `VolumeEcShardsGenerate`); then
-    each volume's own cut-over: spread, mount, delete of the original,
-    `on_done(vid)`, in that order. The freezes, and later the cut-overs, of
+    each volume's own cut-over: spread (where the sweep planned it:
+    `plan["alloc"]`), mount, delete of the original, `on_done(vid)`, in
+    that order. The freezes, and later the cut-overs, of
     the batch's volumes run side by side, `_POOL` volumes at once (a
     batch of one on this thread); all of them together keep at most `_POOL`
     `VolumeEcShardsCopy` in flight against the source server, what one
@@ -300,7 +305,7 @@ def _encode_batch(
             try:
                 _spread_cutover(
                     env, nodes, plan["locations"], vid, plan["collection"], w, mode.get(vid),
-                    copy_gate,
+                    copy_gate, plan["alloc"],
                 )
                 if on_done is not None:
                     on_done(vid)
@@ -330,14 +335,15 @@ def _spread_cutover(
     w: TextIO,
     gen_mode: Optional[str],
     copy_gate: threading.BoundedSemaphore,
+    alloc: dict[str, list[int]],
 ) -> None:
-    """One generated volume's cut-over: its shards spread and mounted, then
+    """One generated volume's cut-over: its shards spread as `alloc` says
+    (url -> shard ids: the sweep's plan, `_plan_encode`) and mounted, then
     the original and its replicas deleted. `copy_gate` bounds the copies that
     the cut-overs of one source server's volumes pull from it at once."""
     source = locations[0]
     src_addr = grpc_addr(source)
-    # 3. spread: balanced, rack-aware allocation; targets pull from source
-    alloc = allocate_shards(nodes)
+    # 3. spread: targets pull from source
 
     def copy_and_mount(node: dict, sids: list[int]):
         def run():
@@ -433,7 +439,19 @@ def do_ec_encode(args: list[str], env: CommandEnv, w: TextIO) -> None:
         )
         failed += not_encoded
         overlapped += beside
-    trace_obs.annotate(overlapped=overlapped, ckpt_writes=ckpt.writes if ckpt is not None else 0)
+    # the shards the sweep left on each server, most first (level where the
+    # plan was), and the copies that took them there: one a volume and target
+    spread = {n["url"]: 0 for n in nodes}
+    copies = 0
+    for plan in plans:
+        if plan["vid"] not in failed:
+            for url, sids in plan["alloc"].items():
+                spread[url] += len(sids)
+                copies += url != plan["locations"][0]["url"]
+    trace_obs.annotate(
+        overlapped=overlapped, ckpt_writes=ckpt.writes if ckpt is not None else 0, copies=copies,
+        spread="/".join(map(str, sorted(spread.values(), reverse=True))),
+    )
     if failed:
         raise ShellError(f"ec.encode: volumes {failed} were not encoded")
     if ckpt is not None:
@@ -442,7 +460,12 @@ def do_ec_encode(args: list[str], env: CommandEnv, w: TextIO) -> None:
 
 def _plan_encode(fl, env: CommandEnv, w: TextIO):
     """`ec.encode` before its first freeze (the `shell.plan` span): the
-    topology, the selection, the checkpoint, where each volume lives.
+    topology, the selection, the checkpoint, where each volume lives and
+    where its shards will (`alloc`: the spread of ALL the command's volumes
+    is planned here, from the one topology snapshot, each volume's with the
+    shards given to the volumes before it counted into every server's load,
+    so a sweep comes out level and not every volume on the same servers; a
+    volume that later fails keeps its share, the others their plan).
     -> (nodes, plans, checkpoint or None), or None where nothing matches."""
     topo = env.volume_list()
     nodes = env.topology_nodes()
@@ -508,6 +531,7 @@ def _plan_encode(fl, env: CommandEnv, w: TextIO):
             w.write(f"ec.encode: resuming, {len(done)} volume(s) already done\n")
     # plan first: what is left of the selection, where each volume lives
     plans: list[dict] = []
+    given: dict[str, int] = {}
     for vid in vids:
         if vid in done:
             w.write(f"ec.encode volume {vid}: skip (checkpointed)\n")
@@ -515,8 +539,12 @@ def _plan_encode(fl, env: CommandEnv, w: TextIO):
         locations = _volume_locations(nodes, vid)
         if not locations:
             raise ShellError(f"volume {vid} not found on any node")
+        alloc = allocate_shards(nodes, given=given)
+        for url, sids in alloc.items():
+            given[url] = given.get(url, 0) + len(sids)
         plans.append(
-            {"vid": vid, "collection": coll_of[vid], "locations": locations, "size": size_of[vid]}
+            {"vid": vid, "collection": coll_of[vid], "locations": locations, "size": size_of[vid],
+             "alloc": alloc}
         )
     return nodes, plans, ckpt
 
@@ -527,7 +555,10 @@ register(
         "ec.encode -volumeId <id> | -collection <name> [-fullPercent 95] "
         "[-quietFor <secs>] [-force] [-inline] [-checkpoint <file>]\n"
         "\tencode a volume into 14 EC shards, spread them, delete the original;\n"
-        "\twithout -volumeId a sweep: every selected volume, source server by\n"
+        "\twithout -volumeId a sweep: the spread of ALL its volumes is planned\n"
+        "\tbefore the first is frozen, each volume's with the shards already given\n"
+        "\tto the others counted, so the sweep leaves the servers level (no rack\n"
+        "\tover 4 of a volume's 14, as ever); every selected volume, source server by\n"
         "\tsource server in ONE VolumeEcShardsGenerateBatch (their rows share one\n"
         "\tpipeline's device batches; at most 16 volumes or 16 GiB a batch), then\n"
         "\teach volume's own cut-over (spread, mount, delete of the original, in\n"
