@@ -33,6 +33,8 @@ class DataNode:
         self.max_volume_count = hb.max_volume_count
         self.volumes: dict[int, VolumeInformation] = {}
         self.ec_shards: dict[int, ShardBits] = {}
+        # vid -> the geometry THIS node's last heartbeat claimed for it
+        self.ec_geometry: dict[int, dict] = {}
         self.ec_backend: dict = dict(hb.ec_backend)
         self.last_seen = time.monotonic()
 
@@ -127,7 +129,11 @@ class Topology:
         self.ec_collections: dict[int, str] = {}
         # per-volume code geometry + shard size, from heartbeats: the
         # repair scheduler ranks stripes by bytes at risk and computes
-        # missing counts against the VOLUME's (k, k+m), not the legacy 14
+        # missing counts against the VOLUME's (k, k+m), not the legacy 14,
+        # and `ec.rebuild` plans from it (`to_dict`). Where holders
+        # disagree (stale old-geometry shards left beside a converted
+        # volume) the holder of the most shards is believed, whoever
+        # heartbeated last: `_settle_geometry`
         self.ec_geometry: dict[int, dict] = {}
         self.max_volume_id = 0
         # optional observer (the master's repair scheduler): called OUTSIDE
@@ -166,6 +172,7 @@ class Topology:
                 self._layout_for_volume(vi).register(vi, node)
 
             new_shards: dict[int, ShardBits] = {}
+            claims: dict[int, dict] = {}
             for ed in hb.ec_shards:
                 info = EcVolumeInfo.from_dict(ed)
                 new_shards[info.volume_id] = info.shard_bits
@@ -173,7 +180,7 @@ class Topology:
                 if getattr(info, "collection", ""):
                     self.ec_collections[info.volume_id] = info.collection
                 if info.total_shards or info.shard_size:
-                    self.ec_geometry[info.volume_id] = {
+                    claims[info.volume_id] = {
                         "data_shards": info.data_shards,
                         "total_shards": info.total_shards,
                         "shard_size": info.shard_size,
@@ -182,7 +189,18 @@ class Topology:
                 if bits.minus(new_shards.get(vid, ShardBits(0))):
                     shrank = True  # some shard this node held is gone
             self._sync_ec_shards(node, new_shards)
+            # a claim that differs from what is believed, or a holder whose
+            # share of the volume changed: who is believed is decided anew
+            unsettled = [
+                vid
+                for vid in set(claims) | set(node.ec_geometry)
+                if claims.get(vid) != self.ec_geometry.get(vid)
+                or new_shards.get(vid) != node.ec_shards.get(vid)
+            ]
             node.ec_shards = new_shards
+            node.ec_geometry = claims
+            for vid in unsettled:
+                self._settle_geometry(vid)
         if shrank and self.on_ec_shrink is not None:
             try:
                 self.on_ec_shrink()
@@ -211,6 +229,22 @@ class Topology:
                 self.ec_collections.pop(vid, None)
                 self.ec_geometry.pop(vid, None)
 
+    def _settle_geometry(self, vid: int) -> None:
+        """`ec_geometry[vid]` from the holders' claims: the claim of the
+        node that holds the most shards of the volume (the first such node,
+        as `ec.rebuild` used to pick its `VolumeStatus` witness), so a stale
+        holder's later heartbeat never puts an old geometry back."""
+        best = None
+        for node in self.nodes.values():
+            claim = node.ec_geometry.get(vid)
+            held = node.ec_shards.get(vid, ShardBits(0)).shard_id_count()
+            if claim and held and (best is None or held > best[0]):
+                best = (held, claim)
+        if best is None:
+            self.ec_geometry.pop(vid, None)
+        else:
+            self.ec_geometry[vid] = best[1]
+
     def unregister_node(self, url: str) -> None:
         with self._lock:
             node = self.nodes.pop(url, None)
@@ -220,6 +254,8 @@ class Topology:
                 self._layout_for_volume(vi).unregister(vi.id, node)
             held_ec = bool(node.ec_shards)
             self._sync_ec_shards(node, {})
+            for vid in node.ec_geometry:
+                self._settle_geometry(vid)
         if held_ec and self.on_ec_shrink is not None:
             try:
                 self.on_ec_shrink()
@@ -342,5 +378,8 @@ class Topology:
                 },
                 "ec_collections": {
                     str(vid): coll for vid, coll in self.ec_collections.items()
+                },
+                "ec_geometry": {
+                    str(vid): dict(geo) for vid, geo in self.ec_geometry.items()
                 },
             }

@@ -2171,6 +2171,66 @@ class VolumeServer:
             )
             return resp
 
+    def _plan_batch_volume(self, v: dict, executor, run) -> tuple[Optional[dict], str]:
+        """One volume of a `VolumeEcShardsRebuildBatch`, planned under its
+        maintenance lock (the caller holds it): a FRESH holder map, what is
+        missing, the survivors chosen, the shard size they agree on, a slab
+        source a survivor. -> (its job for `stripe.rebuild_ec_files_batch`,
+        "") or (None, its soft error), the sources it had opened closed.
+        `run`: the batch's run span, under which the `rebuild.plan` span
+        goes whichever thread this runs on."""
+        vid = int(v["volume_id"])
+        collection = v.get("collection", "")
+        sources: dict[int, stripe.SlabSource] = {}
+        with trace_mod.attach(run), trace_mod.span("rebuild.plan", volume=vid):
+            try:
+                base = self._base_path_for(vid, collection)
+                # a rebuild wants the freshest holder map, not a TTL-stale one
+                self._invalidate_shard_locations(vid)
+                locs = self._lookup_shard_locations(vid)
+                local = set(stripe.find_local_shards(base))
+                present = sorted(local | set(locs))
+                enc = stripe.encoder_for_base(base, self.store.encoder)
+                missing = [s for s in range(enc.total_shards) if s not in present]
+                if not missing:
+                    return {"base": base, "sources": {}, "shard_size": 0,
+                            "missing": [], "encoder": enc}, ""
+                if len(present) < enc.data_shards:
+                    return None, (
+                        f"only {len(present)} survivors reachable, "
+                        f"need {enc.data_shards}"
+                    )
+                holders = sorted({a for aa in locs.values() for a in aa})
+                self._ensure_ec_index_files(vid, collection, base, holders)
+                chosen = present[: enc.data_shards]
+                # a decode none of whose survivors crosses the network
+                # is planned from the local files' own lengths, which
+                # have to agree: no holder is asked
+                all_local = local.issuperset(chosen)
+                trace_mod.annotate(local=all_local)
+                shard_size, _caps = self._resolve_shard_size(
+                    vid, base, local, [] if all_local else holders
+                )
+                for s in chosen:
+                    if s in local:
+                        sources[s] = stripe.LocalSlabSource(
+                            stripe.shard_file_name(base, s)
+                        )
+                sources.update(
+                    self._remote_slab_sources(
+                        vid, [s for s in chosen if s not in local], executor
+                    )
+                )
+                return {"base": base, "sources": sources, "shard_size": shard_size,
+                        "missing": missing, "encoder": enc}, ""
+            except Exception as e:  # noqa: BLE001 — soft per-volume
+                # sources opened before the failure (local survivor
+                # handles) must not leak fds: the post-run cleanup
+                # only reaches the jobs that were planned
+                for src in sources.values():
+                    src.close()
+                return None, f"{type(e).__name__}: {e}"[:300]
+
     def _rpc_ec_rebuild_batch(self, req: dict, ctx) -> dict:
         """VolumeEcShardsRebuildBatch: this node rebuilds MANY volumes'
         missing shards in one call — the fleet scheduler's dispatch unit,
@@ -2181,7 +2241,8 @@ class VolumeServer:
         the admission-gated bulk read; a volume whose chosen survivors are
         all local (what the shell sends: they never left) is
         planned from its files alone, as `rebuild_ec_files` plans it, and
-        asks no holder anything), then the volumes run group-major through
+        asks no holder anything; `_plan_batch_volume`, the plans of a batch
+        of several side by side), then the volumes run group-major through
         ONE width-packed decode pipeline (`stripe.rebuild_ec_files_batch`).
         Rebuilt shards mount here and the delta heartbeats immediately.
         Per-volume failures are soft (reported in `results[].error`); the
@@ -2206,76 +2267,38 @@ class VolumeServer:
         )
         with ExitStack() as locks, trace_mod.ensure("rebuild.run", klass="maint"):
             trace_mod.annotate(batch=len(vols))
+            run = trace_mod.current()
             # per-volume maintenance locks, vid-sorted so concurrent
-            # batches can never deadlock on each other — but PLANNING runs
-            # in request order below: the scheduler sent the batch in
-            # priority order, and job order becomes the block order of the
-            # fused dispatch (2-missing blocks before 1-missing)
+            # batches can never deadlock on each other, all of them before
+            # any plan starts. The PLANS of a batch of several run side by
+            # side on the batch's executor (a plan is a round trip to the
+            # master, a few dozen file-system calls and, for remote
+            # survivors, a `VolumeStatus` a holder: waits, not work), but
+            # are gathered in REQUEST order: the scheduler sent the batch
+            # in priority order, and job order becomes the block order of
+            # the fused dispatch (2-missing blocks before 1-missing)
             for v in sorted(vols, key=lambda d: int(d["volume_id"])):
                 locks.enter_context(self.maintenance_lock(int(v["volume_id"])))
-            for v in vols:
+            if len(vols) > 1:
+                planned = list(
+                    executor.map(lambda v: self._plan_batch_volume(v, executor, run), vols)
+                )
+            else:
+                planned = [self._plan_batch_volume(vols[0], executor, run)]
+            for v, (job, error) in zip(vols, planned):
                 vid = int(v["volume_id"])
-                collection = v.get("collection", "")
-                sources: dict[int, stripe.SlabSource] = {}
-                try:
-                    base = self._base_path_for(vid, collection)
-                    self._invalidate_shard_locations(vid)
-                    locs = self._lookup_shard_locations(vid)
-                    local = set(stripe.find_local_shards(base))
-                    present = sorted(local | set(locs))
-                    enc = stripe.encoder_for_base(base, self.store.encoder)
-                    missing = [
-                        s for s in range(enc.total_shards) if s not in present
-                    ]
-                    if not missing:
-                        meta.setdefault(base, {"vid": vid, "collection": collection})
-                        jobs.append(
-                            {"base": base, "sources": {}, "shard_size": 0,
-                             "missing": [], "encoder": enc}
-                        )
-                        continue
-                    if len(present) < enc.data_shards:
-                        errors[vid] = (
-                            f"only {len(present)} survivors reachable, "
-                            f"need {enc.data_shards}"
-                        )
-                        continue
-                    holders = sorted({a for aa in locs.values() for a in aa})
-                    self._ensure_ec_index_files(vid, collection, base, holders)
-                    chosen = present[: enc.data_shards]
-                    # a decode none of whose survivors crosses the network
-                    # is planned from the local files' own lengths, which
-                    # have to agree: no holder is asked
-                    shard_size, _caps = self._resolve_shard_size(
-                        vid, base, local, [] if local.issuperset(chosen) else holders
-                    )
-                    for s in chosen:
-                        if s in local:
-                            sources[s] = stripe.LocalSlabSource(
-                                stripe.shard_file_name(base, s)
-                            )
-                    sources.update(
-                        self._remote_slab_sources(
-                            vid, [s for s in chosen if s not in local], executor
-                        )
-                    )
-                    meta[base] = {"vid": vid, "collection": collection}
-                    jobs.append(
-                        {
-                            "base": base,
-                            "sources": sources,
-                            "shard_size": shard_size,
-                            "missing": missing,
-                            "encoder": enc,
-                        }
-                    )
-                except Exception as e:  # noqa: BLE001 — soft per-volume
-                    # sources opened before the failure (local survivor
-                    # handles) must not leak fds: the post-run cleanup
-                    # only reaches jobs that were actually appended
-                    for src in sources.values():
-                        src.close()
-                    errors[vid] = f"{type(e).__name__}: {e}"[:300]
+                if job is None:
+                    errors[vid] = error
+                    continue
+                named = {"vid": vid, "collection": v.get("collection", "")}
+                if job["missing"]:
+                    meta[job["base"]] = named
+                else:
+                    meta.setdefault(job["base"], named)
+                jobs.append(job)
+            trace_mod.annotate(
+                planned=len(vols), plan_ms=round((time.monotonic() - t0) * 1e3, 1)
+            )
             try:
                 res = stripe.rebuild_ec_files_batch(jobs, **tuning)
                 trace_mod.annotate(signature_groups=res["signature_groups"])

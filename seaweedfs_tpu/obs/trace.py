@@ -77,7 +77,8 @@ SPAN_NAMES: dict[str, str] = {
     "ec.copy.file": "one file of a copy: its stream from the source written to `.cpy`, then fsync + rename (ext, bytes); self time is the stream",
     "ec.copy.fsync": "flush + fsync of one copied file, apart from its stream",
     "ec.copy.serve": "VolumeEcShardFileCopy on the source: one file read and streamed out (ext, bytes)",
-    "rebuild.run": "one rebuild pipeline: a whole volume's (local or distributed), or a VolumeEcShardsRebuildBatch's over many (batch= volumes, signature_groups=); ring= says whether its staging ring was reused",
+    "rebuild.run": "one rebuild pipeline: a whole volume's (local or distributed), or a VolumeEcShardsRebuildBatch's over many (batch= volumes, signature_groups=, planned= volumes planned side by side, plan_ms= the RPC's begin to the pipeline's start); ring= says whether its staging ring was reused",
+    "rebuild.plan": "one volume of a VolumeEcShardsRebuildBatch planned (volume=; local= true where no survivor crosses the network): a fresh LookupEcVolume (its ec.lookup child), the local files, the shard size, a slab source a survivor; the plans of a batch of several run side by side on the batch's executor, all before the first rebuild.stage",
     "rebuild.stage": "staging-ring fill for one rebuild batch (disk/wire): its lane reads queued, the drain it runs ahead of (nested), the wait for the reads",
     "rebuild.read": "one survivor's slab read into its staging row (child of rebuild.stage; on a lane thread where the source allows)",
     "rebuild.wait": "the calling thread blocked in a join of lane tasks (a batch's reads, the last drain's writes)",

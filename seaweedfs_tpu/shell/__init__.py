@@ -206,11 +206,14 @@ class CommandEnv:
     def volume_list(self) -> dict:
         return self.master_call("VolumeList", {})
 
-    def topology_nodes(self) -> list[dict]:
+    def topology_nodes(self, topo: Optional[dict] = None) -> list[dict]:
         """Flatten VolumeList's dc -> rack -> node tree, annotating each
-        node dict with its dc/rack."""
+        node dict with its dc/rack. `topo`: an answer the caller has
+        already (no second `VolumeList`)."""
         out = []
-        for dc, racks in self.volume_list().get("data_centers", {}).items():
+        if topo is None:
+            topo = self.volume_list()
+        for dc, racks in topo.get("data_centers", {}).items():
             for rack, nodes in racks.items():
                 for nd in nodes:
                     nd = dict(nd)

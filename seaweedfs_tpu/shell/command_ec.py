@@ -652,12 +652,12 @@ def _copy_missing_to(env: CommandEnv, node: dict, vid: int, collection: str,
     return copied
 
 
-def _ec_collections(env: CommandEnv) -> dict[int, str]:
-    """vid -> collection, from the master's EC registry."""
-    return {
-        int(vid): coll
-        for vid, coll in env.volume_list().get("ec_collections", {}).items()
-    }
+def _ec_collections(env: CommandEnv, topo: Optional[dict] = None) -> dict[int, str]:
+    """vid -> collection, from the master's EC registry (`topo`: a
+    `VolumeList` answer the caller has already)."""
+    if topo is None:
+        topo = env.volume_list()
+    return {int(vid): coll for vid, coll in topo.get("ec_collections", {}).items()}
 
 
 def pick_rebuilder(
@@ -686,7 +686,8 @@ def pick_rebuilder(
 
 def do_ec_rebuild(args: list[str], env: CommandEnv, w: TextIO) -> None:
     """Plan every EC volume of the selection (what is missing, who holds
-    what, the geometry, the rebuilder), then rebuild. First, rebuilder by
+    what, the geometry, the rebuilder: `_plan_rebuild`, one `VolumeList`),
+    then rebuild. First, rebuilder by
     rebuilder, the volumes whose survivors are ALL on their rebuilder
     already go together in ONE `VolumeEcShardsRebuildBatch` (a lone one
     too), whose packed pipeline fills and drains once for all of them.
@@ -727,11 +728,15 @@ def do_ec_rebuild(args: list[str], env: CommandEnv, w: TextIO) -> None:
 
 def _plan_rebuild(fl, env: CommandEnv, w: TextIO) -> dict[str, list[dict]]:
     """`ec.rebuild` before its first copy or rebuild (the `shell.plan`
-    span): every EC volume of the selection that misses shards, with who
-    holds what, its geometry (a `VolumeStatus` a volume) and its rebuilder.
+    span), from ONE `VolumeList`: every EC volume of the selection that
+    misses shards, with who holds what, its geometry (the master's
+    `ec_geometry`, as the holders heartbeat it; a `VolumeStatus` only for a
+    volume the answer names no geometry for) and its rebuilder.
     -> rebuilder url -> its volumes' plans, in id order."""
-    nodes = env.topology_nodes()
-    colls = _ec_collections(env)
+    topo = env.volume_list()
+    nodes = env.topology_nodes(topo)
+    colls = _ec_collections(env, topo)
+    geometry = topo.get("ec_geometry", {})
     ec_vids = sorted(
         {int(e["volume_id"]) for n in nodes for e in n.get("ec_shards", [])}
     )
@@ -743,18 +748,24 @@ def _plan_rebuild(fl, env: CommandEnv, w: TextIO) -> dict[str, list[dict]]:
         # geometry-flexible volumes (ec.convert targets) record their own
         # (k, k+m): missing-shard detection over the legacy 14 would never
         # see a lost shard id >= 14 of a 20+4 volume, and the survivor
-        # gate would mis-assess 12+3. Any holder knows it; old servers
-        # report 0 -> legacy.
-        k, total = DATA_SHARDS_COUNT, TOTAL_SHARDS_COUNT
-        witness = max(nodes, key=lambda n: len(_node_shards_of(n, vid)))
-        try:
-            st = env.vs_call(
-                grpc_addr(witness), "VolumeStatus", {"volume_id": vid}, timeout=10
-            )
-            k = int(st.get("data_shards") or 0) or k
-            total = int(st.get("total_shards") or 0) or total
-        except Exception:  # noqa: BLE001 — unknown geometry: legacy bounds
-            pass
+        # gate would mis-assess 12+3. Any holder knows it and heartbeats it
+        # (a cut-over's heartbeat reaches the master before `ec.convert`
+        # returns); old servers report 0 -> legacy.
+        geo = geometry.get(str(vid)) or {}
+        k, total = int(geo.get("data_shards") or 0), int(geo.get("total_shards") or 0)
+        if not (k and total):
+            # an old master, or holders that heartbeat no geometry: the
+            # holder of the most shards is asked, as before
+            k, total = DATA_SHARDS_COUNT, TOTAL_SHARDS_COUNT
+            witness = max(nodes, key=lambda n: len(_node_shards_of(n, vid)))
+            try:
+                st = env.vs_call(
+                    grpc_addr(witness), "VolumeStatus", {"volume_id": vid}, timeout=10
+                )
+                k = int(st.get("data_shards") or 0) or k
+                total = int(st.get("total_shards") or 0) or total
+            except Exception:  # noqa: BLE001 — unknown geometry: legacy bounds
+                pass
         missing = [s for s in range(total) if s not in holders]
         if not missing:
             continue
@@ -944,7 +955,9 @@ register(
         "ec.rebuild",
         "ec.rebuild [-collection <name>] [-remote] [-trace on|off|auto]\n\tfind "
         "EC volumes with lost shards and reconstruct them on a rebuilder node\n"
-        "\t(those with every survivor on their rebuilder already, in ONE "
+        "\t(planned from ONE VolumeList: the master's answer carries every "
+        "volume's\n\tgeometry, a holder is asked only where it names none;\n"
+        "\tthose with every survivor on their rebuilder already, in ONE "
         "batch;\n\tthose that need survivor copies one rebuild at a time, the "
         "next volume's\n\tcopies gathered meanwhile: a rebuilder's disk holds at "
         "most two volumes'\n\ttemporary copies, and none when the command "

@@ -864,8 +864,9 @@ def test_a_script_is_one_trace_under_one_id(one_script):
     assert kinds.count("shell.script") == 1 and "shell.command" not in kinds
     assert {t["trace_id"] for t in one_script.snap} == {one_script.script["trace_id"]}
     methods = {t["root"]["attrs"]["method"] for t in one_script.snap if t["kind"] == "rpc.server"}
-    assert {"LeaseAdminToken", "VolumeList", "VolumeStatus", "VolumeEcShardsCopy", "VolumeEcShardFileCopy",
+    assert {"LeaseAdminToken", "VolumeList", "VolumeEcShardsCopy", "VolumeEcShardFileCopy",
             "VolumeEcShardsRebuild", "VolumeEcShardsDelete", "ReleaseAdminToken"} <= methods
+    assert "VolumeStatus" not in methods  # the geometry came with the one VolumeList
     assert "ReportTrace" not in methods  # the hand-over is outside the trace it carries
 
 
@@ -885,8 +886,8 @@ def test_the_masters_ring_holds_the_scripts_tree(one_script):
     assert [s["attrs"]["command"] for s in _commands(script)] == ["lock", "ec.rebuild", "unlock"]
     rebuild = _commands(script)[1]
     (plan,) = [s for s in rebuild["spans"] if s["name"] == "shell.plan"]
-    # VolumeList, the collections, a VolumeStatus a volume: all before the first copy
-    assert plan["attrs"] == {"volumes": 2, "rpcs": 4} and len(_descendants(plan, "rpc.client")) == 4
+    # ONE VolumeList (nodes, collections and geometry from it), before the first copy
+    assert plan["attrs"] == {"volumes": 2, "rpcs": 1} and len(_descendants(plan, "rpc.client")) == 1
     first_copy = min(s["t_ms"] for s in _descendants(rebuild, "rpc.client")
                      if s["attrs"]["method"] == "VolumeEcShardsCopy")
     assert plan["t_ms"] + plan["dur_ms"] <= first_copy
@@ -895,7 +896,7 @@ def test_the_masters_ring_holds_the_scripts_tree(one_script):
 
 
 def test_a_command_has_as_many_rpc_client_spans_as_its_rpcs_says(one_script):
-    for cmd, want in zip(_commands(one_script.script), (1, 12, 1)):
+    for cmd, want in zip(_commands(one_script.script), (1, 9, 1)):
         clients = _descendants(cmd, "rpc.client")
         assert len(clients) == cmd["attrs"]["rpcs"] == want, cmd["attrs"]
         assert all(set(s["attrs"]) >= {"method", "target"} for s in clients)
@@ -932,7 +933,7 @@ def test_every_rpc_server_root_lies_inside_its_rpc_client_by_the_wall_clock(one_
         clients.setdefault(s["attrs"]["method"], []).append((t0, t0 + s["dur_ms"] * 1e6))
     served = [t for t in one_script.snap if t["kind"] == "rpc.server"
               and t["root"]["attrs"]["method"] in clients]  # (a peer's VolumeEcShardFileCopy has no shell half)
-    assert len(served) == 14
+    assert len(served) == 11  # one VolumeList and no VolumeStatus: three fewer than before PR 47
     for t in served:
         t0, t1 = t["unix_ns"], t["unix_ns"] + t["duration_s"] * 1e9
         sticks_out = min(max(a - t0, t1 - b, 0) for a, b in clients[t["root"]["attrs"]["method"]])
@@ -952,7 +953,7 @@ def test_ec_trace_prints_the_scripts_tree_with_each_servers_half_in_place(one_sc
         assert lines[i + 1].strip("| ").startswith(f"@ {rebuilder} rpc.server ")
         assert " rebuild.run volume=" in lines[i + 2] and lines[i + 2].startswith(lines[i + 1].split("@")[0] + "|  +-")
     assert text.count(" rebuild.run volume=") == 2 and text.count(" ec.copy source=") == 4
-    assert text.count("@ ") == 14  # every RPC of the script that a server recorded, in its place
+    assert text.count("@ ") == 11  # every RPC of the script that a server recorded, in its place
     # what had no caller among the script's spans comes after, under its server's name
     tail = text[text.rindex("@ "):]
     assert tail.count("rpc.server class=rpc method=VolumeEcShardFileCopy") == text.count("method=VolumeEcShardFileCopy") > 0

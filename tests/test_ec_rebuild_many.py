@@ -191,16 +191,12 @@ def test_one_shard_of_each_of_eight_volumes_is_one_batch(make_many, backend):
     assert stats.EcRebuildRuns.labels(backend).value - runs0 == 8
     assert stats.EcRebuildBatchVolumes.value - batch0 == 8
     # the spans say what ran: the batch's run span, and the shell's count of its RPCs
-    (run,) = [s for t in trace.RING.snapshot(kind="rpc.server", limit=100000)
-              if t["root"]["attrs"].get("method") == "VolumeEcShardsRebuildBatch"
-              for s in trace.iter_spans(t) if s["name"] == "rebuild.run"]
+    run = _batch_run()
     assert run["attrs"]["batch"] == 8 and run["attrs"]["signature_groups"] == 7
     assert run["attrs"]["ring"] in ("reused", "allocated")
-    # the command's span, under the script's root (`shell -c` is ONE trace)
-    (root,) = [s for t in trace.RING.snapshot(kind="shell.script", limit=1000) for s in trace.iter_spans(t)
-               if s["name"] == "shell.command" and s["attrs"].get("command") == "ec.rebuild"]
-    # VolumeList, the collections, a VolumeStatus a volume, and the one batch
-    assert root["attrs"]["rpcs"] == 2 + 8 + 1
+    # the command's span, under the script's root (`shell -c` is ONE trace):
+    # ONE VolumeList (the geometry of all eight comes with it) and the one batch
+    assert _rebuild_command()["attrs"]["rpcs"] == 1 + 0 + 1
 
 
 def test_a_lone_volume_with_its_survivors_at_hand_is_a_batch_of_one(make_many):
@@ -268,6 +264,301 @@ def test_mixed_placement_copies_a_lost_volume_and_a_soft_failure(make_many):
     assert not os.path.exists(c.path(3, 11)) and 11 not in c.listed(3)
     for fid, payload in c.needles[1] + c.needles[4]:
         assert c.client.read(fid) == payload
+
+
+# -- the plan: all volumes at once (PR 47) ----------------------------------------
+
+
+def _rebuild_command():
+    """The `shell.command` span of the ring's one `ec.rebuild`."""
+    (cmd,) = [s for t in trace.RING.snapshot(kind="shell.script", limit=1000) for s in trace.iter_spans(t)
+              if s["name"] == "shell.command" and s["attrs"].get("command") == "ec.rebuild"]
+    return cmd
+
+
+def _clients(span):
+    return [s["attrs"] for s in trace.iter_spans({"root": span}) if s["name"] == "rpc.client"]
+
+
+def _batch_run():
+    """The run span of the ring's one `VolumeEcShardsRebuildBatch` (a root of
+    its own where the caller sent no trace id)."""
+    (run,) = [s for t in trace.RING.snapshot(limit=100000) for s in trace.iter_spans(t)
+              if s["name"] == "rebuild.run" and "batch" in s["attrs"]]
+    return run
+
+
+def test_the_plan_of_eight_volumes_is_one_volume_list_and_no_volume_status(make_many):
+    """The master's answer names every volume's geometry: a flagless
+    `ec.rebuild` of the eight sends ONE `VolumeList`, no `VolumeStatus`, and
+    the one batch; the plan's span says so."""
+    c = make_many("numpy")
+    c.lose({vid: [LOST[vid]] for vid in VIDS})
+    assert {v: (g["data_shards"], g["total_shards"]) for v, g in c.master.topology.ec_geometry.items()} == {
+        vid: (10, 14) for vid in VIDS}
+    before = {m: _calls(m) for m in ("VolumeStatus", "VolumeList")}
+    trace.RING.clear()
+
+    out, err = c.shell("lock; ec.rebuild; unlock")
+
+    assert err is None, out
+    assert {m: _calls(m) - n for m, n in before.items()} == {"VolumeStatus": 0, "VolumeList": 1}
+    cmd = _rebuild_command()
+    assert sorted(a["method"] for a in _clients(cmd)) == ["VolumeEcShardsRebuildBatch", "VolumeList"]
+    (plan,) = [s for s in cmd["spans"] if s["name"] == "shell.plan"]
+    assert plan["attrs"] == {"volumes": 8, "rpcs": 1}
+    for vid in VIDS:
+        with open(c.path(vid, LOST[vid]), "rb") as f:
+            assert f.read() == c.reference[vid][LOST[vid]]
+
+
+def test_a_volume_the_master_names_no_geometry_for_is_asked_of_its_witness(make_many, monkeypatch):
+    """Volume 3's holder heartbeats no geometry (a server from before
+    heartbeats carried one): the master's answer lacks it, that volume alone
+    is asked of the holder of most of its shards, as before, and both lost
+    shards come back."""
+    c = make_many("numpy")
+    infos = c.server.store.ec_volume_infos
+
+    def as_an_old_server():
+        out = infos()
+        for info in out:
+            if info.volume_id == 3:
+                info.shard_size = info.data_shards = info.total_shards = 0
+        return out
+
+    monkeypatch.setattr(c.server.store, "ec_volume_infos", as_an_old_server)
+    c.lose({3: [LOST[3]], 4: [LOST[4]]})
+    cl._wait_for(lambda: 3 not in c.master.topology.ec_geometry, msg="the master forgot volume 3's geometry")
+    assert "3" not in c.env.volume_list()["ec_geometry"] and "4" in c.env.volume_list()["ec_geometry"]
+    status0 = _calls("VolumeStatus")
+    trace.RING.clear()
+
+    out, err = c.shell("lock; ec.rebuild; unlock")
+
+    assert err is None, out
+    assert _calls("VolumeStatus") - status0 == 1
+    asked = [a for a in _clients(_rebuild_command()) if a["method"] == "VolumeStatus"]
+    assert [(a["volume"], a["target"]) for a in asked] == [(3, c.server.grpc_address)]
+    assert f"ec.rebuild batch on {c.server.url}: 2 volumes in 2 signature groups\n" in out
+    for vid in (3, 4):
+        with open(c.path(vid, LOST[vid]), "rb") as f:
+            assert f.read() == c.reference[vid][LOST[vid]]
+
+
+@pytest.mark.parametrize("family,k,total,lost", [("cauchy_12_3", 12, 15, 14), ("merge_20_4", 20, 24, 17)])
+def test_a_converted_volume_is_planned_from_the_masters_geometry(make_many, family, k, total, lost):
+    """`ec.convert`'s cut-over has heartbeated the new geometry before the
+    command returns, so the master's copy is what `VolumeStatus` would say:
+    a lost shard id the legacy 0..13 would never see is planned from it,
+    with no `VolumeStatus`, and rebuilt byte-exact."""
+    c = make_many("numpy")
+    out, err = c.shell(f"lock; ec.convert -volumeId 6 -family {family}; unlock")
+    assert err is None and f"rs_10_4 -> {family}" in out and "cut over" in out, out
+    geo = c.master.topology.ec_geometry[6]  # no wait: the RPC's own heartbeat brought it
+    assert (geo["data_shards"], geo["total_shards"]) == (k, total)
+    assert sorted(c.listed(6)) == list(range(total))
+    with open(c.path(6, lost), "rb") as f:
+        golden = f.read()
+    c.lose({6: [lost]})
+    status0 = _calls("VolumeStatus")
+
+    out, err = c.shell("lock; ec.rebuild; unlock")
+
+    assert err is None, out
+    assert f"ec.rebuild volume 6: rebuilt [{lost}] on {c.server.url}\n" in out
+    assert _calls("VolumeStatus") == status0
+    with open(c.path(6, lost), "rb") as f:
+        assert f.read() == golden
+    assert sorted(c.listed(6)) == list(range(total))
+    for fid, payload in c.needles[6]:
+        assert c.client.read(fid) == payload
+
+
+def test_the_master_believes_the_holder_of_the_most_shards_about_a_geometry():
+    """Holders may disagree for a while (stale old-geometry shards beside a
+    converted volume): the claim of the node that holds the most shards
+    stands, whoever heartbeated last; a holder that reports no geometry
+    claims nothing; `VolumeList` carries what stands."""
+    from seaweedfs_tpu.cluster.topology import Topology
+    from seaweedfs_tpu.ec.shard_bits import EcVolumeInfo, ShardBits
+    from seaweedfs_tpu.pb import Heartbeat
+
+    def beat(port, *infos):
+        return Heartbeat(ip="127.0.0.1", port=port, grpc_port=port + 1000, rack="r", data_center="dc",
+                         max_volume_count=10, ec_shards=list(infos))
+
+    def info(sids, k=0, total=0, size=0):
+        return EcVolumeInfo(7, shard_bits=ShardBits.from_ids(sids), shard_size=size,
+                            data_shards=k, total_shards=total).to_dict()
+
+    old = {"data_shards": 10, "total_shards": 14, "shard_size": 1000}
+    new = {"data_shards": 20, "total_shards": 24, "shard_size": 500}
+    topo = Topology()
+    topo.process_heartbeat(beat(8001, info(range(7), 10, 14, 1000)))
+    topo.process_heartbeat(beat(8002, info(range(7, 14), 10, 14, 1000)))
+    assert topo.ec_geometry == {7: old}
+    topo.process_heartbeat(beat(8001, info(range(24), 20, 24, 500)))  # 8001 converted and cut over
+    assert topo.ec_geometry == {7: new}
+    topo.process_heartbeat(beat(8002, info(range(7, 14), 10, 14, 1000)))  # the stale holder beats later
+    assert topo.ec_geometry == {7: new} and topo.to_dict()["ec_geometry"] == {"7": new}
+    topo.process_heartbeat(beat(8002))  # its stale shards were deleted
+    assert topo.ec_geometry == {7: new}
+    topo.process_heartbeat(beat(8002, info([3], 10, 14, 1000)))  # one stale shard comes back
+    topo.process_heartbeat(beat(8001, info(range(24))))  # the big holder reports no geometry: no claim
+    assert topo.ec_geometry == {7: old}
+    topo.unregister_node("127.0.0.1:8002")
+    assert topo.ec_geometry == {} and topo.to_dict()["ec_geometry"] == {}
+    topo.unregister_node("127.0.0.1:8001")
+    assert topo.ec_geometry == {} and 7 not in topo.ec_locations
+
+
+def _batch_rpc(c, vids):
+    from seaweedfs_tpu.ec import placement
+
+    return c.call(c.server, "VolumeEcShardsRebuildBatch", placement.rebuild_batch_request((v, "") for v in vids))
+
+
+def test_the_plans_of_a_batch_run_side_by_side(make_many, monkeypatch):
+    """The first two `LookupEcVolume` of a batch of eight are answered only
+    once BOTH are in flight (a barrier with a time limit, no clock): plans
+    that ran one after the other would break it and fail their volumes.
+    Every volume still asks the master afresh, and the spans say what ran:
+    a `rebuild.plan` a volume under the run, `planned=` and `plan_ms=`."""
+    import itertools
+    import threading
+
+    c = make_many("numpy")
+    c.lose({vid: [LOST[vid]] for vid in VIDS})
+    query, together, arrivals = c.server._master_query, threading.Barrier(2), itertools.count()
+    asked = []
+
+    def held_back(method, req, *a, **kw):
+        if method == "LookupEcVolume":
+            asked.append(req["volume_id"])
+            if next(arrivals) < 2:
+                together.wait(timeout=30)  # BrokenBarrierError where the second never comes
+        return query(method, req, *a, **kw)
+
+    monkeypatch.setattr(c.server, "_master_query", held_back)
+    trace.RING.clear()
+
+    resp = _batch_rpc(c, VIDS)
+
+    assert [(r["volume_id"], r["rebuilt_shard_ids"], r["error"]) for r in resp["results"]] == [
+        (vid, [LOST[vid]], "") for vid in VIDS]
+    assert not together.broken, "the first lookup waited alone: the plans ran one after the other"
+    assert sorted(asked) == VIDS  # a fresh holder map a volume: none skipped, none from the cache
+    run = _batch_run()
+    assert run["attrs"]["planned"] == 8 and run["attrs"]["batch"] == 8 and run["attrs"]["plan_ms"] > 0
+    plans = [s for s in run["spans"] if s["name"] == "rebuild.plan"]
+    assert sorted(s["attrs"]["volume"] for s in plans) == VIDS and all(s["attrs"]["local"] is True for s in plans)
+    assert all([g["name"] for g in s.get("spans", ())] == ["ec.lookup"] for s in plans)
+    first_stage = min(s["t_ms"] for s in run["spans"] if s["name"] == "rebuild.stage")
+    assert max(s["t_ms"] + s["dur_ms"] for s in plans) <= first_stage  # every plan before the first byte
+    for vid in VIDS:
+        with open(c.path(vid, LOST[vid]), "rb") as f:
+            assert f.read() == c.reference[vid][LOST[vid]]
+
+
+def test_a_mixed_batch_keeps_request_order_and_its_results(make_many):
+    """Job order is request order whatever order the plans finish in: the
+    scheduler's 2-missing volume first, same-signature volumes side by side,
+    a healthy volume rebuilt as nothing, an unknown one a soft error of its
+    own; `results` by volume id, as before."""
+    c = make_many("numpy")
+    c.lose({5: [5, 9], 2: [3], 7: [3], 8: [12]})
+
+    resp = _batch_rpc(c, [5, 8, 1, 7, 99, 2])
+
+    assert resp["block_order"] == [5, 8, 7, 2] and resp["signature_groups"] == 3
+    assert resp["volumes_fused"] == 4 and resp["dispatch_groups"] == 1 and resp["wire_bytes"] == 0
+    results = {r["volume_id"]: r for r in resp["results"]}
+    assert [r["volume_id"] for r in resp["results"]] == [1, 2, 5, 7, 8, 99]
+    assert {v: r["rebuilt_shard_ids"] for v, r in results.items()} == {
+        1: [], 2: [3], 5: [5, 9], 7: [3], 8: [12], 99: []}
+    assert all(not results[v]["error"] for v in (1, 2, 5, 7, 8))
+    assert "ec volume 99 not found" in results[99]["error"]
+    for vid, shards in ((5, [5, 9]), (2, [3]), (7, [3]), (8, [12])):
+        for s in shards:
+            with open(c.path(vid, s), "rb") as f:
+                assert f.read() == c.reference[vid][s]
+            assert c.listed(vid)[s] == {c.server.url}
+
+
+def _open_shard_files(c, vid):
+    """Descriptors of this process onto volume `vid`'s shard files."""
+    prefix = c.base(vid) + ".ec"
+    held = []
+    for fd in os.listdir("/proc/self/fd"):
+        try:
+            target = os.readlink(f"/proc/self/fd/{fd}")
+        except OSError:
+            continue
+        if target.startswith(prefix) and target[len(prefix):].isdigit():
+            held.append(target)
+    return sorted(held)
+
+
+def test_a_plan_that_raises_is_its_volumes_soft_error_and_closes_what_it_opened(make_many, monkeypatch):
+    """Volume 4's plan raises after its ten local survivors were opened: the
+    error is volume 4's alone, the descriptors it opened are closed (the
+    process holds what it held before), the other seven are rebuilt."""
+    c = make_many("numpy")
+    c.lose({vid: [LOST[vid]] for vid in VIDS})
+    held0 = _open_shard_files(c, 4)
+    remote, seen = c.server._remote_slab_sources, []
+
+    def breaks_for_four(vid, shard_ids, executor):
+        if vid == 4:
+            seen.append(len(_open_shard_files(c, 4)) - len(held0))
+            raise OSError("no route to volume 4's holders")
+        return remote(vid, shard_ids, executor)
+
+    monkeypatch.setattr(c.server, "_remote_slab_sources", breaks_for_four)
+
+    resp = _batch_rpc(c, VIDS)
+
+    assert seen == [10]  # the plan had its ten survivors open when it failed
+    assert _open_shard_files(c, 4) == held0
+    results = {r["volume_id"]: r for r in resp["results"]}
+    assert results[4]["error"] == "OSError: no route to volume 4's holders" and not results[4]["rebuilt_shard_ids"]
+    assert 4 not in resp["block_order"] and resp["volumes_fused"] == 7
+    for vid in VIDS:
+        if vid != 4:
+            assert results[vid] == {"volume_id": vid, "rebuilt_shard_ids": [LOST[vid]], "error": "", "wire_bytes": 0}
+            with open(c.path(vid, LOST[vid]), "rb") as f:
+                assert f.read() == c.reference[vid][LOST[vid]]
+    assert not os.path.exists(c.path(4, LOST[4]))
+
+
+@pytest.mark.parametrize("vids,pooled", [([5], 0), ([5, 6, 7], 3)])
+def test_a_batch_of_one_is_planned_on_the_calling_thread(make_many, monkeypatch, vids, pooled):
+    """A batch of one hands its executor nothing (the statements of before,
+    on the RPC's thread, `planned=1`); a batch of several hands it a plan a
+    volume."""
+    from concurrent import futures
+
+    c = make_many("numpy")
+    c.lose({vid: [LOST[vid]] for vid in vids})
+    submit, handed = futures.ThreadPoolExecutor.submit, []
+
+    def counted(self, fn, *args, **kw):
+        if self._thread_name_prefix == "ec-rebuild-batch":
+            handed.append(fn)
+        return submit(self, fn, *args, **kw)
+
+    monkeypatch.setattr(futures.ThreadPoolExecutor, "submit", counted)
+    trace.RING.clear()
+
+    resp = _batch_rpc(c, vids)
+
+    assert len(handed) == pooled
+    assert [(r["volume_id"], r["rebuilt_shard_ids"], r["error"]) for r in resp["results"]] == [
+        (vid, [LOST[vid]], "") for vid in vids]
+    run = _batch_run()
+    assert run["attrs"]["planned"] == len(vids) and run["attrs"]["plan_ms"] > 0
+    assert len([s for s in run["spans"] if s["name"] == "rebuild.plan"]) == len(vids)
 
 
 # -- the packed plan against the loop ---------------------------------------------
