@@ -82,9 +82,11 @@ SPAN_NAMES: dict[str, str] = {
     "rebuild.stage": "staging-ring fill for one rebuild batch (disk/wire): its lane reads queued, the drain it runs ahead of (nested), the wait for the reads",
     "rebuild.read": "one survivor's slab read into its staging row (child of rebuild.stage; on a lane thread where the source allows)",
     "rebuild.wait": "the calling thread blocked in a join of lane tasks (a batch's reads, the last drain's writes)",
-    "rebuild.dispatch": "reconstruct_lazy, or reconstruct_block where a packed batch holds several signature groups (blocks=): device_put (H2D) + the jit call, until it returns; form= says how the slot crossed on the jax backend (exact | as_is: rs_jax.apply_matrix)",
+    "rebuild.dispatch": "reconstruct_lazy, or reconstruct_block where a packed batch holds several signature groups (blocks=): device_put (H2D) + the jit call, until it returns; form= says how the slot crossed: on the jax backend exact | as_is (rs_jax.apply_matrix), on the mesh backend mesh-ring | mesh-alltoall | mesh-cols (the program that took it; its mesh.put child is the crossing)",
+    "mesh.put": "the mesh backend's half of a *.dispatch before its program: the batch laid out for the mesh on the host (the rebuild's dp column slices, shard-major; a padded tail) and device_put over the devices (mesh= dp x sp, variant= ring | alltoall | cols, devices= the batch lay on)",
+    "mesh.restore": "the mesh backend's half of a *.sync after the device has finished: the sharded result fetched from its devices and re-laid as the flat (rows, width) the pipelines write (mesh=, variant=, devices=)",
     "rebuild.drain": "device sync + shard write-out + CRC for one rebuild batch",
-    "rebuild.sync": "np.asarray of one batch's decode: device wait + D2H, nothing else",
+    "rebuild.sync": "np.asarray of one batch's decode: device wait + D2H, nothing else (on the mesh backend its own time is the wait for the devices; the way back is its mesh.restore child)",
     "rebuild.write": "one rebuilt shard's bytes of one batch written to its file (on a lane thread)",
     "rebuild.crc": "zlib.crc32 fold over one rebuilt shard's bytes of one batch (on a lane thread)",
     "rebuild.verify": "rebuilt shards' CRC32s checked against the .eci record",
@@ -95,7 +97,7 @@ SPAN_NAMES: dict[str, str] = {
     "encode.wait": "the calling thread blocked in a join of lane tasks (a batch's reads, its data shards' writes, the last drain's parity writes)",
     "encode.dispatch": "encode_parity_lazy: device_put (H2D) + the jit call, until it returns; form= as on rebuild.dispatch",
     "encode.drain": "device sync + shard write-out + CRC for one encode batch",
-    "encode.sync": "np.asarray of one batch's parity: device wait + D2H, nothing else",
+    "encode.sync": "np.asarray of one batch's parity: device wait + D2H, nothing else (on the mesh backend as rebuild.sync)",
     "encode.write": "one shard's bytes of one batch written to its file (data under its stage, parity under its drain; on a lane thread)",
     "encode.crc": "zlib.crc32 fold over one shard's bytes of one batch (on a lane thread)",
     "ingest.encode": "inline-EC encode of newly-final large rows (one poll)",

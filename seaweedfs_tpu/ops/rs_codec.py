@@ -319,10 +319,12 @@ class Encoder:
         encode_parity_lazy are both defined in terms of it. donate=True
         (jax/pallas, off-CPU only) releases the input's device buffer at
         dispatch-consume time so a streaming pipeline's inflight HBM stays
-        bounded (an early-release hint — see rs_jax's donated-twin note)."""
+        bounded (an early-release hint — see rs_jax's donated-twin note).
+        The mesh backend is not asked: its dispatcher owns the device copy
+        of every batch (parallel/backend.py says how it is released)."""
         self._count_dispatch()
         if self.backend == "mesh":
-            return self._mesh_dispatch().apply(m, shards, donate=donate)
+            return self._mesh_dispatch().apply(m, shards)
         if self.backend == "pallas":
             from seaweedfs_tpu.ops import rs_pallas
 
@@ -430,7 +432,7 @@ class Encoder:
         with this batch's device compute (SURVEY §7.1 double buffering);
         np.asarray() on the result is the synchronization point. donate=True
         releases the batch's device buffer at dispatch-consume time
-        (off-CPU; an early-release hint, see rs_jax's donated-twin note)."""
+        (jax/pallas, off-CPU; an early-release hint, see `_apply_lazy`)."""
         data = np.asarray(data, dtype=np.uint8)
         if data.ndim == 2:
             if data.shape[0] != self.data_shards:
@@ -628,7 +630,8 @@ class Encoder:
         for the whole batch, the `encode_parity_lazy` contract mirrored
         for the repair path; np.asarray() on the result is the
         synchronization point. donate=True releases the stack's device
-        buffer at dispatch-consume time (off-CPU early-release hint)."""
+        buffer at dispatch-consume time (jax/pallas, off-CPU; an
+        early-release hint, see `_apply_lazy`)."""
         stack = np.asarray(stack, dtype=np.uint8)
         if stack.ndim == 2:
             if stack.shape[0] != self.data_shards:
@@ -641,7 +644,7 @@ class Encoder:
             # generic column-sharded apply — same bytes, pod bandwidth
             self._count_dispatch()
             return self._mesh_dispatch().reconstruct(
-                self.reconstruction_matrix(survivors, wanted), stack, donate=donate
+                self.reconstruction_matrix(survivors, wanted), stack
             )
         return self._apply_lazy(
             self.reconstruction_matrix(survivors, wanted), stack, donate=donate
@@ -761,7 +764,7 @@ class Encoder:
             sub = staging[: enc.data_shards, c0:c0 + w]
             if self.backend == "mesh":
                 self._count_dispatch()
-                h = self._mesh_dispatch().apply(m, sub, donate=False)
+                h = self._mesh_dispatch().apply(m, sub)
             else:
                 h = self._apply_lazy(m, sub, donate=False)
             parts.append((m.shape[0], c0, w, h))

@@ -142,7 +142,7 @@ gf_apply_tiled = jax.jit(_gf_apply_tiled_impl)
 _gf_apply_tiled_donated = jax.jit(_gf_apply_tiled_impl, donate_argnums=(1,))
 
 
-def _run_counted(fn, *args) -> jax.Array:
+def run_counted(fn, *args) -> jax.Array:
     """Call a jitted program; a call that grew its jit cache traced and
     compiled (or loaded) a program for a shape this process had not run:
     `weedtpu_codec_programs_compiled_total` counts those. (A jax whose
@@ -262,5 +262,5 @@ def apply_matrix(m: np.ndarray, shards: jax.Array, donate: bool = False) -> jax.
     else:
         b, plain, donated = lifted_matrix(m), gf_apply, _gf_apply_donated
     if donate and donation_supported():
-        return _run_counted(donated, b, jax.device_put(jnp.asarray(shards)))
-    return _run_counted(plain, b, shards)
+        return run_counted(donated, b, jax.device_put(jnp.asarray(shards)))
+    return run_counted(plain, b, shards)

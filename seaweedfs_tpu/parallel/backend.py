@@ -17,6 +17,20 @@ the batch axis laid out flat. `MeshDispatch` shards it:
     1.54 s on 64 MiB shards) or `sharded.make_distributed_rebuild_fn`
     (one all_to_all layout flip), selected by `WEEDTPU_MESH_REBUILD`.
 
+Who owns the device copy: the dispatcher, always. Every dispatch
+`device_put`s the host batch itself (the staging slot it was handed goes
+back to its ring untouched), and on an accelerator the compiled programs
+donate that copy, so it is released the moment the program has consumed
+it; on the CPU nothing is donated. A caller has no say in it and is
+asked for none: `apply` and `reconstruct` take no `donate`.
+
+What a dispatch says of itself: `form=mesh-ring | mesh-alltoall |
+mesh-cols` on the ambient `*.dispatch` span, a `mesh.put` span under it
+(host layout + device_put) and a `mesh.restore` span under the `*.sync`
+that fetches the result (the way back + host re-layout), both in
+`weedtpu_ec_mesh_seconds_total{stage}`; `weedtpu_ec_mesh_batches_total
+{variant, devices}` counts the batches by the devices they lay on.
+
 Byte-identity contract: a column partition never changes any output byte
 (matmul columns are independent; zero pad columns map to zero columns and
 are sliced off before the host sees them), so every mesh path is
@@ -26,7 +40,10 @@ Fully testable off-TPU via `--xla_force_host_platform_device_count=8`.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import threading
+import time
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -34,6 +51,8 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from seaweedfs_tpu import stats
+from seaweedfs_tpu.obs import trace as trace_mod
 from seaweedfs_tpu.ops import rs_jax
 from seaweedfs_tpu.parallel import mesh as mesh_mod
 from seaweedfs_tpu.parallel import ring as ring_mod
@@ -92,18 +111,23 @@ def _evidence_shape(n_devices: int) -> Optional[Tuple[int, int]]:
 
 class _LazyRestore:
     """An inflight mesh dispatch whose host form differs from the device
-    layout: `np.asarray(handle)` (the pipelines' sync point) materializes
-    the sharded device output and restores the flat column layout. Until
-    then the dispatch stays async, exactly like a bare jax array."""
+    layout: `np.asarray(handle)` (the pipelines' sync point) waits for the
+    devices, then fetches the sharded output and restores the flat column
+    layout inside `timed(dev)` (the dispatcher's `mesh.restore` span and
+    counter). Until then the dispatch stays async, exactly like a bare jax
+    array."""
 
-    def __init__(self, dev, restore, shape):
+    def __init__(self, dev, restore, shape, timed):
         self._dev = dev
         self._restore = restore
+        self._timed = timed
         #: host-facing shape (pad sliced off) — what np.asarray returns
         self.shape = tuple(shape)
 
     def __array__(self, dtype=None, copy=None):  # noqa: ARG002 — numpy 2.x kw
-        out = self._restore(np.asarray(self._dev))
+        self._dev.block_until_ready()  # the wait for the devices is the caller's own time
+        with self._timed(self._dev):
+            out = self._restore(np.asarray(self._dev))
         if dtype is not None:
             out = out.astype(dtype, copy=False)
         return out
@@ -152,26 +176,44 @@ class MeshDispatch:
         self._lock = threading.Lock()
         #: distinct devices the last dispatched batch lay on (_check_spread)
         self.last_spread = 0
-        try:
-            from seaweedfs_tpu import stats
-
-            stats.EcMeshDevices.set(self.n_devices)
-        except Exception:  # noqa: BLE001 — metrics must never break dispatch
-            pass
+        stats.EcMeshDevices.set(self.n_devices)
 
     def shape_str(self) -> str:
         return f"{self.dp}x{self.sp}"
 
-    def _check_spread(self, x) -> None:
+    def _check_spread(self, x, variant: str) -> None:
         """A sharded batch really lies on every device of the mesh: a
         placement that quietly put everything on device 0 would still be
-        byte-correct, and would never have been a mesh."""
+        byte-correct, and would never have been a mesh. Counted by what it
+        lay on, before the verdict."""
         self.last_spread = len({s.device for s in x.addressable_shards})
+        stats.EcMeshBatches.labels(variant, self.last_spread).inc()
+        trace_mod.annotate(devices=self.last_spread)
         if self.last_spread != self.n_devices:
             raise RuntimeError(
                 f"mesh {self.shape_str()} batch lies on {self.last_spread} "
                 f"devices, want {self.n_devices}"
             )
+
+    @contextlib.contextmanager
+    def _timed(self, stage: str, sp):
+        """One host-side half of a dispatch: its span `sp` (`mesh.put` /
+        `mesh.restore`) and its seconds in `weedtpu_ec_mesh_seconds_total`."""
+        t0 = time.perf_counter()
+        try:
+            with sp:
+                yield
+        finally:
+            stats.EcMeshSeconds.labels(stage).inc(time.perf_counter() - t0)
+
+    def _timed_put(self, variant: str):
+        """`devices=` is what `_check_spread` finds, inside."""
+        return self._timed("put", trace_mod.span("mesh.put", mesh=self.shape_str(), variant=variant))
+
+    def _timed_restore(self, variant: str, out):
+        return self._timed("restore", trace_mod.span(
+            "mesh.restore", mesh=self.shape_str(), variant=variant,
+            devices=len({s.device for s in out.addressable_shards})))
 
     # -- cached compiled functions -------------------------------------------
 
@@ -237,24 +279,24 @@ class MeshDispatch:
 
     # -- dispatches -----------------------------------------------------------
 
-    def apply(self, m: np.ndarray, shards: np.ndarray, donate: bool = False):  # noqa: ARG002
+    def apply(self, m: np.ndarray, shards: np.ndarray):
         """Generic mesh apply: (C, W) -> lazy (R, W), or (B, C, N) ->
         lazy (B, R, N). Columns shard over the full device set, so every
         chip receives its host slice concurrently and computes its own
-        tile. Donation is managed internally: the dispatcher always owns
-        the device_put'ed copy, and releases it at dispatch-consume time
-        on accelerator platforms regardless of the caller's hint."""
+        tile. The device copy is the dispatcher's (module docstring)."""
         m = np.ascontiguousarray(np.asarray(m, dtype=np.uint8))
         shards = np.asarray(shards, dtype=np.uint8)
         batched = shards.ndim == 3
-        if batched:
-            flat, (b, n) = self._flatten_batch(shards)
-        else:
-            flat = shards
-        padded, w = self._pad_cols(flat, self.width_align)
-        x = jax.device_put(padded, self._col_sharding)
-        self._check_spread(x)
-        out = self._apply_fn(m)(x)
+        trace_mod.annotate(form="mesh-cols")
+        with self._timed_put("cols"):
+            if batched:
+                flat, (b, n) = self._flatten_batch(shards)
+            else:
+                flat = shards
+            padded, w = self._pad_cols(flat, self.width_align)
+            x = jax.device_put(padded, self._col_sharding)
+            self._check_spread(x, "cols")
+        out = rs_jax.run_counted(self._apply_fn(m), x)
         r = m.shape[0]
         if batched:
             def restore(a, r=r, b=b, n=n):
@@ -268,32 +310,37 @@ class MeshDispatch:
                 return a[:, :w]
 
             shape = (r, w)
-        return _LazyRestore(out, restore, shape)
+        return _LazyRestore(out, restore, shape, functools.partial(self._timed_restore, "cols"))
 
-    def reconstruct(self, recon_m: np.ndarray, stack: np.ndarray, donate: bool = False):  # noqa: ARG002
+    def reconstruct(self, recon_m: np.ndarray, stack: np.ndarray):
         """Distributed rebuild of a flat survivor stack: (S, W) -> lazy
         (L, W) (or (B, S, N) -> lazy (B, L, N)) through the selected
         ring/all_to_all formulation. The stack's byte axis is viewed as
         dp column-slice volumes of width W/dp placed SHARD-major
         (P(dp, sp, None)) — each chip holds whole survivor rows of its
         slice, the collective does the layout work, and the output comes
-        back byte-sharded over sp."""
+        back byte-sharded over sp. The device copy is the dispatcher's
+        (module docstring)."""
         recon_m = np.ascontiguousarray(np.asarray(recon_m, dtype=np.uint8))
         stack = np.asarray(stack, dtype=np.uint8)
         batched = stack.ndim == 3
-        if batched:
-            flat, (b, n) = self._flatten_batch(stack)
-        else:
-            flat = stack
-        # W/dp must itself divide over sp, so align the flat width to dp*sp
-        padded, w = self._pad_cols(flat, self.dp * self.sp)
-        s, wp = padded.shape
-        wd = wp // self.dp
-        # (S, dp, wd) -> (dp, S, wd): volume k holds byte columns
-        # [k*wd, (k+1)*wd) of every survivor — a pure column partition
-        surv = padded.reshape(s, self.dp, wd).transpose(1, 0, 2)
-        out = self._rebuild_fn(recon_m)(surv)  # (dp, L, wd) device, async
-        self._check_spread(out)
+        variant = self.rebuild_variant
+        fn = self._rebuild_fn(recon_m)
+        trace_mod.annotate(form="mesh-" + variant)
+        with self._timed_put(variant):
+            if batched:
+                flat, (b, n) = self._flatten_batch(stack)
+            else:
+                flat = stack
+            # W/dp must itself divide over sp, so align the flat width to dp*sp
+            padded, w = self._pad_cols(flat, self.dp * self.sp)
+            s, wp = padded.shape
+            wd = wp // self.dp
+            # (S, dp, wd) -> (dp, S, wd): volume k holds byte columns
+            # [k*wd, (k+1)*wd) of every survivor — a pure column partition
+            x = fn.place(padded.reshape(s, self.dp, wd).transpose(1, 0, 2))
+            self._check_spread(x, variant)
+        out = rs_jax.run_counted(fn.jitted, x)  # (dp, L, wd) device, async
         rows = recon_m.shape[0]
 
         if batched:
@@ -311,4 +358,4 @@ class MeshDispatch:
                 )
 
             shape = (rows, w)
-        return _LazyRestore(out, restore, shape)
+        return _LazyRestore(out, restore, shape, functools.partial(self._timed_restore, variant))

@@ -35,7 +35,7 @@ from jax.sharding import PartitionSpec as P
 
 from seaweedfs_tpu.ops import rs_jax
 from seaweedfs_tpu.parallel import shard_map
-from seaweedfs_tpu.parallel.sharded import matrix_bits, pad_survivor_matrix, place_survivors
+from seaweedfs_tpu.parallel.sharded import matrix_bits, pad_survivor_matrix, placed_runner
 
 
 def make_ring_rebuild_fn(mesh: Mesh, recon_m: np.ndarray, donate: bool = False):
@@ -97,8 +97,4 @@ def make_ring_rebuild_fn(mesh: Mesh, recon_m: np.ndarray, donate: bool = False):
     donate_argnums = (0,) if donate else ()
     rebuild = jax.jit(_ring_rebuild, donate_argnums=donate_argnums)
 
-    def run(survivors: np.ndarray) -> jax.Array:
-        return rebuild(place_survivors(mesh, survivors, n_surv, s_pad))
-
-    run.jitted = rebuild  # what compile tests lower for a described mesh
-    return run
+    return placed_runner(mesh, rebuild, n_surv, s_pad)

@@ -67,6 +67,24 @@ def place_survivors(
     return jax.device_put(survivors, NamedSharding(mesh, P("dp", "sp", None)))
 
 
+def placed_runner(mesh: Mesh, rebuild, n_surv: int, s_pad: int):
+    """The host-facing form of a distributed rebuild program:
+    run(survivors) = rebuild(place(survivors)), with its two halves as
+    attributes: `run.place` (`place_survivors` for this program's survivor
+    count) and `run.jitted` (the jitted program), for a caller that times
+    them apart (the mesh dispatcher) and for compile tests, which lower
+    `jitted` for a described mesh."""
+
+    def place(survivors: np.ndarray) -> jax.Array:
+        return place_survivors(mesh, survivors, n_surv, s_pad)
+
+    def run(survivors: np.ndarray) -> jax.Array:
+        return rebuild(place(survivors))
+
+    run.place, run.jitted = place, rebuild
+    return run
+
+
 def make_matrix_apply_fn(mesh: Mesh, matrix: np.ndarray, donate: bool = False):
     """Column-sharded GF(2^8) matrix apply over the FULL device set:
     (C, W) uint8 with W sharded across every mesh axis -> (R, W), zero
@@ -255,8 +273,4 @@ def make_distributed_rebuild_fn(mesh: Mesh, recon_m: np.ndarray, donate: bool = 
     donate_argnums = (0,) if donate else ()
     rebuild = jax.jit(_rebuild, donate_argnums=donate_argnums)
 
-    def run(survivors: np.ndarray) -> jax.Array:
-        return rebuild(place_survivors(mesh, survivors, n_surv, s_pad))
-
-    run.jitted = rebuild  # what compile tests lower for a described mesh
-    return run
+    return placed_runner(mesh, rebuild, n_surv, s_pad)

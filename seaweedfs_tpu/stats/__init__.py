@@ -454,6 +454,24 @@ EcMeshDevices = REGISTRY.gauge(
     "devices in the mesh backend's dp x sp device mesh (0 = every dispatch "
     "is single-device; set when a mesh encoder builds its mesh)",
 )
+EcMeshSeconds = REGISTRY.counter(
+    "weedtpu_ec_mesh_seconds_total",
+    "host seconds the mesh backend spent around its device programs, by "
+    "stage: `put` = a batch laid out for the mesh and device_put over its "
+    "devices (the mesh.put span), `restore` = a result brought back from "
+    "the devices and re-laid as the flat (rows, width) the pipelines write "
+    "(the mesh.restore span); the device's own time is in neither",
+    ("stage",),
+)
+EcMeshBatches = REGISTRY.counter(
+    "weedtpu_ec_mesh_batches_total",
+    "batches the mesh backend dispatched, by the program that took them "
+    "(`variant`: ring | alltoall = the distributed rebuild, cols = the "
+    "column-sharded apply of encodes and small reads) and by the number of "
+    "distinct devices the batch lay on (`devices`: the mesh's, or the "
+    "dispatch raised)",
+    ("variant", "devices"),
+)
 EcDispatchTotal = REGISTRY.counter(
     "weedtpu_ec_dispatch_total",
     "codec matrix dispatches by backend (one batched device/host apply per "
@@ -469,7 +487,8 @@ EcBackendSelected = REGISTRY.gauge(
 )
 CodecProgramsCompiled = REGISTRY.counter(
     "weedtpu_codec_programs_compiled_total",
-    "device programs the XLA codec (ops/rs_jax) has traced and compiled, or "
+    "device programs the XLA codec (ops/rs_jax, and the mesh backend's "
+    "shard_map programs) has traced and compiled, or "
     "loaded from the persistent cache, since boot: one per new (program, "
     "shapes) of a jit cache. It stands still once a server has run each of "
     "its shapes; a rise under steady traffic is a compile on the hot path",
