@@ -24,12 +24,33 @@ donate that copy, so it is released the moment the program has consumed
 it; on the CPU nothing is donated. A caller has no say in it and is
 asked for none: `apply` and `reconstruct` take no `donate`.
 
+The way back: every program's result is a column partition of the flat
+(rows, W) result, whatever the variant: `cols` leaves device d columns
+[d * W/n, (d + 1) * W/n) (`P(None, ("dp", "sp"))`), `ring` and `alltoall`
+leave device (i, j) columns [i * W/dp + j * W/(dp*sp), ... + W/(dp*sp))
+(`P("dp", None, "sp")` over the (dp, rows, W/dp) volumes). So a restore
+(`MeshDispatch._restore`) starts the fetch of every addressable shard,
+takes ONE host result of the host-facing shape and copies each fetched
+shard straight into its columns, which it reads off the shard's own
+`index`: one pass over the result's bytes, pad columns never copied, a
+batched (B, rows, N) result split at its N boundaries on the way. It
+never asks `np.asarray` of the global array: jax would assemble the
+shards into a host array of the DEVICE layout (and keep it on the array),
+and re-laying that takes a second full copy. Large results come from a
+small pool of kept buffers (`_ResultPool`), so a run of batches writes
+into pages it has touched before; a buffer comes home by itself when the
+result handed out, and every view of it, has died.
+
 What a dispatch says of itself: `form=mesh-ring | mesh-alltoall |
 mesh-cols` on the ambient `*.dispatch` span, a `mesh.put` span under it
 (host layout + device_put) and a `mesh.restore` span under the `*.sync`
-that fetches the result (the way back + host re-layout), both in
-`weedtpu_ec_mesh_seconds_total{stage}`; `weedtpu_ec_mesh_batches_total
-{variant, devices}` counts the batches by the devices they lay on.
+that fetches the result (the way back: `pieces=` shards copied, `copied=`
+host bytes written, `kept=` whether the result's buffer had been used
+before), both in `weedtpu_ec_mesh_seconds_total{stage}`;
+`weedtpu_ec_mesh_restore_bytes_total{kind}` counts a restore's `result`
+bytes and the host bytes it `copied` for them (1 to 1);
+`weedtpu_ec_mesh_batches_total{variant, devices}` counts the batches by
+the devices they lay on.
 
 Byte-identity contract: a column partition never changes any output byte
 (matmul columns are independent; zero pad columns map to zero columns and
@@ -42,8 +63,10 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import math
 import threading
 import time
+import weakref
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -112,25 +135,104 @@ def _evidence_shape(n_devices: int) -> Optional[Tuple[int, int]]:
 class _LazyRestore:
     """An inflight mesh dispatch whose host form differs from the device
     layout: `np.asarray(handle)` (the pipelines' sync point) waits for the
-    devices, then fetches the sharded output and restores the flat column
-    layout inside `timed(dev)` (the dispatcher's `mesh.restore` span and
-    counter). Until then the dispatch stays async, exactly like a bare jax
-    array."""
+    devices, then has `restore(shape, dev)` (`MeshDispatch._restore`: the
+    `mesh.restore` span and counters) fetch the shards, each straight into
+    its columns of one host result of `shape`. The global array is never
+    read as a whole, so it keeps no host copy. Until then the dispatch
+    stays async, exactly like a bare jax array."""
 
-    def __init__(self, dev, restore, shape, timed):
+    def __init__(self, dev, shape, restore):
         self._dev = dev
         self._restore = restore
-        self._timed = timed
         #: host-facing shape (pad sliced off) — what np.asarray returns
         self.shape = tuple(shape)
 
     def __array__(self, dtype=None, copy=None):  # noqa: ARG002 — numpy 2.x kw
         self._dev.block_until_ready()  # the wait for the devices is the caller's own time
-        with self._timed(self._dev):
-            out = self._restore(np.asarray(self._dev))
+        out = self._restore(self.shape, self._dev)
         if dtype is not None:
             out = out.astype(dtype, copy=False)
         return out
+
+
+#: what a `_ResultPool` keeps between restores, at most: three results (a
+#: pipelined run holds depth + 1 = 3) of four rows of the widest slot, the
+#: encode's (4, 6553600), rounded up to 32 MiB each
+RESULT_POOL_MAX_BYTES = 3 * 32 * 1024 * 1024
+#: results under this size are malloc's own business (the served reads)
+RESULT_POOL_MIN_BYTES = 1 << 20
+
+
+class _ResultPool:
+    """Host results a mesh dispatcher keeps between restores, the staging
+    pool's way (`ec/stripe._ring_for`): flat buffers, the smallest that
+    fits serves a result, at most `max_bytes` are kept and the smallest go
+    first. What is handed out is an array over a buffer's first bytes; the
+    buffer is a `bytearray`, no ndarray, so numpy makes the ARRAY the
+    `base` of every view of it (a `decoded[k, a:b]` on a lane's queue):
+    when the array and its last view have died, its finalizer brings the
+    buffer home. A result somebody keeps for good is ordinary garbage, as
+    a fresh one would be, and the next restore allocates."""
+
+    def __init__(self, max_bytes: int = RESULT_POOL_MAX_BYTES):
+        self.max_bytes = max_bytes
+        self._free: list[bytearray] = []  # smallest first
+        self._lock = threading.Lock()
+
+    def kept_bytes(self) -> int:
+        with self._lock:
+            return sum(map(len, self._free))
+
+    def take(self, shape) -> tuple[np.ndarray, bool]:
+        """(a C-contiguous writable uint8 array of `shape`, whether its
+        buffer had been handed out before)."""
+        n = math.prod(shape)
+        if n < RESULT_POOL_MIN_BYTES:
+            return np.empty(shape, dtype=np.uint8), False
+        raw = None
+        with self._lock:
+            for i, b in enumerate(self._free):
+                if len(b) >= n:
+                    raw = self._free.pop(i)
+                    break
+        kept = raw is not None
+        if raw is None:
+            raw = bytearray(n)
+        out = np.ndarray(shape, dtype=np.uint8, buffer=raw)
+        weakref.finalize(out, self._give_back, raw)
+        return out, kept
+
+    def _give_back(self, raw: bytearray) -> None:
+        # a finalizer may run wherever the collector does, under this very
+        # lock too: it never waits, a buffer it cannot file is dropped
+        if not self._lock.acquire(blocking=False):
+            return
+        try:
+            self._free.append(raw)
+            self._free.sort(key=len)
+            over = sum(map(len, self._free)) - self.max_bytes
+            while over > 0:
+                over -= len(self._free.pop(0))
+        finally:
+            self._lock.release()
+
+
+def _copy_columns(out: np.ndarray, c0: int, block: np.ndarray) -> int:
+    """`block` (rows, m) holds flat columns [c0, c0 + m) of every row of a
+    result: copy those that `out` has, (rows, w) or (b, rows, n) with flat
+    column bi * n + ni at [bi, :, ni]; the columns past the last are pad
+    and stay where they are. Returns the bytes written."""
+    n = out.shape[-1]
+    c1 = min(c0 + block.shape[-1], n if out.ndim == 2 else out.shape[0] * n)
+    if c1 <= c0:
+        return 0
+    if out.ndim == 2:
+        out[:, c0:c1] = block[:, : c1 - c0]
+    else:
+        for bi in range(c0 // n, -(-c1 // n)):
+            lo, hi = max(c0, bi * n), min(c1, (bi + 1) * n)
+            out[bi, :, lo - bi * n : hi - bi * n] = block[:, lo - c0 : hi - c0]
+    return block.shape[0] * (c1 - c0)
 
 
 class MeshDispatch:
@@ -174,6 +276,7 @@ class MeshDispatch:
         self._apply_fns: dict = {}
         self._rebuild_fns: dict = {}
         self._lock = threading.Lock()
+        self._results = _ResultPool()
         #: distinct devices the last dispatched batch lay on (_check_spread)
         self.last_spread = 0
         stats.EcMeshDevices.set(self.n_devices)
@@ -210,10 +313,42 @@ class MeshDispatch:
         """`devices=` is what `_check_spread` finds, inside."""
         return self._timed("put", trace_mod.span("mesh.put", mesh=self.shape_str(), variant=variant))
 
-    def _timed_restore(self, variant: str, out):
-        return self._timed("restore", trace_mod.span(
-            "mesh.restore", mesh=self.shape_str(), variant=variant,
-            devices=len({s.device for s in out.addressable_shards})))
+    def _restore(self, variant: str, shape: tuple, dev) -> np.ndarray:
+        """The way back of one result (module docstring): `dev` is the
+        program's output, the flat (rows, W) result as (rows, Wp) (`cols`)
+        or as (dp, rows, Wp/dp) volumes (the rebuilds), column-sharded;
+        `shape` is what the host is owed, (rows, w) or (b, rows, n) with
+        flat column bi * n + ni at [bi, :, ni]; columns from w = b * n on
+        are pad. A shard's place is its own `index`."""
+        shards = [s for s in dev.addressable_shards if s.replica_id == 0]
+        with self._timed("restore", trace_mod.span(
+                "mesh.restore", mesh=self.shape_str(), variant=variant,
+                devices=len({s.device for s in shards}))):
+            for s in shards:
+                s.data.copy_to_host_async()
+            out, kept = self._results.take(shape)
+            wd = dev.shape[-1]
+            pieces = copied = 0
+            for s in shards:
+                host = np.asarray(s.data)
+                first = s.index[-1].start or 0
+                if host.ndim == 2:
+                    done = _copy_columns(out, first, host)
+                else:  # volume v of the rebuilds' (dp, rows, wd) is columns [v * wd, (v + 1) * wd)
+                    v0 = s.index[0].start or 0
+                    done = sum(_copy_columns(out, (v0 + vi) * wd + first, block)
+                               for vi, block in enumerate(host))
+                pieces += done > 0
+                copied += done
+            if copied != out.size:
+                raise RuntimeError(
+                    f"mesh {self.shape_str()} {variant} result of {out.size} bytes: "
+                    f"its shards cover {copied}"
+                )
+            stats.EcMeshRestoreBytes.labels("result").inc(out.size)
+            stats.EcMeshRestoreBytes.labels("copied").inc(copied)
+            trace_mod.annotate(pieces=pieces, copied=copied, kept=kept)
+        return out
 
     # -- cached compiled functions -------------------------------------------
 
@@ -298,19 +433,8 @@ class MeshDispatch:
             self._check_spread(x, "cols")
         out = rs_jax.run_counted(self._apply_fn(m), x)
         r = m.shape[0]
-        if batched:
-            def restore(a, r=r, b=b, n=n):
-                return np.ascontiguousarray(
-                    np.moveaxis(a[:, : b * n].reshape(r, b, n), 1, 0)
-                )
-
-            shape = (b, r, n)
-        else:
-            def restore(a, w=w):
-                return a[:, :w]
-
-            shape = (r, w)
-        return _LazyRestore(out, restore, shape, functools.partial(self._timed_restore, "cols"))
+        shape = (b, r, n) if batched else (r, w)
+        return _LazyRestore(out, shape, functools.partial(self._restore, "cols"))
 
     def reconstruct(self, recon_m: np.ndarray, stack: np.ndarray):
         """Distributed rebuild of a flat survivor stack: (S, W) -> lazy
@@ -342,20 +466,5 @@ class MeshDispatch:
             self._check_spread(x, variant)
         out = rs_jax.run_counted(fn.jitted, x)  # (dp, L, wd) device, async
         rows = recon_m.shape[0]
-
-        if batched:
-            def restore(a, rows=rows, wp=wp, b=b, n=n):
-                flat_out = a.transpose(1, 0, 2).reshape(rows, wp)[:, : b * n]
-                return np.ascontiguousarray(
-                    np.moveaxis(flat_out.reshape(rows, b, n), 1, 0)
-                )
-
-            shape = (b, rows, n)
-        else:
-            def restore(a, rows=rows, wp=wp, w=w):
-                return np.ascontiguousarray(
-                    a.transpose(1, 0, 2).reshape(rows, wp)[:, :w]
-                )
-
-            shape = (rows, w)
-        return _LazyRestore(out, restore, shape, functools.partial(self._timed_restore, variant))
+        shape = (b, rows, n) if batched else (rows, w)
+        return _LazyRestore(out, shape, functools.partial(self._restore, variant))

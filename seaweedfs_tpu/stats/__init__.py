@@ -459,9 +459,19 @@ EcMeshSeconds = REGISTRY.counter(
     "host seconds the mesh backend spent around its device programs, by "
     "stage: `put` = a batch laid out for the mesh and device_put over its "
     "devices (the mesh.put span), `restore` = a result brought back from "
-    "the devices and re-laid as the flat (rows, width) the pipelines write "
-    "(the mesh.restore span); the device's own time is in neither",
+    "the devices, each shard into its columns of the flat (rows, width) the "
+    "pipelines write (the mesh.restore span); the device's own time and the "
+    "wait for the devices are in neither",
     ("stage",),
+)
+EcMeshRestoreBytes = REGISTRY.counter(
+    "weedtpu_ec_mesh_restore_bytes_total",
+    "bytes of the mesh backend's way back, by kind: `result` = bytes of the "
+    "host results its restores handed out, `copied` = host bytes the "
+    "restores wrote to make them (each fetched shard copied once into its "
+    "columns of the result: copied / result is 1.0; an assembled and then "
+    "re-laid result would read 2.0 or more)",
+    ("kind",),
 )
 EcMeshBatches = REGISTRY.counter(
     "weedtpu_ec_mesh_batches_total",

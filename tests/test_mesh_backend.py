@@ -84,6 +84,168 @@ def test_mesh_serving_reconstruct_and_verify():
         assert np.array_equal(rec[s], shards[s]), s
 
 
+# -- the way back: each shard into its place (PR 49) --------------------------
+
+
+def _recon_matrix(lost=(0, 3, 11, 13)):
+    surv = [i for i in range(14) if i not in lost][:10]
+    return _golden().reconstruction_matrix(surv, list(lost))
+
+
+_RESTORE_LAYOUTS = {
+    # name -> shape of the host batch: flat (10, w), or batched (b, 10, n)
+    "flat_aligned": (10, 4096),
+    "flat_padded_tail": (10, 1003),
+    # n = 257 is no multiple of a shard's width on any of the meshes below
+    "batched": (3, 10, 257),
+}
+
+
+@pytest.mark.parametrize("layout", list(_RESTORE_LAYOUTS))
+@pytest.mark.parametrize("form", ["ring", "alltoall", "cols"])
+@pytest.mark.parametrize("shape", [(2, 2), (4, 2), (8, 1)])
+def test_mesh_restore_puts_every_shard_in_its_place(shape, form, layout):
+    """A result comes back byte-identical to the golden codec's in every form
+    and layout, as ONE C-contiguous writable array of the handle's shape whose
+    row slices are contiguous, with every shard copied once (`pieces`, `copied`,
+    the byte counter) and jax's assembled host copy never taken."""
+    from seaweedfs_tpu import stats
+    from seaweedfs_tpu.obs import trace
+    from seaweedfs_tpu.ops import gf8
+    from seaweedfs_tpu.parallel.backend import MeshDispatch
+
+    md = MeshDispatch(shape=shape, rebuild="ring" if form == "cols" else form)
+    m = _golden().parity_matrix if form == "cols" else _recon_matrix()
+    x = np.random.default_rng(49).integers(0, 256, size=_RESTORE_LAYOUTS[layout], dtype=np.uint8)
+    want = np.stack([gf8.gf_mat_vec(m, v) for v in x]) if x.ndim == 3 else gf8.gf_mat_vec(m, x)
+    handle = md.apply(m, x) if form == "cols" else md.reconstruct(m, x)
+    before = {k: stats.EcMeshRestoreBytes.labels(k).value for k in ("result", "copied")}
+    with trace.start("test.sync") as root:
+        got = np.asarray(handle)
+    assert got.shape == handle.shape == want.shape and got.dtype == np.uint8
+    assert np.array_equal(got, want)
+    assert got.flags.c_contiguous and got.flags.writeable
+    row = got[1, 3:200] if got.ndim == 2 else got[2, 1, 3:200]
+    assert row.flags.c_contiguous and np.array_equal(row, want[1, 3:200] if got.ndim == 2 else want[2, 1, 3:200])
+    assert handle._dev._npy_value is None  # the global array was never read as a whole
+    rose = {k: stats.EcMeshRestoreBytes.labels(k).value - v for k, v in before.items()}
+    assert rose == {"result": got.size, "copied": got.size}
+    (sp,) = [c for c in root.children if c.name == "mesh.restore"]
+    n_dev = shape[0] * shape[1]
+    assert sp.attrs["pieces"] == sp.attrs["devices"] == n_dev and sp.attrs["copied"] == got.size
+    assert sp.attrs["variant"] == form and sp.attrs["mesh"] == md.shape_str()
+
+
+def test_mesh_restore_leaves_whole_pad_shards_alone():
+    """A tail so short that some devices hold nothing but pad: their shards
+    are not copied (`pieces` counts the others), the bytes are the golden's."""
+    from seaweedfs_tpu.obs import trace
+    from seaweedfs_tpu.ops import gf8
+    from seaweedfs_tpu.parallel.backend import MeshDispatch
+
+    md = MeshDispatch(shape=(4, 2), rebuild="ring")
+    m = _recon_matrix()
+    x = np.random.default_rng(50).integers(0, 256, size=(10, 3), dtype=np.uint8)
+    with trace.start("test.sync") as root:
+        got = np.asarray(md.reconstruct(m, x))
+    assert np.array_equal(got, gf8.gf_mat_vec(m, x))
+    (sp,) = [c for c in root.children if c.name == "mesh.restore"]
+    assert sp.attrs["pieces"] == 3 and sp.attrs["copied"] == got.size == 12
+
+
+def test_mesh_restore_refuses_shards_that_do_not_cover_the_result():
+    from seaweedfs_tpu.parallel.backend import MeshDispatch
+
+    md = MeshDispatch(shape=(2, 2), rebuild="ring")
+    handle = md.reconstruct(_recon_matrix(), np.zeros((10, 64), dtype=np.uint8))
+    with pytest.raises(RuntimeError, match="its shards cover"):
+        md._restore("ring", (4, 128), handle._dev)
+
+
+_POOLED = (4, 262144)  # 1 MiB: the smallest result the pool keeps
+
+
+def _address(a: np.ndarray) -> int:
+    return a.__array_interface__["data"][0]
+
+
+@pytest.mark.parametrize("held_by", ["the_array", "a_row_slice", "a_slice_of_a_slice"])
+def test_result_pool_hands_a_buffer_out_again_only_after_its_last_holder_died(held_by):
+    from seaweedfs_tpu.parallel.backend import _ResultPool
+
+    pool = _ResultPool()
+    out, kept = pool.take(_POOLED)
+    assert not kept and out.shape == _POOLED and out.flags.c_contiguous and out.flags.writeable
+    where = _address(out)
+    holder = {"the_array": out, "a_row_slice": out[2, 10:5000], "a_slice_of_a_slice": out[1:3][1, 7:9]}[held_by]
+    holder[...] = 7
+    del out
+    other, kept = pool.take(_POOLED)  # the first is still held: never the same memory
+    assert not kept and _address(other) != where and pool.kept_bytes() == 0
+    assert (holder == 7).all()
+    del holder
+    assert pool.kept_bytes() == _POOLED[0] * _POOLED[1]  # came home by itself
+    again, kept = pool.take(_POOLED)
+    assert kept and _address(again) == where and pool.kept_bytes() == 0
+
+
+@pytest.mark.parametrize("case", ["bound", "smaller_result_fits", "small_results_bypass"])
+def test_result_pool_bounds_and_sizes(case):
+    from seaweedfs_tpu.parallel import backend as mb
+
+    if case == "bound":
+        pool = mb._ResultPool(max_bytes=5 << 20)
+        outs = [pool.take((2, 1 << 20))[0] for _ in range(2)] + [pool.take((3, 1 << 20))[0]]
+        del outs
+        # 2 + 2 + 3 MiB came home, the smallest went first: at most the bound stays
+        assert pool.kept_bytes() == 5 << 20
+        taken = [pool.take(shape) for shape in ((3, 1 << 20), (2, 1 << 20), (2, 1 << 20))]
+        assert [kept for _, kept in taken] == [True, True, False]
+    elif case == "smaller_result_fits":
+        pool = mb._ResultPool()
+        where = _address(pool.take((4, 1 << 20))[0])
+        out, kept = pool.take((4, 300000))  # a narrower tail batch takes the kept buffer's first bytes
+        assert kept and _address(out) == where and out.shape == (4, 300000) and out.flags.c_contiguous
+    else:
+        pool = mb._ResultPool()
+        out, kept = pool.take((4, 1000))
+        assert not kept and out.base is None
+        del out
+        assert pool.kept_bytes() == 0
+        assert mb.RESULT_POOL_MAX_BYTES >= 3 * 4 * 6553600  # three results of the widest slot
+
+
+def test_mesh_restores_of_a_run_share_kept_results():
+    """Through the dispatcher: a result that died serves the next restore of
+    its size (`kept=` on the span says so), one that lives never does, and
+    what the pool holds stays under its bound."""
+    from seaweedfs_tpu.obs import trace
+    from seaweedfs_tpu.ops import gf8
+    from seaweedfs_tpu.parallel.backend import RESULT_POOL_MAX_BYTES, MeshDispatch
+
+    md = MeshDispatch(shape=(2, 2), rebuild="ring")
+    m = _recon_matrix()
+    rng = np.random.default_rng(51)
+    seen, held = [], []
+    for i in range(5):
+        x = rng.integers(0, 256, size=(10, _POOLED[1]), dtype=np.uint8)
+        with trace.start("test.sync") as root:
+            got = np.asarray(md.reconstruct(m, x))
+        (sp,) = [c for c in root.children if c.name == "mesh.restore"]
+        assert np.array_equal(got[:, :4096], gf8.gf_mat_vec(m, x[:, :4096]))
+        assert np.array_equal(got[:, -4096:], gf8.gf_mat_vec(m, x[:, -4096:]))
+        seen.append((sp.attrs["kept"], _address(got)))
+        if i < 2:
+            held.append(got[3, 5:50])  # a lane's view of batches 0 and 1 outlives them
+        del got
+    kept, where = zip(*seen)
+    assert kept == (False, False, False, True, True)
+    assert len(set(where[:3])) == 3 and where[3] == where[2] and where[4] == where[2]
+    assert md._results.kept_bytes() <= RESULT_POOL_MAX_BYTES
+    del held
+    assert md._results.kept_bytes() == 3 * _POOLED[0] * _POOLED[1]
+
+
 # -- file-pipeline byte-identity (the production path) ------------------------
 
 

@@ -268,7 +268,15 @@ def test_a_four_device_mesh_server_rebuilds_four_lost_shards_of_two_volumes_in_o
             assert p["attrs"] == mesh
         for s in syncs:
             (r,) = [c for c in s["spans"] if c["name"] == "mesh.restore"]
-            assert r["attrs"] == mesh and r["dur_ms"] <= s["dur_ms"]
+            # every device's shard copied once into its columns of one result that holds the batch's rows
+            a = r["attrs"]
+            assert a == {**mesh, "pieces": 4, "copied": a["copied"], "kept": a["kept"]} and r["dur_ms"] <= s["dur_ms"]
+            assert a["copied"] >= s["attrs"]["bytes"] and a["kept"] in (True, False)
+        restored = sum(r["attrs"]["copied"] for r in _spans(root, "mesh.restore"))
+        # the counter (which the checks' reads between the two scrapes moved too): a byte copied a byte of result
+        assert 2 * len(LOST) * shard_bytes <= restored <= _rose(
+            before, after, 'weedtpu_ec_mesh_restore_bytes_total{kind="result"}') == _rose(
+            before, after, 'weedtpu_ec_mesh_restore_bytes_total{kind="copied"}')
         # nothing else ran on the mesh, and the counter's seconds enclose its spans' (it is read around them)
         spans_put = sum(p["dur_ms"] for p in _spans(root, "mesh.put")) / 1e3
         assert {p["attrs"]["variant"] for p in _spans(root, "mesh.put")} == {variant}
